@@ -24,8 +24,7 @@
 // sample/update out as one rc_sample_stripe / rc_update_stripe call PER
 // STRIPE through a persistent thread pool — ctypes releases the GIL, so
 // the stripe calls genuinely overlap in wall-clock on multicore hosts
-// (tests/test_native_dedup.py pins the overlap; the BENCH_r06 note about
-// the wrapper serializing striped calls is fixed).  Add/import still
+// (tests/test_native_dedup.py pins the overlap).  Add/import still
 // serialize under the wrapper lock (carry-resolver state is Python-side).
 // n_stripes=1 reduces bit-for-bit to the numpy DedupReplay (the oracle:
 // tests/test_native_dedup.py).
@@ -322,8 +321,7 @@ void rc_update(void* h, int64_t n, const int64_t* idx, const float* prio) {
 // Per-stripe half of rc_sample, for the wrapper's PARALLEL fan-out
 // (replay/native_dedup.py dispatches one call per stripe through a
 // persistent thread pool; ctypes releases the GIL so stripe calls overlap
-// in wall-clock — the BENCH_r06 "striped4 wrapper serializes calls"
-// defect, fixed).  Samples Bk rows from stripe `s_i` using u[0..Bk) and
+// in wall-clock).  Samples Bk rows from stripe `s_i` using u[0..Bk) and
 // writes RAW (unnormalized) IS weights — the caller normalizes by the max
 // across ALL stripes, reproducing rc_sample's arithmetic bit-for-bit.
 // The gather runs outside the stripe lock, like rc_sample's (the Python

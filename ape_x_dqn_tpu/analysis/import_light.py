@@ -6,7 +6,7 @@ remote host's workers, and the bench's producer processes fork per
 section.  All of them import a contracted set of modules — and none of
 those may reach jax/flax/optax through ANY transitive module-scope
 import, because one heavy import turns a sub-second respawn into a
-multi-second fleet stall (and on a tunneled platform, a device grab).
+multi-second fleet stall (and, beside a chip owner, a grab for its device).
 
 The proof is a static module-graph walk: module-scope imports only
 (function-scope imports are lazy by construction — the repo's blessed

@@ -33,13 +33,6 @@ from flax import struct
 from ape_x_dqn_tpu.ops import losses
 from ape_x_dqn_tpu.types import PrioritizedBatch, TrainState
 
-# Modern jax.shard_map tracks replication and its AD transpose psums param
-# cotangents implicitly; the 0.4.x experimental fallback (see
-# parallel.mesh.shard_map) does not — build_train_step's grad_reduce_axis
-# branch keys on this (details at the branch).
-_SHARD_MAP_IMPLICIT_GRAD_PSUM = hasattr(jax, "shard_map")
-
-
 @struct.dataclass
 class StepMetrics:
     loss: jax.Array            # float32 []
@@ -269,18 +262,9 @@ def build_train_step(
         # (equal-size shards); an explicit pmean here would double-count
         # (measured: exactly n× updates).  The scalar loss is still
         # per-shard varying and needs a real pmean for reporting.
-        #
-        # On 0.4.x jax (the experimental shard_map via parallel.mesh's
-        # compat wrapper, check_rep=False) there is NO replication tracking:
-        # the transpose inserts no psum and grads arrive shard-LOCAL, so
-        # the explicit pmean is the reduction — gated on the modern
-        # spelling's presence, same predicate the wrapper dispatches on.
         if grad_reduce_axis is not None:
-            if _SHARD_MAP_IMPLICIT_GRAD_PSUM:
-                n_sh = jax.lax.psum(1, grad_reduce_axis)
-                grads = jax.tree_util.tree_map(lambda g: g / n_sh, grads)
-            else:
-                grads = jax.lax.pmean(grads, grad_reduce_axis)
+            n_sh = jax.lax.psum(1, grad_reduce_axis)
+            grads = jax.tree_util.tree_map(lambda g: g / n_sh, grads)
             loss = jax.lax.pmean(loss, grad_reduce_axis)
         updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)

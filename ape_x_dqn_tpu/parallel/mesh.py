@@ -29,23 +29,15 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the top-level spelling landed
-    after 0.4.x, where it lives at ``jax.experimental.shard_map.shard_map``
-    (same semantics) — every shard_map call in the repo routes through here
-    so the sharded fused paths run on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # check_rep=False: the 0.4.x static replication checker can't see
-    # through psum-producing bodies (the grad all-reduce) and rejects
-    # replicated out_specs the newer checker accepts.
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
+def device_info() -> dict:
+    """The backend this process runs on, as jax reports it: what every
+    entry point stamps on its first record."""
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
+    }
 
 
 def make_mesh(

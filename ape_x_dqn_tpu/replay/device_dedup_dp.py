@@ -23,9 +23,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ape_x_dqn_tpu.parallel.mesh import shard_map
 
 from ape_x_dqn_tpu.replay.device import fused_scan_body
 from ape_x_dqn_tpu.replay.device_dedup import (

@@ -42,9 +42,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ape_x_dqn_tpu.parallel.mesh import shard_map
 
 from ape_x_dqn_tpu.replay.device import (
     DeviceReplayState,

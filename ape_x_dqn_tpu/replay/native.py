@@ -4,7 +4,8 @@ Compiles ``_native/sum_tree.cc`` with g++ on first use (cached .so next to
 the source, keyed by source mtime) and exposes ``NativeSumTree`` with the
 exact interface of the numpy ``SumTree`` — the replay buffer takes either via
 its ``sum_tree_cls`` parameter.  If no compiler is available the import still
-succeeds and ``native_available()`` returns False; callers fall back to numpy.
+succeeds, ``native_available()`` returns False and one
+``native_core_unavailable`` event names the cause; callers fall back to numpy.
 
 pybind11 is not in this image, so the boundary is a C ABI + ctypes — zero
 copies (numpy arrays passed as raw pointers), no Python objects crossing.
@@ -18,6 +19,8 @@ import subprocess
 import threading
 
 import numpy as np
+
+from ape_x_dqn_tpu.utils.metrics import emit_event
 
 _HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_HERE, "_native", "sum_tree.cc")
@@ -79,6 +82,9 @@ def _load():
             _lib = lib
         except Exception as e:  # compiler missing, build failure, load failure
             _lib_err = f"{type(e).__name__}: {e}"
+            # Said once, out loud: callers fall back to the numpy tree.
+            emit_event("native_core_unavailable", core="sum_tree",
+                       error=_lib_err, fallback="numpy SumTree")
         return _lib
 
 

@@ -1,5 +1,5 @@
 """ctypes bindings for the native frame-dedup replay core — the
-paper-scale host path (round-4 verdict item 1b).
+paper-scale host path.
 
 ``NativeDedupReplay`` is a drop-in for ``replay.dedup.DedupReplay`` (same
 constructor surface + add/sample/update_priorities/size/state_dict), with
@@ -13,9 +13,8 @@ stripes and device shards without changing the estimator.  At
 ``n_stripes > 1`` sample/update fan out as one GIL-released C call PER
 STRIPE (``rc_sample_stripe`` / ``rc_update_stripe``) through a
 persistent thread pool, so stripe work genuinely overlaps in wall-clock
-on multicore hosts — the BENCH_r06 "striped4 wrapper serializes calls"
-defect, fixed; tests assert the overlap and bit-parity with the serial
-spelling.  Ingest (``add``) still serializes under the wrapper lock
+on multicore hosts; tests assert the overlap and bit-parity with the
+serial spelling.  Ingest (``add``) still serializes under the wrapper lock
 (carry-resolver state is Python-side).  ``n_stripes=1`` is bit-exact
 with the numpy twin (tests/test_native_dedup.py pins it).
 
@@ -37,6 +36,7 @@ import numpy as np
 
 from ape_x_dqn_tpu.replay.dedup import CarryResolver
 from ape_x_dqn_tpu.types import DedupChunk, NStepTransition, PrioritizedBatch
+from ape_x_dqn_tpu.utils.metrics import emit_event
 
 _HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_HERE, "_native", "replay_core.cc")
@@ -180,6 +180,9 @@ def _load():
             _lib = lib
         except Exception as e:  # compiler missing, build/load failure
             _lib_err = f"{type(e).__name__}: {e}"
+            # Said once, out loud: callers fall back to the numpy replay.
+            emit_event("native_core_unavailable", core="replay_core",
+                       error=_lib_err, fallback="numpy DedupReplay")
         return _lib
 
 

@@ -1,7 +1,7 @@
 """Two-tier frame store: hot DRAM span cache over a CRC-framed cold file.
 
 ROADMAP item 6 ("break the DRAM wall on the frame ring"): the 2M-slot dedup
-layout pins 17.6 GB of frames in one host's DRAM (BENCH_r06
+layout pins 17.6 GB of frames in one host's DRAM (bench.py
 ``host_dedup_2m.frames_gb``) — capacity, not speed, is the binding
 constraint on replay scale.  This module is the cold tier that decouples
 them, the way external replay services (Reverb) decouple replay capacity

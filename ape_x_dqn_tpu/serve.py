@@ -31,6 +31,7 @@ checkpoint dir.
 from __future__ import annotations
 
 import argparse
+import os
 import signal
 import sys
 import threading
@@ -151,8 +152,16 @@ def _client_loop(server, obs_shape, stop, errors, seed):
 def _run_fleet(args, cfg, logger) -> int:
     """--replicas N: router + param hub + N replica children, watching
     the checkpoint dir and fanning new steps out as deltas."""
+    # The fleet parent does no inference — it owns a params template, the
+    # router and the hub.  Keep it off the chip (a trainer beside it may
+    # hold it): assigned before this process first imports jax.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    from ape_x_dqn_tpu.parallel.mesh import device_info
     from ape_x_dqn_tpu.runtime.components import build_components
     from ape_x_dqn_tpu.serving import CheckpointParamSource, ServingFleet
+    from ape_x_dqn_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if not args.checkpoint:
         print("--replicas requires --checkpoint (the fleet's param feed)",
@@ -191,7 +200,8 @@ def _run_fleet(args, cfg, logger) -> int:
         fleet.stop()
         return 3
     logger.event("serving_listen", port=fleet.port, host=host,
-                 replicas=n, mode="router")
+                 replicas=n, mode="router",
+                 platform=device_info()["platform"])
 
     obs_server = None
     obs_port = args.obs_port if args.obs_port is not None \
@@ -264,6 +274,10 @@ def main(argv=None) -> int:
     if args.replicas is not None:
         return _run_fleet(args, cfg, logger)
 
+    from ape_x_dqn_tpu.parallel.mesh import device_info
+    from ape_x_dqn_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from ape_x_dqn_tpu.runtime.components import build_components
     from ape_x_dqn_tpu.serving import (
         CheckpointParamSource,
@@ -275,6 +289,7 @@ def main(argv=None) -> int:
 
     pipe = None
     trainer_thread = None
+    trainer_error: list = []
     if args.attach:
         # One process, both halves: the trainer owns the device hot loop,
         # the serving batcher rides the same device between learner
@@ -284,9 +299,15 @@ def main(argv=None) -> int:
         pipe = AsyncPipeline(cfg, logger=logger, log_every=10_000)
         comps = pipe.comps
         source = pipe.store
+
+        def _train():
+            try:
+                pipe.run(learner_steps=args.steps)
+            except BaseException as e:  # noqa: BLE001 — re-raised by main
+                trainer_error.append(e)
+
         trainer_thread = threading.Thread(
-            target=lambda: pipe.run(learner_steps=args.steps),
-            name="attached-trainer", daemon=True,
+            target=_train, name="attached-trainer", daemon=True,
         )
     else:
         comps = build_components(cfg)
@@ -340,8 +361,10 @@ def main(argv=None) -> int:
             run_token=args.run_token,
         ).start()
         server.attach_transport(net_srv.stats)
+        device = device_info()
         logger.event("serving_listen", port=net_srv.port, host=host,
-                     mode="replica")
+                     mode="replica", platform=device["platform"],
+                     device_kind=device["device_kind"])
 
     # Serving staleness policy (runtime/supervisor): past
     # serving.param_stale_s of source silence the server sheds with the
@@ -448,6 +471,9 @@ def main(argv=None) -> int:
         if hasattr(source, "close"):
             source.close()
         logger.close()
+    if trainer_error:
+        # A dead trainer is a failed run, not a clean end of serving.
+        raise RuntimeError("attached trainer died") from trainer_error[0]
     return 0 if not errors else 1
 
 

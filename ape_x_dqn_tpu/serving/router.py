@@ -359,6 +359,9 @@ class ReplicaProcess:
         self.proc: Optional[subprocess.Popen] = None
         self.port: Optional[int] = None
         self.obs_port: Optional[int] = None
+        # The backend the child says it runs on (its serving_listen event)
+        # — "cpu", by the assignment in spawn().
+        self.platform: Optional[str] = None
         self.respawns = 0
         self._events: List[dict] = []
         self._reader: Optional[threading.Thread] = None
@@ -380,7 +383,10 @@ class ReplicaProcess:
             os.path.dirname(os.path.abspath(__file__))
         ))
         env = dict(self._env if self._env is not None else os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # Assigned, not defaulted: a replica is a CPU process whatever the
+        # parent's environment exports — N replicas must never contend for
+        # the one chip a trainer beside them owns.
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
         cmd = [
             sys.executable, "-m", "ape_x_dqn_tpu.serve",
@@ -417,6 +423,7 @@ class ReplicaProcess:
                     if len(self._events) > 256:
                         del self._events[:-128]
                 if rec.get("event") == "serving_listen":
+                    self.platform = rec.get("platform")
                     self.port = int(rec["port"])
                 elif rec.get("event") == "obs_exporter":
                     self.obs_port = int(rec["port"])
@@ -822,6 +829,7 @@ class ServingFleet:
                     "alive": rep.alive(),
                     "port": rep.port,
                     "obs_port": rep.obs_port,
+                    "platform": rep.platform,
                     "attempt": rep.attempt,
                     "respawns": rep.respawns,
                     "retired": rid in self.retired,

@@ -63,8 +63,7 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument(
         "--profile-dir", default=None,
         help="capture a jax.profiler device trace of the run into this dir "
-        "(TensorBoard-viewable); degrades to a warning on platforms whose "
-        "profiler plugin cannot trace",
+        "(TensorBoard-viewable); a profiler that cannot start fails the run",
     )
     p.add_argument(
         "--profile-port", type=int, default=None,
@@ -82,7 +81,10 @@ def build_argparser() -> argparse.ArgumentParser:
     return p
 
 
-def main(argv=None) -> int:
+def main(argv=None, inspect=None) -> int:
+    """Run the trainer.  ``inspect(pipeline, final_record)``, when given, is
+    called after an async run completes — chip_smoke.py uses it to check
+    where the train state and the replay ring live."""
     args = build_argparser().parse_args(argv)
     if args.coordinator:
         # Must run before anything touches the jax backend: after this,
@@ -98,26 +100,36 @@ def main(argv=None) -> int:
             args.coordinator, args.num_processes, args.process_id
         )
     cfg = load_config(args.params_file, overrides=args.overrides)
-    print("config:", to_dict(cfg), file=sys.stderr)
+    from ape_x_dqn_tpu.parallel.mesh import device_info
+    from ape_x_dqn_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    # Named on the run's first record (and the config line) so a CPU run
+    # can never be read as a chip run.
+    device = device_info()
+    print("config:", {"device": device, **to_dict(cfg)}, file=sys.stderr)
     logger = MetricLogger(
         stream=sys.stdout,
         path=args.metrics_file,
         tensorboard_dir=args.tensorboard_dir,
     )
+    logger.event("run_device", **device)
     import contextlib
 
-    from ape_x_dqn_tpu.utils.profiling import start_server, trace
+    from ape_x_dqn_tpu.utils.profiling import trace
 
     if args.profile_port is not None:
-        start_server(args.profile_port)
+        import jax
+
+        jax.profiler.start_server(args.profile_port)  # raises if it cannot
     profile_ctx = (
         trace(args.profile_dir) if args.profile_dir else contextlib.nullcontext()
     )
     with profile_ctx:
-        return _run(args, cfg, logger)
+        return _run(args, cfg, logger, inspect)
 
 
-def _run(args, cfg, logger) -> int:
+def _run(args, cfg, logger, inspect=None) -> int:
     if args.mode == "async":
         from ape_x_dqn_tpu.runtime import AsyncPipeline
 
@@ -127,6 +139,8 @@ def _run(args, cfg, logger) -> int:
         )
         final = pipe.run(learner_steps=args.steps)
         print("final:", final, file=sys.stderr)
+        if inspect is not None:
+            inspect(pipe, final)
     else:
         from ape_x_dqn_tpu.runtime import SingleProcessDriver
 

@@ -3,7 +3,7 @@ critical path.
 
 ``save_checkpoint`` (utils/checkpoint.py) serializes the ENTIRE replay
 inline on the learner thread: at config3 scale the dedup frame ring is
-~17.6 GB (PROFILE.md round 5) — minutes of dead air per checkpoint, exactly
+~17.6 GB — minutes of dead air per checkpoint, exactly
 the stall Ape-X decouples actors/learner to avoid, and the same
 off-critical-path discipline orbax's async checkpointing applies to params.
 This module replaces the replay leg with an incremental, non-blocking

@@ -1,0 +1,335 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py
+
+Drives the trainer's main path once through ``ape_x_dqn_tpu.train.main``, the
+entry point a user calls, at the full width of the one model the repo
+supports — dueling conv 64/64/64 with 512-unit streams, 84x84x1 uint8 frames
+from ``fake-atari`` (the full wrapper stack), batch 32, a 100k-slot HBM ring,
+K=2048 fused steps per dispatch, sample-ahead, rmsprop with bf16 second
+moment and bf16 target.  Depth is cut (a few fused calls per leg) and the
+weights are random, made from the config's seed.  Legs, all in THIS process,
+which owns the chip:
+
+  thread    64 thread actors sharing the learner's device
+  process   2 worker processes x 8 actors; the children must stay on the CPU
+  serving   actors select actions through the in-process PolicyServer
+  dp4       learner.data_parallel=4 + replay.dedup (only with >= 4 devices)
+
+Sets no platform itself.  Exits non-zero, with one line saying why and no
+result, before compiling anything if jax's default backend is not a TPU, and
+in a directory that holds nothing else of the repo.  Fails if any leg fails
+or either native replay core does not build.  On success the last line of
+stdout is ``{"ok": true, "device": {...}}`` with the device as jax reports it.
+
+Module scope stays import-light: the process leg's spawned workers re-import
+this file as ``__mp_main__`` and must not meet jax here.
+"""
+
+from __future__ import annotations
+
+import gc
+import json
+import os
+import sys
+import time
+import traceback
+
+CAPACITY = 100_000
+OBS_SHAPE = (84, 84, 1)
+K = 2048
+MAIN_PATH = [
+    "--set", "network=conv",
+    "--set", "env.name=fake-atari",
+    "--set", "learner.device_replay=true",
+    "--set", "learner.sample_ahead=true",
+    "--set", "learner.replay_sample_size=32",
+    "--set", f"replay.capacity={CAPACITY}",
+    "--set", f"learner.steps_per_call={K}",
+    "--set", "learner.optimizer=rmsprop",
+    "--set", "learner.second_moment_dtype=bfloat16",
+    "--set", "learner.target_dtype=bfloat16",
+    # Poll for params every fleet step: the legs are seconds long.
+    "--set", "actor.sync_every=1",
+    "--log-every", str(K),
+]
+
+
+def say(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+def cache_entries(path: str) -> int:
+    return len(os.listdir(path)) if os.path.isdir(path) else 0
+
+
+def check_on_tpu(name: str, tree) -> int:
+    """Every array leaf of ``tree`` lives on TPU devices only."""
+    import jax
+
+    leaves = [x for x in jax.tree_util.tree_leaves(tree)
+              if hasattr(x, "devices")]
+    assert leaves, f"{name}: no device arrays to check"
+    for leaf in leaves:
+        plats = {d.platform for d in leaf.devices()}
+        assert plats == {"tpu"}, f"{name}: leaf {leaf.shape} on {plats}"
+    return len(leaves)
+
+
+def check_run(leg: str, pipe, final: dict, steps: int) -> None:
+    """What every leg asserts: step target, finite loss, residency."""
+    import math
+
+    assert final["step"] >= steps, f"{leg}: step {final['step']} < {steps}"
+    loss = final["learner/loss"]
+    assert math.isfinite(loss), f"{leg}: loss {loss}"
+    assert final["actor_steps"] > 0, f"{leg}: no actor steps"
+    n_state = check_on_tpu(f"{leg} train state", pipe.fused.state)
+    n_ring = check_on_tpu(f"{leg} ring", pipe.fused._replay)
+    say(f"{leg}: step={final['step']} loss={loss:.5f} "
+        f"actor_steps={final['actor_steps']} "
+        f"param_version={final['param_version']} "
+        f"state_leaves_on_tpu={n_state} ring_leaves_on_tpu={n_ring}")
+
+
+def run_leg(leg: str, argv: list, steps: int, inspect) -> None:
+    from ape_x_dqn_tpu import train
+
+    def _inspect(pipe, final):
+        check_run(leg, pipe, final, steps)
+        inspect(pipe, final)
+
+    rc = train.main(MAIN_PATH + argv + ["--steps", str(steps)],
+                    inspect=_inspect)
+    assert rc == 0, f"{leg}: train.main returned {rc}"
+
+
+def ring_footprint() -> None:
+    """bytes_in_use taken by the ring's own allocation beside its logical
+    size: the ring is uint8[C,84,84,1] and a trailing dimension of 1 is
+    where TPU tiling can pad."""
+    import jax
+
+    from ape_x_dqn_tpu.replay.device import init_device_replay
+
+    dev = jax.devices()[0]
+    before = dev.memory_stats()["bytes_in_use"]
+    ring = jax.block_until_ready(init_device_replay(CAPACITY, OBS_SHAPE))
+    used = dev.memory_stats()["bytes_in_use"] - before
+    logical = sum(x.nbytes for x in jax.tree_util.tree_leaves(ring))
+    frames = 2 * CAPACITY * 84 * 84
+    say(f"ring: bytes_in_use={used} logical={logical} "
+        f"(frames 2x{CAPACITY}x7056={frames}) ratio={used / logical:.3f}")
+    del ring
+
+
+def time_fused_forcing(pipe) -> None:
+    """One fused call timed twice on the finished run's own learner: forced
+    by jax.block_until_ready, and by a host read of the loss."""
+    import jax
+    import numpy as np
+
+    fused = pipe.fused
+    float(np.asarray(fused.train(0.4).loss)[-1])  # settle the queue
+    t0 = time.perf_counter()
+    m = fused.train(0.4)
+    t_enqueue = time.perf_counter() - t0
+    jax.block_until_ready(m.loss)
+    t_block = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    m = fused.train(0.4)
+    float(np.asarray(m.loss)[-1])
+    t_read = time.perf_counter() - t0
+    say(f"one fused call (K={K}): enqueue returned after "
+        f"{t_enqueue * 1e3:.1f} ms; forced by block_until_ready "
+        f"{t_block * 1e3:.1f} ms; forced by host read of the loss "
+        f"{t_read * 1e3:.1f} ms")
+
+
+def leg_thread() -> None:
+    steps = 8 * K  # >= 3 fused calls; long enough for actors to see a publish
+
+    def inspect(pipe, final):
+        import jax
+
+        seen = pipe.worker.param_version
+        assert final["param_version"] >= 1, "thread: nothing was published"
+        assert seen >= 1, f"thread: actors never saw a publish (v{seen})"
+        say(f"thread: actors adopted param_version {seen} of "
+            f"{final['param_version']} published")
+        time_fused_forcing(pipe)
+        used = jax.devices()[0].memory_stats()
+        say(f"thread: device bytes_in_use at end of leg "
+            f"{used['bytes_in_use']} peak {used.get('peak_bytes_in_use')}")
+
+    run_leg("thread", [
+        "--set", "actor.num_actors=64",
+        "--set", "learner.min_replay_mem_size=4096",
+    ], steps, inspect)
+
+
+def leg_process() -> None:
+    def inspect(pipe, final):
+        pool = pipe.worker.pool
+        assert not pool.worker_errors, f"process: {pool.worker_errors}"
+        assert final["actor_restarts"] == 0, final["actor_restarts"]
+        assert final["supervisor"]["respawns"] == 0, final["supervisor"]
+        workers = final["workers"]
+        assert len(workers) == 2, workers
+        for wid, w in workers.items():
+            assert w["env_steps"] > 0, f"process: worker {wid} idle: {w}"
+        say("process: 2 workers on the CPU (each checks its own backend), "
+            "0 deaths, 0 respawns, env_steps="
+            f"{[int(w['env_steps']) for w in workers.values()]}")
+
+    run_leg("process", [
+        "--set", "actor.mode=process",
+        "--set", "actor.num_workers=2",
+        "--set", "actor.num_actors=16",
+        "--set", "learner.min_replay_mem_size=2048",
+    ], 2 * K, inspect)
+
+
+def leg_serving() -> None:
+    def inspect(pipe, final):
+        server = pipe._central_server
+        assert server is not None, "serving: no in-process PolicyServer"
+        stats = server.stats()
+        check_on_tpu("serving params", server._live[0])
+        buckets = server.batcher.buckets
+        compiled = server._apply._cache_size()
+        assert compiled == len(buckets), (
+            f"serving: {compiled} compiled shapes for buckets {buckets} — "
+            "a request shape was not warmed"
+        )
+        assert stats["served_total"] >= 300, stats
+        assert stats["error_total"] == 0, stats
+        inf = final["inference"]
+        say(f"serving: served={stats['served_total']} errors=0 "
+            f"buckets_warmed={buckets} batch_hist={stats['batch_hist']} "
+            f"p50_ms={stats['latency'].get('p50_ms')} "
+            f"p99_ms={stats['latency'].get('p99_ms')} "
+            f"reply_param_version={inf.get('param_version')}")
+
+    run_leg("serving", [
+        "--set", "actor.num_actors=64",
+        "--set", "actor.inference=central",
+        "--set", "learner.min_replay_mem_size=4096",
+    ], 2 * K, inspect)
+
+
+def leg_dp4() -> None:
+    import jax
+
+    def inspect(pipe, final):
+        fused = pipe.fused
+        devs = jax.devices()[:4]
+        frames = fused._replay.frames
+        shard_devs = [s.device for s in frames.addressable_shards]
+        assert len(shard_devs) == 4 and set(shard_devs) == set(devs), (
+            f"dp4: frame ring shards on {shard_devs}"
+        )
+        rows = {s.data.shape[0] for s in frames.addressable_shards}
+        assert len(rows) == 1, f"dp4: uneven ring shards {rows}"
+        for leaf in jax.tree_util.tree_leaves(fused.state.params):
+            assert leaf.sharding.is_fully_replicated, leaf.sharding
+            assert {s.device for s in leaf.addressable_shards} == set(devs)
+        used = [d.memory_stats()["bytes_in_use"] for d in devs]
+        spread = (max(used) - min(used)) / max(used)
+        assert spread <= 0.10, f"dp4: bytes_in_use per device {used}"
+        say(f"dp4: frame ring {frames.shape} in 4 shards of "
+            f"{rows.pop()} rows on {[d.id for d in shard_devs]}; params "
+            f"replicated on all 4; bytes_in_use per device {used} "
+            f"(spread {spread:.1%})")
+
+    run_leg("dp4", [
+        "--set", "actor.num_actors=64",
+        "--set", "learner.data_parallel=4",
+        "--set", "replay.dedup=true",
+        "--set", "learner.min_replay_mem_size=4096",
+    ], 2 * K, inspect)
+
+
+def main() -> int:
+    t_start = time.perf_counter()
+    try:
+        from ape_x_dqn_tpu.utils.compile_cache import (
+            cache_dir,
+            enable_compile_cache,
+        )
+    except ImportError as e:
+        print(f"chip_smoke: the repo is not here ({e})", file=sys.stderr)
+        return 2
+    import jax
+
+    if jax.default_backend() != "tpu":
+        print("chip_smoke: needs a TPU, jax's default backend is "
+              f"{jax.default_backend()!r}; nothing compiled, nothing run",
+              file=sys.stderr)
+        return 2
+    enable_compile_cache()
+    import importlib.metadata as md
+
+    import jaxlib
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    entries_before = cache_entries(cache_dir())
+    say(f"device={device} jax={jax.__version__} jaxlib={jaxlib.__version__} "
+        f"libtpu={md.version('libtpu')}")
+    say(f"compile cache: {cache_dir()} entries_before={entries_before}")
+
+    from ape_x_dqn_tpu.replay.native import native_available, native_error
+    from ape_x_dqn_tpu.replay.native_dedup import (
+        native_dedup_available,
+        native_dedup_error,
+    )
+
+    failed = []
+    if not native_available():
+        failed.append(f"native sum_tree core: {native_error()}")
+    if not native_dedup_available():
+        failed.append(f"native replay_core: {native_dedup_error()}")
+    if not failed:
+        say("native cores: sum_tree and replay_core built and loaded")
+
+    legs = [("ring_footprint", ring_footprint), ("thread", leg_thread),
+            ("process", leg_process), ("serving", leg_serving)]
+    if len(devs) >= 4:
+        legs.append(("dp4", leg_dp4))
+    for name, fn in legs:
+        t0 = time.perf_counter()
+        say(f"leg {name}: starts with bytes_in_use per device "
+            f"{[d.memory_stats()['bytes_in_use'] for d in devs]}")
+        ok = True
+        try:
+            fn()
+        except Exception:  # noqa: BLE001 — reported, and the run fails
+            traceback.print_exc()
+            failed.append(f"leg {name}")
+            ok = False
+        gc.collect()
+        say(f"leg {name}: wall {time.perf_counter() - t0:.1f} s"
+            + ("" if ok else " FAILED"))
+    if len(devs) < 4:
+        say(f"leg dp4: DID NOT RUN — needs >= 4 devices, jax found {len(devs)}")
+
+    import multiprocessing as mp
+
+    for child in mp.active_children():
+        failed.append(f"child process {child.pid} left running")
+        child.kill()
+    say(f"compile cache: entries_before={entries_before} "
+        f"entries_after={cache_entries(cache_dir())}")
+    say(f"total wall {time.perf_counter() - t_start:.1f} s")
+    if failed:
+        print(f"chip_smoke: FAILED: {failed}", file=sys.stderr)
+        return 1
+    sys.stderr.flush()
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -19,7 +19,6 @@ def main() -> int:
     )
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     pid, n, port = int(sys.argv[1]), int(sys.argv[2]), int(sys.argv[3])
     mode = sys.argv[4] if len(sys.argv) > 4 else "step"
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))

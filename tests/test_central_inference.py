@@ -602,6 +602,9 @@ def central_thread_run():
     cfg.actor.inference_codec = "zlib"
     cfg.serving.max_batch = 8
     cfg.serving.max_wait_ms = 2.0
+    # The 80 learner steps last ~0.2 s: at the default 0.25 s poll, whether
+    # one hot reload lands inside the run is a matter of thread phase.
+    cfg.serving.reload_poll_s = 0.02
     cfg.learner.min_replay_mem_size = 256
     cfg.learner.publish_every = 5
     cfg.learner.total_steps = 80
@@ -612,6 +615,39 @@ def central_thread_run():
     pipe = AsyncPipeline(cfg, logger=MetricLogger(stream=buf), log_every=40)
     final = pipe.run(learner_steps=80, warmup_timeout=180.0)
     return {"final_record": final, "pipe": pipe}
+
+
+def test_in_process_server_params_do_not_alias_train_state():
+    """The learner's jitted step donates the train state, and on a TPU a
+    donated buffer is deleted: a server whose first params ARE the train
+    state's buffers (device_put of an on-device array is no copy) answers
+    with errors from the first learner call until its first hot reload —
+    found by chip_smoke.py's serving leg, invisible on the CPU backend."""
+    import jax
+
+    from ape_x_dqn_tpu.config import ApexConfig
+    from ape_x_dqn_tpu.runtime.async_pipeline import AsyncPipeline
+    from ape_x_dqn_tpu.utils.metrics import MetricLogger
+
+    cfg = ApexConfig()
+    cfg.network = "mlp"
+    cfg.env.name = "chain:6"
+    cfg.actor.num_actors = 2
+    cfg.actor.inference = "central"
+    cfg.serving.max_batch = 2
+    cfg.learner.min_replay_mem_size = 64
+    cfg.replay.capacity = 256
+    cfg.validate()
+    pipe = AsyncPipeline(cfg, logger=MetricLogger(stream=io.StringIO()))
+    try:
+        state = {
+            id(x) for x in jax.tree_util.tree_leaves(pipe.comps.state.params)
+        }
+        served = jax.tree_util.tree_leaves(pipe._central_server._live[0])
+        assert served and not any(id(x) in state for x in served)
+    finally:
+        pipe._close_obs()
+        pipe._publisher.close()
 
 
 class TestObsSchema:

@@ -150,7 +150,7 @@ def test_driver_restore_gate(tmp_path):
 
 
 def test_async_pipeline_kill_and_resume(tmp_path):
-    """VERDICT r2 item 6: train, checkpoint, then a NEW pipeline resumes —
+    """Train, checkpoint, then a NEW pipeline resumes —
     learner step AND replay contents both survive the restart."""
     from ape_x_dqn_tpu.config import ApexConfig
     from ape_x_dqn_tpu.runtime.async_pipeline import AsyncPipeline

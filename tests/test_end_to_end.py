@@ -88,7 +88,7 @@ def test_truncation_unbiased_value_sync():
     bootstrapping the value fixed point is 1/(1−γ) = 10.  Collapsing
     truncation into termination drags Q toward the mean remaining-horizon
     return (≲ 6.5 at γ=0.9, T=10) — assert we converge near the unbiased
-    fixed point instead (VERDICT r2 item 5)."""
+    fixed point instead."""
     cfg = tiny_config(env_name="loop:10")
     cfg.actor.gamma = 0.9
     cfg.learner.loss = "squared"

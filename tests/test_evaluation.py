@@ -156,7 +156,7 @@ class TestGreedyEvaluator:
 
 
 class TestHNSEndToEnd:
-    """VERDICT weak #7: the median-HNS aggregation path exercised END TO
+    """The median-HNS aggregation path exercised END TO
     END — real GreedyEvaluator rollouts over the full DQN wrapper stack on
     the ALE-faithful fake emulator, scores flowing through the human/random
     table into the suite-level median, with an unknown-game fallback."""

@@ -239,8 +239,8 @@ class TestStripedLaw:
 
 class TestStripedFanOut:
     """The parallel per-stripe fan-out (rc_sample_stripe / rc_update_stripe
-    through the wrapper's persistent thread pool) — ISSUE 5 satellite: the
-    BENCH_r06 'striped4 wrapper serializes calls' defect, fixed."""
+    through the wrapper's persistent thread pool): striped calls must
+    overlap, not serialize behind the wrapper."""
 
     def _filled(self, n_stripes=2, capacity=256):
         nat = NativeDedupReplay(capacity, OBS, frame_ratio=2.0,

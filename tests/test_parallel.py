@@ -144,7 +144,7 @@ def test_conv_network_dp_step():
 def test_async_pipeline_data_parallel_end_to_end():
     """learner.data_parallel=4 runs the WHOLE async runtime — actor thread,
     host replay, prefetch infeed, sharded train step, priority write-back,
-    param publish — over a 4-device mesh (VERDICT r2 item 4)."""
+    param publish — over a 4-device mesh."""
     from ape_x_dqn_tpu.config import ApexConfig
     from ape_x_dqn_tpu.runtime.async_pipeline import AsyncPipeline
 

@@ -1,4 +1,4 @@
-"""Process-parallel actor tests (VERDICT r2 item 3): shared-memory seqlock,
+"""Process-parallel actor tests: shared-memory seqlock,
 worker processes feeding a learner, param-version propagation.
 
 These run real OS processes (spawn context, CPU-only jax in workers), so
@@ -112,7 +112,7 @@ class TestStoreAndSource:
 
 class TestEndToEnd:
     def test_two_actor_processes_feed_learner(self):
-        """VERDICT r2 'done' criterion: >=2 actor *processes* + learner
+        """>=2 actor *processes* + learner
         training the chain MDP, with param-version propagation asserted."""
         from ape_x_dqn_tpu.runtime.async_pipeline import AsyncPipeline
 

@@ -120,9 +120,7 @@ class TestShardedSampler:
 
             from jax.sharding import PartitionSpec as P
 
-            from ape_x_dqn_tpu.parallel.mesh import shard_map
-
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=(replay_specs(),),
                 out_specs=(P(None, "data"), P(None, "data")),
             )(st)

@@ -83,10 +83,8 @@ def main(argv=None) -> int:
     ap.add_argument("--deadline", type=float, default=420.0)
     args = ap.parse_args(argv)
 
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"  # before the first jax import
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from ape_x_dqn_tpu.autopilot import ServingFleetActuator

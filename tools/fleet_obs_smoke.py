@@ -90,10 +90,7 @@ def main(argv=None) -> int:
     ap.add_argument("--deadline", type=float, default=420.0)
     args = ap.parse_args(argv)
 
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"  # before the first jax import
     import numpy as np
 
     from ape_x_dqn_tpu.config import ApexConfig, apply_overrides

@@ -3,7 +3,7 @@
 # Gate 1: compileall — an import-time syntax regression anywhere in the
 #         package or tools fails in seconds, not after an 870 s pytest run.
 # Gate 2: xp_transport smoke — bench.py's CI-sized transport point +
-#         SIGKILL barrage (host-only, no backend probe), so a regression
+#         SIGKILL barrage (host-only, no jax), so a regression
 #         in the experience transport or bench wiring can't reach the
 #         driver unseen.
 # Gate 3: checkpoint round-trip smoke — train on the tiny config with
