@@ -32,6 +32,7 @@ from flax import struct
 
 from ape_x_dqn_tpu.ops import losses
 from ape_x_dqn_tpu.types import PrioritizedBatch, TrainState
+from ape_x_dqn_tpu.utils.profiling import stage
 
 @struct.dataclass
 class StepMetrics:
@@ -234,20 +235,25 @@ def build_train_step(
     """
 
     def loss_fn(params, target_params, batch: PrioritizedBatch):
-        t = batch.transition
-        B = t.action.shape[0]
-        # One online forward over [obs; next_obs] (2B) instead of two B-sized
-        # passes — bigger matmuls tile better on the MXU.
-        q_both = network.apply(params, jnp.concatenate([t.obs, t.next_obs], axis=0))[2]
-        q_values, q_next_online = q_both[:B], q_both[B:]
-        q_next_target = network.apply(target_params, t.next_obs)[2]
-        targets = losses.double_q_target(
-            q_next_online, q_next_target, t.reward, t.discount
-        )
-        delta = losses.td_error(q_values, t.action, targets)
-        weights = batch.is_weights if use_is_weights else None
-        loss = losses.td_loss(delta, weights, kind=loss_kind, huber_kappa=huber_kappa)
-        return loss, (delta, q_values)
+        # One scope for the loss: AD names its ops jvp(stage:forward) and
+        # the backward pass's transpose(jvp(stage:forward)).
+        with stage("forward"):
+            t = batch.transition
+            B = t.action.shape[0]
+            # One online forward over [obs; next_obs] (2B) instead of two
+            # B-sized passes — bigger matmuls tile better on the MXU.
+            q_both = network.apply(
+                params, jnp.concatenate([t.obs, t.next_obs], axis=0))[2]
+            q_values, q_next_online = q_both[:B], q_both[B:]
+            q_next_target = network.apply(target_params, t.next_obs)[2]
+            targets = losses.double_q_target(
+                q_next_online, q_next_target, t.reward, t.discount
+            )
+            delta = losses.td_error(q_values, t.action, targets)
+            weights = batch.is_weights if use_is_weights else None
+            loss = losses.td_loss(
+                delta, weights, kind=loss_kind, huber_kappa=huber_kappa)
+            return loss, (delta, q_values)
 
     def train_step(state: TrainState, batch: PrioritizedBatch):
         (loss, (delta, q_values)), grads = jax.value_and_grad(
@@ -262,12 +268,14 @@ def build_train_step(
         # (equal-size shards); an explicit pmean here would double-count
         # (measured: exactly n× updates).  The scalar loss is still
         # per-shard varying and needs a real pmean for reporting.
-        if grad_reduce_axis is not None:
-            n_sh = jax.lax.psum(1, grad_reduce_axis)
-            grads = jax.tree_util.tree_map(lambda g: g / n_sh, grads)
-            loss = jax.lax.pmean(loss, grad_reduce_axis)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with stage("optimizer"):
+            if grad_reduce_axis is not None:
+                n_sh = jax.lax.psum(1, grad_reduce_axis)
+                grads = jax.tree_util.tree_map(lambda g: g / n_sh, grads)
+                loss = jax.lax.pmean(loss, grad_reduce_axis)
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         step = state.step + 1
         if sync_in_step:
             # Intended target sync: copy exactly every target_sync_freq steps
@@ -289,11 +297,13 @@ def build_train_step(
             mean_abs_td = jax.lax.pmean(mean_abs_td, grad_reduce_axis)
             max_abs_td = jax.lax.pmax(max_abs_td, grad_reduce_axis)
             mean_q = jax.lax.pmean(mean_q, grad_reduce_axis)
+        with stage("restamp"):
+            priorities = losses.priorities_from_td(delta, priority_epsilon)
         metrics = StepMetrics(
             loss=loss,
             mean_abs_td=mean_abs_td,
             max_abs_td=max_abs_td,
-            priorities=losses.priorities_from_td(delta, priority_epsilon),
+            priorities=priorities,
             mean_q=mean_q,
         )
         new_state = TrainState(

@@ -1,14 +1,12 @@
 """On-demand ``jax.profiler`` capture, triggered from /varz?trace=1.
 
-The ROADMAP's open profiler item (the 4.5k→12.5k steps/s gap) has no
-committed trace partly because capturing one meant stopping the run and
-re-launching ``tools/trace_capture.py`` under the right config.  This
-hook removes that step: hit ``/varz?trace=1`` on a LIVE trainer and a
-background thread traces the next N learner steps into a TensorBoard
-logdir, then tries to parse the xplane protobuf into the same op-level
-JSON summary ``tools/trace_capture.py`` produces (its ``summarize_xplane``
-is loaded by file path — ``tools/`` is not a package — and skipped
-gracefully when tensorflow isn't importable).
+Hit ``/varz?trace=1`` on a LIVE trainer and a background thread traces the
+next N learner steps into a TensorBoard logdir, then reduces the xplane
+with the program's own reader (``utils/profiling.summarize_trace``:
+``jax.profiler.ProfileData``, nothing else): device busy share, seconds
+per stage of the fused learner, and the longest device gaps named by the
+runtime's ``apex:<stage>`` span beside each.  The record lands in
+``<logdir>/summary.json``.
 
 Hitting the endpoint must never kill a run: a profiler that fails to
 start or stop (``utils/profiling.trace`` raises) is reported as
@@ -23,27 +21,6 @@ import tempfile
 import threading
 import time
 from typing import Callable, Optional
-
-
-def _load_summarizer():
-    """``tools/trace_capture.summarize_xplane`` by file path, or None —
-    the tools tree may be absent in an installed package, and its
-    tensorflow import is too heavy to pay at module scope."""
-    try:
-        import importlib.util
-
-        root = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        )
-        path = os.path.join(root, "tools", "trace_capture.py")
-        if not os.path.exists(path):
-            return None
-        spec = importlib.util.spec_from_file_location("_trace_capture", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.summarize_xplane
-    except Exception:  # noqa: BLE001 — summary is best-effort garnish
-        return None
 
 
 class TraceOnDemand:
@@ -80,7 +57,7 @@ class TraceOnDemand:
         return dict(self.last)
 
     def _capture(self, logdir: str, n: int) -> None:
-        from ape_x_dqn_tpu.utils.profiling import trace
+        from ape_x_dqn_tpu.utils.profiling import summarize_trace, trace
 
         rec = {"logdir": logdir, "steps_requested": n}
         try:
@@ -96,14 +73,10 @@ class TraceOnDemand:
                 else:
                     time.sleep(min(2.0, self._timeout_s))
             rec["wall_s"] = round(time.monotonic() - t0, 3)
-            summarize = _load_summarizer()
-            if summarize is not None:
-                try:
-                    rec["summary"] = summarize(logdir)
-                except Exception as e:  # noqa: BLE001 — best-effort
-                    rec["summary"] = {
-                        "error": f"{type(e).__name__}: {e}"
-                    }
+            try:
+                rec["summary"] = summarize_trace(logdir)
+            except Exception as e:  # noqa: BLE001 — the trace is on disk
+                rec["summary"] = {"error": f"{type(e).__name__}: {e}"}
             try:
                 with open(os.path.join(logdir, "summary.json"),
                           "w") as f:

@@ -32,6 +32,7 @@ from flax import struct
 
 from ape_x_dqn_tpu.ops.pallas.sampling import sample_indices
 from ape_x_dqn_tpu.types import NStepTransition, PrioritizedBatch
+from ape_x_dqn_tpu.utils.profiling import jit_fused, stage
 
 
 @struct.dataclass
@@ -80,19 +81,21 @@ def device_replay_add(
         raise ValueError(
             f"chunk of {M} transitions exceeds replay capacity {state.capacity}"
         )
-    idx = (state.cursor + jnp.arange(M, dtype=jnp.int32)) % state.capacity
-    mass = jnp.power(jnp.maximum(priorities.astype(jnp.float32), 1e-12),
-                     priority_exponent)
-    return state.replace(
-        obs=state.obs.at[idx].set(transitions.obs),
-        next_obs=state.next_obs.at[idx].set(transitions.next_obs),
-        action=state.action.at[idx].set(transitions.action.astype(jnp.int32)),
-        reward=state.reward.at[idx].set(transitions.reward),
-        discount=state.discount.at[idx].set(transitions.discount),
-        mass=state.mass.at[idx].set(mass),
-        cursor=(state.cursor + M) % state.capacity,
-        count=state.count + M,
-    )
+    with stage("ingest"):
+        idx = (state.cursor + jnp.arange(M, dtype=jnp.int32)) % state.capacity
+        mass = jnp.power(jnp.maximum(priorities.astype(jnp.float32), 1e-12),
+                         priority_exponent)
+        return state.replace(
+            obs=state.obs.at[idx].set(transitions.obs),
+            next_obs=state.next_obs.at[idx].set(transitions.next_obs),
+            action=state.action.at[idx].set(
+                transitions.action.astype(jnp.int32)),
+            reward=state.reward.at[idx].set(transitions.reward),
+            discount=state.discount.at[idx].set(transitions.discount),
+            mass=state.mass.at[idx].set(mass),
+            cursor=(state.cursor + M) % state.capacity,
+            count=state.count + M,
+        )
 
 
 def device_replay_sample(
@@ -143,41 +146,56 @@ def device_replay_sample_many(
     single-ring law exactly.
     """
     K, B = num_batches, batch_size
-    total = jnp.sum(state.mass)
-    bounds = total / B
-    u = jax.random.uniform(rng, (K, B))
-    targets = (jnp.arange(B, dtype=jnp.float32)[None, :] + u) * bounds
-    targets = jnp.minimum(targets, total * (1.0 - 1e-7))
-    idx = sample_indices(state.mass, targets.reshape(-1))      # [K*B]
-    size_i = jnp.maximum(jnp.minimum(state.count, state.capacity), 1)
-    idx = jnp.minimum(idx, size_i - 1)  # zero-mass guard (see sample above)
-    probs = state.mass[idx] / jnp.maximum(total, 1e-12)
-    if axis_name is None:
-        n_shards = 1
-        size_global = size_i
-    else:
-        n_shards = jax.lax.psum(1, axis_name)
-        size_global = jax.lax.psum(size_i, axis_name)
-    weights = jnp.power(
-        jnp.maximum(size_global.astype(jnp.float32) * probs / n_shards, 1e-12),
-        -beta,
-    ).reshape(K, B)
-    wmax = jnp.max(weights, axis=1, keepdims=True)
-    if axis_name is not None:
-        wmax = jax.lax.pmax(wmax, axis_name)
-    weights = weights / wmax
+    idx, weights = sample_slots(state, rng, K, B, beta, axis_name)
     idx2 = idx.reshape(K, B)
-    return PrioritizedBatch(
-        transition=NStepTransition(
+    with stage("gather"):
+        transition = NStepTransition(
             obs=state.obs[idx].reshape(K, B, *state.obs.shape[1:]),
             action=state.action[idx2],
             reward=state.reward[idx2],
             discount=state.discount[idx2],
             next_obs=state.next_obs[idx].reshape(K, B, *state.next_obs.shape[1:]),
-        ),
-        indices=idx2,
-        is_weights=weights.astype(jnp.float32),
+        )
+    return PrioritizedBatch(
+        transition=transition, indices=idx2, is_weights=weights,
     )
+
+
+def sample_slots(
+    state, rng: jax.Array, num_batches: int, batch_size: int, beta, axis_name
+) -> Tuple[jax.Array, jax.Array]:
+    """The ``sample`` stage, one spelling for both ring layouts (it reads
+    ``mass``, ``count`` and ``capacity`` alone): the stratified inverse-CDF
+    draw of K·B slots from the current masses and their importance weights
+    under the law ``device_replay_sample_many`` documents.  Returns
+    (idx int32 [K·B], weights float32 [K, B])."""
+    K, B = num_batches, batch_size
+    with stage("sample"):
+        total = jnp.sum(state.mass)
+        bounds = total / B
+        u = jax.random.uniform(rng, (K, B))
+        targets = (jnp.arange(B, dtype=jnp.float32)[None, :] + u) * bounds
+        targets = jnp.minimum(targets, total * (1.0 - 1e-7))
+        idx = sample_indices(state.mass, targets.reshape(-1))      # [K*B]
+        size_i = jnp.maximum(jnp.minimum(state.count, state.capacity), 1)
+        idx = jnp.minimum(idx, size_i - 1)  # a zero-mass tail is never drawn
+        probs = state.mass[idx] / jnp.maximum(total, 1e-12)
+        if axis_name is None:
+            n_shards = 1
+            size_global = size_i
+        else:
+            n_shards = jax.lax.psum(1, axis_name)
+            size_global = jax.lax.psum(size_i, axis_name)
+        weights = jnp.power(
+            jnp.maximum(
+                size_global.astype(jnp.float32) * probs / n_shards, 1e-12
+            ),
+            -beta,
+        ).reshape(K, B)
+        wmax = jnp.max(weights, axis=1, keepdims=True)
+        if axis_name is not None:
+            wmax = jax.lax.pmax(wmax, axis_name)
+        return idx, (weights / wmax).astype(jnp.float32)
 
 
 def device_replay_restamp_last(
@@ -260,10 +278,11 @@ def fused_scan_body(
             return t_state, metrics
 
         train_state, metrics = jax.lax.scan(body_pre, train_state, batches)
-        replay_state = device_replay_restamp_last(
-            replay_state, batches.indices, metrics.priorities,
-            priority_exponent,
-        )
+        with stage("restamp"):
+            replay_state = device_replay_restamp_last(
+                replay_state, batches.indices, metrics.priorities,
+                priority_exponent,
+            )
     else:
 
         def body(carry, step_rng):
@@ -273,9 +292,11 @@ def fused_scan_body(
                 sample_many_fn(r_state, step_rng, 1, B, beta, axis_name),
             )
             t_state, metrics = train_step_fn(t_state, batch)
-            r_state = device_replay_update_priorities(
-                r_state, batch.indices, metrics.priorities, priority_exponent
-            )
+            with stage("restamp"):
+                r_state = device_replay_update_priorities(
+                    r_state, batch.indices, metrics.priorities,
+                    priority_exponent,
+                )
             return (t_state, r_state), metrics
 
         rngs = jax.random.split(rng, K)
@@ -283,18 +304,19 @@ def fused_scan_body(
             body, (train_state, replay_state), rngs
         )
     if target_sync_freq is not None:
-        crossed = (train_state.step // target_sync_freq) > (
-            step_before // target_sync_freq
-        )
-        train_state = train_state.replace(
-            target_params=jax.tree_util.tree_map(
-                lambda online, target: jnp.where(
-                    crossed, online.astype(target.dtype), target
-                ),
-                train_state.params,
-                train_state.target_params,
+        with stage("target_sync"):
+            crossed = (train_state.step // target_sync_freq) > (
+                step_before // target_sync_freq
             )
-        )
+            train_state = train_state.replace(
+                target_params=jax.tree_util.tree_map(
+                    lambda online, target: jnp.where(
+                        crossed, online.astype(target.dtype), target
+                    ),
+                    train_state.params,
+                    train_state.target_params,
+                )
+            )
     return train_state, replay_state, metrics
 
 
@@ -374,5 +396,5 @@ def build_fused_learn_step(
         fused = fused_no_ingest
 
     if jit:
-        return jax.jit(fused, donate_argnums=(0, 1))
+        return jit_fused(fused, donate_argnums=(0, 1))
     return fused

@@ -36,8 +36,9 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
-from ape_x_dqn_tpu.replay.device import fused_scan_body
+from ape_x_dqn_tpu.replay.device import fused_scan_body, sample_slots
 from ape_x_dqn_tpu.types import NStepTransition, PrioritizedBatch
+from ape_x_dqn_tpu.utils.profiling import jit_fused, stage
 
 
 def _next_pow2(n: int) -> int:
@@ -119,11 +120,12 @@ def dedup_device_add_frames(
     if U > Cf:
         raise ValueError(f"frame block {U} exceeds frame ring {Cf}")
     Q = state.seq_modulus
-    idx = ((state.fcount + jnp.arange(U, dtype=jnp.int32)) % Q) % Cf
-    return state.replace(
-        frames=state.frames.at[idx].set(frames),
-        fcount=(state.fcount + U) % Q,
-    )
+    with stage("ingest"):
+        idx = ((state.fcount + jnp.arange(U, dtype=jnp.int32)) % Q) % Cf
+        return state.replace(
+            frames=state.frames.at[idx].set(frames),
+            fcount=(state.fcount + U) % Q,
+        )
 
 
 def _age(state: DedupDeviceReplayState, ref: jax.Array) -> jax.Array:
@@ -149,23 +151,24 @@ def dedup_device_add_transitions(
         raise ValueError(
             f"chunk of {M} transitions exceeds replay capacity {state.capacity}"
         )
-    idx = (state.cursor + jnp.arange(M, dtype=jnp.int32)) % state.capacity
-    mass = jnp.power(jnp.maximum(priorities.astype(jnp.float32), 1e-12),
-                     priority_exponent)
-    new = state.replace(
-        obs_ref=state.obs_ref.at[idx].set(obs_ref.astype(jnp.int32)),
-        next_ref=state.next_ref.at[idx].set(next_ref.astype(jnp.int32)),
-        action=state.action.at[idx].set(action.astype(jnp.int32)),
-        reward=state.reward.at[idx].set(reward),
-        discount=state.discount.at[idx].set(discount),
-        mass=state.mass.at[idx].set(mass),
-        cursor=(state.cursor + M) % state.capacity,
-        count=jnp.minimum(state.count + M, jnp.int32(1 << 30)),
-    )
-    # Sweep: obs_ref is each row's OLDEST frame (DedupChunk layout
-    # contract), so one age test invalidates exactly the frame-dead rows.
-    dead = _age(new, new.obs_ref) > new.frame_capacity
-    return new.replace(mass=jnp.where(dead, 0.0, new.mass))
+    with stage("ingest"):
+        idx = (state.cursor + jnp.arange(M, dtype=jnp.int32)) % state.capacity
+        mass = jnp.power(jnp.maximum(priorities.astype(jnp.float32), 1e-12),
+                         priority_exponent)
+        new = state.replace(
+            obs_ref=state.obs_ref.at[idx].set(obs_ref.astype(jnp.int32)),
+            next_ref=state.next_ref.at[idx].set(next_ref.astype(jnp.int32)),
+            action=state.action.at[idx].set(action.astype(jnp.int32)),
+            reward=state.reward.at[idx].set(reward),
+            discount=state.discount.at[idx].set(discount),
+            mass=state.mass.at[idx].set(mass),
+            cursor=(state.cursor + M) % state.capacity,
+            count=jnp.minimum(state.count + M, jnp.int32(1 << 30)),
+        )
+        # Sweep: obs_ref is each row's OLDEST frame (DedupChunk layout
+        # contract), so one age test invalidates exactly the frame-dead rows.
+        dead = _age(new, new.obs_ref) > new.frame_capacity
+        return new.replace(mass=jnp.where(dead, 0.0, new.mass))
 
 
 def dedup_sample_many(
@@ -177,49 +180,24 @@ def dedup_sample_many(
     axis_name: str | None = None,
 ) -> PrioritizedBatch:
     """Stratified PER sample over the dedup layout — identical law and IS
-    weights to ``device_replay_sample_many`` (shared spec: the weight math
-    below mirrors replay/device.py:146-169 line for line); only the frame
-    gather goes through the ref indirection."""
-    from ape_x_dqn_tpu.ops.pallas.sampling import sample_indices
-
+    weights to ``device_replay_sample_many`` (the same ``sample_slots``);
+    only the frame gather goes through the ref indirection."""
     K, B = num_batches, batch_size
-    total = jnp.sum(state.mass)
-    bounds = total / B
-    u = jax.random.uniform(rng, (K, B))
-    targets = (jnp.arange(B, dtype=jnp.float32)[None, :] + u) * bounds
-    targets = jnp.minimum(targets, total * (1.0 - 1e-7))
-    idx = sample_indices(state.mass, targets.reshape(-1))      # [K*B]
-    size_i = jnp.maximum(jnp.minimum(state.count, state.capacity), 1)
-    idx = jnp.minimum(idx, size_i - 1)
-    probs = state.mass[idx] / jnp.maximum(total, 1e-12)
-    if axis_name is None:
-        n_shards = 1
-        size_global = size_i
-    else:
-        n_shards = jax.lax.psum(1, axis_name)
-        size_global = jax.lax.psum(size_i, axis_name)
-    weights = jnp.power(
-        jnp.maximum(size_global.astype(jnp.float32) * probs / n_shards, 1e-12),
-        -beta,
-    ).reshape(K, B)
-    wmax = jnp.max(weights, axis=1, keepdims=True)
-    if axis_name is not None:
-        wmax = jax.lax.pmax(wmax, axis_name)
-    weights = weights / wmax
+    idx, weights = sample_slots(state, rng, K, B, beta, axis_name)
     idx2 = idx.reshape(K, B)
     Cf = state.frame_capacity
-    obs = state.frames[state.obs_ref[idx] % Cf]
-    next_obs = state.frames[state.next_ref[idx] % Cf]
-    return PrioritizedBatch(
-        transition=NStepTransition(
+    with stage("gather"):
+        obs = state.frames[state.obs_ref[idx] % Cf]
+        next_obs = state.frames[state.next_ref[idx] % Cf]
+        transition = NStepTransition(
             obs=obs.reshape(K, B, *state.frames.shape[1:]),
             action=state.action[idx2],
             reward=state.reward[idx2],
             discount=state.discount[idx2],
             next_obs=next_obs.reshape(K, B, *state.frames.shape[1:]),
-        ),
-        indices=idx2,
-        is_weights=weights.astype(jnp.float32),
+        )
+    return PrioritizedBatch(
+        transition=transition, indices=idx2, is_weights=weights,
     )
 
 
@@ -272,5 +250,5 @@ def build_dedup_fused_learn_step(
         fused = fused_ingest
 
     if jit:
-        return jax.jit(fused, donate_argnums=(0, 1))
+        return jit_fused(fused, donate_argnums=(0, 1))
     return fused

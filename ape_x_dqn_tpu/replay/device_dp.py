@@ -50,6 +50,7 @@ from ape_x_dqn_tpu.replay.device import (
     device_replay_add,
     fused_scan_body,
 )
+from ape_x_dqn_tpu.utils.profiling import jit_fused
 
 _AXIS = "data"
 
@@ -199,5 +200,10 @@ def build_sharded_fused_learn_step(
         out_specs=(P(), specs, metrics_specs),
     )
     if jit:
-        return jax.jit(fn, donate_argnums=(0, 1))
+        # The callers commit both states to the mesh; beta and the key
+        # arrive uncommitted and jit places them.
+        return jit_fused(
+            fn, mesh=mesh, arg_specs=(P(), specs, None, None),
+            donate_argnums=(0, 1),
+        )
     return fn

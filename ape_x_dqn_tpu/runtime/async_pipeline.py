@@ -132,8 +132,9 @@ class _IngestStagerThread:
     """
 
     def __init__(self, fused, stop_event: threading.Event, drain_fn,
-                 period_s: float = 0.005, stall_fn=None):
+                 timers: StageTimer, period_s: float = 0.005, stall_fn=None):
         self._fused = fused
+        self._timers = timers
         self._stop = stop_event
         self._drain_fn = drain_fn
         # Chaos gate (obs/chaos.ChaosMonkey.stager_stalled): while it
@@ -163,7 +164,16 @@ class _IngestStagerThread:
                 if self._stall_fn is not None and self._stall_fn():
                     self._done.wait(self._period)
                     continue
-                n = self._fused.prepare_staged(drain=bool(self._drain_fn()))
+                # The staging half of ingest, off the learner thread and so
+                # outside its ``ingest`` stage.  Only a poll that staged
+                # rows counts, so the mean is per staging, not per poll.
+                t0 = time.perf_counter()
+                with self._timers.span("ingest_prepare"):
+                    n = self._fused.prepare_staged(
+                        drain=bool(self._drain_fn()))
+                if n:
+                    self._timers.add("ingest_prepare",
+                                     time.perf_counter() - t0)
                 self.prepared_rows += n
                 self.heartbeat = time.monotonic()
                 if not n:
@@ -1421,6 +1431,7 @@ class AsyncPipeline:
         self._dispatch_pipeline = pipeline
         stager = _IngestStagerThread(
             fused, self.stop_event, lambda: self.worker.finished,
+            self.timers,
             stall_fn=(self._chaos.stager_stalled
                       if self._chaos is not None else None),
         )
@@ -1502,7 +1513,8 @@ class AsyncPipeline:
                     # The snapshot reads the device ring: everything
                     # dispatched must have landed.
                     pipeline.sync()
-                    self._save_fused_checkpoint()
+                    with self.timers.stage("checkpoint"):
+                        self._save_fused_checkpoint()
                     next_ckpt += cfg.learner.checkpoint_every
                 self._maybe_eval()
                 if self._learner_step >= next_log:
@@ -1603,7 +1615,8 @@ class AsyncPipeline:
                     with self.timers.stage("publish"):
                         self._publish(fused.params_for_publish())
                 if next_ckpt is not None and self._learner_step >= next_ckpt:
-                    self._save_fused_checkpoint()
+                    with self.timers.stage("checkpoint"):
+                        self._save_fused_checkpoint()
                     next_ckpt += cfg.learner.checkpoint_every
                 self._maybe_eval()
                 if self._learner_step >= next_log:

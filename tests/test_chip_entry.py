@@ -115,7 +115,7 @@ def test_profiling_trace_raises_when_the_profiler_cannot_start(
 
     from ape_x_dqn_tpu.utils.profiling import trace
 
-    def boom(logdir):
+    def boom(logdir, **_options):
         raise RuntimeError("profiler plugin missing")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)

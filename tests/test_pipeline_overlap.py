@@ -340,6 +340,39 @@ class TestOverlappedRuntime:
         snap = pipe.obs_registry.snapshot()
         assert "learner/host_syncs" in snap
         assert "learner/overlap_gap_ms" in snap
+        # The stager thread's staging is a stage of its own.
+        assert pipe.timers.snapshot()["ingest_prepare"]["calls"] >= 1
+        assert "ingest_prepare" in final["stage_us"]
+
+    def test_stager_counts_only_polls_that_staged_rows(self):
+        """``ingest_prepare``'s mean is per staging, not per 5 ms poll: an
+        empty poll leaves a span in a trace and nothing in the counters."""
+        import threading
+        import time
+
+        from ape_x_dqn_tpu.runtime.async_pipeline import _IngestStagerThread
+        from ape_x_dqn_tpu.utils.profiling import StageTimer
+
+        script = [0, 64, 0, 0, 128, 0]
+
+        class Fused:
+            polls = 0
+
+            def prepare_staged(self, drain=False):
+                self.polls += 1
+                return script.pop(0) if script else 0
+
+        fused, timers = Fused(), StageTimer()
+        stager = _IngestStagerThread(fused, threading.Event(), lambda: False,
+                                     timers, period_s=0.001)
+        stager.start()
+        deadline = time.monotonic() + 30
+        while script and time.monotonic() < deadline:
+            time.sleep(0.005)
+        stager.stop()
+        assert stager.error is None and stager.prepared_rows == 192
+        assert fused.polls >= 6
+        assert timers.snapshot()["ingest_prepare"]["calls"] == 2
 
     def test_host_path_batched_writeback(self):
         """pipeline_depth > 1 on the HOST-replay path batches the deferred
