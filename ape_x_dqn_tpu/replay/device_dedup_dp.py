@@ -1,7 +1,7 @@
 """Sharded frame-dedup device replay: per-device dedup ring shards + the
 fused K-step scan under ``shard_map`` — the configuration that makes
 config3's 2M-slot replay FEASIBLE per chip (round-4 verdict item 1a:
-2M × 84×84 dedup ≈ 16.5 GB global ≈ 4.2 GB/chip at dp=4, vs the
+2M × 1.25 rows of 7,168 B ≈ 17.9 GB global ≈ 4.5 GB/chip at dp=4, vs the
 double-store's 28 GB that OOMed a 16 GB chip).
 
 Structure mirrors replay/device_dp.py (the double-store sharded ring) with
@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ape_x_dqn_tpu.replay.device import fused_scan_body
 from ape_x_dqn_tpu.replay.device_dedup import (
     DedupDeviceReplayState,
+    RowFormat,
     dedup_device_add_frames,
     dedup_device_add_transitions,
     dedup_sample_many,
@@ -78,9 +79,11 @@ def init_sharded_dedup_replay(
         )
     sh = NamedSharding(mesh, P(_AXIS))
 
+    fmt = RowFormat.of(obs_shape, obs_dtype)
+
     def init():
         return DedupDeviceReplayState(
-            frames=jnp.zeros((frame_capacity, *obs_shape), obs_dtype),
+            rows=fmt.zeros(frame_capacity), fmt=fmt,
             obs_ref=jnp.zeros((capacity,), jnp.int32),
             next_ref=jnp.zeros((capacity,), jnp.int32),
             action=jnp.zeros((capacity,), jnp.int32),

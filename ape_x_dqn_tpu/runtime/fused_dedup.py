@@ -575,7 +575,7 @@ class FusedDedupLearner:
         self._flush_prepared()
         n = self._n_shards
         C_local = self._capacity // n
-        Cf_global = int(self._replay.frames.shape[0])
+        Cf_global = self._replay.frame_capacity
         Cf_local = Cf_global // n
         with self._lock:
             ing_now, shipped_now = self._chain_now()
@@ -625,7 +625,9 @@ class FusedDedupLearner:
             "txn_reward": jnp.take(r.reward, ti, axis=0),
             "txn_discount": jnp.take(r.discount, ti, axis=0),
             "frame_gidx": fidx,
-            "frame_rows": jnp.take(r.frames, fi, axis=0),
+            # Logical rows, whatever the ring stores: a delta written
+            # before the ring was stored in packed rows applies after.
+            "frame_rows": r.fmt.unpack(jnp.take(r.rows, fi, axis=0)),
             "mass": jnp.copy(r.mass),
             # Counters recomputed host-side — bit-identical to the device's
             # mod-C / saturating / mod-Q arithmetic, no device sync needed.
@@ -655,7 +657,7 @@ class FusedDedupLearner:
             )
         if (int(delta["capacity"]) != self._capacity
                 or int(delta["frame_capacity"])
-                != int(self._replay.frames.shape[0])):
+                != self._replay.frame_capacity):
             raise ValueError("delta ring layout != configured layout")
         with self._lock:
             ing_now, shipped_now = self._chain_now()
@@ -680,10 +682,10 @@ class FusedDedupLearner:
             )
         ti = jnp.asarray(np.asarray(delta["txn_gidx"], np.int32))
         fi = jnp.asarray(np.asarray(delta["frame_gidx"], np.int32))
-        from ape_x_dqn_tpu.replay.device_dedup import DedupDeviceReplayState
-
-        self._replay = DedupDeviceReplayState(
-            frames=r.frames.at[fi].set(jnp.asarray(delta["frame_rows"])),
+        self._replay = r.replace(
+            rows=r.rows.at[fi].set(
+                jnp.asarray(r.fmt.pack(np.asarray(delta["frame_rows"])))
+            ),
             obs_ref=r.obs_ref.at[ti].set(
                 jnp.asarray(np.asarray(delta["txn_obs_ref"], np.int32))
             ),
@@ -718,7 +720,8 @@ class FusedDedupLearner:
                 "snapshot is not a dedup-ring snapshot — replay layouts "
                 "(replay.dedup) must match across save/restore"
             )
-        want = tuple(self._replay.frames.shape)
+        fmt = self._replay.fmt
+        want = (self._replay.frame_capacity, *fmt.obs_shape)
         got = tuple(state["frames"].shape)
         if want != got:
             raise ValueError(
@@ -728,16 +731,18 @@ class FusedDedupLearner:
             raise ValueError(
                 "snapshot shard layout != configured data_parallel extent"
             )
-        from ape_x_dqn_tpu.replay.device_dedup import DedupDeviceReplayState
-
         if self._mesh is not None:
             place = lambda key, live: jax.device_put(  # noqa: E731
                 np.asarray(state[key]), live.sharding
             )
         else:
             place = lambda key, live: jnp.asarray(state[key])  # noqa: E731
-        self._replay = DedupDeviceReplayState(
-            frames=place("frames", self._replay.frames),
+        # A snapshot holds the logical [rows, *obs_shape] ring; the rows
+        # are packed on the host, so the device never holds both.
+        state = dict(
+            state, rows=fmt.pack(np.asarray(state["frames"], fmt.dtype)))
+        self._replay = self._replay.replace(
+            rows=place("rows", self._replay.rows),
             obs_ref=place("obs_ref", self._replay.obs_ref),
             next_ref=place("next_ref", self._replay.next_ref),
             action=place("action", self._replay.action),

@@ -989,7 +989,7 @@ def _dedup_fused_bench(args, jnp, jax) -> dict:
     return {
         "learner_steps_per_sec": round(rate, 1),
         "us_per_step": round(dt / (calls * K) * 1e6, 1),
-        "hbm_frames_mb": round(replay.frames.nbytes / 1e6, 1),
+        "hbm_frames_mb": round(replay.rows.nbytes / 1e6, 1),
         "double_store_frames_mb": round(
             2 * C * int(np.prod(obs_shape)) / 1e6, 1
         ),

@@ -224,7 +224,7 @@ def leg_dp4() -> None:
     def inspect(pipe, final):
         fused = pipe.fused
         devs = jax.devices()[:4]
-        frames = fused._replay.frames
+        frames = fused._replay.rows
         shard_devs = [s.device for s in frames.addressable_shards]
         assert len(shard_devs) == 4 and set(shard_devs) == set(devs), (
             f"dp4: frame ring shards on {shard_devs}"
@@ -237,7 +237,7 @@ def leg_dp4() -> None:
         used = [d.memory_stats()["bytes_in_use"] for d in devs]
         spread = (max(used) - min(used)) / max(used)
         assert spread <= 0.10, f"dp4: bytes_in_use per device {used}"
-        say(f"dp4: frame ring {frames.shape} in 4 shards of "
+        say(f"dp4: frame ring rows {frames.shape} in 4 shards of "
             f"{rows.pop()} rows on {[d.id for d in shard_devs]}; params "
             f"replicated on all 4; bytes_in_use per device {used} "
             f"(spread {spread:.1%})")

@@ -1,0 +1,269 @@
+"""Compile-only: the dedup programs, compiled for a TPU v5e that is described
+and not attached, keep the frame ring's index major and touch no array of the
+ring's size but the ring itself.
+
+At the benchmark check's ring (4,096 slots, 5,120 observations of 84x84x4) the
+optimized text of the frame add, the transition add and the fused program, on
+one chip and under ``shard_map`` over four, holds no instruction that makes an
+array of the ring's size except the scatter that writes into the donated ring.
+Stored as ``[Cf, 84, 84, 4]`` the chip put the ring index in the lanes and
+every program copied the whole ring (``copy.3``/``copy.5`` around the
+scatter, ``copy.25`` before the gather).
+
+Every test here shares one description of the topology, made in a fixture:
+only one process may load the TPU's library (on-chip-measurement guide).
+"""
+
+import os
+import re
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from ape_x_dqn_tpu.replay.device_dedup import (
+    build_dedup_fused_learn_step,
+    dedup_device_add_frames,
+    dedup_device_add_transitions,
+    init_dedup_device_replay,
+)
+from ape_x_dqn_tpu.replay.device_dedup_dp import (
+    build_sharded_dedup_add_frames,
+    build_sharded_dedup_add_transitions,
+    build_sharded_dedup_fused_learn_step,
+    dedup_replay_specs,
+)
+
+OBS = (84, 84, 4)
+SLOTS, FRAMES = 4096, 5120      # a chip's ring in the benchmark's check
+ROWS, BLOCK = 256, 320          # transitions and observations a call
+BATCH, K = 32, 2                # global batch; 8 a chip over four
+COMPILE_LIMIT_S = 240.0
+
+# ------------------------------------------------ reading the optimized text
+
+_DTYPE_BYTES = {"pred": 1, "u8": 1, "s8": 1, "u16": 2, "s16": 2, "bf16": 2,
+                "f16": 2, "u32": 4, "s32": 4, "f32": 4, "u64": 8, "s64": 8,
+                "f64": 8}
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%?(?P<name>[\w.\-]+) = (?P<shape>.*?) (?P<op>[\w\-]+)\((?P<args>.*)$")
+_ARRAY = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\](\{[^}]*\})?")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+# Opcodes that hand an array on without making one.
+_PASS_THROUGH = {"parameter", "tuple", "get-tuple-element", "bitcast", "while",
+                 "optimization-barrier"}
+
+
+def _arrays(shape_text: str):
+    """(bytes, layout text) of every array in an instruction's shape."""
+    for dtype, dims, layout in _ARRAY.findall(shape_text):
+        if dtype in _DTYPE_BYTES:
+            n = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            yield n * _DTYPE_BYTES[dtype], layout
+
+
+def ring_sized_instructions(hlo_text: str, ring_bytes: int) -> list:
+    """[(name, opcode, line)] of the instructions of the optimized module
+    that produce an array of at least ``ring_bytes``, outside fused
+    computations (whose instructions make no buffer of their own) and
+    leaving out what only hands an array on."""
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo_text))
+    out, inside = [], None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m or inside in fused or m.group("op") in _PASS_THROUGH:
+            continue
+        if any(b >= ring_bytes for b, _ in _arrays(m.group("shape"))):
+            out.append((m.group("name"), m.group("op"), line.strip()))
+    return out
+
+
+def ring_parameters(hlo_text: str, ring_bytes: int) -> dict:
+    """{name: layout text} of the entry parameters of the ring's size."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    out = {}
+    for line in entry.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group("op") == "parameter":
+            for b, layout in _arrays(m.group("shape")):
+                if b >= ring_bytes:
+                    out[m.group("name")] = layout
+    return out
+
+
+def assert_ring_stays_put(hlo_text: str, ring_bytes: int, scatters: int):
+    """Dimension 0 major in the ring parameter's layout; ``scatters``
+    ring-sized instructions, each a scatter (alone or as a fusion) whose
+    first operand is the ring parameter itself and whose output the module
+    aliases to a parameter."""
+    rings = ring_parameters(hlo_text, ring_bytes)
+    assert rings, "no parameter of the ring's size"
+    for layout in rings.values():
+        order = re.match(r"\{([\d,]+)", layout).group(1).split(",")
+        assert order[-1] == "0", f"ring held {layout}: its index is not major"
+    big = ring_sized_instructions(hlo_text, ring_bytes)
+    lines = "\n".join(line[:200] for _, _, line in big)
+    assert len(big) == scatters, f"ring-sized instructions:\n{lines}"
+    for name, op, line in big:
+        assert op in ("fusion", "scatter") and "scatter" in line, lines
+        first = _INSTRUCTION.match("  " + line).group("args").split(",")[0]
+        assert first.strip().lstrip("%") in rings, (
+            f"{name} does not take the ring parameter first:\n{lines}")
+    if scatters:
+        assert "input_output_alias" in hlo_text.split("\n", 1)[0]
+
+
+# ------------------------------------------------------- what is compiled
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no compiler for the chip here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without the chip: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _learner():
+    from ape_x_dqn_tpu.learner.train_step import init_train_state, make_optimizer
+    from ape_x_dqn_tpu.models.dueling import build_network
+
+    net = build_network("nature", 4, channels=(8, 8, 8), hidden=32,
+                        compute_dtype=jnp.bfloat16)
+    opt = make_optimizer("rmsprop", learning_rate=1e-4)
+    state = jax.eval_shape(
+        lambda k: init_train_state(net, opt, k, jnp.zeros((1, *OBS), jnp.uint8)),
+        jax.random.PRNGKey(0))
+    return net, opt, state
+
+
+def _with(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)
+
+
+def _programs(topo, n: int) -> dict:
+    """{name: (jitted, argument shapes)} of the three programs, on the first
+    chip of the topology (``n`` = 1) or under ``shard_map`` over ``n``."""
+    from ape_x_dqn_tpu.learner.train_step import build_train_step
+
+    net, opt, tstate = _learner()
+    step_fn = build_train_step(
+        net, opt, loss_kind="squared", sync_in_step=False, jit=False,
+        grad_reduce_axis="data" if n > 1 else None)
+    ring = jax.eval_shape(
+        lambda: init_dedup_device_replay(SLOTS * n, OBS, frame_capacity=FRAMES * n))
+    kw = dict(steps_per_call=K, target_sync_freq=K, sample_ahead=True)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    if n == 1:
+        dev = SingleDeviceSharding(topo.devices[0])
+        ring, tstate = _with(ring, dev), _with(tstate, dev)
+        arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=dev)  # noqa: E731
+        lead = ()
+        add_f = jax.jit(dedup_device_add_frames, donate_argnums=(0,))
+        add_t = jax.jit(
+            lambda st, *a: dedup_device_add_transitions(st, *a, 0.6),
+            donate_argnums=(0,))
+        fused = build_dedup_fused_learn_step(step_fn, BATCH, **kw)
+    else:
+        mesh = Mesh(np.array(topo.devices[:n]), ("data",))
+        row = NamedSharding(mesh, P("data"))
+        ring = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape if x.ndim else (n,), x.dtype, sharding=row), ring)
+        tstate = _with(tstate, NamedSharding(mesh, P()))
+        arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=row)  # noqa: E731
+        lead = (n,)
+        add_f = build_sharded_dedup_add_frames(mesh)
+        add_t = build_sharded_dedup_add_transitions(mesh, 0.6)
+        fused = build_sharded_dedup_fused_learn_step(step_fn, mesh, BATCH, **kw)
+        assert jax.tree_util.tree_structure(ring) == jax.tree_util.tree_structure(
+            dedup_replay_specs())
+    vec = lambda dt: arg((*lead, ROWS), dt)  # noqa: E731
+    return {
+        "add_frames": (add_f, (ring, arg((*lead, BLOCK, *OBS), jnp.uint8))),
+        "add_transitions": (add_t, (
+            ring, vec(jnp.int32), vec(jnp.int32), vec(jnp.int32),
+            vec(jnp.float32), vec(jnp.float32), vec(jnp.float32))),
+        "fused": (fused, (tstate, ring, 0.4, key)),
+    }
+
+
+def _compile_text(jitted, args) -> str:
+    """The optimized text, or a failure once ``COMPILE_LIMIT_S`` have gone
+    (the compile runs on a thread of this process, which holds the TPU's
+    library; a stuck one is left behind as a daemon)."""
+    box = {}
+
+    def work():
+        try:
+            box["text"] = jitted.lower(*args).compile().as_text()
+        except BaseException as e:  # noqa: BLE001 - re-raised below
+            box["error"] = e
+
+    t = threading.Thread(target=work, daemon=True)
+    t.start()
+    t.join(COMPILE_LIMIT_S)
+    if t.is_alive():
+        pytest.fail(f"compile for v5e not done in {COMPILE_LIMIT_S:.0f} s")
+    if "error" in box:
+        raise box["error"]
+    return box["text"]
+
+
+@pytest.mark.parametrize("program,scatters", [
+    ("add_frames", 1), ("add_transitions", 0), ("fused", 0)])
+@pytest.mark.parametrize("chips", [1, 4])
+def test_no_program_copies_the_ring(topo, no_compile_cache, chips, program, scatters):
+    jitted, args = _programs(topo, chips)[program]
+    ring_bytes = FRAMES * int(np.prod(OBS))  # a chip's ring, unpadded
+    assert_ring_stays_put(_compile_text(jitted, args), ring_bytes, scatters)
+
+
+def test_reader_finds_a_copied_ring():
+    """The reader on the text the old storage gave: the ring index in the
+    lanes, a copy in, the scatter, a copy out."""
+    text = """HloModule jit_add, input_output_alias={ {0}: (0, {}, may-alias) }
+
+%fused_computation (p: u8[5120,84,84,4]) -> u8[5120,84,84,4] {
+  %p = u8[5120,84,84,4]{2,3,1,0:T(8,128)(4,1)} parameter(0)
+  ROOT %s = u8[5120,84,84,4]{2,3,1,0:T(8,128)(4,1)} scatter(%p, %p, %p)
+}
+
+ENTRY %main (state_frames.1: u8[5120,84,84,4]) -> u8[5120,84,84,4] {
+  %state_frames.1 = u8[5120,84,84,4]{0,3,2,1:T(4,128)(4,1)} parameter(0)
+  %copy.3 = u8[5120,84,84,4]{2,3,1,0:T(8,128)(4,1)} copy(%state_frames.1)
+  %fusion = u8[5120,84,84,4]{2,3,1,0:T(8,128)(4,1)} fusion(%copy.3, %x, %y), kind=kCustom, calls=%fused_computation, metadata={op_name="scatter"}
+  ROOT %copy.5 = u8[5120,84,84,4]{0,3,2,1:T(4,128)(4,1)} copy(%fusion)
+}
+"""
+    ring_bytes = 5120 * 84 * 84 * 4
+    assert ring_parameters(text, ring_bytes) == {
+        "state_frames.1": "{0,3,2,1:T(4,128)(4,1)}"}
+    assert [n for n, _, _ in ring_sized_instructions(text, ring_bytes)] == [
+        "copy.3", "fusion", "copy.5"]
+    with pytest.raises(AssertionError, match="not major"):
+        assert_ring_stays_put(text, ring_bytes, 1)

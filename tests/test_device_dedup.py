@@ -131,11 +131,15 @@ class TestDedupRing:
         )
 
     def test_footprint_observable(self):
-        dd = init_dedup_device_replay(1024, OBS, frame_ratio=1.25)
-        ds = init_device_replay(1024, OBS)
-        frames_dd = dd.frames.nbytes
+        # At a real row: the stored rows are the HBM footprint, each padded
+        # to whole 128-word tiles (84x84x1: +1.6%; a toy row pads to 512 B).
+        obs = (84, 84, 1)
+        dd = init_dedup_device_replay(64, obs, frame_ratio=1.25)
+        ds = init_device_replay(64, obs)
+        frames_dd = dd.rows.nbytes
         frames_ds = ds.obs.nbytes + ds.next_obs.nbytes
-        assert frames_dd == pytest.approx(0.625 * frames_ds, rel=0.01)
+        assert frames_dd == pytest.approx(0.625 * frames_ds, rel=0.02)
+        assert dd.frames.nbytes == 0.625 * frames_ds
 
 
 def build_learner(seed=0):

@@ -10,7 +10,7 @@ record):
   * steps_per_call 8 / min_replay 4096 / total 64 steps (CPU-speed),
 
 while keeping what the validation is FOR at full scale: the 2M-transition
-frame-dedup ring with frame_ratio 1.25 (17.6 GB of frames), sharded over a
+frame-dedup ring with frame_ratio 1.25 (17.9 GB of stored rows), sharded over a
 data_parallel=4 virtual mesh, ingested from live dedup-emitting actors and
 trained by the sharded fused K-step scan.  Asserts the run completes, the
 loss is finite, ingest kept up (no shard starved below the warmup bar),
@@ -90,9 +90,9 @@ def main() -> int:
         log_every=10**9,
     )
     ring = pipe.fused._replay
-    frame_bytes = int(ring.frames.nbytes)
+    frame_bytes = int(ring.rows.nbytes)
     double_store_bytes = 2 * cfg.replay.capacity * int(
-        np.prod(ring.frames.shape[1:])
+        np.prod(ring.fmt.obs_shape)
     )
     result = pipe.run(learner_steps=64, warmup_timeout=3600.0)
     wall = time.time() - t0
