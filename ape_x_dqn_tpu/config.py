@@ -843,7 +843,11 @@ class ApexConfig:
         default_factory=AutopilotConfig
     )
     chaos: ChaosConfig = dataclasses.field(default_factory=ChaosConfig)
-    network: str = "conv"                 # "conv" | "nature" | "mlp"
+    network: str = "conv"                 # "conv" | "nature" | "mlp" | "lfm2_moe"
+    # network=lfm2_moe: the torso's block under the published config.json's
+    # keys plus the cut (models/lfm2_moe.spec_from_config); optionally the
+    # stem's ``channels`` and the head's ``hidden``.
+    torso: dict = dataclasses.field(default_factory=dict)
     seed: int = 0
 
     def validate(self) -> "ApexConfig":
@@ -1073,8 +1077,10 @@ class ApexConfig:
              "actor.num_actors must cover local (incl. max_workers "
              "headroom) + remote workers in process mode"),
             (0.0 <= r.is_exponent <= 1.0, "replay.is_exponent must be in [0, 1]"),
-            (self.network in ("conv", "nature", "mlp"),
+            (self.network in ("conv", "nature", "mlp", "lfm2_moe"),
              f"unknown network kind: {self.network}"),
+            ((self.network == "lfm2_moe") == bool(self.torso),
+             "torso holds the block of network=lfm2_moe, and of no other"),
             (l.optimizer in ("rmsprop", "adam"),
              f"unknown optimizer kind: {l.optimizer}"),
             (l.loss in ("huber", "squared"), f"unknown loss kind: {l.loss}"),
@@ -1248,7 +1254,7 @@ def _from_native_json(data: dict) -> ApexConfig:
             if unknown:
                 raise ValueError(f"unknown config keys in {key}: {sorted(unknown)}")
             setattr(cfg, key, sections[key](**value))
-        elif key in ("network", "seed"):
+        elif key in ("network", "seed", "torso"):
             setattr(cfg, key, data[key])
         elif key.startswith("_"):
             pass  # "_comment" and friends: documentation, not config
@@ -1259,6 +1265,20 @@ def _from_native_json(data: dict) -> ApexConfig:
 
 def to_dict(cfg: ApexConfig) -> dict:
     return dataclasses.asdict(cfg)
+
+
+def network_kwargs(cfg: ApexConfig) -> dict:
+    """What ``models.dueling.build_network`` takes beside the kind, the
+    action count and the dtypes: the torso's block, with the stem's and
+    head's widths where it states them."""
+    if cfg.network != "lfm2_moe":
+        return {}
+    kw = {"torso": {k: v for k, v in cfg.torso.items() if not k.startswith("_")}}
+    if "channels" in cfg.torso:
+        kw["channels"] = tuple(cfg.torso["channels"])
+    if "hidden" in cfg.torso:
+        kw["hidden"] = int(cfg.torso["hidden"])
+    return kw
 
 
 def transport_budget(cfg: ApexConfig, num_workers: Optional[int] = None,

@@ -22,7 +22,7 @@ params/opt-state update in place in HBM.
 
 from __future__ import annotations
 
-from typing import Callable, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +31,7 @@ from flax import linen as nn
 from flax import struct
 
 from ape_x_dqn_tpu.ops import losses
-from ape_x_dqn_tpu.types import PrioritizedBatch, TrainState
+from ape_x_dqn_tpu.types import ROUTING, PrioritizedBatch, TrainState
 from ape_x_dqn_tpu.utils.profiling import stage
 
 @struct.dataclass
@@ -41,6 +41,9 @@ class StepMetrics:
     max_abs_td: jax.Array      # float32 []
     priorities: jax.Array      # float32 [B] — new replay priorities
     mean_q: jax.Array          # float32 []
+    # A network's ``routing_metrics`` of what its layers sowed (types.ROUTING),
+    # summed over the step's three forwards; None for a network without them.
+    routing: Optional[dict] = None
 
 
 def _scale_by_rms_lowp(
@@ -179,8 +182,19 @@ def init_train_state(
     ~0.4% relative rounding on Q-targets while halving the target-params HBM
     read on every step.  Syncs cast online → target dtype."""
     params = network.init(rng, sample_obs)
+    # Leaves a network keeps in float32 whatever the target's type (a
+    # router's scores decide a top-k; rounding them decides it otherwise).
+    keep = tuple(getattr(network, "float32_leaves", ()))
     if target_dtype is None:
         target = jax.tree_util.tree_map(jnp.copy, params)
+    elif keep:
+        target = jax.tree_util.tree_map_with_path(
+            lambda path, p: jnp.copy(p)
+            if p.dtype == target_dtype or any(
+                k in jax.tree_util.keystr(path) for k in keep)
+            else p.astype(target_dtype),
+            params,
+        )
     else:
         # A no-op astype (param dtype == target_dtype, e.g. bf16 params +
         # bf16 target) returns the SAME array — params and target_params
@@ -234,18 +248,38 @@ def build_train_step(
     batch sharding (parallel/dp.py).  Per-row priorities stay per-shard.
     """
 
+    # A network that says so (``bootstrap_apart``) runs the bootstrap's online
+    # forward apart from the differentiated one: the backward pass, and what
+    # the forward keeps for it, then covers B rows and not 2B.
+    apart = bool(getattr(network, "bootstrap_apart", False))
+    # A network whose layers sow counts (types.ROUTING) reads them itself:
+    # ``routing_metrics(sown)`` for StepMetrics, ``rebalanced(params, sown)``
+    # for what it moves by them after the update (an expert bias).
+    routing_metrics = getattr(network, "routing_metrics", None)
+    rebalanced = getattr(network, "rebalanced", None)
+
+    def q_of(params, obs):
+        """(Q, what the network's layers sowed)."""
+        out, sown = network.apply(params, obs, mutable=[ROUTING])
+        return out[2], sown
+
     def loss_fn(params, target_params, batch: PrioritizedBatch):
         # One scope for the loss: AD names its ops jvp(stage:forward) and
         # the backward pass's transpose(jvp(stage:forward)).
         with stage("forward"):
             t = batch.transition
             B = t.action.shape[0]
-            # One online forward over [obs; next_obs] (2B) instead of two
-            # B-sized passes — bigger matmuls tile better on the MXU.
-            q_both = network.apply(
-                params, jnp.concatenate([t.obs, t.next_obs], axis=0))[2]
-            q_values, q_next_online = q_both[:B], q_both[B:]
-            q_next_target = network.apply(target_params, t.next_obs)[2]
+            if apart:
+                q_values, s1 = q_of(params, t.obs)
+                q_next_online, s2 = q_of(jax.lax.stop_gradient(params), t.next_obs)
+                online = [s1, s2]
+            else:
+                # One online forward over [obs; next_obs] (2B) instead of two
+                # B-sized passes — bigger matmuls tile better on the MXU.
+                q_both, s1 = q_of(
+                    params, jnp.concatenate([t.obs, t.next_obs], axis=0))
+                q_values, q_next_online, online = q_both[:B], q_both[B:], [s1]
+            q_next_target, s3 = q_of(target_params, t.next_obs)
             targets = losses.double_q_target(
                 q_next_online, q_next_target, t.reward, t.discount
             )
@@ -253,12 +287,15 @@ def build_train_step(
             weights = batch.is_weights if use_is_weights else None
             loss = losses.td_loss(
                 delta, weights, kind=loss_kind, huber_kappa=huber_kappa)
-            return loss, (delta, q_values)
+            return loss, (delta, q_values, (online, s3))
 
     def train_step(state: TrainState, batch: PrioritizedBatch):
-        (loss, (delta, q_values)), grads = jax.value_and_grad(
+        (loss, (delta, q_values, (online, sown_target))), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(state.params, state.target_params, batch)
+        add = lambda *trees: jax.tree_util.tree_map(lambda *xs: sum(xs), *trees)  # noqa: E731
+        routing = None if routing_metrics is None else add(
+            *(routing_metrics(s) for s in (*online, sown_target)))
         # Under plain pjit the mean inside loss_fn makes XLA insert the
         # gradient all-reduce over ICI automatically.  Inside shard_map
         # (varying-axes AD semantics): the params enter unvarying while the
@@ -276,6 +313,12 @@ def build_train_step(
             updates, new_opt_state = optimizer.update(
                 grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
+            if rebalanced is not None:
+                # by the online forwards' counts, every shard's together
+                loads = add(*online)
+                if grad_reduce_axis is not None:
+                    loads = jax.lax.psum(loads, grad_reduce_axis)
+                new_params = rebalanced(new_params, loads)
         step = state.step + 1
         if sync_in_step:
             # Intended target sync: copy exactly every target_sync_freq steps
@@ -305,6 +348,7 @@ def build_train_step(
             max_abs_td=max_abs_td,
             priorities=priorities,
             mean_q=mean_q,
+            routing=routing,
         )
         new_state = TrainState(
             params=new_params,

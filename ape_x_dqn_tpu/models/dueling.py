@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ape_x_dqn_tpu.utils.profiling import part
+
 
 class DuelingOutput(NamedTuple):
     """(value, advantage, q) — index [2] for Q, as reference callers do.
@@ -41,6 +43,59 @@ class DuelingOutput(NamedTuple):
 
 def _dueling_aggregate(value: jax.Array, advantage: jax.Array) -> jax.Array:
     return value + advantage - jnp.mean(advantage, axis=-1, keepdims=True)
+
+
+def conv_stem(x: jax.Array, channels: Sequence[int], compute_dtype, param_dtype,
+              out_dtype=None) -> jax.Array:
+    """Conv(8x8/4) -> Conv(4x4/2) -> Conv(3x3/1), VALID, ReLU, on NHWC uint8
+    or float observations: [B, H, W, C] -> [B, h, w, channels[-1]].  Called
+    inside a module's ``@nn.compact`` method; the convolutions are that
+    module's ``Conv_0..2``.  ``out_dtype`` is the type the last convolution
+    sums and returns in (default ``compute_dtype``)."""
+    # Guard against the reference's NCHW layout, which otherwise fails deep
+    # inside flax.
+    if x.ndim != 4:
+        raise ValueError(f"expected NHWC [B, H, W, C] observations, got shape {x.shape}")
+    if x.shape[1] <= 4 and x.shape[3] > 4 and x.shape[2] == x.shape[3]:
+        # A tiny axis-1 extent with a large *square* trailing pair is the
+        # NCHW frame signature (B, C, H, W); square spatial dims keep
+        # legitimate small-H NHWC inputs like (B, 4, 4, 8) usable.
+        raise ValueError(
+            f"observations look NCHW (shape {x.shape}); this framework uses "
+            "NHWC [B, H, W, C] — transpose with x.transpose(0, 2, 3, 1)"
+        )
+    kernels = ((8, 8), (4, 4), (3, 3))
+    strides = ((4, 4), (2, 2), (1, 1))
+    if len(channels) != len(kernels):
+        raise ValueError(
+            f"channels must have exactly {len(kernels)} entries, got {channels}"
+        )
+    with part("stem"):
+        if x.dtype == jnp.uint8:
+            x = x.astype(compute_dtype) / 255.0
+        else:
+            x = x.astype(compute_dtype)
+        dtypes = [compute_dtype] * (len(channels) - 1) + [out_dtype or compute_dtype]
+        for ch, k, s, dtype in zip(channels, kernels, strides, dtypes):
+            x = nn.Conv(ch, k, s, padding="VALID", dtype=dtype,
+                        param_dtype=param_dtype)(x)
+            x = nn.relu(x)
+    return x
+
+
+def dueling_head(x: jax.Array, num_actions: int, hidden: int, compute_dtype,
+                 param_dtype) -> DuelingOutput:
+    """Two ``hidden``-unit streams on flat features [B, F], value (1) and
+    advantage (A) heads in float32, Q = V + A - mean(A).  Called inside a
+    module's ``@nn.compact`` method; the layers are its ``Dense_0..3``."""
+    with part("head"):
+        v = nn.relu(nn.Dense(hidden, dtype=compute_dtype, param_dtype=param_dtype)(x))
+        a = nn.relu(nn.Dense(hidden, dtype=compute_dtype, param_dtype=param_dtype)(x))
+        value = nn.Dense(1, dtype=jnp.float32, param_dtype=param_dtype)(v)
+        advantage = nn.Dense(num_actions, dtype=jnp.float32, param_dtype=param_dtype)(a)
+        value = value.astype(jnp.float32)
+        advantage = advantage.astype(jnp.float32)
+        return DuelingOutput(value, advantage, _dueling_aggregate(value, advantage))
 
 
 class DuelingDQN(nn.Module):
@@ -65,45 +120,9 @@ class DuelingDQN(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        # Accept NHWC uint8 or float; [B, H, W, C].  Guard against the
-        # reference's NCHW layout, which otherwise fails deep inside flax.
-        if x.ndim != 4:
-            raise ValueError(f"expected NHWC [B, H, W, C] observations, got shape {x.shape}")
-        if x.shape[1] <= 4 and x.shape[3] > 4 and x.shape[2] == x.shape[3]:
-            # A tiny axis-1 extent with a large *square* trailing pair is the
-            # NCHW frame signature (B, C, H, W); square spatial dims keep
-            # legitimate small-H NHWC inputs like (B, 4, 4, 8) usable.
-            raise ValueError(
-                f"observations look NCHW (shape {x.shape}); this framework uses "
-                "NHWC [B, H, W, C] — transpose with x.transpose(0, 2, 3, 1)"
-            )
-        if x.dtype == jnp.uint8:
-            x = x.astype(self.compute_dtype) / 255.0
-        else:
-            x = x.astype(self.compute_dtype)
-        kernels = ((8, 8), (4, 4), (3, 3))
-        strides = ((4, 4), (2, 2), (1, 1))
-        if len(self.channels) != len(kernels):
-            raise ValueError(
-                f"channels must have exactly {len(kernels)} entries, got {self.channels}"
-            )
-        for ch, k, s in zip(self.channels, kernels, strides):
-            x = nn.Conv(ch, k, s, padding="VALID", dtype=self.compute_dtype,
-                        param_dtype=self.param_dtype)(x)
-            x = nn.relu(x)
-        x = x.reshape((x.shape[0], -1))
-        v = nn.relu(nn.Dense(self.hidden, dtype=self.compute_dtype,
-                             param_dtype=self.param_dtype)(x))
-        a = nn.relu(nn.Dense(self.hidden, dtype=self.compute_dtype,
-                             param_dtype=self.param_dtype)(x))
-        value = nn.Dense(1, dtype=jnp.float32,
-                         param_dtype=self.param_dtype)(v)
-        advantage = nn.Dense(self.num_actions, dtype=jnp.float32,
-                             param_dtype=self.param_dtype)(a)
-        value = value.astype(jnp.float32)
-        advantage = advantage.astype(jnp.float32)
-        q = _dueling_aggregate(value, advantage)
-        return DuelingOutput(value, advantage, q)
+        x = conv_stem(x, self.channels, self.compute_dtype, self.param_dtype)
+        return dueling_head(x.reshape((x.shape[0], -1)), self.num_actions,
+                            self.hidden, self.compute_dtype, self.param_dtype)
 
     def q_values(self, x: jax.Array) -> jax.Array:
         return self(x)[2]
@@ -157,7 +176,9 @@ def build_greedy_apply(network: nn.Module):
 
 
 def build_network(kind: str, num_actions: int, **kwargs) -> nn.Module:
-    """Factory keyed by config string: {"conv", "nature", "mlp"}."""
+    """Factory keyed by config string: {"conv", "nature", "mlp", "lfm2_moe"}.
+    ``lfm2_moe`` takes ``torso``: the published config's keys and the cut
+    (``models/lfm2_moe.spec_from_config``)."""
     if kind == "conv":
         return DuelingDQN(num_actions=num_actions, **kwargs)
     if kind == "nature":
@@ -165,4 +186,11 @@ def build_network(kind: str, num_actions: int, **kwargs) -> nn.Module:
         return DuelingDQN(num_actions=num_actions, **kwargs)
     if kind == "mlp":
         return DuelingMLP(num_actions=num_actions, **kwargs)
+    if kind == "lfm2_moe":
+        from ape_x_dqn_tpu.models.lfm2_moe import Lfm2MoeQ, spec_from_config
+
+        if not kwargs.get("torso"):
+            raise ValueError("network kind lfm2_moe needs torso=<the block's config>")
+        return Lfm2MoeQ(num_actions=num_actions,
+                        spec=spec_from_config(kwargs.pop("torso")), **kwargs)
     raise ValueError(f"unknown network kind: {kind}")

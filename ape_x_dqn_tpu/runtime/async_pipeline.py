@@ -360,6 +360,10 @@ class AsyncPipeline:
         # fleet is ~8k transitions), so narrow windows see 0 or 1 bursts.
         self._fps = RateCounter(window_s=30.0)
         self._steps_rate = RateCounter(window_s=30.0)
+        # The expert layers' counters of the last logged fused call
+        # (StepMetrics.routing), for the JSONL line and /varz; {} for a
+        # network without such layers.
+        self._routing: dict = {}
         # Per-stage wall-clock accumulators (SURVEY §5 tracing subsystem):
         # µs/step per pipeline stage, exported in every metrics emit.
         self.timers = StageTimer()
@@ -1154,6 +1158,8 @@ class AsyncPipeline:
             )
         except Exception:  # noqa: BLE001 — scrape must not crash
             pass
+        if self._routing:
+            out["routing"] = dict(self._routing)
         return out
 
     def _maybe_eval(self):
@@ -1949,6 +1955,10 @@ class AsyncPipeline:
             # One host sync per log period, not per call.
             self.logger.log("learner/loss", float(np.asarray(metrics.loss)[-1]))
             self.logger.log("learner/mean_q", float(np.asarray(metrics.mean_q)[-1]))
+            if metrics.routing is not None:
+                # Expert layers' counters, a step (mean over the call's K).
+                self._routing = {k: float(np.mean(np.asarray(v)))
+                                 for k, v in metrics.routing.items()}
         return self.logger.emit(
             step=self._learner_step,
             actor_steps=self.worker.actor_steps,
@@ -1960,6 +1970,7 @@ class AsyncPipeline:
             actor_restarts=self.worker.restarts,
             actor_heartbeat_age=round(time.monotonic() - self.worker.heartbeat, 3),
             stage_us=self.timers.us_per_call(),
+            **({"routing": self._routing} if self._routing else {}),
             final=final,
             **self._pipeline_extra(),
             **self._transport_extra(),

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from ape_x_dqn_tpu.actors import ActorFleet
-from ape_x_dqn_tpu.config import ApexConfig
+from ape_x_dqn_tpu.config import ApexConfig, network_kwargs
 from ape_x_dqn_tpu.envs import make_env
 from ape_x_dqn_tpu.learner.train_step import init_train_state, make_optimizer
 from ape_x_dqn_tpu.models.dueling import build_network
@@ -231,7 +231,7 @@ def build_components(cfg: ApexConfig) -> Components:
         )
 
     _dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32, None: None}
-    net_kwargs = {}
+    net_kwargs = network_kwargs(cfg)
     if _dtypes[cfg.learner.param_dtype] is not None:
         net_kwargs["param_dtype"] = _dtypes[cfg.learner.param_dtype]
     network = build_network(cfg.network, num_actions, **net_kwargs)

@@ -259,6 +259,7 @@ def _cfg_from_dict(cfg_dict: dict):
         obs=ObsConfig(**cfg_dict.get("obs", {})),
         chaos=ChaosConfig(**cfg_dict.get("chaos", {})),
         network=cfg_dict["network"],
+        torso=cfg_dict.get("torso", {}),
         seed=cfg_dict["seed"],
     )
 
@@ -272,6 +273,7 @@ def network_and_template(cfg):
     import jax.numpy as jnp
 
     from ape_x_dqn_tpu.envs import make_env
+    from ape_x_dqn_tpu.config import network_kwargs
     from ape_x_dqn_tpu.models.dueling import build_network
 
     env_kwargs = dict(
@@ -281,7 +283,7 @@ def network_and_template(cfg):
         clip_rewards=cfg.env.clip_rewards,
     )
     probe = make_env(cfg.env.name, seed=cfg.seed, **env_kwargs)
-    net_kwargs = {}
+    net_kwargs = network_kwargs(cfg)
     if cfg.learner.param_dtype is not None:
         net_kwargs["param_dtype"] = {
             "bfloat16": jnp.bfloat16, "float32": jnp.float32,

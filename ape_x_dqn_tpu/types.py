@@ -30,6 +30,11 @@ from flax import struct
 Array = jax.Array
 PyTree = Any
 
+# The flax collection a network's layers sow per-call counts in (an expert
+# layer's pairs per expert).  The train step asks every network for it; what a
+# network makes of it is the network's (``routing_metrics``, ``rebalanced``).
+ROUTING = "routing"
+
 
 @struct.dataclass
 class NStepTransition:

@@ -49,6 +49,12 @@ from typing import Dict, Iterator, Optional
 STAGES = ("ingest", "sample", "gather", "forward", "backward", "optimizer",
           "restamp", "target_sync")
 SCOPE_PREFIX = "stage:"   # device side: jax.named_scope("stage:<name>")
+# The parts of a network, under a prefix of their own: the readers of a
+# stage take the innermost ``stage:`` of an op, and a part nested under that
+# prefix would take the network's time out of ``forward`` and lose its
+# backward pass.  A part is read beside its stage, not in its place.
+PARTS = ("stem", "mixer", "router", "experts", "dense_ffn", "head")
+PART_PREFIX = "torso:"    # device side: jax.named_scope("torso:<name>")
 SPAN_PREFIX = "apex:"     # host side: TraceAnnotation("apex:<name>")
 
 
@@ -59,6 +65,15 @@ def stage(name: str):
     import jax
 
     return jax.named_scope(SCOPE_PREFIX + name)
+
+
+def part(name: str):
+    """``jax.named_scope("torso:<name>")`` for a name in ``PARTS``."""
+    if name not in PARTS:
+        raise ValueError(f"unknown part {name!r}; PARTS = {PARTS}")
+    import jax
+
+    return jax.named_scope(PART_PREFIX + name)
 
 
 class StageTimer:
@@ -291,6 +306,25 @@ def hlo_stages(hlo_text: str) -> Dict[str, str]:
     return out
 
 
+_PART = re.compile(re.escape(PART_PREFIX) + r"(\w+)")
+
+
+def hlo_parts(hlo_text: str) -> Dict[str, str]:
+    """{instruction: part} for the instructions whose own ``op_name`` holds a
+    ``torso:<name>`` (the innermost), forward, recomputation and backward
+    alike."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if not m or m.group(1) in out:
+            continue
+        op = _HLO_OP_NAME.search(m.group(2))
+        found = _PART.findall(op.group(1)) if op else []
+        if found:
+            out[m.group(1)] = found[-1]
+    return out
+
+
 def _merged(intervals) -> list:
     out: list = []
     for s, e in sorted(intervals):
@@ -325,7 +359,8 @@ def _own_seconds(events) -> list:
 def summarize_trace(logdir: str) -> dict:
     """Reduce the newest ``*.xplane.pb`` under ``logdir`` with
     ``jax.profiler.ProfileData``: the share of the traced span with an op
-    running on the device; seconds per stage, from the ops inside the runs
+    running on the device; seconds per stage (and per part of the network,
+    ``part_s``, where the program names parts), from the ops inside the runs
     of every fused program whose text is known (``fused_hlo_texts``; ops of
     any other program, the actors' action selection say, are
     ``other_programs``), with the share of that time on instructions the
@@ -342,9 +377,11 @@ def summarize_trace(logdir: str) -> dict:
     if not found:
         raise FileNotFoundError(f"no *.xplane.pb under {logdir}")
     stages: Dict[str, Dict[str, str]] = {}  # program -> instruction -> stage
+    parts: Dict[str, Dict[str, str]] = {}   # program -> instruction -> part
     for program in list(_fused_programs):
         for text in fused_hlo_texts(program):
             stages.setdefault(program, {}).update(hlo_stages(text))
+            parts.setdefault(program, {}).update(hlo_parts(text))
 
     def events(line) -> list:
         return [(e.name, e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
@@ -363,6 +400,7 @@ def summarize_trace(logdir: str) -> dict:
         raise ValueError("the trace holds no device plane with an XLA Ops line")
     busy = span = fused_s = named_s = 0.0
     stage_s: Dict[str, float] = defaultdict(float)
+    part_s: Dict[str, float] = defaultdict(float)
     idle: list = []
     for ops, modules in devices:
         if not ops:
@@ -379,6 +417,8 @@ def summarize_trace(logdir: str) -> dict:
             if i >= 0 and start < runs[i][1]:
                 name = hlo.split(" = ", 1)[0].lstrip("%").strip()
                 stage = stages[runs[i][2]].get(name, OTHER)
+                if name in parts[runs[i][2]]:
+                    part_s[parts[runs[i][2]][name]] += own / len(devices)
                 fused_s += own
                 named_s += own if name in stages[runs[i][2]] else 0.0
             else:
@@ -398,6 +438,9 @@ def summarize_trace(logdir: str) -> dict:
         "devices": len(devices),
         "device_busy_share": busy / span if span else 0.0,
         "stage_s": {k: round(v, 6) for k, v in sorted(stage_s.items())},
+        # seconds on instructions a part of the network names (PARTS),
+        # forward and backward together; a part is read beside its stage
+        "part_s": {k: round(v, 6) for k, v in sorted(part_s.items())},
         "stage_named_share": named_s / fused_s if fused_s else None,
         "host_spans": len(spans),
         "longest_gaps": [[beside(t0, t1), round(length, 6)]

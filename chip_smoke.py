@@ -15,6 +15,12 @@ which owns the chip:
   process   2 worker processes x 8 actors; the children must stay on the CPU
   serving   actors select actions through the in-process PolicyServer
   dp4       learner.data_parallel=4 + replay.dedup (only with >= 4 devices)
+  lfm2moe   configs/config6_lfm2moe_q_ep8.json (the 455 M parameter expert
+            torso at its published widths, batch 512, K=2) with 16 thread
+            actors on the learner's chip, 24 learner steps: action
+            selection serves the network too.  Only with --lfm2moe: the leg
+            compiles for minutes and is the on-chip run ISSUE 28 asks of
+            its builder, not part of the quick proof
 
 Sets no platform itself.  Exits non-zero, with one line saying why and no
 result, before compiling anything if jax's default backend is not a TPU, and
@@ -250,6 +256,36 @@ def leg_dp4() -> None:
     ], 2 * K, inspect)
 
 
+def leg_lfm2moe() -> None:
+    from ape_x_dqn_tpu import train
+
+    steps = 24
+
+    def inspect(pipe, final):
+        check_run("lfm2moe", pipe, final, steps)
+        assert type(pipe.comps.network).__name__ == "Lfm2MoeQ"
+        assert final["param_version"] >= 1, "lfm2moe: nothing was published"
+        routing = final.get("routing") or {}
+        assert routing.get("held_pairs", 0) > 0, f"lfm2moe: no routing counters: {final}"
+        say(f"lfm2moe: routing a step {routing}; actors adopted param_version "
+            f"{pipe.worker.param_version} of {final['param_version']}")
+
+    rc = train.main([
+        "--params-file", os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                      "configs", "config6_lfm2moe_q_ep8.json"),
+        "--set", "env.name=fake-atari",
+        "--set", "actor.mode=thread", "--set", "actor.num_actors=16",
+        "--set", "actor.sync_every=1",
+        # the ring a quarter of the cell's: the actors hold a copy of the
+        # parameters beside the learner's state and temporaries
+        "--set", "replay.capacity=32768",
+        "--set", "learner.min_replay_mem_size=2048",
+        "--set", "learner.publish_every=4",
+        "--log-every", "4", "--steps", str(steps),
+    ], inspect=inspect)
+    assert rc == 0, f"lfm2moe: train.main returned {rc}"
+
+
 def main() -> int:
     t_start = time.perf_counter()
     try:
@@ -298,6 +334,8 @@ def main() -> int:
             ("process", leg_process), ("serving", leg_serving)]
     if len(devs) >= 4:
         legs.append(("dp4", leg_dp4))
+    if "--lfm2moe" in sys.argv[1:]:
+        legs = [("lfm2moe", leg_lfm2moe)]
     for name, fn in legs:
         t0 = time.perf_counter()
         say(f"leg {name}: starts with bytes_in_use per device "
