@@ -248,10 +248,6 @@ def build_train_step(
     batch sharding (parallel/dp.py).  Per-row priorities stay per-shard.
     """
 
-    # A network that says so (``bootstrap_apart``) runs the bootstrap's online
-    # forward apart from the differentiated one: the backward pass, and what
-    # the forward keeps for it, then covers B rows and not 2B.
-    apart = bool(getattr(network, "bootstrap_apart", False))
     # A network whose layers sow counts (types.ROUTING) reads them itself:
     # ``routing_metrics(sown)`` for StepMetrics, ``rebalanced(params, sown)``
     # for what it moves by them after the update (an expert bias).
@@ -268,17 +264,15 @@ def build_train_step(
         # the backward pass's transpose(jvp(stage:forward)).
         with stage("forward"):
             t = batch.transition
-            B = t.action.shape[0]
-            if apart:
-                q_values, s1 = q_of(params, t.obs)
-                q_next_online, s2 = q_of(jax.lax.stop_gradient(params), t.next_obs)
-                online = [s1, s2]
-            else:
-                # One online forward over [obs; next_obs] (2B) instead of two
-                # B-sized passes — bigger matmuls tile better on the MXU.
-                q_both, s1 = q_of(
-                    params, jnp.concatenate([t.obs, t.next_obs], axis=0))
-                q_values, q_next_online, online = q_both[:B], q_both[B:], [s1]
+            q_values, s1 = q_of(params, t.obs)
+            # The bootstrap's online forward runs apart from the
+            # differentiated one.  next_obs reaches the loss through an argmax
+            # only: joined with obs in one 2B forward, its B rows ride through
+            # the whole backward pass with cotangents of zero (neither jax nor
+            # XLA drops them) and the forward keeps their activations.  Joining
+            # saves one read of the parameters and never paid for that on a
+            # v5e, not even at B=32 (PERF.md section 6, PR 29).
+            q_next_online, s2 = q_of(jax.lax.stop_gradient(params), t.next_obs)
             q_next_target, s3 = q_of(target_params, t.next_obs)
             targets = losses.double_q_target(
                 q_next_online, q_next_target, t.reward, t.discount
@@ -287,7 +281,7 @@ def build_train_step(
             weights = batch.is_weights if use_is_weights else None
             loss = losses.td_loss(
                 delta, weights, kind=loss_kind, huber_kappa=huber_kappa)
-            return loss, (delta, q_values, (online, s3))
+            return loss, (delta, q_values, ((s1, s2), s3))
 
     def train_step(state: TrainState, batch: PrioritizedBatch):
         (loss, (delta, q_values, (online, sown_target))), grads = jax.value_and_grad(

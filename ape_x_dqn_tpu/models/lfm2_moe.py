@@ -345,9 +345,6 @@ class Lfm2MoeQ(nn.Module):
     compute_dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
-    # The train step runs the bootstrap's online forward apart from the one
-    # it differentiates, so that the backward pass holds one batch.
-    bootstrap_apart = True
     # A target network in a lower type keeps these leaves in float32: the
     # router's scores and bias decide a top-k.
     float32_leaves = ("router", "expert_bias")

@@ -10,11 +10,17 @@ Stored as ``[Cf, 84, 84, 4]`` the chip put the ring index in the lanes and
 every program copied the whole ring (``copy.3``/``copy.5`` around the
 scatter, ``copy.25`` before the gather).
 
+The fused program at the paper's shapes (``benchmark/configs/apex_b512.json``,
+one chip and the four-chip shard) holds no convolution or product over two
+batches of rows: the backward pass covers the rows that carry a gradient.
+
 Every test here shares one description of the topology, made in a fixture:
 only one process may load the TPU's library (on-chip-measurement guide).
 """
 
+import json
 import os
+import pathlib
 import re
 import threading
 
@@ -38,10 +44,22 @@ from ape_x_dqn_tpu.replay.device_dedup_dp import (
 )
 
 OBS = (84, 84, 4)
-SLOTS, FRAMES = 4096, 5120      # a chip's ring in the benchmark's check
 ROWS, BLOCK = 256, 320          # transitions and observations a call
-BATCH, K = 32, 2                # global batch; 8 a chip over four
+# A chip's ring in the benchmark's check; global batch 32, 8 a chip over four.
+CHECK = dict(channels=(8, 8, 8), hidden=32, actions=4, batch=32, k=2,
+             slots=4096, frames=5120)
 COMPILE_LIMIT_S = 240.0
+
+
+def _paper() -> dict:
+    """``apex_b512`` as its cells run it: network, batch, K, a chip's ring."""
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / "apex_b512.json").read_text())
+    assert tuple(cfg["obs_shape"]) == OBS and cfg["replay_layout"] == "dedup"
+    return dict(channels=tuple(cfg["channels"]), hidden=cfg["hidden"],
+                actions=cfg["num_actions"], batch=cfg["batch_size"],
+                k=cfg["steps_per_call"], slots=cfg["replay_capacity"],
+                frames=int(cfg["replay_capacity"] * cfg["frame_ratio"]))
 
 # ------------------------------------------------ reading the optimized text
 
@@ -147,12 +165,12 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _learner():
+def _learner(shapes: dict):
     from ape_x_dqn_tpu.learner.train_step import init_train_state, make_optimizer
     from ape_x_dqn_tpu.models.dueling import build_network
 
-    net = build_network("nature", 4, channels=(8, 8, 8), hidden=32,
-                        compute_dtype=jnp.bfloat16)
+    net = build_network("nature", shapes["actions"], channels=shapes["channels"],
+                        hidden=shapes["hidden"], compute_dtype=jnp.bfloat16)
     opt = make_optimizer("rmsprop", learning_rate=1e-4)
     state = jax.eval_shape(
         lambda k: init_train_state(net, opt, k, jnp.zeros((1, *OBS), jnp.uint8)),
@@ -165,18 +183,18 @@ def _with(tree, sharding):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)
 
 
-def _programs(topo, n: int) -> dict:
+def _programs(topo, n: int, shapes: dict = CHECK) -> dict:
     """{name: (jitted, argument shapes)} of the three programs, on the first
     chip of the topology (``n`` = 1) or under ``shard_map`` over ``n``."""
     from ape_x_dqn_tpu.learner.train_step import build_train_step
 
-    net, opt, tstate = _learner()
+    net, opt, tstate = _learner(shapes)
     step_fn = build_train_step(
         net, opt, loss_kind="squared", sync_in_step=False, jit=False,
         grad_reduce_axis="data" if n > 1 else None)
     ring = jax.eval_shape(
-        lambda: init_dedup_device_replay(SLOTS * n, OBS, frame_capacity=FRAMES * n))
-    kw = dict(steps_per_call=K, target_sync_freq=K, sample_ahead=True)
+        lambda: init_dedup_device_replay(shapes["slots"] * n, OBS, frame_capacity=shapes["frames"] * n))
+    kw = dict(steps_per_call=shapes["k"], target_sync_freq=shapes["k"], sample_ahead=True)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     if n == 1:
         dev = SingleDeviceSharding(topo.devices[0])
@@ -187,7 +205,7 @@ def _programs(topo, n: int) -> dict:
         add_t = jax.jit(
             lambda st, *a: dedup_device_add_transitions(st, *a, 0.6),
             donate_argnums=(0,))
-        fused = build_dedup_fused_learn_step(step_fn, BATCH, **kw)
+        fused = build_dedup_fused_learn_step(step_fn, shapes["batch"], **kw)
     else:
         mesh = Mesh(np.array(topo.devices[:n]), ("data",))
         row = NamedSharding(mesh, P("data"))
@@ -199,7 +217,7 @@ def _programs(topo, n: int) -> dict:
         lead = (n,)
         add_f = build_sharded_dedup_add_frames(mesh)
         add_t = build_sharded_dedup_add_transitions(mesh, 0.6)
-        fused = build_sharded_dedup_fused_learn_step(step_fn, mesh, BATCH, **kw)
+        fused = build_sharded_dedup_fused_learn_step(step_fn, mesh, shapes["batch"], **kw)
         assert jax.tree_util.tree_structure(ring) == jax.tree_util.tree_structure(
             dedup_replay_specs())
     vec = lambda dt: arg((*lead, ROWS), dt)  # noqa: E731
@@ -239,8 +257,63 @@ def _compile_text(jitted, args) -> str:
 @pytest.mark.parametrize("chips", [1, 4])
 def test_no_program_copies_the_ring(topo, no_compile_cache, chips, program, scatters):
     jitted, args = _programs(topo, chips)[program]
-    ring_bytes = FRAMES * int(np.prod(OBS))  # a chip's ring, unpadded
+    ring_bytes = CHECK["frames"] * int(np.prod(OBS))  # a chip's ring, unpadded
     assert_ring_stays_put(_compile_text(jitted, args), ring_bytes, scatters)
+
+
+def convolution_dims(hlo_text: str) -> dict:
+    """{name: every dimension of its result and operands} of the optimized
+    module's convolutions (on the TPU a matrix product is one too).  A
+    weight gradient contracts over the rows, so they show in its operands."""
+    shapes, convs = {}, {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            shapes[m.group("name")] = m.group("shape")
+            if m.group("op") == "convolution":
+                convs[m.group("name")] = re.findall(r"%([\w.\-]+)", m.group("args").split(")")[0])
+    return {name: {int(d) for text in (shapes[name], *(shapes[o] for o in operands))
+                   for _, dims, _ in _ARRAY.findall(text) for d in dims.split(",") if d}
+            for name, operands in convs.items()}
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_no_convolution_at_paper_shapes_covers_two_batches(topo, no_compile_cache, chips):
+    """``apex_b512``'s fused program: the convolutions and products of three
+    forwards and one backward pass, every one over a chip's 512 rows (128 of
+    four).  Joined with ``obs``, the bootstrap's rows made the online forward
+    and the whole backward pass 1,024 (256) rows long."""
+    shapes = _paper()
+    jitted, args = _programs(topo, chips, shapes)["fused"]
+    rows = shapes["batch"] // chips
+    convs = convolution_dims(_compile_text(jitted, args))
+    doubled = {name: sorted(dims) for name, dims in convs.items() if 2 * rows in dims}
+    assert not doubled, doubled
+    # 7 a forward and 12 backward, less what the compiler merges
+    assert sum(rows in dims for dims in convs.values()) >= 20, sorted(convs)
+
+
+def test_reader_finds_a_joined_forward():
+    """The reader on a forward over ``[obs; next_obs]`` and its weight
+    gradient, as the optimized text had them until PR 29."""
+    text = """HloModule jit_fused
+
+%fused_computation.1 (p0: bf16[1024,20,20,32], p1: bf16[4,4,32,64]) -> bf16[1024,9,9,64] {
+  %p0 = bf16[1024,20,20,32]{3,0,2,1:T(8,128)(2,1)} parameter(0)
+  %p1 = bf16[4,4,32,64]{3,2,1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %conv_general_dilated.1 = bf16[1024,9,9,64]{3,0,2,1:T(8,128)(2,1)} convolution(%p0, %p1), window={size=4x4 stride=2x2}, dim_labels=b01f_01io->b01f
+}
+
+%fused_computation.2 (p2: bf16[1024,20,20,32], p3: bf16[1024,9,9,64]) -> bf16[4,4,32,64] {
+  %p2 = bf16[1024,20,20,32]{3,0,2,1:T(8,128)(2,1)} parameter(0)
+  %p3 = bf16[1024,9,9,64]{3,0,2,1:T(8,128)(2,1)} parameter(1)
+  ROOT %conv_general_dilated.2 = bf16[4,4,32,64]{3,2,1,0:T(8,128)(2,1)} convolution(%p2, %p3), window={size=9x9 rhs_dilate=2x2}, dim_labels=f01b_i01o->01bf
+}
+"""
+    convs = convolution_dims(text)
+    assert sorted(convs) == ["conv_general_dilated.1", "conv_general_dilated.2"]
+    assert all(1024 in dims for dims in convs.values())
+    assert convs["conv_general_dilated.2"] == {1024, 20, 32, 9, 64, 4}
 
 
 def test_reader_finds_a_copied_ring():

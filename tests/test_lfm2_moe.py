@@ -191,7 +191,7 @@ def test_other_networks_report_no_routing():
     net = build_network("nature", 6, channels=(8, 8, 8), hidden=32)
     step, state, batch = _train_pieces(net)
     _, metrics = jax.jit(step)(state, batch)
-    assert metrics.routing is None and not getattr(net, "bootstrap_apart", False)
+    assert metrics.routing is None
 
 
 def test_parts_are_scoped_beside_the_stages():

@@ -1,8 +1,14 @@
-"""Fused train-step tests: descent, target sync cadence, priorities."""
+"""Fused train-step tests: descent, target sync cadence, priorities, and the
+rows its forwards and its backward pass cover."""
+
+import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
+import pytest
 
 from ape_x_dqn_tpu.learner.train_step import (
     StepMetrics,
@@ -10,7 +16,8 @@ from ape_x_dqn_tpu.learner.train_step import (
     init_train_state,
     make_optimizer,
 )
-from ape_x_dqn_tpu.models.dueling import DuelingMLP
+from ape_x_dqn_tpu.models.dueling import DuelingMLP, build_network
+from ape_x_dqn_tpu.ops import losses
 from ape_x_dqn_tpu.types import NStepTransition, PrioritizedBatch
 
 
@@ -145,3 +152,164 @@ def test_bf16_params_with_f32_master_track_f32_training():
         np.testing.assert_array_equal(
             np.asarray(m.astype(jnp.bfloat16)), np.asarray(p)
         )
+
+
+# ------------------------------------------- the rows a step's products cover
+
+def product_rows(jaxpr) -> set:
+    """The leading dimension of every operand and result of every convolution
+    and ``dot_general`` of a jaxpr, nested jaxprs included."""
+    rows = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("conv_general_dilated", "dot_general"):
+            rows |= {v.aval.shape[0] for v in (*eqn.invars, *eqn.outvars) if v.aval.shape}
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    rows |= product_rows(sub)
+    return rows
+
+
+def _recording(opt: optax.GradientTransformation) -> optax.GradientTransformation:
+    """``opt``, with the gradients it was last given kept beside its state."""
+
+    def init(params):
+        return opt.init(params), jax.tree_util.tree_map(jnp.zeros_like, params)
+
+    def update(grads, state, params=None):
+        updates, inner = opt.update(grads, state[0], params)
+        return updates, (inner, grads)
+
+    return optax.GradientTransformation(init, update)
+
+
+def _joined_step(net, opt, loss_kind, axis):
+    """The oracle: the step as it was written until PR 29, one online forward
+    over ``[obs; next_obs]``, whose backward pass covers 2B rows.  Returns
+    (loss, priorities, gradients, updated parameters)."""
+
+    def loss_fn(params, target_params, batch):
+        t = batch.transition
+        B = t.action.shape[0]
+        q_both = net.apply(params, jnp.concatenate([t.obs, t.next_obs], axis=0))[2]
+        targets = losses.double_q_target(
+            q_both[B:], net.apply(target_params, t.next_obs)[2], t.reward, t.discount)
+        delta = losses.td_error(q_both[:B], t.action, targets)
+        return losses.td_loss(delta, batch.is_weights, kind=loss_kind), delta
+
+    def step(state, batch):
+        (loss, delta), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state.params, state.target_params, batch)
+        if axis is not None:  # as build_train_step says of shard_map
+            grads = jax.tree_util.tree_map(lambda g: g / jax.lax.psum(1, axis), grads)
+            loss = jax.lax.pmean(loss, axis)
+        updates, _ = opt.update(grads, state.opt_state, state.params)
+        return (loss, losses.priorities_from_td(delta, 1e-6), grads,
+                optax.apply_updates(state.params, updates))
+
+    return step
+
+
+SMALL = {
+    "conv": (dict(channels=(8, 8, 8), hidden=32, compute_dtype=jnp.float32), (44, 44, 2)),
+    "nature": (dict(channels=(8, 16, 16), hidden=32, compute_dtype=jnp.float32), (44, 44, 4)),
+    "mlp": (dict(hidden_sizes=(32,)), (7,)),
+}
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["one_chip", "shard_map"])
+@pytest.mark.parametrize("loss_kind", ["huber", "squared"])
+@pytest.mark.parametrize("kind", sorted(SMALL))
+def test_step_is_the_joined_forward_on_half_the_backward_rows(kind, loss_kind, sharded):
+    """Two B-row online forwards give the loss, priorities, gradients and
+    updated parameters of one 2B-row forward, and no product of the step
+    covers 2B rows, where the joined form's do."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from ape_x_dqn_tpu.parallel import make_mesh
+
+    kwargs, obs_shape = SMALL[kind]
+    n, B = 4 if sharded else 1, 12
+    axis = "data" if sharded else None
+    net = build_network(kind, 5, **kwargs)
+    opt = make_optimizer("rmsprop", learning_rate=1e-2, max_grad_norm=1.0)
+    state = init_train_state(net, _recording(opt), jax.random.PRNGKey(0),
+                             jnp.zeros((1, *obs_shape)))
+    # a target that differs from the online net, so the argmax matters
+    state = state.replace(target_params=jax.tree_util.tree_map(
+        lambda p: p * 0.9 + 0.01, state.params))
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    batch = PrioritizedBatch(
+        transition=NStepTransition(
+            obs=jax.random.normal(ks[0], (B, *obs_shape)),
+            action=jax.random.randint(ks[1], (B,), 0, 5),
+            reward=jax.random.normal(ks[2], (B,)),
+            discount=jnp.full((B,), 0.97),
+            next_obs=jax.random.normal(ks[3], (B, *obs_shape)),
+        ),
+        indices=jnp.arange(B, dtype=jnp.int32),
+        is_weights=jax.random.uniform(ks[4], (B,), minval=0.2, maxval=1.0),
+    )
+    step = build_train_step(net, _recording(opt), loss_kind=loss_kind, sync_in_step=False,
+                            grad_reduce_axis=axis, jit=False)
+
+    def program(state, batch):
+        new, m = step(state, batch)
+        return m.loss, m.priorities, new.opt_state[1], new.params
+
+    oracle_state = state.replace(opt_state=state.opt_state[0])
+    oracle = lambda batch: _joined_step(net, opt, loss_kind, axis)(oracle_state, batch)  # noqa: E731
+    run = lambda batch: program(state, batch)  # noqa: E731
+    if sharded:
+        wrap = lambda f: shard_map(  # noqa: E731
+            f, mesh=make_mesh(n), in_specs=(P("data"),),
+            out_specs=(P(), P("data"), P(), P()))
+        run, oracle = wrap(run), wrap(oracle)
+    got, want = jax.jit(run)(batch), jax.jit(oracle)(batch)
+    for name, g, w in zip(("loss", "priorities", "gradients", "parameters"), got, want):
+        for a, b in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(w)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=1e-6,
+                                       err_msg=name)
+    assert float(jnp.max(jnp.abs(jax.tree_util.tree_leaves(got[2])[0]))) > 1e-4
+    rows, seen = B // n, product_rows(jax.make_jaxpr(run)(batch).jaxpr)
+    assert rows in seen and 2 * rows not in seen, sorted(seen)
+    assert 2 * rows in product_rows(jax.make_jaxpr(oracle)(batch).jaxpr)
+
+
+CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+
+
+@pytest.mark.parametrize("config,rows,tokens", [
+    ("ref_b32", 32, 1), ("apex_b512", 512, 1), ("apex_b512", 128, 1),
+    ("lfm2moe_q_ep8", 512, 49)],
+    ids=["ref_b32", "apex_b512", "apex_b512_shard_of_4", "lfm2moe_q_ep8"])
+def test_no_product_covers_two_batches_at_the_cells_shapes(config, rows, tokens):
+    """The step traced at the benchmark's shapes (abstract, nothing compiled
+    or allocated): every convolution and matrix product, forward and
+    backward, covers one batch of rows or of tokens, whatever the network."""
+    cfg = json.loads((CONFIGS / f"{config}.json").read_text())
+    prec = cfg["precision"]
+    kw = dict(channels=tuple(cfg["channels"]), hidden=cfg["hidden"],
+              compute_dtype=jnp.dtype(prec["compute"]))
+    if "layer_types" in cfg:
+        kw["torso"] = cfg
+    net = build_network(cfg["network"], cfg["num_actions"], **kw)
+    opt = make_optimizer(cfg["optimizer"], max_grad_norm=cfg["max_grad_norm"],
+                         second_moment_dtype=jnp.dtype(prec["second_moment"]))
+    step = build_train_step(net, opt, loss_kind=cfg["loss"], sync_in_step=False, jit=False)
+    obs = jax.ShapeDtypeStruct((rows, *cfg["obs_shape"]), jnp.uint8)
+    vec = lambda dt: jax.ShapeDtypeStruct((rows,), dt)  # noqa: E731
+    batch = PrioritizedBatch(
+        transition=NStepTransition(obs=obs, action=vec(jnp.int32), reward=vec(jnp.float32),
+                                   discount=vec(jnp.float32), next_obs=obs),
+        indices=vec(jnp.int32), is_weights=vec(jnp.float32))
+    state = jax.eval_shape(
+        lambda key: init_train_state(
+            net, opt, key, jnp.zeros((1, *cfg["obs_shape"]), jnp.uint8),
+            target_dtype=jnp.dtype(prec["target_params"])),
+        jax.random.PRNGKey(0))
+    seen = product_rows(jax.make_jaxpr(step)(state, batch).jaxpr)
+    assert rows in seen and rows * tokens in seen
+    assert not seen & {2 * rows, 2 * rows * tokens}, sorted(seen)
