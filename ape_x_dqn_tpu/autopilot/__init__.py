@@ -10,7 +10,7 @@ rollup and driving CAPACITY, not just recovery:
   * **actor fleet** — grow/retire worker processes through the pool's
     elastic primitives (``ProcessActorPool.grow``/``retire``: fresh wids
     on the SAME global ε-ladder partition, scale-down via clean drain,
-    never SIGKILL) and tune the drain budget / pipeline depth, to hold
+    never SIGKILL) and tune the drain budget, to hold
     age-of-experience p95 under its bound and ring occupancy in band;
   * **serving fleet** — grow/retire replicas through
     ``ServingFleet.spawn()`` and the router's proven zero-drop

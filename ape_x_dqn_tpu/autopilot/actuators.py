@@ -3,7 +3,7 @@
 Thin, state-light adapters: every capacity primitive they call is owned
 by the fleet object itself (``ProcessActorPool.grow``/``retire``/
 ``set_drain_budget``, ``ServingFleet.spawn``/``retire``,
-``ReplayServiceFleet.grow``/``retire``, ``DispatchPipeline.degrade``) —
+``ReplayServiceFleet.grow``/``retire``) —
 the actuator only names the protocol the controller speaks
 (``size``/``busy``/``scale_up``/``scale_down`` + the actor loop's
 tuning ladder), so unit tests drive the controller with dict-recording
@@ -16,18 +16,11 @@ from typing import Callable, Optional
 
 
 class ActorPoolActuator:
-    """Actor-fleet actuator over a ``ProcessActorPool``.
+    """Actor-fleet actuator over a ``ProcessActorPool``."""
 
-    ``pipeline_fn`` (optional) resolves the live DispatchPipeline at
-    call time — AsyncPipeline constructs it after the pool, so a
-    deferred lookup is the only correct binding.
-    """
-
-    def __init__(self, pool, *, pipeline_fn: Optional[Callable] = None):
+    def __init__(self, pool):
         self._pool = pool
-        self._pipeline_fn = pipeline_fn
         self._drain_base = max(1, int(pool.drain_budget_bytes))
-        self._pipeline_tuned = False
 
     def size(self) -> int:
         return len(self._pool.live_workers())
@@ -59,18 +52,6 @@ class ActorPoolActuator:
         )
         return {"drain_budget_bytes": budget,
                 "factor": round(self.drain_factor(), 2)}
-
-    def tune_pipeline(self) -> Optional[dict]:
-        """Ceiling fallback: degrade the overlapped dispatch pipeline to
-        strict depth 1 (fresher priority write-backs) — once."""
-        if self._pipeline_tuned or self._pipeline_fn is None:
-            return None
-        pipeline = self._pipeline_fn()
-        if pipeline is None or getattr(pipeline, "depth", 1) <= 1:
-            return None
-        pipeline.degrade()
-        self._pipeline_tuned = True
-        return {"pipeline_depth": pipeline.depth}
 
 
 class ServingFleetActuator:

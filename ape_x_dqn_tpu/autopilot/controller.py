@@ -16,10 +16,7 @@ Control law, per fleet, per tick:
   * the actor loop's ring-occupancy-high response is a LADDER: tune the
     pool's drain budget up (×2 per action, bounded by
     ``autopilot.drain_tune_max_factor``) before any worker is retired —
-    drain harder first, shrink the fleet last;
-  * when scale-up is wanted but the fleet is at its ceiling, the actor
-    loop degrades the dispatch pipeline to strict depth 1 instead
-    (fresher priority write-backs — the same lever the watchdog pulls).
+    drain harder first, shrink the fleet last.
 
 Every decision passes :class:`Guardrails` — min/max bounds,
 per-direction cooldowns, a hold window against the opposite direction,
@@ -200,7 +197,7 @@ class AutopilotController:
     def attach_actor(self, actuator) -> "AutopilotController":
         """Actor-fleet actuator (autopilot/actuators.ActorPoolActuator
         shape: size/capacity/busy/scale_up/scale_down/tune_drain/
-        drain_factor/tune_pipeline)."""
+        drain_factor)."""
         self._make_fleet(
             "actor", actuator,
             min_size=self.cfg.actor_min_workers,
@@ -338,15 +335,6 @@ class AutopilotController:
             rule = ups[0]
             reason = fleet.guard.check("up", act.size(), now,
                                        busy=act.busy())
-            if reason == "at_max" and fleet.name == "actor":
-                # Ceiling ladder: no more workers to add — degrade the
-                # dispatch pipeline to strict depth instead (fresher
-                # priorities), once.
-                tune = getattr(act, "tune_pipeline", None)
-                if tune is not None and fleet.guard.check(
-                        "up", act.size(), now, bounded=False) is None:
-                    return self._fire(fleet, "up", "tune_pipeline", rule,
-                                      tune, now)
             if reason is not None:
                 self._suppress(fleet, "up", reason)
                 return None

@@ -292,24 +292,6 @@ class LearnerConfig:
     # priority staleness, the same order the async Ape-X loop already
     # tolerates.  False is strict sequential PER (the test oracle).
     sample_ahead: bool = False
-    # Overlapped dispatch pipeline (runtime/infeed.DispatchPipeline): max
-    # fused dispatches in flight with no blocking host read between them.
-    # 1 = strict (force each call before the next dispatch — the legacy
-    # fused_inflight policy).  >1 chains dispatches back-to-back: metric
-    # outputs come back via async device→host copies drained one dispatch
-    # behind, so the learner thread blocks on the device once per sync
-    # instead of once per call, and host-side ingest staging runs on its
-    # own thread while the device scans (double-buffered ingest).  On the host-replay path, >1 batches the
-    # deferred priority write-back over this many steps instead of one.
-    pipeline_depth: int = 1
-    # Steps between full host syncs of the overlapped pipeline (drain every
-    # in-flight dispatch, blocking).  Bounds how stale the host's view of
-    # loss/metrics can get and is the knob the pipeline-smoke gate asserts
-    # against (host_syncs <= steps/sync_every + slack).  0 = no cadence
-    # sync: the pipeline only blocks when a not-yet-ready dispatch must be
-    # drained for flow control (depth reached) or at emit/exit boundaries.
-    # Fused (device_replay) mode only; ignored at pipeline_depth=1.
-    sync_every: int = 0
 
 
 @dataclasses.dataclass
@@ -613,11 +595,10 @@ class SupervisorConfig:
     # of hot-looping spawns against a deterministic crash.
     crash_loop_window_s: float = 120.0
     crash_loop_budget: int = 5
-    # Learner watchdog: no observable progress (learner step or host-sync
-    # count) for stall_deadline_s degrades the dispatch pipeline to strict
-    # depth 1; still no progress wedge_deadline_s later declares the run
-    # wedged (structured event + /healthz 503) — the operator signal, not
-    # an automatic kill.
+    # Learner watchdog: no observable progress (the learner step) for
+    # stall_deadline_s raises a degraded event; still no progress
+    # wedge_deadline_s later declares the run wedged (structured event +
+    # /healthz 503) — the operator signal, not an automatic kill.
     stall_deadline_s: float = 120.0
     wedge_deadline_s: float = 120.0
     poll_s: float = 0.5                   # supervisor thread cadence
@@ -763,9 +744,6 @@ class ChaosConfig:
     # Flip one byte in a committed APXC chunk file (the restore-fallback
     # path's trigger; takes effect at the next restore, not mid-run).
     corrupt_chunk_interval_s: float = 0.0
-    # Hold the fused-mode ingest stager idle for stuck_stager_hold_s.
-    stuck_stager_interval_s: float = 0.0
-    stuck_stager_hold_s: float = 1.0
     # Transient /dev/shm pressure: allocate shm_fill_bytes for hold_s.
     shm_fill_interval_s: float = 0.0
     shm_fill_bytes: int = 64 << 20
@@ -805,8 +783,6 @@ class ChaosConfig:
             ("sigstop_hold_s", self.sigstop_hold_s),
             ("torn_record_interval_s", self.torn_record_interval_s),
             ("corrupt_chunk_interval_s", self.corrupt_chunk_interval_s),
-            ("stuck_stager_interval_s", self.stuck_stager_interval_s),
-            ("stuck_stager_hold_s", self.stuck_stager_hold_s),
             ("shm_fill_interval_s", self.shm_fill_interval_s),
             ("shm_fill_hold_s", self.shm_fill_hold_s),
             ("env_latency_ms", self.env_latency_ms),
@@ -1085,11 +1061,6 @@ class ApexConfig:
              f"unknown optimizer kind: {l.optimizer}"),
             (l.loss in ("huber", "squared"), f"unknown loss kind: {l.loss}"),
             (l.steps_per_call >= 1, "learner.steps_per_call must be >= 1"),
-            (l.pipeline_depth >= 1, "learner.pipeline_depth must be >= 1"),
-            (l.sync_every >= 0, "learner.sync_every must be >= 0"),
-            (not l.sync_every or l.device_replay,
-             "learner.sync_every requires device_replay=True (it paces "
-             "the overlapped fused-dispatch pipeline)"),
             (l.ingest_block >= 1, "learner.ingest_block must be >= 1"),
             (not (l.device_replay and l.data_parallel > 1)
              or l.ingest_block % l.data_parallel == 0,

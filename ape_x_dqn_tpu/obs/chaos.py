@@ -16,9 +16,9 @@ seen has an injector here:
     under a live writer would corrupt the SPSC discipline itself.
   * **Corrupted APXC chunk** — one byte flipped (or the file truncated) in
     a committed checkpoint chunk: the restore fallback's trigger.
-  * **Stuck stager / slow env / /dev/shm pressure** — liveness and
-    capacity faults: a gate the ingest stager polls, a latency wrapper
-    around worker envs, a transient shared-memory allocation.
+  * **Slow env / /dev/shm pressure** — liveness and capacity faults: a
+    latency wrapper around worker envs, a transient shared-memory
+    allocation.
 
 ``ChaosMonkey`` sequences these on a schedule derived entirely from
 ``chaos.seed`` (config.ChaosConfig): same seed, same fault times, same
@@ -237,16 +237,15 @@ class ChaosMonkey:
     chunk, which byte) come from the same rng, so the whole fault sequence
     is a pure function of ``(config, seed)``.
 
-    Targets are late-bound: ``attach(pool=..., ckpt_dirs=...,
-    stager_gate=...)`` — the tools construct the monkey before the
-    pipeline exists.  Every executed fault lands in ``self.log`` (a
-    bounded list of dicts), on the optional metrics registry
-    (``chaos/<kind>`` counters), and through the optional ``emit``
-    callback (the JSONL stream).
+    Targets are late-bound: ``attach(pool=..., ckpt_dirs=...)`` — the
+    tools construct the monkey before the pipeline exists.  Every
+    executed fault lands in ``self.log`` (a bounded list of dicts), on the
+    optional metrics registry (``chaos/<kind>`` counters), and through
+    the optional ``emit`` callback (the JSONL stream).
     """
 
     KINDS = ("kill", "sigstop", "torn_record", "corrupt_chunk",
-             "stuck_stager", "shm_fill", "kill_shard")
+             "shm_fill", "kill_shard")
 
     def __init__(self, cfg, registry=None, emit=None,
                  horizon_s: float = 3600.0):
@@ -265,7 +264,6 @@ class ChaosMonkey:
         self._pool = None
         self._replay_fleet = None   # ReplayServiceFleet (kill_shard kind)
         self._ckpt_dirs: List[str] = []
-        self._stager_stall = threading.Event()
         self._filler = ShmFiller()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -279,7 +277,6 @@ class ChaosMonkey:
             "sigstop": self.cfg.sigstop_interval_s,
             "torn_record": self.cfg.torn_record_interval_s,
             "corrupt_chunk": self.cfg.corrupt_chunk_interval_s,
-            "stuck_stager": self.cfg.stuck_stager_interval_s,
             "shm_fill": self.cfg.shm_fill_interval_s,
             "kill_shard": getattr(self.cfg, "kill_shard_interval_s", 0.0),
         }
@@ -308,10 +305,6 @@ class ChaosMonkey:
             self._replay_fleet = replay_fleet
         return self
 
-    def stager_stalled(self) -> bool:
-        """Polled by the ingest stager's loop (the stuck-stager gate)."""
-        return self._stager_stall.is_set()
-
     def state(self) -> dict:
         by_kind = {}
         for rec in self.log:
@@ -320,7 +313,6 @@ class ChaosMonkey:
             "scheduled": len(self.schedule),
             "executed": len(self.log),
             "by_kind": by_kind,
-            "stager_stalled": self._stager_stall.is_set(),
         }
 
     def counts(self) -> dict:
@@ -339,7 +331,6 @@ class ChaosMonkey:
 
     def stop(self) -> None:
         self._stop.set()
-        self._stager_stall.clear()
         self._filler.release()
         if self._thread is not None:
             self._thread.join(timeout=10.0)
@@ -391,8 +382,6 @@ class ChaosMonkey:
                 return self._do_sigstop()
             if kind == "corrupt_chunk":
                 return self._do_corrupt_chunk()
-            if kind == "stuck_stager":
-                return self._do_stuck_stager()
             if kind == "shm_fill":
                 return self._do_shm_fill()
             if kind == "kill_shard":
@@ -452,13 +441,6 @@ class ChaosMonkey:
                     return self._record(corrupt_chunk(path, rng=self._rng))
         return self._record({"fault": "corrupt_chunk",
                              "skipped": "no committed chunks"})
-
-    def _do_stuck_stager(self) -> dict:
-        hold = float(self.cfg.stuck_stager_hold_s)
-        self._stager_stall.set()
-        self._stop.wait(hold)
-        self._stager_stall.clear()
-        return self._record({"fault": "stuck_stager", "hold_s": hold})
 
     def _do_kill_shard(self) -> Optional[dict]:
         """SIGKILL one live replay-service shard (seeded victim) — the

@@ -1,8 +1,7 @@
 """Two-tier frame store: hot DRAM span cache over a CRC-framed cold file.
 
 ROADMAP item 6 ("break the DRAM wall on the frame ring"): the 2M-slot dedup
-layout pins 17.6 GB of frames in one host's DRAM (bench.py
-``host_dedup_2m.frames_gb``) — capacity, not speed, is the binding
+layout pins 17.6 GB of frames in one host's DRAM — capacity, not speed, is the binding
 constraint on replay scale.  This module is the cold tier that decouples
 them, the way external replay services (Reverb) decouple replay capacity
 from learner memory:
@@ -32,8 +31,8 @@ from learner memory:
     crosses the high one.  Spilling a clean span (disk copy current) is
     free: drop the block.  The owning replay exposes ``spill_cold()`` and
     a ``TierEvictor`` thread calls it off the learner's critical path
-    (runtime/async_pipeline — same discipline as the ingest stager and
-    the checkpoint writer).
+    (runtime/async_pipeline — same discipline as the checkpoint
+    writer).
   * **Checkpoint refs** — ``cold_refs()`` describes every cold span as
     (span id, file offset, length, crc): an incremental base snapshot of
     a mostly-cold replay embeds its *hot* frames and references the cold
@@ -1018,8 +1017,8 @@ class SpanTierIndex:
 
 
 class TierEvictor(threading.Thread):
-    """Background eviction — the stager/writer-thread pattern applied to
-    the cold tier: the learner thread never pays for a spill; it only
+    """Background eviction — the checkpoint writer's thread pattern
+    applied to the cold tier: the learner thread never pays for a spill; it only
     faults what it samples.  Wakes on a short cadence, spills in bounded
     batches (each batch is one replay-lock acquisition) whenever the ring
     is over its high watermark."""

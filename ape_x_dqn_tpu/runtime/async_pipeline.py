@@ -117,74 +117,6 @@ class _AsyncPublisher:
                     self._cv.notify_all()
 
 
-class _IngestStagerThread:
-    """Double-buffered ingest: assemble the NEXT dispatch's replay-add
-    blocks while the device scans the current one.
-
-    The fused learners split ingest into host-CPU assembly
-    (``prepare_staged`` — drain the actor-staged chunks, concatenate, carve
-    fixed ``ingest_block`` staging buffers) and the device dispatch
-    (``add_block`` / ``train_with_ingest`` — learner thread only, donation
-    discipline).  This thread runs the assembly half continuously, so the
-    learner thread's per-iteration ingest cost shrinks to the dispatches
-    themselves and host ingest comes off the learner's critical path —
-    tentpole piece (2) of the overlapped pipeline.
-    """
-
-    def __init__(self, fused, stop_event: threading.Event, drain_fn,
-                 timers: StageTimer, period_s: float = 0.005, stall_fn=None):
-        self._fused = fused
-        self._timers = timers
-        self._stop = stop_event
-        self._drain_fn = drain_fn
-        # Chaos gate (obs/chaos.ChaosMonkey.stager_stalled): while it
-        # returns True the stager idles WITHOUT beating its heartbeat —
-        # exactly what a genuinely wedged stager looks like to /healthz.
-        self._stall_fn = stall_fn
-        self._period = float(period_s)
-        self.heartbeat = time.monotonic()
-        self.prepared_rows = 0
-        self.error: Optional[BaseException] = None
-        self._done = threading.Event()
-        self._thread = threading.Thread(
-            target=self._loop, name="ingest-stager", daemon=True
-        )
-
-    def start(self) -> None:
-        self._thread.start()
-
-    def stop(self, timeout: float = 10.0) -> None:
-        self._done.set()
-        if self._thread.is_alive():
-            self._thread.join(timeout)
-
-    def _loop(self) -> None:
-        while not self._stop.is_set() and not self._done.is_set():
-            try:
-                if self._stall_fn is not None and self._stall_fn():
-                    self._done.wait(self._period)
-                    continue
-                # The staging half of ingest, off the learner thread and so
-                # outside its ``ingest`` stage.  Only a poll that staged
-                # rows counts, so the mean is per staging, not per poll.
-                t0 = time.perf_counter()
-                with self._timers.span("ingest_prepare"):
-                    n = self._fused.prepare_staged(
-                        drain=bool(self._drain_fn()))
-                if n:
-                    self._timers.add("ingest_prepare",
-                                     time.perf_counter() - t0)
-                self.prepared_rows += n
-                self.heartbeat = time.monotonic()
-                if not n:
-                    # Nothing staged: idle briefly instead of spinning a
-                    # core the actors need.
-                    self._done.wait(self._period)
-            except BaseException as e:  # noqa: BLE001 — surfaced by runtime
-                self.error = e
-                return
-
-
 class _ActorWorker:
     """Supervised actor-fleet thread with respawn-on-crash."""
 
@@ -337,6 +269,16 @@ class _ActorWorker:
             trim_malloc()
 
 
+# Fused calls dispatched and not yet forced, at most.  With no cap the
+# learner enqueues K-step programs back-to-back and every thread actor's
+# policy_step waits behind the whole backlog — actors starve (measured: FPS
+# drops ~30x).  Two keeps the next call queued behind the running one, so
+# the device never waits for the host (the benchmark's feed holds the same
+# two and idles the device under 1% — PERF.md section 5), and bounds an
+# actor's wait to about one call.
+_FUSED_INFLIGHT = 2
+
+
 class AsyncPipeline:
     """One-host async runtime.  ``run()`` blocks the caller as the learner."""
 
@@ -347,7 +289,6 @@ class AsyncPipeline:
         log_every: int = 500,
         prefetch_depth: int = 2,
         max_actor_restarts: int = 3,
-        fused_inflight: int | None = None,
         eval_every: int = 0,
         eval_episodes: int = 10,
     ):
@@ -368,40 +309,6 @@ class AsyncPipeline:
         # µs/step per pipeline stage, exported in every metrics emit.
         self.timers = StageTimer()
         self._prefetch_depth = prefetch_depth
-        # Device-queue fairness (fused mode): with no cap the learner
-        # enqueues K-step programs back-to-back and every actor policy_step
-        # waits behind the whole backlog — actors starve (measured: FPS
-        # drops ~30x).  Capping in-flight fused calls to ``fused_inflight``
-        # (forcing call i-1's metrics to host before dispatching i+1)
-        # bounds actor latency to ~one fused call.
-        #
-        # Drain policy: in THREAD mode, pop ONE per call (steady fairness —
-        # actors interleave between fused calls).  In PROCESS mode no actor
-        # touches the device, so the queue fills to the cap and drains ALL
-        # at once: one sync burst per ``fused_inflight`` calls instead of
-        # one blocking host read per call.
-        # ``None`` = mode-dependent default (2 thread / 8 process); an
-        # explicit value is honored as
-        # passed (round-4 advisor: the old max(value, 8) silently deepened
-        # the staleness window beyond what the caller asked for).
-        self._fused_drain_all = cfg.actor.mode == "process"
-        if fused_inflight is None:
-            fused_inflight = 8 if self._fused_drain_all else 2
-        self._fused_inflight = max(1, int(fused_inflight))
-        # Overlapped dispatch pipeline (learner.pipeline_depth /
-        # learner.sync_every — runtime/infeed.DispatchPipeline): depth > 1
-        # or an explicit sync cadence routes the fused loop through
-        # _run_fused_overlapped, which chains dispatches with zero
-        # intervening host syncs, assembles ingest blocks on a dedicated
-        # stager thread, and drains outputs one dispatch behind.  The
-        # default (1, 0) keeps the legacy force-per-fused_inflight loop.
-        self._pipeline_depth = max(1, int(cfg.learner.pipeline_depth))
-        self._sync_every = max(0, int(cfg.learner.sync_every))
-        self._overlapped = (
-            self._pipeline_depth > 1 or self._sync_every > 0
-        )
-        self._dispatch_pipeline = None
-        self._run_start_step = 0
         self.fused = None
         self.mesh = None
         # SPMD process identity (multi-host; 1/0 when jax.distributed was
@@ -495,23 +402,6 @@ class AsyncPipeline:
         # mounted socket front end, ...) can ride the trainer's periodic
         # JSONL emit as their own named section — register_jsonl_section.
         self._jsonl_sections: dict = {}
-        # Pipeline-overlap instruments (ISSUE 5): host_syncs counts every
-        # BLOCKING device read on the learner thread (a free read of an
-        # already-landed async copy is not a sync — no device idle);
-        # overlap_gap_ms is the observed device
-        # idle window between fused dispatches (0 when new work arrived
-        # while the device was still busy — ingest fully hidden).  Both
-        # live on /varz + /metrics and the JSONL `pipeline` section
-        # (docs/METRICS.md).
-        self._host_syncs = self.obs_registry.counter(
-            "learner/host_syncs",
-            help="blocking device reads on the learner thread",
-        )
-        self._overlap_gap = self.obs_registry.histogram(
-            "learner/overlap_gap_ms",
-            help="device idle between fused dispatches (ms)",
-            min_s=1e-2, max_s=6e4, per_decade=10,
-        )
         # Host-memory gauge (utils/memory.rss_bytes): the flat-RSS
         # observable for hours-scale soaks — malloc_trim runs at emit
         # cadence; this is the number that proves it held.
@@ -548,7 +438,7 @@ class AsyncPipeline:
                 "replay_tier", _tier_replay.tier_stats
             )
             # Background evictor: spills ride this thread, never the
-            # learner's critical path (the stager/writer discipline).
+            # learner's critical path (the checkpoint writer's discipline).
             self._tier_evictor = TierEvictor(_tier_replay)
         self.health = Health(stale_after_s=ocfg.heartbeat_stale_s)
         # Replay-as-a-service client (replay/service.py): its degradation
@@ -817,16 +707,13 @@ class AsyncPipeline:
                 url=self.obs_server.url,
             )
         if self.supervisor is not None:
-            # Learner watchdog: progress is (step, host-sync count) — a
-            # learner wedged INSIDE a dispatch advances neither.  The
-            # degrade action drops a live overlapped pipeline to strict
-            # depth 1; a second silent deadline declares the run wedged
-            # (event + /healthz 503 via the supervisor component).
+            # Learner watchdog: progress is the step count — a learner
+            # wedged inside a dispatch or a force does not advance it.  One
+            # silent deadline raises a degraded event, a second declares
+            # the run wedged (event + /healthz 503 via the supervisor
+            # component).
             self.supervisor.attach_learner(
-                progress_fn=lambda: (
-                    self._learner_step, int(self._host_syncs.value)
-                ),
-                degrade_fn=self._degrade_pipeline,
+                progress_fn=lambda: self._learner_step,
             )
         if self.cfg.chaos.enabled:
             # Chaos monkey (obs/chaos): a seeded fault schedule against
@@ -907,9 +794,7 @@ class AsyncPipeline:
             slo.subscribe(self.autopilot.on_slo_event)
             pool = getattr(self.worker, "pool", None)
             if pool is not None:
-                self.autopilot.attach_actor(ActorPoolActuator(
-                    pool, pipeline_fn=lambda: self._dispatch_pipeline,
-                ))
+                self.autopilot.attach_actor(ActorPoolActuator(pool))
             self.obs_registry.register_provider(
                 "autopilot", self.autopilot.state
             )
@@ -1098,14 +983,6 @@ class AsyncPipeline:
         out["batch_occupancy_mean"] = occ
         return out
 
-    def _degrade_pipeline(self) -> None:
-        """Watchdog degrade action: strict dispatch from now on (and a
-        flight-recorder mark — the post-mortem should show the ladder)."""
-        self.recorder.record("pipeline_degraded", step=self._learner_step)
-        p = self._dispatch_pipeline
-        if p is not None:
-            p.degrade()
-
     def _resolve_postmortem_dir(self) -> Optional[str]:
         """obs.postmortem_dir policy: explicit path wins; "auto" lands
         post-mortems under the checkpoint dir a checkpointed run already
@@ -1232,20 +1109,10 @@ class AsyncPipeline:
             except Exception:  # noqa: BLE001 — exit-path teardown; writer errors surfaced via _finish_checkpoints
                 pass
 
-    def _flush_priority_writeback(self, pending: list) -> None:
-        """Commit deferred (indices, priorities) in ONE batched update —
-        step order preserved, so the sum-tree's documented last-write-wins
-        resolves duplicate slots exactly as sequential per-step updates
-        would.  Clears ``pending`` in place."""
+    def _write_back_priorities(self, idx, priorities) -> None:
+        """Commit one step's deferred (indices, device priorities)."""
         with self.timers.stage("priority_writeback"):
-            if len(pending) == 1:
-                idx = pending[0][0]
-                prio = self._priorities_host(pending[0][1])
-            else:
-                idx = np.concatenate([i for i, _ in pending])
-                prio = np.concatenate(
-                    [self._priorities_host(p) for _, p in pending]
-                )
+            prio = self._priorities_host(priorities)
             if self._remote_replay is not None:
                 # Remote replay: a traced experience among these slots
                 # stamps the write-back RPC — the timeline's final hop.
@@ -1257,10 +1124,9 @@ class AsyncPipeline:
             else:
                 self.comps.replay.update_priorities(idx, prio)
         if self._lineage is not None:
-            # The write-back forced the batched steps' device work —
-            # their slots are now TRAINED.
+            # The write-back forced the step's device work — its slots
+            # are now TRAINED.
             self._lineage.on_trained(idx)
-        pending.clear()
 
     def _force_fused(self, metrics) -> None:
         """Force one fused call's completion (a host read of its last
@@ -1305,8 +1171,6 @@ class AsyncPipeline:
         cfg = self.cfg
         target = learner_steps if learner_steps is not None else cfg.learner.total_steps
         if self.fused is not None:
-            if self._overlapped:
-                return self._run_fused_overlapped(target, warmup_timeout)
             return self._run_fused(target, warmup_timeout)
         self._obs_run_start(target)
         self.worker.start()
@@ -1323,11 +1187,10 @@ class AsyncPipeline:
                 place_fn=self._place,
                 depth=self._prefetch_depth,
             ) as queue:
-                # (indices, device priorities) of steps whose write-back is
-                # still deferred — flushed in ONE batched update per
-                # learner.pipeline_depth steps (depth 1 = exact legacy
-                # one-step-behind semantics).
-                pending: list = []
+                # (indices, device priorities) of the previous step: its
+                # write-back runs one step behind, so the host read never
+                # blocks on the step in flight.
+                pending = None
                 metrics = None
                 state = self.comps.state
                 while self._learner_step < target and not self.stop_event.is_set():
@@ -1351,16 +1214,9 @@ class AsyncPipeline:
                     self.comps.state = state
                     self._learner_step += 1
                     self._steps_rate.add(1)
-                    # Deferred priority write-back, batched per drained
-                    # window: the accumulated steps' device work finished
-                    # behind later dispatches, so the host reads rarely
-                    # block, and one batched update_priorities (+ one
-                    # lineage on_trained) replaces per-step calls — on the
-                    # striped native replay the batch also fans out across
-                    # stripes concurrently.
-                    if len(pending) >= self._pipeline_depth:
-                        self._flush_priority_writeback(pending)
-                    pending.append((host_indices, metrics.priorities))
+                    if pending is not None:
+                        self._write_back_priorities(*pending)
+                    pending = (host_indices, metrics.priorities)
                     if self._learner_step % cfg.learner.publish_every == 0:
                         with self.timers.stage("publish"):
                             self._publish(state.params)
@@ -1373,8 +1229,8 @@ class AsyncPipeline:
                     self._maybe_eval()
                     if self._learner_step % self.log_every == 0:
                         self._emit(metrics)
-                if pending:
-                    self._flush_priority_writeback(pending)
+                if pending is not None:
+                    self._write_back_priorities(*pending)
             self._finish_publishes()
             self._finish_checkpoints()
         except BaseException as e:
@@ -1399,161 +1255,6 @@ class AsyncPipeline:
         # Final emit carries the last step's metrics (one host sync) so the
         # returned record always has learner/loss — callers assert on it.
         return self._emit(metrics, final=True)
-
-    def _run_fused_overlapped(self, target: int,
-                              warmup_timeout: float) -> dict:
-        """Overlapped dispatch pipeline (learner.pipeline_depth > 1 or an
-        explicit learner.sync_every): chain fused dispatches back-to-back
-        with ZERO intervening host syncs, assemble ingest blocks on the
-        stager thread while the device scans, fold the last full block
-        into the next dispatch (one round trip for add + scan), and drain
-        metric outputs one dispatch behind via async device→host copies.
-
-        Host syncs happen only (a) when flow control must block on a
-        not-yet-ready oldest call (window full), (b) at the sync_every
-        cadence, (c) at emit/checkpoint/exit boundaries — each counted on
-        learner/host_syncs.  Bit-for-bit identical to the strict (depth 1)
-        path given the same chunk arrival order —
-        tests/test_pipeline_overlap.py pins it.
-        """
-        import numpy as np
-
-        from ape_x_dqn_tpu.runtime.infeed import DispatchPipeline
-        from ape_x_dqn_tpu.runtime.single_process import beta_schedule
-
-        cfg = self.cfg
-        fused = self.fused
-        self._obs_run_start(target)
-        self._run_start_step = self._learner_step
-        self.worker.start()
-        last_metrics = None
-        pipeline = DispatchPipeline(
-            self._pipeline_depth,
-            probe_fn=lambda m: m.loss,
-            on_retire=lambda _m, steps: self._steps_rate.add(steps),
-            sync_counter=self._host_syncs,
-            gap_hist_ms=self._overlap_gap,
-        )
-        self._dispatch_pipeline = pipeline
-        stager = _IngestStagerThread(
-            fused, self.stop_event, lambda: self.worker.finished,
-            self.timers,
-            stall_fn=(self._chaos.stager_stalled
-                      if self._chaos is not None else None),
-        )
-        try:
-            self._wait_for_warmup(
-                warmup_timeout,
-                size_fn=lambda: fused.size,
-                tick=lambda: fused.ingest_staged(drain=self.worker.finished),
-            )
-            stager.start()
-            self.health.register(
-                "ingest_stager",
-                lambda: time.monotonic() - stager.heartbeat,
-            )
-            next_log = self._learner_step + self.log_every
-            next_ckpt = (
-                self._learner_step + cfg.learner.checkpoint_every
-                if cfg.learner.checkpoint_every
-                else None
-            )
-            next_sync = (
-                self._learner_step + self._sync_every
-                if self._sync_every else None
-            )
-            while self._learner_step < target \
-                    and not self.stop_event.is_set():
-                self.health.beat("learner")
-                if stager.error is not None:
-                    raise RuntimeError(
-                        "ingest stager failed"
-                    ) from stager.error
-                with self.timers.stage("ingest"):
-                    # Dispatch-only: the blocks were assembled on the
-                    # stager thread.  The last full block rides INSIDE the
-                    # fused call when the learner supports the fold.
-                    blocks = fused.pop_prepared()
-                    fold = None
-                    if blocks and fused.supports_ingest_fold:
-                        prio, _t = blocks[-1]
-                        if len(prio) == cfg.learner.ingest_block:
-                            fold = blocks.pop()
-                    for blk in blocks:
-                        fused.add_block(*blk)
-                beta = beta_schedule(
-                    self._learner_step, cfg.learner.total_steps,
-                    cfg.replay.is_exponent,
-                )
-                with self.timers.stage("fused_dispatch"):
-                    if fold is not None:
-                        last_metrics = pipeline.dispatch(
-                            lambda: fused.train_with_ingest(
-                                beta, fold[0], fold[1]
-                            ),
-                            fused.steps_per_call,
-                        )
-                    else:
-                        last_metrics = pipeline.dispatch(
-                            lambda: fused.train(beta),
-                            fused.steps_per_call,
-                        )
-                self._learner_step += fused.steps_per_call
-                self.comps.state = fused.state
-                if next_sync is not None and self._learner_step >= next_sync:
-                    # Cadence sync: bound how far host-visible metrics and
-                    # flow-control staleness can trail the dispatch edge.
-                    with self.timers.stage("pipeline_sync"):
-                        pipeline.sync()
-                    while next_sync <= self._learner_step:
-                        next_sync += self._sync_every
-                # Publish at most once per fused call (device-side param
-                # copy — not a host sync; the publisher thread does the
-                # slow device_get off this thread).
-                if self._learner_step % max(
-                    cfg.learner.publish_every, fused.steps_per_call
-                ) < fused.steps_per_call:
-                    with self.timers.stage("publish"):
-                        self._publish(fused.params_for_publish())
-                if next_ckpt is not None and self._learner_step >= next_ckpt:
-                    # The snapshot reads the device ring: everything
-                    # dispatched must have landed.
-                    pipeline.sync()
-                    with self.timers.stage("checkpoint"):
-                        self._save_fused_checkpoint()
-                    next_ckpt += cfg.learner.checkpoint_every
-                self._maybe_eval()
-                if self._learner_step >= next_log:
-                    pipeline.sync()  # emit reads last_metrics host-side
-                    self._emit_fused(last_metrics)
-                    next_log += self.log_every
-            # Flush-at-exit: every dispatched call completes before the
-            # final rates/loss are read (one last sync burst).
-            pipeline.sync()
-            self._finish_publishes()
-            self._finish_checkpoints()
-        except BaseException as e:
-            self._obs_fault(e)
-            raise
-        finally:
-            self.stop_event.set()
-            stager.stop()
-            self.worker.join()
-            if self._publisher is not None:
-                self._publisher.close()
-            self._close_checkpoints()
-            self._close_obs()
-        if stager.error is not None and not isinstance(
-            stager.error, Exception
-        ):
-            raise RuntimeError("ingest stager died") from stager.error
-        if self.worker.error is not None:
-            raise RuntimeError("actor worker died") from self.worker.error
-        if last_metrics is not None:
-            loss = np.asarray(last_metrics.loss)
-            if not np.all(np.isfinite(loss)):
-                raise FloatingPointError("non-finite loss in fused learner")
-        return self._emit_fused(last_metrics, final=True)
 
     def _run_fused(self, target: int, warmup_timeout: float) -> dict:
         """Device-replay mode: ingest staged actor chunks, then fused
@@ -1595,21 +1296,13 @@ class AsyncPipeline:
                 with self.timers.stage("fused_dispatch"):
                     last_metrics = fused.train(beta)
                 inflight.append(last_metrics)
-                if len(inflight) >= self._fused_inflight:
-                    # Force completion with a tiny host read of the call's
-                    # last loss.  Thread mode: oldest
-                    # only; process mode: drain the whole queue in one sync
-                    # burst (see __init__'s drain-policy comment).
-                    # steps_per_sec counts steps at FORCE time — dispatch
-                    # runs ahead of the device under deep queues, so
-                    # counting at dispatch would report bursts that haven't
-                    # executed yet.
+                if len(inflight) >= _FUSED_INFLIGHT:
+                    # Force the oldest call with a tiny host read of its
+                    # last loss.  steps_per_sec counts steps at FORCE time —
+                    # dispatch runs ahead of the device, so counting at
+                    # dispatch would report steps that haven't executed yet.
                     with self.timers.stage("force_oldest"):
-                        if self._fused_drain_all:
-                            while inflight:
-                                self._force_fused(inflight.pop(0))
-                        else:
-                            self._force_fused(inflight.pop(0))
+                        self._force_fused(inflight.pop(0))
                 self._learner_step += fused.steps_per_call
                 self.comps.state = fused.state
                 # Publish at most once per fused call — the cap
@@ -1708,7 +1401,7 @@ class AsyncPipeline:
                     replay_suffix=sfx,
                 )
         # Learner-visible checkpoint stall — the number the incremental
-        # subsystem exists to shrink (bench.py checkpoint_stall).
+        # subsystem exists to shrink (demos/ckpt_stall.json).
         stall_ms = (time.perf_counter() - t0) * 1e3
         self.logger.log("ckpt/learner_stall_ms", stall_ms)
         self.recorder.record(
@@ -1879,29 +1572,6 @@ class AsyncPipeline:
             out["net"] = net
         return out
 
-    def _pipeline_extra(self) -> dict:
-        """Overlap accounting on the JSONL stream (docs/METRICS.md
-        ``pipeline`` section): host-sync counts against the steps this
-        session actually ran, plus the device-idle gap distribution —
-        absent unless the overlapped dispatch pipeline is active."""
-        p = self._dispatch_pipeline
-        if p is None:
-            return {}
-        steps = max(1, self._learner_step - self._run_start_step)
-        syncs = self._host_syncs.value
-        gp50 = self._overlap_gap.percentile(50)
-        gp95 = self._overlap_gap.percentile(95)
-        return {"pipeline": {
-            "depth": p.depth,
-            "sync_every": self._sync_every,
-            "host_syncs": int(syncs),
-            "syncs_per_1k_steps": round(1000.0 * syncs / steps, 3),
-            "overlap_gap_ms_p50": round(gp50, 3) if gp50 == gp50 else None,
-            "overlap_gap_ms_p95": round(gp95, 3) if gp95 == gp95 else None,
-            "gaps_observed": p.gaps_observed,
-            "inflight": len(p),
-        }}
-
     def _tier_extra(self) -> dict:
         """Tiered-replay accounting on the JSONL stream (docs/METRICS.md
         ``replay_tier`` section): hot/cold occupancy, spill/fault
@@ -1972,7 +1642,6 @@ class AsyncPipeline:
             stage_us=self.timers.us_per_call(),
             **({"routing": self._routing} if self._routing else {}),
             final=final,
-            **self._pipeline_extra(),
             **self._transport_extra(),
             **self._ckpt_extra(),
             **self._supervisor_extra(),

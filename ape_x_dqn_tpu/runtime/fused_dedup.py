@@ -391,14 +391,8 @@ class FusedDedupLearner:
                 f"data-axis extent {self._n_shards}"
             )
         self._ingest_block //= self._n_shards
+        # The actor threads' appends against the learner thread's takes.
         self._lock = threading.Lock()
-        # Double-buffer stage 2 (mirrors FusedDeviceLearner): blocks the
-        # stager already carved — frame blocks before the transition
-        # blocks that reference them — waiting only for device dispatch.
-        # prepare_staged may run on any thread; dispatch on the train()
-        # caller only.
-        self._prepared: list = []
-        self._prepared_rows = 0
         self._size = 0
         # Incremental-checkpoint mark (utils/checkpoint_inc): per-shard
         # ingest/ship progress at the last snapshot.  Both counters are
@@ -429,7 +423,7 @@ class FusedDedupLearner:
     @property
     def staged_rows(self) -> int:
         with self._lock:
-            return self._stager.staged_rows + self._prepared_rows
+            return self._stager.staged_rows
 
     @property
     def state(self) -> TrainState:
@@ -448,95 +442,46 @@ class FusedDedupLearner:
 
     # ------------------------------------------------------------- learner
 
-    def prepare_staged(self, drain: bool = False) -> int:
-        """Carve shippable blocks onto the prepared queue (host CPU only,
-        any thread): frame blocks first, then the eligible transition
-        blocks — a transition block is only carved once every frame it
+    def ingest_staged(self, drain: bool = False) -> int:
+        """Ship staged frame blocks, then eligible transition blocks, in
+        fixed ``ingest_block`` units.  Learner-thread only (the adds donate
+        the ring).  A transition block is only carved once every frame it
         references has been carved ahead of it, so dispatch order (FIFO)
         preserves the frames-before-transitions invariant.  ``drain=True``
         additionally carves power-of-2 sub-blocks of the tails; whatever
         remains (transitions whose frames are still host-side) stays
-        staged and rides the snapshot.  Returns transition rows carved."""
+        staged and rides the snapshot.  Returns transition rows ingested."""
         m = self._ingest_block
+        st = self._stager
+        blocks: list = []
+        with self._lock:
+            for kind, available, take in (
+                ("f", st.frame_blocks_available, st.take_frame_block),
+                ("t", st.txn_blocks_available, st.take_txn_block),
+            ):
+                # Full blocks, then on a drain the tail in maximal
+                # power-of-2 sub-blocks (static shapes: at most
+                # log2(ingest_block) jit variants, cached).
+                b = m
+                while b >= 1:
+                    while available(b) >= 1:
+                        blocks.append((kind, take(b)))
+                    if not drain:
+                        break
+                    b >>= 1
         rows = 0
-        with self._lock:
-            while self._stager.frame_blocks_available(m) >= 1:
-                self._prepared.append(
-                    ("f", self._stager.take_frame_block(m))
-                )
-            if drain:
-                self._carve_tail_locked(
-                    self._stager.frame_blocks_available,
-                    self._stager.take_frame_block, "f",
-                )
-            while self._stager.txn_blocks_available(m) >= 1:
-                self._prepared.append(
-                    ("t", self._stager.take_txn_block(m))
-                )
-                rows += m * self._n_shards
-            if drain:
-                rows += self._carve_tail_locked(
-                    self._stager.txn_blocks_available,
-                    self._stager.take_txn_block, "t",
-                )
-            self._prepared_rows += rows
+        for kind, block in blocks:
+            if kind == "f":
+                self._replay = self._add_frames(self._replay, block)
+            else:
+                self._replay = self._add_txns(self._replay, block)
+                rows += block["prio"].shape[1] * self._n_shards
+        self._size += rows
         return rows
-
-    def _carve_tail_locked(self, available, take, kind: str) -> int:
-        """Carve a stream's tail in maximal power-of-2 sub-blocks (static
-        shapes: at most log2(ingest_block) jit variants, cached)."""
-        total = 0
-        b = self._ingest_block >> 1
-        while b >= 1:
-            while available(b) >= 1:
-                self._prepared.append((kind, take(b)))
-                if kind == "t":
-                    total += b * self._n_shards
-            b >>= 1
-        return total
-
-    def pop_prepared(self) -> list:
-        """Take every prepared block (dispatch order).  The caller MUST
-        hand each to ``add_block`` on the train()-caller thread."""
-        with self._lock:
-            blocks, self._prepared = self._prepared, []
-            self._prepared_rows = 0
-        return blocks
-
-    def add_block(self, kind: str, block) -> int:
-        """Dispatch one prepared block's device add (learner thread)."""
-        if kind == "f":
-            self._replay = self._add_frames(self._replay, block)
-            return 0
-        self._replay = self._add_txns(self._replay, block)
-        n = block["prio"].shape[1] * self._n_shards
-        self._size += n
-        return n
-
-    def _flush_prepared(self) -> int:
-        """Dispatch every prepared block (train()-caller thread).  The
-        snapshot paths call this first: a prepared block lives in neither
-        the stager nor the device ring, so capturing state around one
-        would silently lose it."""
-        return sum(self.add_block(k, b) for k, b in self.pop_prepared())
-
-    def ingest_staged(self, drain: bool = False) -> int:
-        """Ship staged frame blocks, then eligible transition blocks, in
-        fixed ``ingest_block`` units (carve + dispatch inline — the
-        strict path).  Learner-thread only.  Returns rows ingested."""
-        self.prepare_staged(drain=drain)
-        return self._flush_prepared()
-
-    @property
-    def supports_ingest_fold(self) -> bool:
-        """The dedup ingest is two-stream (frames must land before the
-        transitions that reference them) — no single-dispatch fold."""
-        return False
 
     # -- snapshot (checkpointing) ----------------------------------------
 
     def state_dict(self) -> dict:
-        self._flush_prepared()
         r = jax.device_get(self._replay)
         out = {
             "dedup": np.asarray(True),
@@ -572,7 +517,6 @@ class FusedDedupLearner:
         """
         import jax.numpy as jnp
 
-        self._flush_prepared()
         n = self._n_shards
         C_local = self._capacity // n
         Cf_global = self._replay.frame_capacity

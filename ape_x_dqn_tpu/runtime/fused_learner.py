@@ -83,17 +83,6 @@ class FusedDeviceLearner:
                 include_ingest=False,
                 sample_ahead=sample_ahead,
             )
-            # Folded ingest+scan variant (overlapped pipeline): built
-            # lazily from the same step_fn/knobs — see train_with_ingest.
-            self._fused_build_args = dict(
-                batch_size=batch_size,
-                steps_per_call=self.steps_per_call,
-                priority_exponent=priority_exponent,
-                target_sync_freq=target_sync_freq,
-                sample_ahead=sample_ahead,
-            )
-            self._step_fn = step_fn
-            self._fused_ingest = None
             self._add = jax.jit(
                 lambda r, t, p: device_replay_add(r, t, p, priority_exponent),
                 donate_argnums=(0,),
@@ -155,7 +144,6 @@ class FusedDeviceLearner:
             # device 0 and reshard over ICI.
             row_sh = NamedSharding(mesh, P("data"))
             self._place_rows = lambda a: jax.device_put(np.asarray(a), row_sh)
-            self._fused_ingest = None  # fold unsupported over a mesh
         # Distinct per-seed sampling stream: fold a salt into the state's key
         # (reading a key word breaks — the high word is 0 for seeds < 2^32,
         # which made every seed sample identically; round-2 advisor finding).
@@ -165,19 +153,12 @@ class FusedDeviceLearner:
         self._rng = jax.random.fold_in(self._state.rng, 0x5EED)
         # Host staging: numpy transitions accumulate here until a full
         # fixed-size block exists (static shapes → one compiled ingest).
-        # ``_prepared`` is the second stage of the double buffer: blocks
-        # already carved to ingest_block shape (staging-buffer assembly —
-        # the host-CPU half of ingest), waiting only for their device
-        # dispatch.  ``prepare_staged`` may run on ANY thread (the
-        # overlapped pipeline's ingest worker); dispatch stays on the one
-        # train()-caller thread, preserving the donation discipline.
+        # The lock covers the actor threads' appends against the learner
+        # thread's take.
         self._lock = threading.Lock()
         self._staged: list = []
         self._staged_rows = 0
-        self._prepared: list = []
-        self._prepared_rows = 0
         self._size = 0          # host mirror of device transition count
-        self._ingested_blocks = 0
 
     # ---------------------------------------------------------------- sinks
 
@@ -198,7 +179,7 @@ class FusedDeviceLearner:
     @property
     def staged_rows(self) -> int:
         with self._lock:
-            return self._staged_rows + self._prepared_rows
+            return self._staged_rows
 
     @property
     def state(self) -> TrainState:
@@ -217,18 +198,16 @@ class FusedDeviceLearner:
 
     # ------------------------------------------------------------- learner
 
-    def prepare_staged(self, drain: bool = False) -> int:
-        """Stage-2 assembly (host CPU only, any thread): carve staged rows
-        into fixed ``ingest_block`` blocks on the prepared queue, ready
-        for a device dispatch.  Returns rows prepared.
+    def ingest_staged(self, drain: bool = False) -> int:
+        """Move staged host rows to HBM in fixed ``ingest_block`` blocks.
+        Learner-thread only (the adds donate the ring).  Returns rows
+        ingested.
 
         ``drain=True`` also carves the final partial block into power-of-2
         sub-blocks — static shapes (at most log2(ingest_block) compiled
         variants, cached by jit) with no padding, so drains at checkpoint
         cadence never leak junk slots into the ring; steady state keeps
-        blocks exact.  The overlapped pipeline calls this from its ingest
-        worker thread while the device scans (double-buffered ingest); the
-        strict path calls it inline via ``ingest_staged``.
+        blocks exact.
         """
         with self._lock:
             staged, self._staged = self._staged, []
@@ -238,15 +217,9 @@ class FusedDeviceLearner:
         cat = _concat_chunks([t for _, t in staged])
         prio = np.concatenate([p for p, _ in staged])
         m = self._ingest_block
-        blocks: list = []
         off = 0
-        n_full = len(prio) // m
-        for _ in range(n_full):
-            sl = slice(off, off + m)
-            blocks.append((
-                prio[sl],
-                jax.tree_util.tree_map(lambda a: a[sl], cat),
-            ))
+        for _ in range(len(prio) // m):
+            self._add_rows(prio, cat, slice(off, off + m))
             off += m
         rem = len(prio) - off
         if rem and drain:
@@ -255,63 +228,30 @@ class FusedDeviceLearner:
             g = self._add_granularity
             while rem >= g:
                 sub = g << ((rem // g).bit_length() - 1)  # max g·2^k <= rem
-                sl = slice(off, off + sub)
-                blocks.append((
-                    prio[sl],
-                    jax.tree_util.tree_map(lambda a: a[sl], cat),
-                ))
+                self._add_rows(prio, cat, slice(off, off + sub))
                 off += sub
                 rem -= sub
-        prepared = off
-        with self._lock:
-            self._prepared.extend(blocks)
-            self._prepared_rows += prepared
-            if rem:
-                # Partial tail (or, sharded, a sub-granularity remainder)
-                # goes back to staging; checkpoints still lose nothing —
-                # state_dict snapshots prepared AND staged rows.
-                self._staged.insert(
-                    0,
-                    (
-                        prio[len(prio) - rem:],
-                        jax.tree_util.tree_map(
-                            lambda a: a[len(prio) - rem:], cat
-                        ),
-                    ),
-                )
+        if rem:
+            # Partial tail (or, sharded, a sub-granularity remainder) goes
+            # back to the head of staging; checkpoints still lose nothing —
+            # state_dict snapshots staged rows.
+            tail = (
+                prio[off:],
+                jax.tree_util.tree_map(lambda a: a[off:], cat),
+            )
+            with self._lock:
+                self._staged.insert(0, tail)
                 self._staged_rows += rem
-        return prepared
+        return off
 
-    def pop_prepared(self) -> list:
-        """Take every prepared block (ring order).  The caller MUST hand
-        each one to ``add_block``/``train_with_ingest`` on the learner
-        thread — a popped block no longer rides checkpoints."""
-        with self._lock:
-            blocks, self._prepared = self._prepared, []
-            self._prepared_rows = 0
-        return blocks
-
-    def add_block(self, priorities: np.ndarray, transitions) -> int:
-        """Dispatch one prepared block's device add (learner thread)."""
+    def _add_rows(self, prio: np.ndarray, cat, sl: slice) -> None:
+        """Dispatch the device add of rows ``sl`` of a concatenated take."""
         self._replay = self._add(
             self._replay,
-            jax.tree_util.tree_map(self._place_rows, transitions),
-            self._place_rows(priorities),
+            jax.tree_util.tree_map(lambda a: self._place_rows(a[sl]), cat),
+            self._place_rows(prio[sl]),
         )
-        n = len(priorities)
-        self._size += n
-        if n == self._ingest_block:
-            self._ingested_blocks += 1
-        return n
-
-    def ingest_staged(self, drain: bool = False) -> int:
-        """Move staged host rows to HBM in fixed ``ingest_block`` blocks
-        (assembly + dispatch inline — the strict path).  Learner-thread
-        only.  Returns rows ingested."""
-        self.prepare_staged(drain=drain)
-        return sum(
-            self.add_block(p, t) for p, t in self.pop_prepared()
-        )
+        self._size += sl.stop - sl.start
 
     # -- snapshot (checkpointing) ----------------------------------------
 
@@ -327,9 +267,7 @@ class FusedDeviceLearner:
             "cursor": np.asarray(r.cursor), "count": np.asarray(r.count),
         }
         with self._lock:
-            # Prepared blocks precede staged chunks in ring order (they
-            # were carved from earlier arrivals) — both ride the snapshot.
-            staged = list(self._prepared) + list(self._staged)
+            staged = list(self._staged)
         if staged:
             cat = _concat_chunks([t for _, t in staged])
             out["staged_prio"] = np.concatenate([p for p, _ in staged])
@@ -397,48 +335,6 @@ class FusedDeviceLearner:
         self._state, self._replay, metrics = self._fused(
             self._state, self._replay, beta, sub
         )
-        return metrics
-
-    # -- folded ingest+scan (overlapped dispatch pipeline) ----------------
-
-    @property
-    def supports_ingest_fold(self) -> bool:
-        """True when a full ingest_block can ride INSIDE the fused call
-        (one dispatch for add + K-step scan).  Single-device only — the
-        sharded builder has no include_ingest variant."""
-        return self._mesh is None
-
-    def train_with_ingest(self, beta: float, priorities: np.ndarray,
-                          transitions):
-        """One dispatch: ingest one full ``ingest_block`` + the K-step
-        scan.  Bit-for-bit identical to ``add_block`` followed by
-        ``train`` (pinned by tests/test_pipeline_overlap.py) — the add is
-        sequenced before the scan inside the same XLA program — but costs
-        one host→device dispatch instead of two, which matters on links
-        that charge per round trip."""
-        if len(priorities) != self._ingest_block:
-            raise ValueError(
-                f"train_with_ingest requires a full ingest_block "
-                f"({self._ingest_block} rows), got {len(priorities)}"
-            )
-        if self._fused_ingest is None:
-            if self._mesh is not None:
-                raise RuntimeError(
-                    "ingest folding is single-device only"
-                )
-            self._fused_ingest = build_fused_learn_step(
-                self._step_fn, include_ingest=True,
-                **self._fused_build_args,
-            )
-        self._rng, sub = jax.random.split(self._rng)
-        self._state, self._replay, metrics = self._fused_ingest(
-            self._state, self._replay,
-            jax.tree_util.tree_map(self._place_rows, transitions),
-            self._place_rows(np.asarray(priorities, np.float32)),
-            beta, sub,
-        )
-        self._size += self._ingest_block
-        self._ingested_blocks += 1
         return metrics
 
 

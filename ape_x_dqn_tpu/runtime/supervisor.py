@@ -14,13 +14,12 @@ them, one typed policy per failure class:
     spinning the pool or — the old ``max_restarts`` behavior — declaring
     the whole run failed.  ``ProcessActorPool.supervise()`` consults it
     for every death.
-  * :class:`LearnerWatchdog` — no observable learner progress (step or
-    host-sync count) for ``stall_deadline_s`` first DEGRADES: the
-    overlapped :class:`~ape_x_dqn_tpu.runtime.infeed.DispatchPipeline`
-    drops to strict depth 1 (shrinking the window a wedged dispatch can
-    hide in); still nothing ``wedge_deadline_s`` later and the run is
-    declared WEDGED — a structured event plus a failing /healthz
-    component, the operator signal, never a silent hang.
+  * :class:`LearnerWatchdog` — no observable learner progress (its
+    step count) for ``stall_deadline_s`` first DEGRADES (an event and
+    the caller's hook, where it gave one); still nothing
+    ``wedge_deadline_s`` later and the run is declared WEDGED — a
+    structured event plus a failing /healthz component, the operator
+    signal, never a silent hang.
   * **Serving staleness** — :class:`ServingStalenessPolicy` flips a
     PolicyServer into degraded mode (submissions shed with the typed
     ``ServerOverloaded``; /healthz 503) when its params age past
@@ -133,7 +132,7 @@ class LearnerWatchdog:
     """Progress watchdog with a degrade-before-wedge ladder.
 
     ``progress_fn`` returns any hashable progress token (the pipeline uses
-    ``(learner_step, host_syncs)``); a token unchanged for
+    its learner step); a token unchanged for
     ``stall_deadline_s`` triggers ``degrade_fn`` ONCE (phase ``degraded``),
     and a token still unchanged ``wedge_deadline_s`` after the degrade
     declares the run ``wedged``.  Any progress resets the ladder to

@@ -31,7 +31,7 @@ in-flight window.  Nothing beyond it is lost: the broken splice closes
 the client's connection, the client reconnects (the router now routes
 it to a live replica) and retries the request whole
 (``ServingClient.act``), so the fleet-level contract is zero dropped
-requests, proven by ``tools/serving_net_smoke.py`` (verify gate 9).
+requests, proven by ``tools/serving_net_smoke.py`` (verify gate 8).
 """
 
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """Where JAX keeps its persistent compilation cache.
 
 One rule, applied by every entry point before its first compile
-(``train.main``, ``serve.main``, ``bench.py``, ``chip_smoke.py`` and the
-process-actor worker entry):
+(``train.main``, ``serve.main``, ``chip_smoke.py``, ``benchmark/run.py``
+and the process-actor worker entry):
 
   * ``JAX_COMPILATION_CACHE_DIR`` set — the cache lives there.  JAX reads
     the variable itself; nothing in this repo sets another directory.

@@ -90,31 +90,22 @@ class StageTimer:
         self._count: Dict[str, int] = defaultdict(int)
         self._lock = threading.Lock()
 
-    @staticmethod
-    def span(name: str):
-        """The ``apex:<name>`` span alone, on the profiler's clock; with no
-        profiler session a TraceAnnotation is a flag test.  For a section
-        that learns only afterwards whether it is one to count (``add``)."""
-        import jax
-
-        return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
-
     @contextlib.contextmanager
     def stage(self, name: str) -> Iterator[None]:
+        """Time the section and put the ``apex:<name>`` span on the
+        profiler's clock; with no profiler session a TraceAnnotation is a
+        flag test."""
+        import jax
+
         t0 = time.perf_counter()
         try:
-            with self.span(name):
+            with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
                 yield
         finally:
             dt = time.perf_counter() - t0
             with self._lock:
                 self._total_s[name] += dt
                 self._count[name] += 1
-
-    def add(self, name: str, seconds: float) -> None:
-        with self._lock:
-            self._total_s[name] += seconds
-            self._count[name] += 1
 
     def us_per_call(self) -> Dict[str, float]:
         with self._lock:  # readers too: a concurrent first-use of a stage

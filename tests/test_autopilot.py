@@ -62,7 +62,6 @@ class FakeActor(FakeServing):
         super().__init__(size=size, **kw)
         self._capacity = capacity
         self._drain = 1.0
-        self.pipeline_tunes = 0
 
     def capacity(self):
         return self._capacity
@@ -74,15 +73,6 @@ class FakeActor(FakeServing):
         self.calls.append("tune_drain")
         self._drain *= 2
         return {"factor": self._drain}
-
-    def tune_pipeline(self):
-        # One-shot, like the real ActorPoolActuator: the degrade can
-        # only happen once per run.
-        if self.pipeline_tunes:
-            return None
-        self.calls.append("tune_pipeline")
-        self.pipeline_tunes += 1
-        return {"pipeline_depth": 1}
 
 
 def breach(ctl, rule, **fields):
@@ -232,19 +222,13 @@ class TestControllerDecisions:
         assert [a["fleet"] for a in acted] == ["serving"]
         assert srv.size() == 2
 
-    def test_actor_ceiling_degrades_pipeline_once(self):
+    def test_actor_at_ceiling_is_suppressed_like_any_fleet(self):
         act = FakeActor(size=4, capacity=4)
         c = self.ctl(actor=act)
         breach(c, "age_p95_ms")
-        acted = c.step(now=0.0)
-        assert [a["action"] for a in acted] == ["tune_pipeline"]
-        assert act.pipeline_tunes == 1
-        # The hook self-disarms after the one degrade: further breached
-        # steps at the ceiling are a plain at_max suppression.
-        acted = c.step(now=10.0)
-        assert acted == [] or all(
-            a["action"] != "tune_pipeline" for a in acted)
-        assert act.pipeline_tunes == 1
+        assert c.step(now=0.0) == []
+        assert c.suppressed.get("actor:up:at_max") == 1
+        assert act.calls == [] and act.size() == 4
 
     def test_ring_occupancy_ladder_tunes_drain_before_retiring(self):
         act = FakeActor(size=3, capacity=4)

@@ -68,9 +68,24 @@ def test_overrides():
     assert cfg.learner.learning_rate == 0.001
 
 
-def test_override_unknown_path_rejected():
+# Options a config or a command line of an earlier tree may still name:
+# gone with the overlapped dispatch loop, they fail like any unknown key.
+REMOVED_OPTIONS = [
+    ("learner", "pipeline_depth", 2),
+    ("learner", "sync_every", 64),
+    ("chaos", "stuck_stager_interval_s", 1.0),
+    ("chaos", "stuck_stager_hold_s", 1.0),
+]
+UNKNOWN_OPTIONS = [("actor", "bogus", 1)] + REMOVED_OPTIONS
+unknown_options = pytest.mark.parametrize(
+    "section,field,value", UNKNOWN_OPTIONS,
+    ids=[f"{s}.{f}" for s, f, _ in UNKNOWN_OPTIONS])
+
+
+@unknown_options
+def test_override_unknown_path_rejected(section, field, value):
     with pytest.raises(ValueError, match="unknown config"):
-        apply_overrides(ApexConfig(), ["actor.bogus=1"])
+        apply_overrides(ApexConfig(), [f"{section}.{field}={value}"])
 
 
 def test_load_config_file_formats(tmp_path):
@@ -88,8 +103,9 @@ def test_load_config_file_formats(tmp_path):
     assert cfg.actor.gamma == 0.95
 
 
-def test_native_unknown_key_rejected(tmp_path):
+@unknown_options
+def test_native_unknown_key_rejected(tmp_path, section, field, value):
     native = tmp_path / "native.json"
-    native.write_text(json.dumps({"actor": {"bogus": 1}}))
+    native.write_text(json.dumps({section: {field: value}}))
     with pytest.raises(ValueError, match="unknown config keys"):
         load_config(str(native))

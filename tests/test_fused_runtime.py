@@ -1,7 +1,7 @@
 """FusedDeviceLearner host driver + device-replay async-pipeline mode.
 
 CPU backend (conftest's 8 virtual devices); the same code paths run on the
-real chip via bench.py and the `learner.device_replay=true` CLI config.
+real chip via chip_smoke.py and the `learner.device_replay=true` CLI config.
 """
 
 import jax
@@ -81,6 +81,75 @@ class TestFusedDeviceLearner:
         ring_obs = np.asarray(fl._replay.obs)[:20]
         want = np.concatenate([c1.obs, c2.obs])
         np.testing.assert_array_equal(ring_obs, want)
+
+
+    def test_staged_tail_rides_the_snapshot(self):
+        """Rows that ``ingest_staged()`` left behind (less than a block)
+        are in ``state_dict`` and come back through ``load_state_dict``:
+        a checkpoint loses nothing whatever the block alignment."""
+        fl = make_learner(ingest_block=32)
+        prio = np.arange(1, 41, dtype=np.float32)
+        chunk = np_chunk(40, seed=5)
+        fl.add_chunk(prio, chunk)
+        assert fl.ingest_staged() == 32
+        assert fl.staged_rows == 8
+        snap = fl.state_dict()
+        np.testing.assert_array_equal(snap["staged_prio"], prio[32:])
+        np.testing.assert_array_equal(snap["staged_obs"], chunk.obs[32:])
+        assert int(snap["count"]) == 32
+        other = make_learner(ingest_block=32)
+        other.load_state_dict(snap)
+        assert other.size == 32 and other.staged_rows == 8
+        assert other.ingest_staged(drain=True) == 8
+        np.testing.assert_array_equal(
+            np.asarray(other._replay.obs)[:40], chunk.obs)
+
+
+def test_ingest_inside_the_fused_program_equals_a_separate_add():
+    """``build_fused_learn_step(include_ingest=True)`` — the program the
+    benchmark's ``ref_b32.learner`` cell times — against what the trainer
+    runs, ``device_replay_add`` and then the program without ingest: the
+    add is sequenced before the scan, so train state and ring agree bit
+    for bit."""
+    from ape_x_dqn_tpu.learner.train_step import build_train_step
+    from ape_x_dqn_tpu.replay.device import (
+        build_fused_learn_step,
+        device_replay_add,
+        init_device_replay,
+    )
+
+    net = DuelingMLP(num_actions=3, hidden_sizes=(16,))
+    opt = make_optimizer("rmsprop", learning_rate=1e-3, max_grad_norm=None)
+    step_fn = build_train_step(net, opt, sync_in_step=False, jit=False)
+    build = dict(batch_size=16, steps_per_call=4, target_sync_freq=8,
+                 sample_ahead=True)
+    folded = build_fused_learn_step(step_fn, include_ingest=True, **build)
+    plain = build_fused_learn_step(step_fn, include_ingest=False, **build)
+    add = jax.jit(device_replay_add)
+
+    def run(fold: bool):
+        state = init_train_state(
+            net, opt, jax.random.PRNGKey(3), jnp.zeros((1, 8), jnp.uint8))
+        ring = init_device_replay(128, (8,))
+        rng = jax.random.PRNGKey(11)
+        for call in range(3):
+            chunk = jax.tree_util.tree_map(
+                jnp.asarray, np_chunk(32, seed=20 + call))
+            prio = jnp.asarray(np.random.default_rng(call).uniform(
+                0.1, 2.0, 32).astype(np.float32))
+            rng, sub = jax.random.split(rng)
+            if fold:
+                state, ring, m = folded(state, ring, chunk, prio, 0.4, sub)
+            else:
+                ring = add(ring, chunk, prio)
+                state, ring, m = plain(state, ring, 0.4, sub)
+        return jax.device_get((state, ring, m.loss))
+
+    separate, inside = run(False), run(True)
+    for a, b in zip(jax.tree_util.tree_leaves(separate),
+                    jax.tree_util.tree_leaves(inside)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.isfinite(separate[2]).all()
 
 
 class TestAsyncPipelineFusedMode:

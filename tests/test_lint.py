@@ -6,7 +6,7 @@ Three layers of coverage:
     under tests/fixtures/lint/, asserting it fires with the right
     checker id and file:line (and does NOT fire on the blessed idioms);
   * **the repo itself** — the committed tree must lint clean against
-    the committed baseline (the pytest twin of verify gate 12), and the
+    the committed baseline (the pytest twin of verify gate 14), and the
     import-light contract is re-proven DYNAMICALLY by importing each
     contracted module in a subprocess and asserting jax never loads;
   * **doc-schema pins** — the cheap runtime dict-vs-docs/METRICS.md
@@ -247,7 +247,7 @@ class TestBaselineProtocol:
 
 
 # ---------------------------------------------------------------------------
-# The repo itself: the pytest twin of verify gate 12.
+# The repo itself: the pytest twin of verify gate 14.
 # ---------------------------------------------------------------------------
 
 

@@ -286,7 +286,7 @@ class _HealthStub:
 class TestRouter:
     """Health-aware routing over in-process stub replicas: real sockets,
     real /healthz probes, no subprocesses (the subprocess e2e lives in
-    tools/serving_net_smoke.py, verify gate 9)."""
+    tools/serving_net_smoke.py, verify gate 8)."""
 
     def _fleet(self, n=2):
         replicas = []

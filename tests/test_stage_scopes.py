@@ -229,18 +229,13 @@ def test_stage_timer_stage_is_also_a_trace_annotation(monkeypatch):
     with pytest.raises(RuntimeError):
         with timers.stage("fused_dispatch"):
             raise RuntimeError("dispatch failed")
-    timers.add("publish", 0.25)  # clock only: no annotation
-    with timers.span("ingest_prepare"):  # span only: nothing counted
-        pass
     assert entered == [("enter", "apex:ingest"), ("exit", "apex:ingest"),
-                       ("enter", "apex:fused_dispatch"), ("exit", "apex:fused_dispatch"),
-                       ("enter", "apex:ingest_prepare"), ("exit", "apex:ingest_prepare")]
+                       ("enter", "apex:fused_dispatch"), ("exit", "apex:fused_dispatch")]
     snap = timers.snapshot()
-    assert set(snap) == {"ingest", "fused_dispatch", "publish"}
-    assert snap["publish"] == {"total_s": 0.25, "calls": 1, "us_per_call": 250000.0}
+    assert set(snap) == {"ingest", "fused_dispatch"}
     assert snap["fused_dispatch"]["calls"] == 1
     us = timers.us_per_call()
-    assert us["publish"] == 250000.0 and us["ingest"] >= 0.0
+    assert us["fused_dispatch"] >= 0.0 and us["ingest"] >= 0.0
 
 
 def _fake_profile(ops, modules, spans):

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Elastic-autopilot smoke gate (tools/verify_t1.sh gate 13).
+"""Elastic-autopilot smoke gate (tools/verify_t1.sh gate 12).
 
 ROADMAP item 3's done-condition, CI-sized, on real processes: a mid-run
 load change on EACH fleet absorbed by the capacity controller with the

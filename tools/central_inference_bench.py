@@ -26,8 +26,8 @@ trips, not network latency — the xp_net caveat, on the inference plane.
 The ``replica_kill`` leg embeds tools/central_inference_smoke.py's
 verdict (run as a subprocess): a 2-replica routed fleet takes a mid-run
 SIGKILL under live paramless training — zero torn frames, zero drops,
-training continues.  Output: one JSON line (bench.py
-``central_inference`` section; committed as demos/central_inference.json).
+training continues.  Output: one JSON line (committed as
+demos/central_inference.json).
 """
 
 from __future__ import annotations

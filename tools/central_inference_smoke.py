@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Central-inference smoke gate (tools/verify_t1.sh gate 11).
+"""Central-inference smoke gate (tools/verify_t1.sh gate 10).
 
 The SEED-style production story, CI-sized, end to end on REAL processes
 and real sockets: a training run whose actors hold NO params and select

@@ -1,4 +1,4 @@
-"""Chaos smoke gate (tools/verify_t1.sh gate 6): the fault-tolerance
+"""Chaos smoke gate (tools/verify_t1.sh gate 5): the fault-tolerance
 contract, CI-sized.
 
 One bounded pass (<60 s of run time on a healthy host) over the

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Elastic-replay smoke gate (tools/verify_t1.sh gate 14).
+"""Elastic-replay smoke gate (tools/verify_t1.sh gate 13).
 
 The replay service as the third autopilot-governed fleet, CI-sized, on
 real shard processes and the real discovery plane — no jax, no trainer:

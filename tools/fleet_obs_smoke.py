@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Fleet-observability smoke gate (tools/verify_t1.sh gate 12).
+"""Fleet-observability smoke gate (tools/verify_t1.sh gate 11).
 
 The fleet-wide observability plane end to end, CI-sized, on real
 processes:

@@ -9,7 +9,7 @@ Usage (from the repo root):
 
 The committed suppression file is ``ape_x_dqn_tpu/analysis/baseline.json``;
 every entry must carry a reason, and a finding not in the baseline fails
-the run (verify gate 12 — ``--fail-on-new`` is the default and the flag
+the run (verify gate 14 — ``--fail-on-new`` is the default and the flag
 exists only to make the gate's intent explicit).  Stale baseline entries
 (suppressing nothing) are reported so the file shrinks over time.
 

@@ -19,8 +19,7 @@ Four phases, one JSON artifact:
 Usage:
     python tools/loadgen.py --clients 32 --duration 6 \
         --out demos/serving_loadgen.json
-The result JSON is always printed as the LAST stdout line (bench.py's
-``serving_qps`` section parses it from a CPU-pinned subprocess).
+The result JSON is always printed as the LAST stdout line.
 
 **Socket mode** (ISSUE 9): the same closed loop over REAL sockets —
 ``ServingClient`` connections through the replica router
@@ -32,8 +31,7 @@ SIGKILLs.  Three entry flags:
     it, tear it down; ``--kill-replica-at SEC`` SIGKILLs one replica
     mid-window (the router-recovery measurement);
   * ``--compare-replicas 1,2`` — the scale-out artifact: one fleet per
-    width with matched total load (demos/serving_net.json; bench.py's
-    ``serving_net`` section runs this CPU-pinned);
+    width with matched total load (demos/serving_net.json);
   * ``--connect HOST:PORT`` — clients only, against an external fleet.
 """
 
@@ -818,7 +816,7 @@ def main(argv=None) -> int:
     p.add_argument(
         "--platform", default=None,
         help="force a jax platform (e.g. 'cpu') BEFORE backend init — how "
-        "a parent that owns the chip (bench.py) runs this beside itself; "
+        "a parent that owns the chip runs this beside itself; "
         "socket modes that spawn their own fleet default to 'cpu'",
     )
     p.add_argument("--out", default=None, help="write the result JSON here")

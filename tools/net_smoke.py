@@ -1,4 +1,4 @@
-"""Network-transport smoke gate (tools/verify_t1.sh gate 8).
+"""Network-transport smoke gate (tools/verify_t1.sh gate 7).
 
 The TCP experience transport's end-to-end contract, CI-sized, on the
 REAL process-actor pipeline (actor.transport=tcp, loopback):

@@ -15,8 +15,7 @@ sample + priority write-back per iteration, warm buffer):
 
 On a 1-core host the RPC legs price CPU (serialize/deflate/copy), not
 network — the same caveat the xp_net bench carries.  Output: one JSON
-line (bench.py `replay_svc` section parses it; committed as
-demos/replay_svc.json).
+line (committed as demos/replay_svc.json).
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Replay-as-a-service smoke gate (tools/verify_t1.sh gate 10).
+"""Replay-as-a-service smoke gate (tools/verify_t1.sh gate 9).
 
 The N-learner sharded-replay architecture end to end, CI-sized, on real
 subprocess shards, real CLI learners, and a real remote-worker host:

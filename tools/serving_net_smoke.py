@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Serving-net smoke gate (tools/verify_t1.sh gate 9).
+"""Serving-net smoke gate (tools/verify_t1.sh gate 8).
 
 The network serving tier's end-to-end contract, CI-sized, on REAL
 subprocess replicas and real sockets:

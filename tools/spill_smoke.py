@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Tiered-replay smoke — verify_t1.sh GATE 7 (ISSUE 7).
+"""Tiered-replay smoke — verify_t1.sh GATE 6 (ISSUE 7).
 
 CI-sized proof of the cold tier's whole contract, in seconds:
 

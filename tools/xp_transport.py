@@ -577,6 +577,7 @@ def run_sigkill_barrage(workers: int = 4, rounds: int = 2, rows: int = 64,
 if __name__ == "__main__":
     import argparse
     import json
+    import sys
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--workers", default="4,16,64")
@@ -584,7 +585,23 @@ if __name__ == "__main__":
     ap.add_argument("--rows", type=int, default=64)
     ap.add_argument("--obs", default="84x84x1")
     ap.add_argument("--skip-barrage", action="store_true")
+    ap.add_argument(
+        "--smoke", action="store_true",
+        help="CI gate (tools/verify_t1.sh): one tiny point and a one-round "
+        "barrage, seconds not minutes; exits non-zero on a lost committed "
+        "chunk, so an import-time regression in the transport can't reach "
+        "the driver unseen",
+    )
     args = ap.parse_args()
+    if args.smoke:
+        small = dict(rows=16, obs_shape=(16, 16, 1))
+        out = run_transport_bench([2], seconds=0.5, **small)
+        bar = out["sigkill_barrage"] = run_sigkill_barrage(
+            workers=2, rounds=1, **small)
+        print(json.dumps({"xp_transport_smoke": out}))
+        lost = bar["lost_committed_chunks"] or bar["seq_errors"]
+        sys.exit(f"xp_transport smoke: lost or misordered chunks: {bar}"
+                 if lost else 0)
     obs = tuple(int(x) for x in args.obs.split("x"))
     out = {
         "bench": run_transport_bench(
