@@ -15,12 +15,19 @@ The expert layer is one chip's share of an expert-parallel layer: it is told
 how many experts exist (the router's outputs), how many a token takes and
 which range ``[lo, hi)`` it holds.  It routes over all of them, normalises
 the gates over all the chosen ones, and computes its own experts' part of
-the sum; nothing stands in for the others.  The token-expert pairs that fall
-on held experts are sorted by expert into a buffer of rows and multiplied by
+the sum; nothing stands in for the others.  The token-expert pairs are sorted
+by expert, those on held experts first, and the held ones are walked a tile
+of rows at a time (``held_experts``): gather the tile's tokens, multiply by
 groups (``jax.lax.ragged_dot``: on the TPU a grouped kernel that walks the
-tiles the group sizes name and skips the rows past the last group).  The
-buffer holds the worst case, every pair of every token on a held expert, so
-no pair is ever dropped.  Every layer is recomputed in the backward pass
+tiles the group sizes name and skips the rows past the last group), add each
+row, by its gate, into its token's sum.  The tiles walked are those the held
+pairs fill, a loop whose count the routing of the call gives: every pair on
+a held expert is multiplied, none is ever dropped, and nothing of the worst
+case's ``tokens x k`` rows by a width is built.  A tile's rows come from the
+shapes (``tile_rows``).  The walk's backward pass is written by hand
+(``jax.custom_vjp``): it walks the same tiles, computes each again and keeps
+nothing of a tile, where reverse-mode differentiation of the loop would stack
+every tile's residuals.  Every layer is recomputed in the backward pass
 (``nn.remat``), and consecutive layers of one kind run as one scanned body
 over their stacked parameters (``layers_<first>_<last>``): one copy of the
 layer's kernels in the executable for the run, not one a layer.
@@ -46,6 +53,7 @@ mean(load) - 1, -1, 1)`` over the step's online forwards.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Mapping, Sequence, Tuple
 
 import jax
@@ -256,6 +264,139 @@ def route(scores, bias, spec: TorsoSpec):
     return chosen, gates * spec.routed_scaling_factor
 
 
+# The grouped kernel's own tile: a walk's tile is a whole number of these.
+KERNEL_ROWS = 512
+
+
+def tile_rows(rows, num_held: int, router_outputs: int):
+    """Rows of one tile of the walk over the held pairs, from the shapes:
+    the fill even loads give (``rows`` pairs over the router's outputs,
+    ``num_held`` of them here) plus a third, in whole kernel tiles, and never
+    more than ``rows``.  One tile then covers a layer's held pairs unless the
+    held experts draw a third over their share; a chip that holds every
+    expert gets one tile of ``rows`` rows.  ``rows`` may be traced
+    (``routing_metrics``)."""
+    fill = rows * num_held // router_outputs
+    whole = -(-(fill + fill // 3) // KERNEL_ROWS) * KERNEL_ROWS
+    return min(rows, whole) if isinstance(rows, int) else jnp.minimum(rows, whole)
+
+
+_DW_DIMS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _tile(t, tile: int, k: int, order, starts, ends, gates):
+    """Tile ``t`` of the sorted pairs: (its rows' pairs, their tokens, which
+    rows hold a held pair, their gates, the rows of each expert in it)."""
+    first = t * tile
+    pair = jax.lax.dynamic_slice(order, (first,), (tile,))
+    live = first + jnp.arange(tile) < ends[-1]
+    gate = jnp.where(live, gates.reshape(-1)[pair], 0.0)
+    sizes = jnp.clip(ends, first, first + tile) - jnp.clip(starts, first, first + tile)
+    return pair, pair // k, live, gate, sizes
+
+
+def _walk(order, sizes, tile: int):
+    """(``order`` padded to whole tiles, the experts' first rows and ends,
+    the tiles that hold a held pair)."""
+    ends = jnp.cumsum(sizes)
+    order = jnp.pad(order, (0, -order.shape[0] % tile))
+    return order, ends - sizes, ends, -(-ends[-1] // tile)
+
+
+def _products(xs, w13, w2, sizes):
+    """(h, a, ys) of a tile's rows ``xs``: ``ys = (silu(h1) * h3) @ w2`` by
+    groups, ``[h1, h3] = xs @ w13``."""
+    f = w2.shape[1]
+    h = jax.lax.ragged_dot(xs, w13, sizes)
+    a = jax.nn.silu(h[:, :f]) * h[:, f:]
+    return h, a, jax.lax.ragged_dot(a, w2, sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def held_experts(u, w13, w2, gates, order, sizes, tile: int):
+    """The held experts' part of the layer's sum, [tokens, d] in ``u``'s type.
+
+    ``u`` [tokens, d]; ``w13`` [n, d, 2f], ``w2`` [n, f, d]; ``gates``
+    [tokens, k], zero off the held range; ``order`` [tokens * k]: the pairs
+    sorted by expert, the held ones first; ``sizes`` [n]: the pairs on each
+    held expert.  The sorted pairs are walked ``tile`` rows at a time over the
+    tiles that hold a held pair: gather the rows' tokens, multiply by groups,
+    add ``gate * row`` into the token's float32 sum.  The backward pass walks
+    the same tiles and computes each again: nothing of a tile is kept."""
+    return _held_experts_fwd(u, w13, w2, gates, order, sizes, tile)[0]
+
+
+def _held_experts_fwd(u, w13, w2, gates, order, sizes, tile: int):
+    cd, k = u.dtype, gates.shape[1]
+    with part("router"):
+        padded, starts, ends, tiles = _walk(order, sizes, tile)
+    with part("experts"):
+        w13c, w2c = w13.astype(cd), w2.astype(cd)
+
+    def body(t, y):
+        with part("router"):
+            _, token, live, gate, group = _tile(t, tile, k, padded, starts, ends, gates)
+            xs = u[token]
+        with part("experts"):
+            _, _, ys = _products(xs, w13c, w2c, group)
+            # Rows past the last group are the kernel's to leave unwritten.
+            ys = jnp.where(live[:, None], ys.astype(jnp.float32) * gate[:, None], 0.0)
+        with part("router"):
+            return y.at[token].add(ys)
+
+    with part("router"):
+        y = jax.lax.fori_loop(0, tiles, body, jnp.zeros(u.shape, jnp.float32))
+        return y.astype(cd), (u, w13, w2, gates, order, sizes)
+
+
+def _held_experts_bwd(tile: int, kept, dy):
+    u, w13, w2, gates, order, sizes = kept
+    cd, k, f = u.dtype, gates.shape[1], w2.shape[1]
+    with part("router"):
+        padded, starts, ends, tiles = _walk(order, sizes, tile)
+    with part("experts"):
+        w13c, w2c = w13.astype(cd), w2.astype(cd)
+        w13t, w2t = jnp.swapaxes(w13c, 1, 2), jnp.swapaxes(w2c, 1, 2)
+
+    def body(t, carry):
+        du, dw13, dw2, dgates = carry
+        with part("router"):
+            pair, token, live, gate, group = _tile(t, tile, k, padded, starts, ends, gates)
+            xs, dy_rows = u[token], dy[token]
+        with part("experts"):
+            h, a, ys = _products(xs, w13c, w2c, group)
+            dy_rows = dy_rows.astype(jnp.float32)
+            dgate = jnp.where(live, jnp.sum(dy_rows * ys.astype(jnp.float32), -1), 0.0)
+            dys = jnp.where(live[:, None], dy_rows * gate[:, None], 0.0).astype(cd)
+            da = jax.lax.ragged_dot(dys, w2t, group).astype(jnp.float32)
+            h1, h3 = h[:, :f].astype(jnp.float32), h[:, f:].astype(jnp.float32)
+            s = jax.nn.sigmoid(h1)
+            dh = jnp.concatenate([da * h3 * s * (1.0 + h1 * (1.0 - s)), da * h1 * s],
+                                 axis=-1).astype(cd)
+            # The rows that ``h`` and ``da`` leave unwritten reach no sum: a
+            # ragged contraction reads its groups' rows alone.
+            dw2 = dw2 + jax.lax.ragged_dot_general(
+                a, dys, group, _DW_DIMS, preferred_element_type=jnp.float32)
+            dw13 = dw13 + jax.lax.ragged_dot_general(
+                xs, dh, group, _DW_DIMS, preferred_element_type=jnp.float32)
+            dxs = jax.lax.ragged_dot(dh, w13t, group)
+            dxs = jnp.where(live[:, None], dxs, 0).astype(jnp.float32)
+        with part("router"):
+            return du.at[token].add(dxs), dw13, dw2, dgates.at[pair].add(dgate)
+
+    with part("router"):
+        zeros = lambda x: jnp.zeros(x.shape, jnp.float32)  # noqa: E731
+        du, dw13, dw2, dgates = jax.lax.fori_loop(
+            0, tiles, body, (zeros(u), zeros(w13), zeros(w2), zeros(gates.reshape(-1))))
+        return (du.astype(cd), dw13.astype(w13.dtype), dw2.astype(w2.dtype),
+                dgates.reshape(gates.shape).astype(gates.dtype), None, None)
+
+
+held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 class ExpertShare(nn.Module):
     """One chip's share of a mixture-of-experts layer (module docstring)."""
 
@@ -279,8 +420,7 @@ class ExpertShare(nn.Module):
         w2 = self.param("w2", _lecun(batch_axis=(0,)), (n, f, d), self.param_dtype)
         shape = u.shape
         u = u.reshape(-1, d)
-        tokens = u.shape[0]
-        rows = tokens * k  # the worst case: every pair of every token on a held expert
+        rows = u.shape[0] * k  # every pair of every token
 
         with part("router"):
             scores = jax.nn.sigmoid(jnp.dot(
@@ -289,23 +429,11 @@ class ExpertShare(nn.Module):
             held = (chosen >= lo) & (chosen < hi)
             load = jnp.sum(jax.nn.one_hot(chosen.reshape(-1), sp.router_outputs,
                                           dtype=jnp.int32), axis=0)
-            sizes = load[lo:hi]
             # Pairs on held experts first, by expert; the others after them.
-            key = jnp.where(held, chosen - lo, n).reshape(-1)
-            order = jnp.argsort(key, stable=True)
-            live = jnp.arange(rows) < jnp.sum(sizes)
-            where = jnp.argsort(order).reshape(tokens, k)  # pair -> its row
-            weight = jnp.where(held, gates, 0.0)
-            xs = jnp.where(live[:, None], u[order // k], 0).astype(cd)
-        with part("experts"):
-            h = jax.lax.ragged_dot(xs, w13.astype(cd), sizes)
-            a = jax.nn.silu(h[:, :f]) * h[:, f:]
-            ys = jax.lax.ragged_dot(a, w2.astype(cd), sizes)
-        with part("router"):
-            # Rows past the last group are the kernel's to leave unwritten.
-            ys = jnp.where(live[:, None], ys, 0)
-            y = sum(ys[where[:, j]].astype(jnp.float32) * weight[:, j, None]
-                    for j in range(k)).astype(cd)
+            order = jnp.argsort(jnp.where(held, chosen - lo, n).reshape(-1), stable=True)
+            gates = jnp.where(held, gates, 0.0)
+        y = held_experts(u.astype(cd), w13, w2, gates, order, load[lo:hi],
+                         tile_rows(rows, n, sp.router_outputs))
         if not self.is_initializing():  # ``init`` returns parameters alone
             self.sow(ROUTING, "load", load)
         return y.reshape(shape)
@@ -380,12 +508,18 @@ class Lfm2MoeQ(nn.Module):
         """What one ``apply(..., mutable=[ROUTING])`` sowed, as the train
         step's counters, summed over the expert layers (float32 [] each):
         the pairs on held experts, the largest and the mean load of a held
-        expert."""
+        expert, and the rows the layers walked for them (tiles by the rows
+        of a tile; every pair of a token is on one of the router's outputs,
+        so a layer's loads add up to its ``tokens x k``)."""
         lo, hi = self.spec.experts_held
-        held = jnp.concatenate([v[..., lo:hi].reshape(-1, hi - lo).astype(jnp.float32)
-                                for v in jax.tree_util.tree_leaves(sown[ROUTING])])
+        loads = jnp.concatenate([v.reshape(-1, v.shape[-1])
+                                 for v in jax.tree_util.tree_leaves(sown[ROUTING])])
+        held = loads[:, lo:hi].astype(jnp.float32)
+        tile = tile_rows(jnp.sum(loads, -1), hi - lo, self.spec.router_outputs)
+        walked = -(-jnp.sum(loads[:, lo:hi], -1) // tile) * tile
         return {"held_pairs": jnp.sum(held), "load_max": jnp.sum(jnp.max(held, -1)),
-                "load_mean": jnp.sum(jnp.mean(held, -1))}
+                "load_mean": jnp.sum(jnp.mean(held, -1)),
+                "rows_walked": jnp.sum(walked).astype(jnp.float32)}
 
     def rebalanced(self, params, sown):
         """``params`` after the balancing rule (module docstring) on the

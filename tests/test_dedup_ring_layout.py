@@ -14,6 +14,12 @@ The fused program at the paper's shapes (``benchmark/configs/apex_b512.json``,
 one chip and the four-chip shard) holds no convolution or product over two
 batches of rows: the backward pass covers the rows that carry a gradient.
 
+One expert block of ``benchmark/configs/lfm2moe_q_ep8.json`` at its published
+widths, forward and backward: no array of the worst case's ``tokens x k``
+rows by an expert's width, every grouped product inside a loop over the
+tiles the routing fills, and fewer temporaries than the layer that built the
+worst case's buffers took.
+
 Every test here shares one description of the topology, made in a fixture:
 only one process may load the TPU's library (on-chip-measurement guide).
 """
@@ -230,15 +236,15 @@ def _programs(topo, n: int, shapes: dict = CHECK) -> dict:
     }
 
 
-def _compile_text(jitted, args) -> str:
-    """The optimized text, or a failure once ``COMPILE_LIMIT_S`` have gone
-    (the compile runs on a thread of this process, which holds the TPU's
-    library; a stuck one is left behind as a daemon)."""
+def _compile(jitted, args):
+    """The executable, or a failure once ``COMPILE_LIMIT_S`` have gone (the
+    compile runs on a thread of this process, which holds the TPU's library;
+    a stuck one is left behind as a daemon)."""
     box = {}
 
     def work():
         try:
-            box["text"] = jitted.lower(*args).compile().as_text()
+            box["done"] = jitted.lower(*args).compile()
         except BaseException as e:  # noqa: BLE001 - re-raised below
             box["error"] = e
 
@@ -249,7 +255,11 @@ def _compile_text(jitted, args) -> str:
         pytest.fail(f"compile for v5e not done in {COMPILE_LIMIT_S:.0f} s")
     if "error" in box:
         raise box["error"]
-    return box["text"]
+    return box["done"]
+
+
+def _compile_text(jitted, args) -> str:
+    return _compile(jitted, args).as_text()
 
 
 @pytest.mark.parametrize("program,scatters", [
@@ -340,3 +350,55 @@ ENTRY %main (state_frames.1: u8[5120,84,84,4]) -> u8[5120,84,84,4] {
         "copy.3", "fusion", "copy.5"]
     with pytest.raises(AssertionError, match="not major"):
         assert_ring_stays_put(text, ring_bytes, 1)
+
+
+# The temporaries of one expert block's forward and backward at the published
+# widths when the layer built the worst case's buffers (the parent of PR 31,
+# compiled here for v5e by this test's own program).
+WORST_CASE_BLOCK_TEMP_BYTES = 3_154_748_928
+
+
+def test_the_expert_layer_builds_no_worst_case_buffer(topo, no_compile_cache):
+    from flax import linen as nn
+
+    from ape_x_dqn_tpu.models.lfm2_moe import Block, spec_from_config
+
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / "lfm2moe_q_ep8.json").read_text())
+    spec = spec_from_config(cfg)
+    assert (spec.hidden_size, spec.moe_intermediate_size, spec.num_held,
+            spec.router_outputs, spec.num_experts_per_tok) == (2048, 1536, 8, 64, 4)
+    tokens, k = (cfg["batch_size"], 49), spec.num_experts_per_tok
+    block = nn.remat(Block)(spec, "conv", "moe", jnp.bfloat16, jnp.float32)
+    dev = SingleDeviceSharding(topo.devices[0])
+    params = _with(jax.eval_shape(
+        lambda key: block.init(key, jnp.zeros((1, 49, spec.hidden_size), jnp.bfloat16)),
+        jax.random.PRNGKey(0)), dev)
+    h = jax.ShapeDtypeStruct((*tokens, spec.hidden_size), jnp.bfloat16, sharding=dev)
+
+    def loss(p, h):
+        (out, _), sown = block.apply(p, h, mutable=["routing"])
+        return jnp.sum(jnp.square(out.astype(jnp.float32))), sown
+
+    compiled = _compile(jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)),
+                        (params, h))
+    text = compiled.as_text()
+    rows = tokens[0] * tokens[1] * k
+    assert rows == 100_352
+    wide = [dims for _, dims, _ in _ARRAY.findall(text)
+            if re.fullmatch(rf"{rows},\d+", dims)
+            and int(dims.split(",")[1]) >= spec.moe_intermediate_size]
+    assert not wide, sorted(set(wide))
+    bodies = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
+    inside, products = None, []
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            inside = head.group(1)
+        elif 'custom_call_target="tpu_custom_call"' in line:
+            products.append(inside)
+    # two products a forward tile; those two again, two data and two weight
+    # gradients a backward tile; the recomputed forward's loop is dead and gone
+    assert len(bodies) == 2 and len(products) >= 8 and set(products) == bodies, products
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < WORST_CASE_BLOCK_TEMP_BYTES, temp

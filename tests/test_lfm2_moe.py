@@ -3,6 +3,8 @@
 forwards, the ``torso:`` scopes, the configuration path and the trainer's
 loop, all at small widths on the CPU."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,8 +12,10 @@ import pytest
 
 from ape_x_dqn_tpu.config import ApexConfig, load_config, network_kwargs
 from ape_x_dqn_tpu.models.dueling import build_greedy_apply, build_network
+from ape_x_dqn_tpu.models import lfm2_moe
 from ape_x_dqn_tpu.models.lfm2_moe import (
-    BIAS_UPDATE_RATE, Attention, ShortConv, layer_runs, spec_from_config,
+    BIAS_UPDATE_RATE, Attention, ExpertShare, ShortConv, layer_runs, route, spec_from_config,
+    tile_rows,
 )
 from ape_x_dqn_tpu.utils import profiling
 
@@ -74,7 +78,113 @@ def test_forward_counts_every_pair():
     totals = net.routing_metrics(sown)
     assert float(totals["held_pairs"]) == sum(int(v[:2].sum()) for v in loads)
     assert float(totals["load_max"]) >= float(totals["load_mean"]) > 0
-    assert set(totals) == {"held_pairs", "load_max", "load_mean"}
+    assert set(totals) == {"held_pairs", "load_max", "load_mean", "rows_walked"}
+    # at these widths one tile holds every pair, and each layer holds some
+    assert float(totals["rows_walked"]) == 2 * 72
+
+
+def worst_case_share(p, u, sp):
+    """The expert layer as one buffer of ``tokens x k`` rows, every pair of
+    every token on a held expert, in float32: the tiled walk's oracle."""
+    f, n, k = sp.moe_intermediate_size, sp.num_held, sp.num_experts_per_tok
+    lo, hi = sp.experts_held
+    shape = u.shape
+    u = u.reshape(-1, shape[-1])
+    tokens, rows = u.shape[0], u.shape[0] * k
+    scores = jax.nn.sigmoid(jnp.dot(u, p["router"], precision=jax.lax.Precision.HIGHEST))
+    chosen, gates = route(scores, jax.lax.stop_gradient(p["expert_bias"]), sp)
+    held = (chosen >= lo) & (chosen < hi)
+    sizes = jnp.sum(jax.nn.one_hot(chosen.reshape(-1), sp.router_outputs,
+                                   dtype=jnp.int32), axis=0)[lo:hi]
+    order = jnp.argsort(jnp.where(held, chosen - lo, n).reshape(-1), stable=True)
+    live = jnp.arange(rows) < jnp.sum(sizes)
+    where = jnp.argsort(order).reshape(tokens, k)  # pair -> its row
+    weight = jnp.where(held, gates, 0.0)
+    xs = jnp.where(live[:, None], u[order // k], 0)
+    h = jax.lax.ragged_dot(xs, p["w13"], sizes)
+    ys = jax.lax.ragged_dot(jax.nn.silu(h[:, :f]) * h[:, f:], p["w2"], sizes)
+    ys = jnp.where(live[:, None], ys, 0)
+    return sum(ys[where[:, j]] * weight[:, j, None] for j in range(k)).reshape(shape)
+
+
+# 36 tokens, 2 of the router's 4 outputs a token, outputs [0, 2) held: 72 pairs.
+# (the bias that places the pairs, rows a tile, tiles walked; None: the loads say)
+WALKS = {
+    "typical_loads": ([0.0, 0.0, 0.0, 0.0], 8, None),
+    "every_pair_held": ([5.0, 5.0, -5.0, -5.0], 8, 9),
+    "every_pair_held_last_tile_part_filled": ([5.0, 5.0, -5.0, -5.0], 16, 5),
+    "no_pair_held": ([-5.0, -5.0, 5.0, 5.0], 8, 0),
+    "whole_tiles": ([5.0, -5.0, 0.0, 0.0], 12, 3),
+    "one_expert_holds_everything": ([5.0, -5.0, 0.0, 0.0], 8, 5),
+}
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_the_tiled_walk_is_the_worst_case_buffer(monkeypatch, walk):
+    """Values and gradients (tokens, both expert weights, the router) of the
+    layer that walks its held pairs a tile at a time, against one buffer of
+    every pair, with the tile forced small."""
+    bias, tile, tiles = WALKS[walk]
+    monkeypatch.setattr(lfm2_moe, "tile_rows", lambda rows, held, outputs: tile)
+    sp = spec_from_config(TORSO)
+    layer = ExpertShare(sp, jnp.float32, jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(11), (4, 9, 64))
+    cot = jax.random.normal(jax.random.PRNGKey(12), u.shape)
+    params = dict(layer.init(jax.random.PRNGKey(13), u)["params"], expert_bias=jnp.array(bias))
+
+    def tiled(p, u):
+        y, sown = layer.apply({"params": p}, u, mutable=["routing"])
+        return jnp.sum(y * cot), (y, sown["routing"]["load"][0])
+
+    def plain(p, u):
+        y = worst_case_share(p, u, sp)
+        return jnp.sum(y * cot), y
+
+    grad = lambda fn: jax.jit(jax.value_and_grad(fn, argnums=(0, 1), has_aux=True))  # noqa: E731
+    (_, (y, load)), (dp, du) = grad(tiled)(params, u)
+    (_, want_y), (want_dp, want_du) = grad(plain)(params, u)
+    held = int(load[:2].sum())
+    assert int(load.sum()) == 72
+    if tiles is None:
+        assert 3 * tile < held < 72 and held % tile
+    else:
+        assert -(-held // tile) == tiles and (walk != "whole_tiles" or held == tiles * tile)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=2e-6)
+    np.testing.assert_allclose(np.asarray(du), np.asarray(want_du), atol=2e-6)
+    for leaf in ("w13", "w2", "router", "expert_bias"):
+        np.testing.assert_allclose(np.asarray(dp[leaf]), np.asarray(want_dp[leaf]),
+                                   atol=1e-5, err_msg=leaf)
+    if held:
+        assert all(float(jnp.max(jnp.abs(g))) > 1e-3
+                   for g in (y, du, dp["w13"], dp["w2"], dp["router"]))
+    else:
+        assert not any(np.asarray(g).any() for g in (y, du, *jax.tree_util.tree_leaves(dp)))
+    if walk == "one_expert_holds_everything":
+        assert int(load[0]) == held and not np.asarray(dp["w13"][1]).any()
+
+
+def test_a_tile_comes_from_the_shapes():
+    """A chip that holds every expert walks one tile of ``tokens x k`` rows,
+    today's buffer; one that holds a share walks whole kernel tiles a little
+    over the fill even loads give."""
+    for rows in (72, 6272, 100352):
+        assert tile_rows(rows, 4, 4) == rows == tile_rows(rows, 64, 64)
+    assert tile_rows(72, 2, 4) == 72                      # the toy networks: one tile
+    cell = tile_rows(512 * 49 * 4, 8, 64)                 # lfm2moe_q_ep8: 12,544 expected
+    assert cell % lfm2_moe.KERNEL_ROWS == 0 and 12544 < cell < 2 * 12544
+    serving = tile_rows(32 * 49 * 4, 8, 64)               # action selection: 784 expected
+    assert serving % lfm2_moe.KERNEL_ROWS == 0 and 784 < serving <= 1536
+
+
+def test_rows_walked_is_tiles_by_tile(monkeypatch):
+    monkeypatch.setattr(lfm2_moe, "tile_rows", lambda rows, held, outputs: 8)
+    net = small_net()
+    x = obs(jax.random.PRNGKey(2))
+    _, sown = net.apply(net.init(jax.random.PRNGKey(3), x), x, mutable=["routing"])
+    loads = [np.asarray(v) for v in jax.tree_util.tree_leaves(sown["routing"])]
+    want = sum(-(-int(v[:2].sum()) // 8) * 8 for v in loads)
+    totals = net.routing_metrics(sown)
+    assert float(totals["rows_walked"]) == want and want > float(totals["held_pairs"]) > want - 16
 
 
 LONG = dict(layer_types=["conv", "conv", "full_attention", "conv", "conv", "conv"],
@@ -214,6 +324,23 @@ def test_parts_are_scoped_beside_the_stages():
     # a part is read beside its stage: the experts' instructions are the
     # forward's and the backward's
     assert {stages[name] for name, p in parts.items() if p == "experts"} >= {"forward", "backward"}
+    # the expert layers' hand-written backward pass: one loop a layer, and every
+    # instruction of it that is named at all is the backward's and a part's
+    loops = [m.groups() for m in re.finditer(
+        r"%?([\w.\-]+) = [^\n]*? while\([^\n]*?condition=%?([\w.\-]+), body=%?([\w.\-]+)",
+        compiled) if stages[m.group(1)] == "backward"]
+    assert len(loops) == 2 and all(parts[loop] == "router" for loop, _, _ in loops)
+    found = {name: set() for _, cond, body in loops for name in (cond, body)}
+    computation = None
+    for line in compiled.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            computation = head.group(1)
+        elif computation in found and "op_name=" in line:
+            name = re.match(r"^\s+(?:ROOT )?%?([\w.\-]+) = ", line).group(1)
+            assert stages[name] == "backward" and parts.get(name) in ("router", "experts"), line
+            found[computation].add(parts[name])
+    assert all(found[body] == {"router", "experts"} for _, _, body in loops), found
 
 
 def test_greedy_apply_serves_the_network():
