@@ -231,6 +231,9 @@ class ActorFleet:
         if got is None:
             return False
         params, self.param_version = got
+        # The old copy goes before the new one comes: beside a learner on
+        # the same chip two copies of a large network do not fit.
+        self.params = None
         self.params = jax.device_put(params)
         return True
 

@@ -37,6 +37,10 @@ import dataclasses
 import json
 from typing import Any, Optional, Sequence
 
+# Network kinds whose torso is a stack of blocks built from ``ApexConfig.torso``
+# (the keys of models/dueling.TORSO_KINDS).
+TORSO_NETWORKS = ("lfm2_moe", "laguna_moe")
+
 
 @dataclasses.dataclass
 class EnvConfig:
@@ -819,9 +823,10 @@ class ApexConfig:
         default_factory=AutopilotConfig
     )
     chaos: ChaosConfig = dataclasses.field(default_factory=ChaosConfig)
-    network: str = "conv"                 # "conv" | "nature" | "mlp" | "lfm2_moe"
-    # network=lfm2_moe: the torso's block under the published config.json's
-    # keys plus the cut (models/lfm2_moe.spec_from_config); optionally the
+    network: str = "conv"   # "conv" | "nature" | "mlp" | "lfm2_moe" | "laguna_moe"
+    # network=lfm2_moe | laguna_moe: the torso's block under the published
+    # config.json's keys plus the cut (spec_from_config of models/lfm2_moe.py
+    # or models/laguna_moe.py); optionally the
     # stem's ``channels`` and the head's ``hidden``.
     torso: dict = dataclasses.field(default_factory=dict)
     seed: int = 0
@@ -1053,10 +1058,14 @@ class ApexConfig:
              "actor.num_actors must cover local (incl. max_workers "
              "headroom) + remote workers in process mode"),
             (0.0 <= r.is_exponent <= 1.0, "replay.is_exponent must be in [0, 1]"),
-            (self.network in ("conv", "nature", "mlp", "lfm2_moe"),
+            (self.network in ("conv", "nature", "mlp", *TORSO_NETWORKS),
              f"unknown network kind: {self.network}"),
-            ((self.network == "lfm2_moe") == bool(self.torso),
-             "torso holds the block of network=lfm2_moe, and of no other"),
+            ((self.network in TORSO_NETWORKS) == bool(self.torso),
+             f"torso holds the block of network={' | '.join(TORSO_NETWORKS)}, "
+             "and of no other"),
+            (self.network != "laguna_moe" or self.env.frame_stack > 1,
+             "network=laguna_moe reads an observation as a history of single "
+             "frames: env.frame_stack must be over 1"),
             (l.optimizer in ("rmsprop", "adam"),
              f"unknown optimizer kind: {l.optimizer}"),
             (l.loss in ("huber", "squared"), f"unknown loss kind: {l.loss}"),
@@ -1242,7 +1251,7 @@ def network_kwargs(cfg: ApexConfig) -> dict:
     """What ``models.dueling.build_network`` takes beside the kind, the
     action count and the dtypes: the torso's block, with the stem's and
     head's widths where it states them."""
-    if cfg.network != "lfm2_moe":
+    if cfg.network not in TORSO_NETWORKS:
         return {}
     kw = {"torso": {k: v for k, v in cfg.torso.items() if not k.startswith("_")}}
     if "channels" in cfg.torso:

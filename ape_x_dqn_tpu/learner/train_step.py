@@ -44,6 +44,9 @@ class StepMetrics:
     # A network's ``routing_metrics`` of what its layers sowed (types.ROUTING),
     # summed over the step's three forwards; None for a network without them.
     routing: Optional[dict] = None
+    # A network's ``attention_metrics`` of the step's three forwards, from the
+    # shapes (blocked attention's pairs in the mask and blocks visited).
+    attention: Optional[dict] = None
 
 
 def _scale_by_rms_lowp(
@@ -253,6 +256,7 @@ def build_train_step(
     # for what it moves by them after the update (an expert bias).
     routing_metrics = getattr(network, "routing_metrics", None)
     rebalanced = getattr(network, "rebalanced", None)
+    attention_metrics = getattr(network, "attention_metrics", None)
 
     def q_of(params, obs):
         """(Q, what the network's layers sowed)."""
@@ -290,6 +294,8 @@ def build_train_step(
         add = lambda *trees: jax.tree_util.tree_map(lambda *xs: sum(xs), *trees)  # noqa: E731
         routing = None if routing_metrics is None else add(
             *(routing_metrics(s) for s in (*online, sown_target)))
+        counted = attention_metrics and attention_metrics(batch.transition.obs.shape)
+        attention = {k: jnp.float32(3.0 * v) for k, v in counted.items()} if counted else None
         # Under plain pjit the mean inside loss_fn makes XLA insert the
         # gradient all-reduce over ICI automatically.  Inside shard_map
         # (varying-axes AD semantics): the params enter unvarying while the
@@ -343,6 +349,7 @@ def build_train_step(
             priorities=priorities,
             mean_q=mean_q,
             routing=routing,
+            attention=attention,
         )
         new_state = TrainState(
             params=new_params,

@@ -45,6 +45,10 @@ def _dueling_aggregate(value: jax.Array, advantage: jax.Array) -> jax.Array:
     return value + advantage - jnp.mean(advantage, axis=-1, keepdims=True)
 
 
+# The stem's three convolutions, VALID: (kernel, stride) a side.
+STEM_WINDOWS = ((8, 4), (4, 2), (3, 1))
+
+
 def conv_stem(x: jax.Array, channels: Sequence[int], compute_dtype, param_dtype,
               out_dtype=None) -> jax.Array:
     """Conv(8x8/4) -> Conv(4x4/2) -> Conv(3x3/1), VALID, ReLU, on NHWC uint8
@@ -64,8 +68,8 @@ def conv_stem(x: jax.Array, channels: Sequence[int], compute_dtype, param_dtype,
             f"observations look NCHW (shape {x.shape}); this framework uses "
             "NHWC [B, H, W, C] — transpose with x.transpose(0, 2, 3, 1)"
         )
-    kernels = ((8, 8), (4, 4), (3, 3))
-    strides = ((4, 4), (2, 2), (1, 1))
+    kernels = tuple((k, k) for k, _ in STEM_WINDOWS)
+    strides = tuple((s, s) for _, s in STEM_WINDOWS)
     if len(channels) != len(kernels):
         raise ValueError(
             f"channels must have exactly {len(kernels)} entries, got {channels}"
@@ -175,10 +179,17 @@ def build_greedy_apply(network: nn.Module):
     return greedy_apply
 
 
+# Network kinds whose torso is a stack of blocks: module under models/ -> class.
+TORSO_KINDS = {"lfm2_moe": "Lfm2MoeQ", "laguna_moe": "LagunaMoeQ"}
+
+
 def build_network(kind: str, num_actions: int, **kwargs) -> nn.Module:
-    """Factory keyed by config string: {"conv", "nature", "mlp", "lfm2_moe"}.
-    ``lfm2_moe`` takes ``torso``: the published config's keys and the cut
-    (``models/lfm2_moe.spec_from_config``)."""
+    """Factory keyed by config string: {"conv", "nature", "mlp", "lfm2_moe",
+    "laguna_moe"}.  The last two are torsos of transformer blocks
+    (``models/expert_torso.py``) and take ``torso``: the published config's
+    keys and the cut (``spec_from_config`` of ``models/lfm2_moe.py``, one
+    frame's positions as tokens, and of ``models/laguna_moe.py``, a history
+    of single frames)."""
     if kind == "conv":
         return DuelingDQN(num_actions=num_actions, **kwargs)
     if kind == "nature":
@@ -186,11 +197,12 @@ def build_network(kind: str, num_actions: int, **kwargs) -> nn.Module:
         return DuelingDQN(num_actions=num_actions, **kwargs)
     if kind == "mlp":
         return DuelingMLP(num_actions=num_actions, **kwargs)
-    if kind == "lfm2_moe":
-        from ape_x_dqn_tpu.models.lfm2_moe import Lfm2MoeQ, spec_from_config
+    if kind in TORSO_KINDS:
+        import importlib
 
+        family = importlib.import_module("ape_x_dqn_tpu.models." + kind)
         if not kwargs.get("torso"):
-            raise ValueError("network kind lfm2_moe needs torso=<the block's config>")
-        return Lfm2MoeQ(num_actions=num_actions,
-                        spec=spec_from_config(kwargs.pop("torso")), **kwargs)
+            raise ValueError(f"network kind {kind} needs torso=<the block's config>")
+        return getattr(family, TORSO_KINDS[kind])(
+            num_actions=num_actions, spec=family.spec_from_config(kwargs.pop("torso")), **kwargs)
     raise ValueError(f"unknown network kind: {kind}")

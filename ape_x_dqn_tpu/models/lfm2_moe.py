@@ -1,179 +1,54 @@
-"""A Q-network whose torso is a stack of LFM2-MoE blocks over the stem's grid.
-
-The convolutional stem and the dueling head are ``dueling.py``'s; between
-them the ``h x w`` positions of the stem's output are tokens (raster order,
-Obando-Ceron et al. 2024, arXiv:2402.08609, "PerConv"), projected to the
-torso's width and run through the layers of ``lfm2_moe``
-(LiquidAI/LFM2-24B-A2B, ``config.json``), each
-
-    h <- h + Op(RMSNorm(h))     Op  = gated short convolution | attention
-    h <- h + FFN(RMSNorm(h))    FFN = SwiGLU | mixture of experts
-
-then RMSNorm, the mean over the tokens and the dueling head.
-
-The expert layer is one chip's share of an expert-parallel layer: it is told
-how many experts exist (the router's outputs), how many a token takes and
-which range ``[lo, hi)`` it holds.  It routes over all of them, normalises
-the gates over all the chosen ones, and computes its own experts' part of
-the sum; nothing stands in for the others.  The token-expert pairs are sorted
-by expert, those on held experts first, and the held ones are walked a tile
-of rows at a time (``held_experts``): gather the tile's tokens, multiply by
-groups (``jax.lax.ragged_dot``: on the TPU a grouped kernel that walks the
-tiles the group sizes name and skips the rows past the last group), add each
-row, by its gate, into its token's sum.  The tiles walked are those the held
-pairs fill, a loop whose count the routing of the call gives: every pair on
-a held expert is multiplied, none is ever dropped, and nothing of the worst
-case's ``tokens x k`` rows by a width is built.  A tile's rows come from the
-shapes (``tile_rows``).  The walk's backward pass is written by hand
-(``jax.custom_vjp``): it walks the same tiles, computes each again and keeps
-nothing of a tile, where reverse-mode differentiation of the loop would stack
-every tile's residuals.  Every layer is recomputed in the backward pass
-(``nn.remat``), and consecutive layers of one kind run as one scanned body
-over their stacked parameters (``layers_<first>_<last>``): one copy of the
-layer's kernels in the executable for the run, not one a layer.
-
-The tokens are centred before they are projected: the mean over a frame's
-positions is taken off the stem's output, in float32 (the stem's last
-convolution returns float32 here).  A fresh stem's positions share most of
-their direction (its ReLU outputs are positive), and a router fed that
-direction sends nearly every token to the same few experts, whatever its
-bias.
-
-Per call an expert layer sows, in the collection ``types.ROUTING``, ``load``:
-the pairs on each of the router's outputs, held or not.  The network reads
-it for the train step: ``routing_metrics`` (the counters of the held range)
-and ``rebalanced`` (the balancing rule).
-
-The expert bias is the model's load-balancing buffer: a parameter no
-gradient reaches, which the train step moves after every update against
-each output's load error, ``bias -= BIAS_UPDATE_RATE * clip(load /
-mean(load) - 1, -1, 1)`` over the step's online forwards.
-"""
+"""LFM2-MoE's block (LiquidAI/LFM2-24B-A2B, ``config.json``) as a Q-network's
+torso: its two mixers, a gated short convolution and grouped-query attention
+with q/k norms and RoPE over one frame's 49 positions, and the spec made from
+the published keys (a sigmoid router with a balancing bias, 4 of 64 experts a
+token, no shared expert).  The expert layer, the block, the walk over the
+held pairs and the Q-network around them are ``models/expert_torso.py``'s,
+shared with every torso of blocks."""
 
 from __future__ import annotations
 
-import dataclasses
-import functools
-from typing import Mapping, Sequence, Tuple
+from typing import Mapping
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ape_x_dqn_tpu.models.dueling import conv_stem, dueling_head
-from ape_x_dqn_tpu.types import ROUTING
-from ape_x_dqn_tpu.utils.profiling import part
-
-OPS = ("conv", "full_attention")
-FFNS = ("dense", "moe")
-# The balancing rule's rate (the published config has ``use_expert_bias`` and
-# names no rule).  A fresh
-# router's scores put about 0.8 of the tokens per unit of score at the top-k
-# threshold, so a sixteenth of the tokens an output moves its load by about
-# 13 times the bias's move, as a share of the mean: 0.05 takes two thirds of a
-# load error off a step, and under 0.15 the rule cannot overshoot.
-BIAS_UPDATE_RATE = 0.05
-
-
-@dataclasses.dataclass(frozen=True)
-class TorsoSpec:
-    """The widths and layers of the torso, under the published config's names."""
-
-    hidden_size: int
-    intermediate_size: int            # the leading dense layers' SwiGLU
-    moe_intermediate_size: int        # one expert's SwiGLU
-    num_attention_heads: int
-    num_key_value_heads: int
-    head_dim: int
-    conv_L_cache: int
-    norm_eps: float
-    rope_theta: float
-    router_outputs: int               # experts that exist
-    num_experts_per_tok: int
-    experts_held: Tuple[int, int]     # [lo, hi) of the router's outputs
-    layers: Tuple[Tuple[str, str], ...]  # (op, ffn) per layer run here
-    norm_topk_prob: bool = True
-    routed_scaling_factor: float = 1.0
-    use_expert_bias: bool = True
-
-    def __post_init__(self):
-        lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.router_outputs:
-            raise ValueError(
-                f"experts_held {self.experts_held} is no range of the router's "
-                f"{self.router_outputs} outputs")
-        if self.num_attention_heads % self.num_key_value_heads:
-            raise ValueError("num_attention_heads must divide by num_key_value_heads")
-        for op, ffn in self.layers:
-            if op not in OPS or ffn not in FFNS:
-                raise ValueError(f"unknown layer ({op!r}, {ffn!r}); ops {OPS}, ffns {FFNS}")
-
-    @property
-    def num_held(self) -> int:
-        return self.experts_held[1] - self.experts_held[0]
+from ape_x_dqn_tpu.models.expert_torso import (  # noqa: F401  (this kind's public names)
+    BIAS_UPDATE_RATE, KERNEL_ROWS, Block, ExpertShare, RMSNorm, SwiGLU, TorsoQ, TorsoSpec,
+    _lecun, cut_from_config, held_experts, layer_runs, route, tile_rows,
+)
 
 
 def spec_from_config(cfg: Mapping) -> TorsoSpec:
     """A ``TorsoSpec`` from the published ``config.json``'s keys, plus what a
-    cut states: ``layers_held`` (indices into ``layer_types``; default the
-    first ``num_hidden_layers``), ``router_outputs`` (default
-    ``num_experts``) and ``experts_held`` (default all)."""
+    cut states (``expert_torso.cut_from_config``)."""
     types = list(cfg["layer_types"])
-    held = list(cfg.get("layers_held", range(int(cfg.get("num_hidden_layers", len(types))))))
+    held, outputs, experts = cut_from_config(cfg)
     dense = int(cfg["num_dense_layers"])
-    outputs = int(cfg.get("router_outputs", cfg["num_experts"]))
     heads = int(cfg["num_attention_heads"])
+    kv = int(cfg["num_key_value_heads"])
+    if heads % kv:
+        raise ValueError("num_attention_heads must divide by num_key_value_heads")
     return TorsoSpec(
         hidden_size=int(cfg["hidden_size"]),
         intermediate_size=int(cfg["intermediate_size"]),
         moe_intermediate_size=int(cfg["moe_intermediate_size"]),
-        num_attention_heads=heads,
-        num_key_value_heads=int(cfg["num_key_value_heads"]),
-        head_dim=int(cfg.get("head_dim") or cfg["hidden_size"] // heads),
-        conv_L_cache=int(cfg["conv_L_cache"]),
         norm_eps=float(cfg["norm_eps"]),
-        rope_theta=float(cfg["rope_parameters"]["rope_theta"]),
         router_outputs=outputs,
         num_experts_per_tok=int(cfg["num_experts_per_tok"]),
-        experts_held=tuple(cfg.get("experts_held", (0, outputs))),
+        experts_held=experts,
         layers=tuple((types[i], "dense" if i < dense else "moe") for i in held),
+        mixers=(("conv", ShortConv), ("full_attention", Attention)),
+        mixer_args=(
+            ("num_attention_heads", heads), ("num_key_value_heads", kv),
+            ("head_dim", int(cfg.get("head_dim") or cfg["hidden_size"] // heads)),
+            ("conv_L_cache", int(cfg["conv_L_cache"])),
+            ("rope_theta", float(cfg["rope_parameters"]["rope_theta"]))),
         norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
         routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
         use_expert_bias=bool(cfg.get("use_expert_bias", True)),
     )
-
-
-def layer_runs(layers: Sequence[Tuple[str, str]]) -> list:
-    """[(first index, count, (op, ffn))]: the consecutive layers of one kind."""
-    runs: list = []
-    for i, kind in enumerate(layers):
-        if runs and runs[-1][2] == kind:
-            runs[-1] = (runs[-1][0], runs[-1][1] + 1, kind)
-        else:
-            runs.append((i, 1, kind))
-    return runs
-
-
-def _lecun(fan_in_axis: int = -2, batch_axis=()):
-    return nn.initializers.variance_scaling(
-        1.0, "fan_in", "normal", in_axis=fan_in_axis, batch_axis=batch_axis)
-
-
-def _bias_init(key, shape, dtype=jnp.float32):
-    return 0.01 * jax.random.normal(key, shape, dtype)
-
-
-class RMSNorm(nn.Module):
-    eps: float
-    compute_dtype: jnp.dtype
-    param_dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        w = self.param("weight", nn.initializers.ones, (x.shape[-1],), self.param_dtype)
-        x32 = x.astype(jnp.float32)
-        x32 = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + self.eps)
-        return (x32 * w.astype(jnp.float32)).astype(self.compute_dtype)
 
 
 def rope(x, theta: float):
@@ -193,12 +68,13 @@ class ShortConv(nn.Module):
     """``W_out (c * conv(b * x))``: a gated depthwise causal convolution."""
 
     spec: TorsoSpec
+    op: str
     compute_dtype: jnp.dtype
     param_dtype: jnp.dtype
 
     @nn.compact
     def __call__(self, u):
-        d, k = self.spec.hidden_size, self.spec.conv_L_cache
+        d, k = self.spec.hidden_size, self.spec.arg("conv_L_cache")
         w_in = self.param("w_in", _lecun(), (d, 3 * d), self.param_dtype)
         kernel = self.param("kernel", _lecun(-1), (d, k), self.param_dtype)
         w_out = self.param("w_out", _lecun(), (d, d), self.param_dtype)
@@ -213,13 +89,15 @@ class Attention(nn.Module):
     """Grouped-query causal attention, q/k RMSNorm per head, RoPE."""
 
     spec: TorsoSpec
+    op: str
     compute_dtype: jnp.dtype
     param_dtype: jnp.dtype
 
     @nn.compact
     def __call__(self, u):
         sp, cd = self.spec, self.compute_dtype
-        d, h, kv, hd = sp.hidden_size, sp.num_attention_heads, sp.num_key_value_heads, sp.head_dim
+        d, theta = sp.hidden_size, sp.arg("rope_theta")
+        h, kv, hd = (sp.arg(n) for n in ("num_attention_heads", "num_key_value_heads", "head_dim"))
         wq = self.param("w_q", _lecun(), (d, h * hd), self.param_dtype)
         wk = self.param("w_k", _lecun(), (d, kv * hd), self.param_dtype)
         wv = self.param("w_v", _lecun(), (d, kv * hd), self.param_dtype)
@@ -229,7 +107,7 @@ class Attention(nn.Module):
         q = norm("q_norm")((u @ wq.astype(cd)).reshape(bsz, s, h, hd))
         k = norm("k_norm")((u @ wk.astype(cd)).reshape(bsz, s, kv, hd))
         v = (u @ wv.astype(cd)).reshape(bsz, s, kv, hd)
-        q, k = rope(q, sp.rope_theta), rope(k, sp.rope_theta)
+        q, k = rope(q, theta), rope(k, theta)
         q = q.reshape(bsz, s, kv, h // kv, hd)  # each key-value head serves h/kv query heads
         scores = jnp.einsum("bsgrd,btgd->bgrst", q, k,
                             preferred_element_type=jnp.float32) / jnp.sqrt(float(hd))
@@ -239,303 +117,5 @@ class Attention(nn.Module):
         return out @ wo.astype(cd)
 
 
-class SwiGLU(nn.Module):
-    width: int
-    compute_dtype: jnp.dtype
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, u):
-        d, cd = u.shape[-1], self.compute_dtype
-        w1 = self.param("w1", _lecun(), (d, self.width), self.param_dtype)
-        w3 = self.param("w3", _lecun(), (d, self.width), self.param_dtype)
-        w2 = self.param("w2", _lecun(), (self.width, d), self.param_dtype)
-        return (jax.nn.silu(u @ w1.astype(cd)) * (u @ w3.astype(cd))) @ w2.astype(cd)
-
-
-def route(scores, bias, spec: TorsoSpec):
-    """(chosen experts [T, k], gates [T, k]) from float32 scores [T, E]:
-    the top k of ``scores + bias``, gates the chosen scores normalised over
-    all k (``norm_topk_prob``)."""
-    _, chosen = jax.lax.top_k(scores + bias, spec.num_experts_per_tok)
-    gates = jnp.take_along_axis(scores, chosen, axis=-1)
-    if spec.norm_topk_prob:
-        gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-6)
-    return chosen, gates * spec.routed_scaling_factor
-
-
-# The grouped kernel's own tile: a walk's tile is a whole number of these.
-KERNEL_ROWS = 512
-
-
-def tile_rows(rows, num_held: int, router_outputs: int):
-    """Rows of one tile of the walk over the held pairs, from the shapes:
-    the fill even loads give (``rows`` pairs over the router's outputs,
-    ``num_held`` of them here) plus a third, in whole kernel tiles, and never
-    more than ``rows``.  One tile then covers a layer's held pairs unless the
-    held experts draw a third over their share; a chip that holds every
-    expert gets one tile of ``rows`` rows.  ``rows`` may be traced
-    (``routing_metrics``)."""
-    fill = rows * num_held // router_outputs
-    whole = -(-(fill + fill // 3) // KERNEL_ROWS) * KERNEL_ROWS
-    return min(rows, whole) if isinstance(rows, int) else jnp.minimum(rows, whole)
-
-
-_DW_DIMS = jax.lax.RaggedDotDimensionNumbers(
-    dot_dimension_numbers=(((0,), (0,)), ((), ())),
-    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-
-
-def _tile(t, tile: int, k: int, order, starts, ends, gates):
-    """Tile ``t`` of the sorted pairs: (its rows' pairs, their tokens, which
-    rows hold a held pair, their gates, the rows of each expert in it)."""
-    first = t * tile
-    pair = jax.lax.dynamic_slice(order, (first,), (tile,))
-    live = first + jnp.arange(tile) < ends[-1]
-    gate = jnp.where(live, gates.reshape(-1)[pair], 0.0)
-    sizes = jnp.clip(ends, first, first + tile) - jnp.clip(starts, first, first + tile)
-    return pair, pair // k, live, gate, sizes
-
-
-def _walk(order, sizes, tile: int):
-    """(``order`` padded to whole tiles, the experts' first rows and ends,
-    the tiles that hold a held pair)."""
-    ends = jnp.cumsum(sizes)
-    order = jnp.pad(order, (0, -order.shape[0] % tile))
-    return order, ends - sizes, ends, -(-ends[-1] // tile)
-
-
-def _products(xs, w13, w2, sizes):
-    """(h, a, ys) of a tile's rows ``xs``: ``ys = (silu(h1) * h3) @ w2`` by
-    groups, ``[h1, h3] = xs @ w13``."""
-    f = w2.shape[1]
-    h = jax.lax.ragged_dot(xs, w13, sizes)
-    a = jax.nn.silu(h[:, :f]) * h[:, f:]
-    return h, a, jax.lax.ragged_dot(a, w2, sizes)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def held_experts(u, w13, w2, gates, order, sizes, tile: int):
-    """The held experts' part of the layer's sum, [tokens, d] in ``u``'s type.
-
-    ``u`` [tokens, d]; ``w13`` [n, d, 2f], ``w2`` [n, f, d]; ``gates``
-    [tokens, k], zero off the held range; ``order`` [tokens * k]: the pairs
-    sorted by expert, the held ones first; ``sizes`` [n]: the pairs on each
-    held expert.  The sorted pairs are walked ``tile`` rows at a time over the
-    tiles that hold a held pair: gather the rows' tokens, multiply by groups,
-    add ``gate * row`` into the token's float32 sum.  The backward pass walks
-    the same tiles and computes each again: nothing of a tile is kept."""
-    return _held_experts_fwd(u, w13, w2, gates, order, sizes, tile)[0]
-
-
-def _held_experts_fwd(u, w13, w2, gates, order, sizes, tile: int):
-    cd, k = u.dtype, gates.shape[1]
-    with part("router"):
-        padded, starts, ends, tiles = _walk(order, sizes, tile)
-    with part("experts"):
-        w13c, w2c = w13.astype(cd), w2.astype(cd)
-
-    def body(t, y):
-        with part("router"):
-            _, token, live, gate, group = _tile(t, tile, k, padded, starts, ends, gates)
-            xs = u[token]
-        with part("experts"):
-            _, _, ys = _products(xs, w13c, w2c, group)
-            # Rows past the last group are the kernel's to leave unwritten.
-            ys = jnp.where(live[:, None], ys.astype(jnp.float32) * gate[:, None], 0.0)
-        with part("router"):
-            return y.at[token].add(ys)
-
-    with part("router"):
-        y = jax.lax.fori_loop(0, tiles, body, jnp.zeros(u.shape, jnp.float32))
-        return y.astype(cd), (u, w13, w2, gates, order, sizes)
-
-
-def _held_experts_bwd(tile: int, kept, dy):
-    u, w13, w2, gates, order, sizes = kept
-    cd, k, f = u.dtype, gates.shape[1], w2.shape[1]
-    with part("router"):
-        padded, starts, ends, tiles = _walk(order, sizes, tile)
-    with part("experts"):
-        w13c, w2c = w13.astype(cd), w2.astype(cd)
-        w13t, w2t = jnp.swapaxes(w13c, 1, 2), jnp.swapaxes(w2c, 1, 2)
-
-    def body(t, carry):
-        du, dw13, dw2, dgates = carry
-        with part("router"):
-            pair, token, live, gate, group = _tile(t, tile, k, padded, starts, ends, gates)
-            xs, dy_rows = u[token], dy[token]
-        with part("experts"):
-            h, a, ys = _products(xs, w13c, w2c, group)
-            dy_rows = dy_rows.astype(jnp.float32)
-            dgate = jnp.where(live, jnp.sum(dy_rows * ys.astype(jnp.float32), -1), 0.0)
-            dys = jnp.where(live[:, None], dy_rows * gate[:, None], 0.0).astype(cd)
-            da = jax.lax.ragged_dot(dys, w2t, group).astype(jnp.float32)
-            h1, h3 = h[:, :f].astype(jnp.float32), h[:, f:].astype(jnp.float32)
-            s = jax.nn.sigmoid(h1)
-            dh = jnp.concatenate([da * h3 * s * (1.0 + h1 * (1.0 - s)), da * h1 * s],
-                                 axis=-1).astype(cd)
-            # The rows that ``h`` and ``da`` leave unwritten reach no sum: a
-            # ragged contraction reads its groups' rows alone.
-            dw2 = dw2 + jax.lax.ragged_dot_general(
-                a, dys, group, _DW_DIMS, preferred_element_type=jnp.float32)
-            dw13 = dw13 + jax.lax.ragged_dot_general(
-                xs, dh, group, _DW_DIMS, preferred_element_type=jnp.float32)
-            dxs = jax.lax.ragged_dot(dh, w13t, group)
-            dxs = jnp.where(live[:, None], dxs, 0).astype(jnp.float32)
-        with part("router"):
-            return du.at[token].add(dxs), dw13, dw2, dgates.at[pair].add(dgate)
-
-    with part("router"):
-        zeros = lambda x: jnp.zeros(x.shape, jnp.float32)  # noqa: E731
-        du, dw13, dw2, dgates = jax.lax.fori_loop(
-            0, tiles, body, (zeros(u), zeros(w13), zeros(w2), zeros(gates.reshape(-1))))
-        return (du.astype(cd), dw13.astype(w13.dtype), dw2.astype(w2.dtype),
-                dgates.reshape(gates.shape).astype(gates.dtype), None, None)
-
-
-held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
-
-
-class ExpertShare(nn.Module):
-    """One chip's share of a mixture-of-experts layer (module docstring)."""
-
-    spec: TorsoSpec
-    compute_dtype: jnp.dtype
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, u):
-        sp, cd = self.spec, self.compute_dtype
-        d, f, n, k = sp.hidden_size, sp.moe_intermediate_size, sp.num_held, sp.num_experts_per_tok
-        lo, hi = sp.experts_held
-        w_r = self.param("router", _lecun(), (d, sp.router_outputs), jnp.float32)
-        # A buffer, not trained: it rides in the parameter tree (so that it
-        # is saved, published and copied to the target network with the
-        # rest) and no gradient reaches it.
-        bias = (jax.lax.stop_gradient(self.param(
-            "expert_bias", _bias_init, (sp.router_outputs,), jnp.float32))
-                if sp.use_expert_bias else jnp.zeros((sp.router_outputs,), jnp.float32))
-        w13 = self.param("w13", _lecun(batch_axis=(0,)), (n, d, 2 * f), self.param_dtype)
-        w2 = self.param("w2", _lecun(batch_axis=(0,)), (n, f, d), self.param_dtype)
-        shape = u.shape
-        u = u.reshape(-1, d)
-        rows = u.shape[0] * k  # every pair of every token
-
-        with part("router"):
-            scores = jax.nn.sigmoid(jnp.dot(
-                u.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST))
-            chosen, gates = route(scores, bias, sp)
-            held = (chosen >= lo) & (chosen < hi)
-            load = jnp.sum(jax.nn.one_hot(chosen.reshape(-1), sp.router_outputs,
-                                          dtype=jnp.int32), axis=0)
-            # Pairs on held experts first, by expert; the others after them.
-            order = jnp.argsort(jnp.where(held, chosen - lo, n).reshape(-1), stable=True)
-            gates = jnp.where(held, gates, 0.0)
-        y = held_experts(u.astype(cd), w13, w2, gates, order, load[lo:hi],
-                         tile_rows(rows, n, sp.router_outputs))
-        if not self.is_initializing():  # ``init`` returns parameters alone
-            self.sow(ROUTING, "load", load)
-        return y.reshape(shape)
-
-
-class Block(nn.Module):
-    spec: TorsoSpec
-    op: str
-    ffn: str
-    compute_dtype: jnp.dtype
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, h, _=None):
-        """(h, None) -> (the layer's output, None): a ``scan``'s body."""
-        sp, cd, pd = self.spec, self.compute_dtype, self.param_dtype
-        with part("mixer"):
-            u = RMSNorm(sp.norm_eps, cd, pd, name="operator_norm")(h)
-            mixer = (ShortConv if self.op == "conv" else Attention)(sp, cd, pd, name=self.op)
-            h = h + mixer(u)
-        if self.ffn == "dense":
-            with part("dense_ffn"):
-                u = RMSNorm(sp.norm_eps, cd, pd, name="ffn_norm")(h)
-                return h + SwiGLU(sp.intermediate_size, cd, pd, name="dense")(u), None
-        with part("router"):
-            u = RMSNorm(sp.norm_eps, cd, pd, name="ffn_norm")(h)
-        return h + ExpertShare(sp, cd, pd, name="moe")(u), None
-
-
-class Lfm2MoeQ(nn.Module):
+class Lfm2MoeQ(TorsoQ):
     """Stem -> tokens -> LFM2-MoE layers -> norm, mean over tokens -> dueling head."""
-
-    num_actions: int
-    spec: TorsoSpec
-    channels: Sequence[int] = (32, 64, 64)
-    hidden: int = 512
-    compute_dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.float32
-
-    # A target network in a lower type keeps these leaves in float32: the
-    # router's scores and bias decide a top-k.
-    float32_leaves = ("router", "expert_bias")
-
-    @nn.compact
-    def __call__(self, x):
-        sp, cd, pd = self.spec, self.compute_dtype, self.param_dtype
-        z = conv_stem(x, self.channels, cd, pd, out_dtype=jnp.float32)  # [B, h, w, C]
-        with part("stem"):
-            z = z.reshape(z.shape[0], -1, z.shape[-1])             # raster order
-            z = (z - jnp.mean(z, axis=1, keepdims=True)).astype(cd)
-            w_tok = self.param("w_tok", _lecun(), (z.shape[-1], sp.hidden_size), pd)
-            h = z @ w_tok.astype(cd)
-        block = nn.remat(Block)
-        for first, count, (op, ffn) in layer_runs(sp.layers):
-            if count == 1:
-                h, _ = block(sp, op, ffn, cd, pd, name=f"layer_{first}")(h)
-            else:
-                run = nn.scan(nn.remat(Block, prevent_cse=False),  # a scan's body is never merged
-                              variable_axes={"params": 0, ROUTING: 0},
-                              split_rngs={"params": True}, length=count)
-                h, _ = run(sp, op, ffn, cd, pd,
-                           name=f"layers_{first}_{first + count - 1}")(h, None)
-        with part("head"):
-            h = RMSNorm(sp.norm_eps, cd, pd, name="final_norm")(h)
-            pooled = jnp.mean(h.astype(jnp.float32), axis=1).astype(cd)
-        return dueling_head(pooled, self.num_actions, self.hidden, cd, pd)
-
-    def q_values(self, x):
-        return self(x)[2]
-
-    def routing_metrics(self, sown) -> dict:
-        """What one ``apply(..., mutable=[ROUTING])`` sowed, as the train
-        step's counters, summed over the expert layers (float32 [] each):
-        the pairs on held experts, the largest and the mean load of a held
-        expert, and the rows the layers walked for them (tiles by the rows
-        of a tile; every pair of a token is on one of the router's outputs,
-        so a layer's loads add up to its ``tokens x k``)."""
-        lo, hi = self.spec.experts_held
-        loads = jnp.concatenate([v.reshape(-1, v.shape[-1])
-                                 for v in jax.tree_util.tree_leaves(sown[ROUTING])])
-        held = loads[:, lo:hi].astype(jnp.float32)
-        tile = tile_rows(jnp.sum(loads, -1), hi - lo, self.spec.router_outputs)
-        walked = -(-jnp.sum(loads[:, lo:hi], -1) // tile) * tile
-        return {"held_pairs": jnp.sum(held), "load_max": jnp.sum(jnp.max(held, -1)),
-                "load_mean": jnp.sum(jnp.mean(held, -1)),
-                "rows_walked": jnp.sum(walked).astype(jnp.float32)}
-
-    def rebalanced(self, params, sown):
-        """``params`` after the balancing rule (module docstring) on the
-        loads ``sown`` holds: every expert layer's bias moved against the
-        load error of each of the router's outputs."""
-        if not self.spec.use_expert_bias:
-            return params
-        # .../moe/load/0 in the collection is .../moe/expert_bias in params
-        loads = {jax.tree_util.keystr(path[:-2]): v.astype(jnp.float32)
-                 for path, v in jax.tree_util.tree_leaves_with_path(sown[ROUTING])}
-
-        def leaf(path, x):
-            if getattr(path[-1], "key", None) != "expert_bias":
-                return x
-            load = loads[jax.tree_util.keystr(path[1:-1])]
-            error = load / jnp.mean(load, -1, keepdims=True) - 1.0
-            return x - BIAS_UPDATE_RATE * jnp.clip(error, -1.0, 1.0)
-
-        return jax.tree_util.tree_map_with_path(leaf, params)

@@ -65,6 +65,12 @@ from ape_x_dqn_tpu.utils.profiling import jit_fused, stage
 
 _LANES = 128          # the chip's minor tile extent, in 32-bit words
 _PACK_BLOCK = 256    # rows packed at a time where a whole ring is packed
+# The widest row, in words, that the chip's row gather fetches whole (eight
+# rows, double-buffered, in its 2 MiB of scoped memory).  A wider row (a
+# 32-frame history is 56,448) it cuts in column halves of the WHOLE ring,
+# each a copy: 2.3 GB of temporaries a fused call at 10,240 rows (compiled for
+# v5e, PR 32).  ``gather_rows`` fetches such rows one at a time.
+_GATHER_WORDS = 32768
 _FIELDS = ("rows", "obs_ref", "next_ref", "action", "reward", "discount",
            "mass", "cursor", "count", "fcount")
 
@@ -354,6 +360,16 @@ def dedup_device_add_transitions(
         return new.replace(mass=jnp.where(dead, 0.0, new.mass))
 
 
+def gather_rows(rows: jax.Array, slots: jax.Array) -> jax.Array:
+    """``rows[slots]``: [Cf, stride] and [...] -> [..., stride].  Rows the
+    chip's gather takes whole go through it; wider ones are read one after
+    the other, each a dynamic slice of the ring (``_GATHER_WORDS``)."""
+    if rows.shape[1] <= _GATHER_WORDS:
+        return rows[slots]
+    one = lambda slot: jax.lax.dynamic_index_in_dim(rows, slot, 0, keepdims=False)  # noqa: E731
+    return jax.lax.map(one, slots.reshape(-1)).reshape(*slots.shape, rows.shape[1])
+
+
 def dedup_sample_many(
     state: DedupDeviceReplayState,
     rng: jax.Array,
@@ -371,7 +387,7 @@ def dedup_sample_many(
     Cf = state.frame_capacity
     with stage("gather"):
         take = lambda ref: state.fmt.unpack(  # noqa: E731
-            state.rows[ref[idx2] % Cf])
+            gather_rows(state.rows, ref[idx2] % Cf))
         transition = NStepTransition(
             obs=take(state.obs_ref),
             action=state.action[idx2],

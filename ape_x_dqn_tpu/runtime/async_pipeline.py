@@ -305,6 +305,7 @@ class AsyncPipeline:
         # (StepMetrics.routing), for the JSONL line and /varz; {} for a
         # network without such layers.
         self._routing: dict = {}
+        self._attention: dict = {}    # StepMetrics.attention, likewise
         # Per-stage wall-clock accumulators (SURVEY §5 tracing subsystem):
         # µs/step per pipeline stage, exported in every metrics emit.
         self.timers = StageTimer()
@@ -614,10 +615,12 @@ class AsyncPipeline:
         # params with one cheap device-side copy; device_get + serialize +
         # store write happen on the publisher thread (see _AsyncPublisher —
         # measured seconds per publish under worker CPU contention).
-        # Multi-host keeps the synchronous per-leaf local-replica path.
+        # Multi-host keeps the synchronous per-leaf local-replica path, and
+        # so does a network whose second copy would not fit beside the
+        # learner (_copy_fits).
         self._publisher = None
         self._param_copy = None
-        if self._n_proc == 1:
+        if self._n_proc == 1 and self._copy_fits(self.comps.state.params):
             import jax.numpy as jnp
 
             self._param_copy = jax.jit(
@@ -1037,6 +1040,8 @@ class AsyncPipeline:
             pass
         if self._routing:
             out["routing"] = dict(self._routing)
+        if self._attention:
+            out["attention"] = dict(self._attention)
         return out
 
     def _maybe_eval(self):
@@ -1062,6 +1067,24 @@ class AsyncPipeline:
             )
         self.eval_scores.append(res.mean_score)
         log_result(self.logger, res)
+
+    @staticmethod
+    def _copy_fits(params) -> bool:
+        """Whether a device-side copy of ``params`` is cheap beside the
+        learner: under an eighth of the device's memory, where the device
+        says how much it has.  The two sizes this was read at (TPU v5e,
+        16.9 GB; chip_smoke.py's --lfm2moe and --laguna legs): 455 M
+        parameters, 1.8 GB, fit beside their state and a fused call's
+        temporaries; 737 M, 2.95 GB, do not, and there a publish reads the
+        live parameters synchronously and stalls the learner 0.79 s
+        (PERF.md, PR 32).  The eighth lies between the two and is no finer
+        than that."""
+        import jax
+
+        leaves = jax.tree_util.tree_leaves(params)
+        stats = next(iter(leaves[0].devices())).memory_stats() if leaves else None
+        limit = (stats or {}).get("bytes_limit")
+        return not limit or 8 * sum(x.nbytes for x in leaves) <= limit
 
     def _publish(self, params) -> None:
         if self._publisher is not None:
@@ -1629,6 +1652,9 @@ class AsyncPipeline:
                 # Expert layers' counters, a step (mean over the call's K).
                 self._routing = {k: float(np.mean(np.asarray(v)))
                                  for k, v in metrics.routing.items()}
+            if getattr(metrics, "attention", None) is not None:
+                self._attention = {k: float(np.mean(np.asarray(v)))
+                                   for k, v in metrics.attention.items()}
         return self.logger.emit(
             step=self._learner_step,
             actor_steps=self.worker.actor_steps,
@@ -1641,6 +1667,7 @@ class AsyncPipeline:
             actor_heartbeat_age=round(time.monotonic() - self.worker.heartbeat, 3),
             stage_us=self.timers.us_per_call(),
             **({"routing": self._routing} if self._routing else {}),
+            **({"attention": self._attention} if self._attention else {}),
             final=final,
             **self._transport_extra(),
             **self._ckpt_extra(),

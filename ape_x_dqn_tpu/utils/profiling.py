@@ -53,7 +53,8 @@ SCOPE_PREFIX = "stage:"   # device side: jax.named_scope("stage:<name>")
 # stage take the innermost ``stage:`` of an op, and a part nested under that
 # prefix would take the network's time out of ``forward`` and lose its
 # backward pass.  A part is read beside its stage, not in its place.
-PARTS = ("stem", "mixer", "router", "experts", "dense_ffn", "head")
+PARTS = ("stem", "mixer", "router", "experts", "dense_ffn", "head",
+         "attn_window", "attn_full", "shared_expert")
 PART_PREFIX = "torso:"    # device side: jax.named_scope("torso:<name>")
 SPAN_PREFIX = "apex:"     # host side: TraceAnnotation("apex:<name>")
 

@@ -21,6 +21,12 @@ which owns the chip:
             selection serves the network too.  Only with --lfm2moe: the leg
             compiles for minutes and is the on-chip run ISSUE 28 asks of
             its builder, not part of the quick proof
+  laguna    configs/config7_laguna_q_ep32.json (the 737 M parameter torso of
+            window and full attention layers over a 32-frame history, 1,568
+            tokens) with 4 thread actors on the learner's chip, 12 learner
+            steps at batch 2 on a 1,024-slot ring: beside the learner's 5.9
+            GB of state the actors' copy of the parameters (2.95 GB) leaves
+            the cell's batch of 8 no room.  Only with --laguna
 
 Sets no platform itself.  Exits non-zero, with one line saying why and no
 result, before compiling anything if jax's default backend is not a TPU, and
@@ -286,6 +292,38 @@ def leg_lfm2moe() -> None:
     assert rc == 0, f"lfm2moe: train.main returned {rc}"
 
 
+def leg_laguna() -> None:
+    from ape_x_dqn_tpu import train
+
+    steps = 12
+
+    def inspect(pipe, final):
+        check_run("laguna", pipe, final, steps)
+        assert type(pipe.comps.network).__name__ == "LagunaMoeQ"
+        assert final["param_version"] >= 1, "laguna: nothing was published"
+        routing, attention = final.get("routing") or {}, final.get("attention") or {}
+        assert routing.get("held_pairs", 0) > 0, f"laguna: no routing counters: {final}"
+        assert 0 < attention.get("blocks_visited_window", 0) < attention.get(
+            "blocks_total_window", 0), f"laguna: no attention counters: {final}"
+        say(f"laguna: routing a step {routing}; attention a step {attention}; actors "
+            f"adopted param_version {pipe.worker.param_version} of {final['param_version']}")
+
+    rc = train.main([
+        "--params-file", os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                      "configs", "config7_laguna_q_ep32.json"),
+        "--set", "env.name=fake-atari",
+        "--set", "actor.mode=thread", "--set", "actor.num_actors=4",
+        "--set", "actor.sync_every=1",
+        # batch and ring cut to what fits beside the actors' parameters
+        "--set", "learner.replay_sample_size=2",
+        "--set", "replay.capacity=1024",
+        "--set", "learner.min_replay_mem_size=64",
+        "--set", "learner.publish_every=4",
+        "--log-every", "4", "--steps", str(steps),
+    ], inspect=inspect)
+    assert rc == 0, f"laguna: train.main returned {rc}"
+
+
 def main() -> int:
     t_start = time.perf_counter()
     try:
@@ -336,6 +374,8 @@ def main() -> int:
         legs.append(("dp4", leg_dp4))
     if "--lfm2moe" in sys.argv[1:]:
         legs = [("lfm2moe", leg_lfm2moe)]
+    if "--laguna" in sys.argv[1:]:
+        legs = [("laguna", leg_laguna)]
     for name, fn in legs:
         t0 = time.perf_counter()
         say(f"leg {name}: starts with bytes_in_use per device "

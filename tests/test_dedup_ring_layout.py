@@ -402,3 +402,69 @@ def test_the_expert_layer_builds_no_worst_case_buffer(topo, no_compile_cache):
     assert len(bodies) == 2 and len(products) >= 8 and set(products) == bodies, products
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < WORST_CASE_BLOCK_TEMP_BYTES, temp
+
+
+def test_the_history_torsos_fused_program_fits_the_chip(topo, no_compile_cache, monkeypatch):
+    """``benchmark/configs/laguna_q_ep32.json``'s fused program at the cell's
+    shapes (737 M parameters, B=8, 1,568 tokens, the 4,096-slot ring of
+    56,448-word rows): state, ring and temporaries fit a v5e beside the
+    comparison's chunks; no part of the ring is copied for the gather (rows
+    wider than the chip's gather takes whole are read one at a time); no
+    [T, T] score tensor is made; the attention kernels are in the executable,
+    the sliding layers' once for their scanned run."""
+    from ape_x_dqn_tpu.learner.train_step import (
+        build_train_step, init_train_state, make_optimizer,
+    )
+    from ape_x_dqn_tpu.models.dueling import build_network
+    from ape_x_dqn_tpu.ops.pallas import blocked_attention
+
+    monkeypatch.setattr(blocked_attention, "INTERPRET", False)   # this process sees the CPU
+    monkeypatch.setitem(globals(), "COMPILE_LIMIT_S", 900.0)     # about 90 s alone
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / "laguna_q_ep32.json").read_text())
+    prec = cfg["precision"]
+    net = build_network(cfg["network"], cfg["num_actions"], torso=cfg,
+                        channels=tuple(cfg["channels"]), hidden=cfg["hidden"],
+                        compute_dtype=jnp.dtype(prec["compute"]),
+                        param_dtype=jnp.dtype(prec["params"]))
+    opt = make_optimizer(cfg["optimizer"], learning_rate=cfg["learning_rate"],
+                         rmsprop_decay=cfg["rmsprop_decay"], rmsprop_eps=cfg["rmsprop_eps"],
+                         max_grad_norm=cfg["max_grad_norm"],
+                         second_moment_dtype=jnp.dtype(prec["second_moment"]))
+    step_fn = build_train_step(net, opt, loss_kind=cfg["loss"], sync_in_step=False, jit=False)
+    fused = build_dedup_fused_learn_step(
+        step_fn, cfg["batch_size"], steps_per_call=cfg["steps_per_call"],
+        priority_exponent=cfg["priority_exponent"], target_sync_freq=cfg["target_sync_freq"],
+        sample_ahead=cfg["sample_ahead"])
+    dev = SingleDeviceSharding(topo.devices[0])
+    obs = tuple(cfg["obs_shape"])
+    state = _with(jax.eval_shape(
+        lambda k: init_train_state(net, opt, k, jnp.zeros((1, *obs), jnp.uint8),
+                                   target_dtype=jnp.dtype(prec["target_params"])),
+        jax.random.PRNGKey(0)), dev)
+    frames = int(cfg["replay_capacity"] * cfg["frame_ratio"])
+    ring = _with(jax.eval_shape(lambda: init_dedup_device_replay(
+        cfg["replay_capacity"], obs, frame_capacity=frames)), dev)
+    assert ring.rows.shape == (5120, 56448)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev)
+    compiled = _compile(fused, (state, ring, 0.4, key))
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    hbm = 16_909_336_064                      # a v5e's, PERF.md Open question 11
+    chunks = 4 * 256 * 56448 * 4              # the comparison's resident chunks
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + chunks < 0.95 * hbm, mem
+    ring_bytes = frames * 56448 * 4
+    assert_ring_stays_put(text, ring_bytes, 0)
+    part_of_ring = [line[:160] for name, op, line in ring_sized_instructions(text, ring_bytes // 4)
+                    if re.search(rf"\[{frames},\d+\]", line.split(" = ", 1)[1].split("(", 1)[0])]
+    assert not part_of_ring and "mini-gather" not in text, part_of_ring
+    square = [dims for _, dims, _ in _ARRAY.findall(text)
+              if re.search(r"(?:^|,)(1568|1792|2048),\1(?:,|$)", dims)]
+    assert not square, sorted(set(square))[:5]
+    kernels = re.findall(r"%(splash_mha_\w+?)[.\d]* = ", text)
+    assert {"splash_mha_fwd_residuals", "splash_mha_dq_no_residuals",
+            "splash_mha_dkv_no_residuals"} <= set(kernels)
+    # two full layers apart and one scanned body of sliding layers: three of
+    # each backward kernel, not five
+    assert kernels.count("splash_mha_dq_no_residuals") == 3, kernels
+    assert kernels.count("splash_mha_dkv_no_residuals") == 3, kernels

@@ -12,7 +12,7 @@ import pytest
 
 from ape_x_dqn_tpu.config import ApexConfig, load_config, network_kwargs
 from ape_x_dqn_tpu.models.dueling import build_greedy_apply, build_network
-from ape_x_dqn_tpu.models import lfm2_moe
+from ape_x_dqn_tpu.models import expert_torso, lfm2_moe
 from ape_x_dqn_tpu.models.lfm2_moe import (
     BIAS_UPDATE_RATE, Attention, ExpertShare, ShortConv, layer_runs, route, spec_from_config,
     tile_rows,
@@ -42,7 +42,7 @@ def obs(key, rows=4, side=52):
 def test_mixers_are_causal(mixer):
     """A later token changes no earlier output."""
     spec = spec_from_config(TORSO)
-    layer = mixer(spec, jnp.float32, jnp.float32)
+    layer = mixer(spec, "x", jnp.float32, jnp.float32)
     u = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 64))
     params = layer.init(jax.random.PRNGKey(1), u)
     base = layer.apply(params, u)
@@ -125,7 +125,7 @@ def test_the_tiled_walk_is_the_worst_case_buffer(monkeypatch, walk):
     layer that walks its held pairs a tile at a time, against one buffer of
     every pair, with the tile forced small."""
     bias, tile, tiles = WALKS[walk]
-    monkeypatch.setattr(lfm2_moe, "tile_rows", lambda rows, held, outputs: tile)
+    monkeypatch.setattr(expert_torso, "tile_rows", lambda rows, held, outputs: tile)
     sp = spec_from_config(TORSO)
     layer = ExpertShare(sp, jnp.float32, jnp.float32)
     u = jax.random.normal(jax.random.PRNGKey(11), (4, 9, 64))
@@ -177,7 +177,7 @@ def test_a_tile_comes_from_the_shapes():
 
 
 def test_rows_walked_is_tiles_by_tile(monkeypatch):
-    monkeypatch.setattr(lfm2_moe, "tile_rows", lambda rows, held, outputs: 8)
+    monkeypatch.setattr(expert_torso, "tile_rows", lambda rows, held, outputs: 8)
     net = small_net()
     x = obs(jax.random.PRNGKey(2))
     _, sown = net.apply(net.init(jax.random.PRNGKey(3), x), x, mutable=["routing"])
@@ -307,13 +307,14 @@ def test_other_networks_report_no_routing():
 def test_parts_are_scoped_beside_the_stages():
     """The train step's text names every part under ``torso:``, forward and
     backward, and the ``stage:`` readers still see ``forward``."""
-    assert profiling.PARTS == ("stem", "mixer", "router", "experts", "dense_ffn", "head")
+    lfm2_parts = ("stem", "mixer", "router", "experts", "dense_ffn", "head")
+    assert profiling.PARTS[:6] == lfm2_parts  # the rest are another torso's
     with pytest.raises(ValueError):
         profiling.part("torso")
     net = small_net()
     step, state, batch = _train_pieces(net)
     text = jax.jit(step).lower(state, batch).as_text(debug_info=True)
-    for part in profiling.PARTS:
+    for part in lfm2_parts:
         assert f"torso:{part}" in text, part
     assert "transpose(jvp(stage:forward))" in text and "torso:experts" in text
     compiled = jax.jit(step).lower(state, batch).compile().as_text()
