@@ -289,12 +289,15 @@ class LearnerConfig:
     # HBM read on every forward/backward.  Updates accumulate in float32, so
     # learning quality matches float32 params (chain-MDP test covers it).
     param_dtype: Optional[str] = None
-    # Fused-mode sampling cadence: True samples all K batches of a dispatch
-    # in ONE batched inverse-CDF call from call-entry priorities and
-    # restamps once after the scan (device_replay_sample_many) — drops
-    # ~95 µs/step of fixed op overhead at B=32 for up to K steps of
+    # Fused-mode sampling cadence: True draws the slots of all K batches of
+    # a dispatch in ONE batched inverse-CDF call from call-entry priorities
+    # and restamps once after the scan (replay/device.py sample_slots) —
+    # drops ~95 µs/step of fixed op overhead at B=32 for up to K steps of
     # priority staleness, the same order the async Ape-X loop already
-    # tolerates.  False is strict sequential PER (the test oracle).
+    # tolerates.  The double store also gathers the K batches' observations
+    # in that call (device_replay_sample_many); the dedup ring fetches each
+    # batch's rows inside the scan, so its K is not bound by HBM.  False is
+    # strict sequential PER (the test oracle).
     sample_ahead: bool = False
 
 

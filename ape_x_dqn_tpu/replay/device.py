@@ -255,42 +255,53 @@ def fused_scan_body(
     sample_ahead: bool,
     axis_name: str | None = None,
     sample_many_fn=None,
+    fetch_fn=None,
 ):
     """The K-step [sample → train → restamp] scan + hoisted target sync —
     the ONE body shared by the single-device builder below, the sharded
     builder (replay/device_dp.py, where it runs per shard inside shard_map
     with ``axis_name="data"`` and a per-shard batch size), and the
-    frame-dedup layouts (replay/device_dedup.py, which inject their
-    sampler via ``sample_many_fn``; restamp/update only touch ``.mass``,
-    which every layout carries)."""
+    frame-dedup layouts (replay/device_dedup.py; restamp/update only touch
+    ``.mass``, which every layout carries).
+
+    A layout is two functions.  ``sample_many_fn(state, rng, K, B, beta,
+    axis_name)`` returns what it sampled, leaves ``[K, B, ...]`` with
+    ``.indices``; ``fetch_fn(state, one)`` makes a step's slice of that
+    (leaves ``[B, ...]``) the step's ``PrioritizedBatch``, inside the scan's
+    body, from the ring the body closes over: with ``sample_ahead`` what
+    crosses into the scan is what was sampled, and the batch-sized work is
+    done a step at a time.  With no ``fetch_fn`` the slice is the batch (the
+    double store: its rows are its observations)."""
     K, B = steps_per_call, batch_size
     step_before = train_state.step
     if sample_many_fn is None:
         sample_many_fn = device_replay_sample_many
+    if fetch_fn is None:
+        fetch_fn = lambda r_state, one: one  # noqa: E731
 
     if sample_ahead:
-        batches = sample_many_fn(
+        sampled = sample_many_fn(
             replay_state, rng, K, B, beta, axis_name
         )
 
-        def body_pre(t_state, batch):
-            t_state, metrics = train_step_fn(t_state, batch)
+        def body_pre(t_state, one):
+            t_state, metrics = train_step_fn(t_state, fetch_fn(replay_state, one))
             return t_state, metrics
 
-        train_state, metrics = jax.lax.scan(body_pre, train_state, batches)
+        train_state, metrics = jax.lax.scan(body_pre, train_state, sampled)
         with stage("restamp"):
             replay_state = device_replay_restamp_last(
-                replay_state, batches.indices, metrics.priorities,
+                replay_state, sampled.indices, metrics.priorities,
                 priority_exponent,
             )
     else:
 
         def body(carry, step_rng):
             t_state, r_state = carry
-            batch = jax.tree_util.tree_map(
+            batch = fetch_fn(r_state, jax.tree_util.tree_map(
                 lambda a: a[0],
                 sample_many_fn(r_state, step_rng, 1, B, beta, axis_name),
-            )
+            ))
             t_state, metrics = train_step_fn(t_state, batch)
             with stage("restamp"):
                 r_state = device_replay_update_priorities(

@@ -23,8 +23,8 @@ tile (a multiple of 128 words) the ring is held row-major and a row is a
 row.  HBM sizing: ``frame_capacity × row_stride × 4`` bytes (153,600
 observations of 84×84×4 = 4.40 GB).  ``DedupDeviceReplayState(frames=...)``
 packs a logical ``[Cf, *obs_shape]`` block, ``.frames`` unpacks one (a copy:
-not for a hot path); ingest packs the incoming block (U rows), the sampler
-unpacks the K·B gathered rows; checkpoints hold logical rows.
+not for a hot path); ingest packs the incoming block (U rows), the fused
+scan unpacks the B rows of the step it is in; checkpoints hold logical rows.
 
 Reference addressing under XLA's int32 world:
   * frame sequence numbers live modulo ``Q = (2^30 // frame_capacity) ·
@@ -41,10 +41,25 @@ Reference addressing under XLA's int32 world:
     sampled slots, and dead slots are never sampled.)
 
 Sampling/IS-weight law, batched restamp, and the K-step fused scan are
-shared with the double-store via ``fused_scan_body(sample_many_fn=...)``
-(replay/device.py) — the two layouts cannot drift semantically.  Equal-
-semantics oracle: tests/test_device_dedup.py pins the dedup fused step
-against the double-store fused step on an identical ingest stream.
+shared with the double-store via ``fused_scan_body(sample_many_fn=...,
+fetch_fn=...)`` (replay/device.py) — the two layouts cannot drift
+semantically.  Equal-semantics oracle: tests/test_device_dedup.py pins the
+dedup fused step against the double-store fused step on an identical ingest
+stream.
+
+Where the gather stage runs.  The sampler has two halves.
+``dedup_sample_slots`` is done ahead of the scan (with ``sample_ahead`` for
+all K batches at once, from call-entry masses): the draw, the weights, the
+small per-transition fields and, in place of each observation, the ring slot
+that holds it (``ref % Cf``).  ``dedup_fetch`` is done in the scan's body, a
+step at a time: the B rows of each side are fetched from the ring the body
+closes over (``gather_rows``) and taken apart (``RowFormat.unpack``), so the
+program makes no array of K·B observations (at 84×84×4, B=512, K=64 such
+an array is 0.93 GB, and gathering ahead made four a side, each written to
+HBM and read back).  The ring's rows are not written inside
+the scan (only ``mass`` is restamped), so a row fetched in step t is the row
+that would have been fetched ahead: same slots, same rows, same bits.
+``dedup_sample_many`` is the two halves one after the other.
 """
 
 from __future__ import annotations
@@ -370,6 +385,50 @@ def gather_rows(rows: jax.Array, slots: jax.Array) -> jax.Array:
     return jax.lax.map(one, slots.reshape(-1)).reshape(*slots.shape, rows.shape[1])
 
 
+def dedup_sample_slots(
+    state: DedupDeviceReplayState,
+    rng: jax.Array,
+    num_batches: int,
+    batch_size: int,
+    beta: jax.Array | float = 0.4,
+    axis_name: str | None = None,
+) -> PrioritizedBatch:
+    """The sampler's half that is done ahead: the stratified PER draw of
+    ``device_replay_sample_many`` (the same ``sample_slots``: identical law
+    and IS weights) and the small per-transition fields, leaves [K, B].  In
+    place of the observations stand the ring slots that hold them
+    (``ref % Cf``, int32): ``dedup_fetch`` reads them."""
+    K, B = num_batches, batch_size
+    idx, weights = sample_slots(state, rng, K, B, beta, axis_name)
+    idx2 = idx.reshape(K, B)
+    Cf = state.frame_capacity
+    with stage("gather"):
+        transition = NStepTransition(
+            obs=state.obs_ref[idx2] % Cf,
+            action=state.action[idx2],
+            reward=state.reward[idx2],
+            discount=state.discount[idx2],
+            next_obs=state.next_ref[idx2] % Cf,
+        )
+    return PrioritizedBatch(
+        transition=transition, indices=idx2, is_weights=weights,
+    )
+
+
+def dedup_fetch(
+    state: DedupDeviceReplayState, sampled: PrioritizedBatch
+) -> PrioritizedBatch:
+    """The half done where a batch is used: the sampled slots' rows fetched
+    from the ring and taken apart into observations (any leading shape; the
+    fused scan hands it one step's [B])."""
+    with stage("gather"):
+        take = lambda slots: state.fmt.unpack(  # noqa: E731
+            gather_rows(state.rows, slots))
+        transition = sampled.transition
+        return sampled.replace(transition=transition.replace(
+            obs=take(transition.obs), next_obs=take(transition.next_obs)))
+
+
 def dedup_sample_many(
     state: DedupDeviceReplayState,
     rng: jax.Array,
@@ -378,26 +437,9 @@ def dedup_sample_many(
     beta: jax.Array | float = 0.4,
     axis_name: str | None = None,
 ) -> PrioritizedBatch:
-    """Stratified PER sample over the dedup layout — identical law and IS
-    weights to ``device_replay_sample_many`` (the same ``sample_slots``);
-    only the frame gather goes through the ref indirection."""
-    K, B = num_batches, batch_size
-    idx, weights = sample_slots(state, rng, K, B, beta, axis_name)
-    idx2 = idx.reshape(K, B)
-    Cf = state.frame_capacity
-    with stage("gather"):
-        take = lambda ref: state.fmt.unpack(  # noqa: E731
-            gather_rows(state.rows, ref[idx2] % Cf))
-        transition = NStepTransition(
-            obs=take(state.obs_ref),
-            action=state.action[idx2],
-            reward=state.reward[idx2],
-            discount=state.discount[idx2],
-            next_obs=take(state.next_ref),
-        )
-    return PrioritizedBatch(
-        transition=transition, indices=idx2, is_weights=weights,
-    )
+    """K whole batches at once: both halves, one after the other."""
+    return dedup_fetch(state, dedup_sample_slots(
+        state, rng, num_batches, batch_size, beta, axis_name))
 
 
 def build_dedup_fused_learn_step(
@@ -430,7 +472,7 @@ def build_dedup_fused_learn_step(
             steps_per_call=steps_per_call, batch_size=batch_size,
             priority_exponent=priority_exponent,
             target_sync_freq=target_sync_freq, sample_ahead=sample_ahead,
-            sample_many_fn=dedup_sample_many,
+            sample_many_fn=dedup_sample_slots, fetch_fn=dedup_fetch,
         )
 
     if include_ingest:

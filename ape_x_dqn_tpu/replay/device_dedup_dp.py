@@ -32,7 +32,8 @@ from ape_x_dqn_tpu.replay.device_dedup import (
     RowFormat,
     dedup_device_add_frames,
     dedup_device_add_transitions,
-    dedup_sample_many,
+    dedup_fetch,
+    dedup_sample_slots,
 )
 from ape_x_dqn_tpu.utils.profiling import jit_fused
 
@@ -184,7 +185,8 @@ def build_sharded_dedup_fused_learn_step(
             steps_per_call=K, batch_size=B_local,
             priority_exponent=priority_exponent,
             target_sync_freq=target_sync_freq, sample_ahead=sample_ahead,
-            axis_name=_AXIS, sample_many_fn=dedup_sample_many,
+            axis_name=_AXIS, sample_many_fn=dedup_sample_slots,
+            fetch_fn=dedup_fetch,
         )
         return train_state, _packed(r), metrics
 

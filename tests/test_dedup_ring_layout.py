@@ -13,6 +13,8 @@ scatter, ``copy.25`` before the gather).
 The fused program at the paper's shapes (``benchmark/configs/apex_b512.json``,
 one chip and the four-chip shard) holds no convolution or product over two
 batches of rows: the backward pass covers the rows that carry a gradient.
+It makes no array of K batches of observations either: the scan is handed
+the sampled slots and fetches and takes apart a batch's rows in its body.
 
 One expert block of ``benchmark/configs/lfm2moe_q_ep8.json`` at its published
 widths, forward and backward: no array of the worst case's ``tokens x k``
@@ -301,6 +303,65 @@ def test_no_convolution_at_paper_shapes_covers_two_batches(topo, no_compile_cach
     assert not doubled, doubled
     # 7 a forward and 12 backward, less what the compiler merges
     assert sum(rows in dims for dims in convs.values()) >= 20, sorted(convs)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_no_array_of_k_batches_at_paper_shapes(topo, no_compile_cache, chips):
+    """``apex_b512``'s fused program makes nothing of K x B observations: no
+    instruction outside a fusion makes an array of K batches of a chip's
+    rows, and a side's rows are fetched inside the ``while`` (gathered ahead
+    there were eight such arrays a chip, 0.92-0.94 GB each on one, and
+    this program took 2,825,479,168 B of temporaries there)."""
+    shapes = _paper()
+    jitted, args = _programs(topo, chips, shapes)["fused"]
+    compiled = _compile(jitted, args)
+    text = compiled.as_text()
+    rows = shapes["batch"] // chips
+    k_batches = shapes["k"] * rows * int(np.prod(OBS))
+    big = ring_sized_instructions(text, k_batches)
+    assert not big, "\n".join(line[:200] for _, _, line in big)
+    # the row fetch of both sides: gathers of a batch's rows, in a loop body
+    bodies = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
+    inside, fetches = None, []
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            inside = head.group(1)
+        elif re.search(rf" = u32\[{rows},7168\]\S* fusion\(.*stage:gather/gather", line):
+            fetches.append(inside)
+    assert len(fetches) == 2 and set(fetches) <= bodies, fetches
+    if chips == 1:
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 500_000_000, temp
+
+
+def test_reader_finds_k_batches_gathered_ahead():
+    """The reader on the entry computation the parent of PR 33 compiled to:
+    a side's rows gathered for all K batches, copied twice, taken apart."""
+    text = """HloModule jit_fused
+
+%fused_computation.7 (p: u32[153600,7168], i: s32[32768]) -> u32[32768,7168] {
+  %p = u32[153600,7168]{1,0:T(8,128)} parameter(0)
+  %i = s32[32768]{0:T(1024)} parameter(1)
+  ROOT %g = u32[32768,7168]{1,0:T(8,128)} gather(%p, %i), offset_dims={1}
+}
+
+ENTRY %main (replay_state_rows.1: u32[153600,7168]) -> u8[64,512,84,84,4] {
+  %replay_state_rows.1 = u32[153600,7168]{1,0:T(8,128)} parameter(0)
+  %fusion.7 = u32[32768,7168]{1,0:T(8,128)} fusion(%replay_state_rows.1, %broadcast_clamp_fusion.1), kind=kCustom, calls=%fused_computation.7, metadata={op_name="jit(fused)/stage:gather/gather"}
+  %bitcast.133 = u32[64,512,7056]{2,1,0:T(8,128)} bitcast(%fusion.7)
+  %copy.36 = u32[64,512,7056]{1,0,2:T(8,128)} copy(%bitcast.133)
+  %bitcast.5 = u32[64,512,84,84]{1,0,3,2:T(8,128)} bitcast(%copy.36)
+  %copy.30 = u32[64,512,84,84]{1,3,2,0:T(8,128)} copy(%bitcast.5)
+  ROOT %fusion.234 = u8[64,512,84,84,4]{1,4,3,2,0:T(4,128)(4,1)} fusion(%copy.30), kind=kLoop, calls=%fused_computation.324, metadata={op_name="jit(fused)/stage:gather/bitcast_convert_type"}
+}
+"""
+    k_batches = 64 * 512 * 84 * 84 * 4
+    assert [n for n, _, _ in ring_sized_instructions(text, k_batches)] == [
+        "fusion.7", "copy.36", "copy.30", "fusion.234"]
+    # a batch's rows inside the loop are far under the threshold
+    assert not ring_sized_instructions(
+        text.replace("64,512,", "1,512,").replace("32768", "512"), k_batches)
 
 
 def test_reader_finds_a_joined_forward():

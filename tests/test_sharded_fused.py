@@ -471,3 +471,80 @@ class TestAwkwardIngestMidScan:
         assert fl2.staged_rows < 4  # everything drainable drained
         m = fl2.train(beta=0.4)
         assert np.isfinite(np.asarray(m.loss)).all()
+
+
+SHARDED_ROW_CASES = [((6, 6, 1), np.uint8), ((5, 3), np.uint8),
+                     ((5, 3), np.uint16), ((5, 3), np.float32)]
+
+
+class TestShardedDedupFetchesInTheStep:
+    @pytest.mark.parametrize("sample_ahead", [False, True])
+    @pytest.mark.parametrize(
+        "obs_shape,dtype", SHARDED_ROW_CASES,
+        ids=["x".join(map(str, s)) + "-" + np.dtype(d).name
+             for s, d in SHARDED_ROW_CASES])
+    def test_same_bits_as_the_sharded_double_store(
+            self, obs_shape, dtype, sample_ahead):
+        """The sharded dedup fused step, whose scan fetches a shard's rows
+        and takes them apart a step at a time, against the sharded double
+        store, which gathers all K batches of observations ahead: the same
+        transitions on every shard, the same key, K > 1; losses, priorities,
+        masses and parameters after two calls equal to the last bit."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from ape_x_dqn_tpu.replay.device_dedup import (
+            DedupDeviceReplayState, RowFormat,
+        )
+        from ape_x_dqn_tpu.replay.device_dedup_dp import (
+            build_sharded_dedup_fused_learn_step,
+        )
+        from test_device_dedup import assert_same_bits as same
+
+        n, cf, c, K, B = 4, 24, 16, 3, 8
+        mesh = make_mesh(num_devices=n)
+        r = np.random.default_rng(11)
+        frames = r.integers(0, 251, (n, cf, *obs_shape)).astype(dtype)
+        ref = np.tile(np.arange(c, dtype=np.int32), n)
+        nxt = np.minimum(ref + 3, cf - 1)
+        shard = np.repeat(np.arange(n), c)
+        small = dict(
+            action=r.integers(0, 3, n * c).astype(np.int32),
+            reward=r.normal(size=n * c).astype(np.float32),
+            discount=np.full(n * c, 0.9, np.float32),
+            mass=r.integers(1, 30, n * c).astype(np.float32),
+            cursor=np.zeros(n, np.int32), count=np.full(n, c, np.int32))
+        row = NamedSharding(mesh, P("data"))
+        put = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+            lambda a: jax.device_put(jnp.asarray(a), row), tree)
+        fmt = RowFormat.of(obs_shape, dtype)
+        dd = put(DedupDeviceReplayState(
+            rows=fmt.pack(frames.reshape(n * cf, *obs_shape)), fmt=fmt,
+            obs_ref=ref, next_ref=nxt, fcount=np.full(n, cf, np.int32), **small))
+        ds = put(DeviceReplayState(
+            obs=frames[shard, ref], next_obs=frames[shard, nxt], **small))
+
+        net = DuelingMLP(num_actions=3, hidden_sizes=(16,))
+        opt = make_optimizer("adam", learning_rate=1e-3)
+        step_fn = build_train_step(
+            net, opt, sync_in_step=False, grad_reduce_axis="data", jit=False)
+        kw = dict(steps_per_call=K, target_sync_freq=K, sample_ahead=sample_ahead)
+        fused_ds = build_sharded_fused_learn_step(step_fn, mesh, B, **kw)
+        fused_dd = build_sharded_dedup_fused_learn_step(step_fn, mesh, B, **kw)
+        state = lambda: jax.device_put(jax.device_get(init_train_state(  # noqa: E731
+            net, opt, jax.random.PRNGKey(0), np.zeros((1, *obs_shape), dtype))),
+            NamedSharding(mesh, P()))
+        t_a, t_b = state(), state()
+        rng = jax.random.PRNGKey(42)
+        for i in range(2):
+            rng, sub = jax.random.split(rng)
+            t_a, ds, m_a = fused_ds(t_a, ds, 0.4, sub)
+            t_b, dd, m_b = fused_dd(t_b, dd, 0.4, sub)
+            assert m_b.priorities.shape == (K, B)
+            same(m_a.loss, m_b.loss, f"call {i} losses")
+            same(m_a.priorities, m_b.priorities, f"call {i} priorities")
+            same(t_a.params, t_b.params, f"call {i} parameters")
+            same(t_a.target_params, t_b.target_params, f"call {i} target")
+        same(ds.mass, dd.mass, "masses")
+        assert int(t_a.step) == int(t_b.step) == 2 * K
+        np.testing.assert_array_equal(
+            np.asarray(dd.frames), frames.reshape(n * cf, *obs_shape))
