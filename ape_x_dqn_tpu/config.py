@@ -39,7 +39,9 @@ from typing import Any, Optional, Sequence
 
 # Network kinds whose torso is a stack of blocks built from ``ApexConfig.torso``
 # (the keys of models/dueling.TORSO_KINDS).
-TORSO_NETWORKS = ("lfm2_moe", "laguna_moe")
+TORSO_NETWORKS = ("lfm2_moe", "laguna_moe", "granite_hybrid")
+# Those whose observation is a history of single frames (``frame_history``).
+HISTORY_NETWORKS = ("laguna_moe", "granite_hybrid")
 
 
 @dataclasses.dataclass
@@ -826,10 +828,10 @@ class ApexConfig:
         default_factory=AutopilotConfig
     )
     chaos: ChaosConfig = dataclasses.field(default_factory=ChaosConfig)
-    network: str = "conv"   # "conv" | "nature" | "mlp" | "lfm2_moe" | "laguna_moe"
-    # network=lfm2_moe | laguna_moe: the torso's block under the published
-    # config.json's keys plus the cut (spec_from_config of models/lfm2_moe.py
-    # or models/laguna_moe.py); optionally the
+    network: str = "conv"   # "conv" | "nature" | "mlp" | one of TORSO_NETWORKS
+    # network=lfm2_moe | laguna_moe | granite_hybrid: the torso's block under
+    # the published config.json's keys plus the cut (spec_from_config of
+    # models/<network>.py); optionally the
     # stem's ``channels`` and the head's ``hidden``.
     torso: dict = dataclasses.field(default_factory=dict)
     seed: int = 0
@@ -1066,8 +1068,8 @@ class ApexConfig:
             ((self.network in TORSO_NETWORKS) == bool(self.torso),
              f"torso holds the block of network={' | '.join(TORSO_NETWORKS)}, "
              "and of no other"),
-            (self.network != "laguna_moe" or self.env.frame_stack > 1,
-             "network=laguna_moe reads an observation as a history of single "
+            (self.network not in HISTORY_NETWORKS or self.env.frame_stack > 1,
+             f"network={self.network} reads an observation as a history of single "
              "frames: env.frame_stack must be over 1"),
             (l.optimizer in ("rmsprop", "adam"),
              f"unknown optimizer kind: {l.optimizer}"),

@@ -47,6 +47,10 @@ class StepMetrics:
     # A network's ``attention_metrics`` of the step's three forwards, from the
     # shapes (blocked attention's pairs in the mask and blocks visited).
     attention: Optional[dict] = None
+    # A network's ``scan_metrics`` of the step's three forwards, from the
+    # shapes (state-space layers: chunks walked, tokens with and without the
+    # padding to whole chunks).
+    scan: Optional[dict] = None
 
 
 def _scale_by_rms_lowp(
@@ -257,6 +261,7 @@ def build_train_step(
     routing_metrics = getattr(network, "routing_metrics", None)
     rebalanced = getattr(network, "rebalanced", None)
     attention_metrics = getattr(network, "attention_metrics", None)
+    scan_metrics = getattr(network, "scan_metrics", None)
 
     def q_of(params, obs):
         """(Q, what the network's layers sowed)."""
@@ -294,8 +299,11 @@ def build_train_step(
         add = lambda *trees: jax.tree_util.tree_map(lambda *xs: sum(xs), *trees)  # noqa: E731
         routing = None if routing_metrics is None else add(
             *(routing_metrics(s) for s in (*online, sown_target)))
-        counted = attention_metrics and attention_metrics(batch.transition.obs.shape)
-        attention = {k: jnp.float32(3.0 * v) for k, v in counted.items()} if counted else None
+        def of_three_forwards(counter):
+            counted = counter and counter(batch.transition.obs.shape)
+            return {k: jnp.float32(3.0 * v) for k, v in counted.items()} if counted else None
+
+        attention, scan = of_three_forwards(attention_metrics), of_three_forwards(scan_metrics)
         # Under plain pjit the mean inside loss_fn makes XLA insert the
         # gradient all-reduce over ICI automatically.  Inside shard_map
         # (varying-axes AD semantics): the params enter unvarying while the
@@ -350,6 +358,7 @@ def build_train_step(
             mean_q=mean_q,
             routing=routing,
             attention=attention,
+            scan=scan,
         )
         new_state = TrainState(
             params=new_params,

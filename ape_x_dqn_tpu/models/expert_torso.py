@@ -1,21 +1,25 @@
-"""What every Q-network with a torso of transformer blocks shares: the spec of
-the widths and layers, the expert layer, the block and the wrapper.
+"""What every Q-network with a torso of blocks shares: the spec of the widths
+and layers, the expert layer, the block and the wrapper.  A torso of blocks
+need not have experts: a spec whose router has no outputs holds none, every
+layer's FFN is the dense SwiGLU, and nothing is routed, sown or counted.
 
 The convolutional stem and the dueling head are ``dueling.py``'s; between
 them the positions of the stem's output are tokens (raster order,
 Obando-Ceron et al. 2024, arXiv:2402.08609, "PerConv"), projected to the
 torso's width and run through the layers the spec names, each
 
-    h <- h + Op(RMSNorm(h))     Op  = one of the spec's mixers, by layer type
-    h <- h + FFN(RMSNorm(h))    FFN = SwiGLU | mixture of experts (+ a shared expert)
+    h <- h + m Op(RMSNorm(h))     Op  = one of the spec's mixers, by layer type
+    h <- h + m FFN(RMSNorm(h))    FFN = SwiGLU | mixture of experts (+ a shared expert)
 
+(``m`` the spec's ``residual_multiplier``, 1 unless a model states one; the
+projected tokens are likewise multiplied by its ``token_multiplier``)
 then RMSNorm, the mean over the tokens and the dueling head.  What is a
 model's is the spec's (``TorsoSpec``): the mixers and their head counts, RoPE
 rules and windows, the router's score function, count and bias, the shared
 expert, whether an observation's tokens are one frame's positions or those
-of a history of frames.  ``models/lfm2_moe.py`` and ``models/laguna_moe.py``
-make a spec from a published ``config.json``'s keys and bring their mixers;
-everything else is here, once.
+of a history of frames.  ``models/lfm2_moe.py``, ``models/laguna_moe.py`` and
+``models/granite_hybrid.py`` make a spec from a published ``config.json``'s
+keys and bring their mixers; everything else is here, once.
 
 The expert layer is one chip's share of an expert-parallel layer: it is told
 how many experts exist (the router's outputs), how many a token takes and
@@ -53,7 +57,9 @@ the pairs on each of the router's outputs, held or not.  The network reads
 it for the train step: ``routing_metrics`` (the counters of the held range)
 and ``rebalanced`` (the balancing rule).  ``attention_metrics`` gives what
 the spec's mixers count from the shapes alone (blocked attention's pairs in
-the mask and blocks visited; ``None`` where no mixer counts anything).
+the mask and blocks visited; ``None`` where no mixer counts anything), and
+``scan_metrics`` what its state-space mixers do (chunks walked, tokens with
+and without their padding).
 
 The expert bias is a model's load-balancing buffer (``use_expert_bias``): a
 parameter no gradient reaches, which the train step moves after every update
@@ -112,10 +118,16 @@ class TorsoSpec:
     score_function: str = "sigmoid"   # of the router's outputs, before the top-k
     shared_expert_intermediate_size: int = 0   # 0: no shared expert
     frame_history: bool = False       # an observation is F single frames
+    residual_multiplier: float = 1.0  # on both branches of a block
+    token_multiplier: float = 1.0     # on the projected tokens
+    float32_leaves: Tuple[str, ...] = ()   # a family's, beside the router's (TorsoQ)
 
     def __post_init__(self):
         lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.router_outputs:
+        if self.router_outputs == 0:      # no expert layer at all
+            if (lo, hi) != (0, 0) or any(ffn == "moe" for _, ffn in self.layers):
+                raise ValueError("a router without outputs holds no expert and has no moe layer")
+        elif not 0 <= lo < hi <= self.router_outputs:
             raise ValueError(
                 f"experts_held {self.experts_held} is no range of the router's "
                 f"{self.router_outputs} outputs")
@@ -137,11 +149,11 @@ class TorsoSpec:
 def cut_from_config(cfg) -> tuple:
     """What a cut states beside a published config's keys: (``layers_held``,
     indices into ``layer_types``, default the first ``num_hidden_layers``;
-    ``router_outputs``, default ``num_experts``; ``experts_held``, default
-    all)."""
+    ``router_outputs``, default ``num_experts``, 0 for a config that names no
+    experts; ``experts_held``, default all)."""
     types = list(cfg["layer_types"])
     held = list(cfg.get("layers_held", range(int(cfg.get("num_hidden_layers", len(types))))))
-    outputs = int(cfg.get("router_outputs", cfg["num_experts"]))
+    outputs = int(cfg.get("router_outputs", cfg.get("num_experts", 0)))
     return held, outputs, tuple(cfg.get("experts_held", (0, outputs)))
 
 
@@ -379,6 +391,13 @@ class ExpertShare(nn.Module):
         return y.reshape(shape)
 
 
+def _added(h, y, multiplier: float):
+    """``h + multiplier y``, the product in float32; no operation at 1."""
+    if multiplier == 1.0:
+        return h + y
+    return h + (multiplier * y.astype(jnp.float32)).astype(h.dtype)
+
+
 class Block(nn.Module):
     spec: TorsoSpec
     op: str
@@ -390,13 +409,14 @@ class Block(nn.Module):
     def __call__(self, h, _=None):
         """(h, None) -> (the layer's output, None): a ``scan``'s body."""
         sp, cd, pd = self.spec, self.compute_dtype, self.param_dtype
+        m = sp.residual_multiplier
         with part("mixer"):
             u = RMSNorm(sp.norm_eps, cd, pd, name="operator_norm")(h)
-            h = h + dict(sp.mixers)[self.op](sp, self.op, cd, pd, name=self.op)(u)
+            h = _added(h, dict(sp.mixers)[self.op](sp, self.op, cd, pd, name=self.op)(u), m)
         if self.ffn == "dense":
             with part("dense_ffn"):
                 u = RMSNorm(sp.norm_eps, cd, pd, name="ffn_norm")(h)
-                return h + SwiGLU(sp.intermediate_size, cd, pd, name="dense")(u), None
+                return _added(h, SwiGLU(sp.intermediate_size, cd, pd, name="dense")(u), m), None
         with part("router"):
             u = RMSNorm(sp.norm_eps, cd, pd, name="ffn_norm")(h)
         y = ExpertShare(sp, cd, pd, name="moe")(u)
@@ -405,7 +425,7 @@ class Block(nn.Module):
             with part("shared_expert"):
                 y = y + SwiGLU(sp.shared_expert_intermediate_size, cd, pd,
                                name="shared_expert")(u)
-        return h + y, None
+        return _added(h, y, m), None
 
 
 class TorsoQ(nn.Module):
@@ -418,9 +438,12 @@ class TorsoQ(nn.Module):
     compute_dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
-    # A target network in a lower type keeps these leaves in float32: the
-    # router's scores and bias decide a top-k.
-    float32_leaves = ("router", "expert_bias")
+    @property
+    def float32_leaves(self) -> tuple:
+        """A target network in a lower type keeps these leaves in float32:
+        the router's scores and bias decide a top-k; a family adds its own
+        (a state-space layer's decay: the spec's)."""
+        return ("router", "expert_bias") + tuple(self.spec.float32_leaves)
 
     @nn.compact
     def __call__(self, x):
@@ -435,6 +458,8 @@ class TorsoQ(nn.Module):
             z = z.reshape(rows, -1, z.shape[-1])                   # time-major over a history
             w_tok = self.param("w_tok", _lecun(), (z.shape[-1], sp.hidden_size), pd)
             h = z @ w_tok.astype(cd)
+            if sp.token_multiplier != 1.0:
+                h = (sp.token_multiplier * h.astype(jnp.float32)).astype(cd)
         block = nn.remat(Block)
         for first, count, (op, ffn) in layer_runs(sp.layers):
             if count == 1:
@@ -457,29 +482,43 @@ class TorsoQ(nn.Module):
             h, w = (h - k) // s + 1, (w - k) // s + 1
         return h * w * (obs_shape[-1] if self.spec.frame_history else 1)
 
-    def attention_metrics(self, obs_shape) -> Optional[dict]:
-        """What the spec's mixers count of one forward of ``obs_shape``
-        ([B, H, W, C]) from the shapes alone, summed over the layers
-        ({name: float}); None where no mixer counts anything."""
+    def _counted(self, obs_shape, method: str) -> Optional[dict]:
+        """The sum over the layers of what their mixers' static ``method``
+        (spec, op, rows, tokens) gives for one forward of ``obs_shape``
+        ([B, H, W, C]), from the shapes alone ({name: float}); None where no
+        mixer has it."""
         out: dict = {}
         for op, _ in self.spec.layers:
-            count = getattr(dict(self.spec.mixers)[op], "count", None)
+            count = getattr(dict(self.spec.mixers)[op], method, None)
             if count is not None:
                 for key, v in count(self.spec, op, obs_shape[0], self.tokens_of(obs_shape)).items():
                     out[key] = out.get(key, 0.0) + v
         return out or None
 
+    def attention_metrics(self, obs_shape) -> Optional[dict]:
+        """What the blocked attention mixers count of one forward (``count``:
+        pairs in the mask, blocks visited and in all)."""
+        return self._counted(obs_shape, "count")
+
+    def scan_metrics(self, obs_shape) -> Optional[dict]:
+        """What the state-space mixers count of one forward (``scan_count``:
+        chunks walked, tokens with and without the padding to whole chunks)."""
+        return self._counted(obs_shape, "scan_count")
+
     def q_values(self, x):
         return self(x)[2]
 
-    def routing_metrics(self, sown) -> dict:
+    def routing_metrics(self, sown) -> Optional[dict]:
         """What one ``apply(..., mutable=[ROUTING])`` sowed, as the train
-        step's counters, summed over the expert layers (float32 [] each):
+        step's counters, summed over the expert layers (float32 [] each;
+        None for a torso without experts):
         the pairs on held experts, the largest and the mean load of a held
         expert, and the rows the layers walked for them (tiles by the rows
         of a tile; every pair of a token is on one of the router's outputs,
         so a layer's loads add up to its ``tokens x k``)."""
         lo, hi = self.spec.experts_held
+        if hi == lo:
+            return None
         loads = jnp.concatenate([v.reshape(-1, v.shape[-1])
                                  for v in jax.tree_util.tree_leaves(sown[ROUTING])])
         held = loads[:, lo:hi].astype(jnp.float32)
@@ -493,7 +532,7 @@ class TorsoQ(nn.Module):
         """``params`` after the balancing rule (module docstring) on the
         loads ``sown`` holds: every expert layer's bias moved against the
         load error of each of the router's outputs."""
-        if not self.spec.use_expert_bias:
+        if not (self.spec.use_expert_bias and self.spec.num_held):
             return params
         # .../moe/load/0 in the collection is .../moe/expert_bias in params
         loads = {jax.tree_util.keystr(path[:-2]): v.astype(jnp.float32)

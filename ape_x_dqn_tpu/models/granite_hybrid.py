@@ -1,0 +1,206 @@
+"""Granite 4.0-H's hybrid block (ibm-granite/granite-4.0-h-micro,
+``config.json``, ``model_type`` ``granitemoehybrid``) as a Q-network's torso
+over a history of frames: its two mixers and the spec made from the published
+keys.  ``mamba`` layers are Mamba-2's (Dao & Gu 2024, arXiv:2405.21060): one
+input projection into a gate, the scan's input with its ``B`` and ``C``, and
+a step size per head; a causal depthwise convolution; the selective scan
+(``ops/chunked_scan.py``, in chunks of ``mamba_chunk_size``); a gated
+RMSNorm; the output projection.  ``attention`` layers are grouped-query
+causal attention with no positional rule (``position_embedding_type``
+``nope``), scores scaled by ``attention_multiplier``, in blocked kernels
+(``ops/pallas/blocked_attention.py``).  Every layer's FFN is the dense SwiGLU
+(``shared_intermediate_size``; ``num_local_experts`` 0: no expert layer),
+both branches of a block are scaled by ``residual_multiplier`` and the
+projected tokens by ``embedding_multiplier``.  The block and the Q-network
+around it are ``models/expert_torso.py``'s.
+
+The attention module stands apart from ``laguna_moe.GatedAttention``: of
+what that one is (a RoPE rule by layer type, a sigmoid gate per head, a
+window, scores over the square root of the head) this layer has none, and
+what is left in common is four projections into ``blocked_attention``.
+
+A Mamba-2 layer's ``A_log``, ``dt_bias`` and ``D`` stay float32 in a target
+network of a lower type (the spec's ``float32_leaves``): they decide
+``exp(dt A)`` over every token of the history, and rounded to bfloat16 the
+decay's exponent is off by up to 0.4%.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Mapping
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from ape_x_dqn_tpu.models.expert_torso import (
+    TorsoQ, TorsoSpec, _bias_init, _lecun, cut_from_config,
+)
+from ape_x_dqn_tpu.ops.chunked_scan import chunked_scan, chunks_of
+from ape_x_dqn_tpu.ops.pallas import blocked_attention as blocked
+from ape_x_dqn_tpu.utils.profiling import part
+
+# Mamba-2's published initialisation: the decay -A ~ U[1, 16], the step size
+# softplus(dt_bias) log-uniform in [1e-3, 1e-1], the skip 1.
+A_RANGE, DT_RANGE = (1.0, 16.0), (1e-3, 1e-1)
+
+
+@dataclasses.dataclass(frozen=True)
+class MambaSizes:
+    """The published ``mamba_*`` keys."""
+
+    heads: int
+    head_dim: int
+    state: int
+    conv: int                         # the convolution's taps
+    chunk: int
+
+    @property
+    def inner(self) -> int:           # the scan's width, mamba_expand x hidden
+        return self.heads * self.head_dim
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, *A_RANGE))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    lo, hi = (math.log(v) for v in DT_RANGE)
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, lo, hi))
+    return dt + jnp.log(-jnp.expm1(-dt))          # softplus's inverse
+
+
+class Mamba2(nn.Module):
+    """``W_out norm(scan(conv(W_in u)))``: module docstring."""
+
+    spec: TorsoSpec
+    op: str
+    compute_dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, u):
+        sp, cd, pd, f32 = self.spec, self.compute_dtype, self.param_dtype, jnp.float32
+        m: MambaSizes = sp.arg("mamba")
+        d, inner, n, k = sp.hidden_size, m.inner, m.state, m.conv
+        mixed = inner + 2 * n                        # x | B | C: what the convolution sees
+        w_in = self.param("w_in", _lecun(), (d, inner + mixed + m.heads), pd)
+        kernel = self.param("conv_kernel", _lecun(-1), (mixed, k), pd)
+        conv_bias = self.param("conv_bias", _bias_init, (mixed,), pd)
+        a_log = self.param("A_log", _a_log_init, (m.heads,), f32)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (m.heads,), f32)
+        skip = self.param("D", nn.initializers.ones, (m.heads,), f32)
+        norm = self.param("norm", nn.initializers.ones, (inner,), pd)
+        w_out = self.param("w_out", _lecun(), (inner, d), pd)
+
+        z, xbc, dt = jnp.split(u @ w_in.astype(cd), (inner, inner + mixed), axis=-1)
+        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))   # zeros before t = 0: causal
+        xbc = jax.nn.silu(conv_bias.astype(cd) + sum(
+            padded[:, j:j + u.shape[1], :] * kernel[:, j].astype(cd) for j in range(k)))
+        x, b, c = jnp.split(xbc, (inner, inner + n), axis=-1)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
+        y = chunked_scan(x.reshape(*x.shape[:2], m.heads, m.head_dim), dt, -jnp.exp(a_log),
+                         b, c, skip, m.chunk)
+        g = y.reshape(x.shape).astype(f32) * jax.nn.silu(z.astype(f32))   # gate first
+        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + sp.norm_eps)
+        return (g * norm.astype(f32)).astype(cd) @ w_out.astype(cd)
+
+    @staticmethod
+    def scan_count(spec: TorsoSpec, op: str, rows: int, tokens: int) -> dict:
+        """One layer's forward over ``rows`` sequences of ``tokens``: the
+        chunks the scan walks, the tokens it walks them over and those that
+        are the sequences' own."""
+        chunks, padded = chunks_of(tokens, spec.arg("mamba").chunk)
+        return {"chunks": float(rows * chunks), "tokens_padded": float(rows * padded),
+                "tokens": float(rows * tokens)}
+
+
+class NopeAttention(nn.Module):
+    """Grouped-query causal attention, no positional rule, no bias: scores
+    ``q k^T`` times the spec's ``attention_multiplier``."""
+
+    spec: TorsoSpec
+    op: str
+    compute_dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, u):
+        sp, cd = self.spec, self.compute_dtype
+        d, h, kv, hd = (sp.hidden_size, sp.arg("num_attention_heads"),
+                        sp.arg("num_key_value_heads"), sp.arg("head_dim"))
+        wq = self.param("w_q", _lecun(), (d, h * hd), self.param_dtype)
+        wk = self.param("w_k", _lecun(), (d, kv * hd), self.param_dtype)
+        wv = self.param("w_v", _lecun(), (d, kv * hd), self.param_dtype)
+        wo = self.param("w_o", _lecun(), (h * hd, d), self.param_dtype)
+        heads_of = lambda w, n: jnp.einsum(  # noqa: E731  [B, n, T, hd]
+            "btd,dnk->bntk", u, w.astype(cd).reshape(d, n, hd))
+        q = (heads_of(wq, h).astype(jnp.float32) * sp.arg("attention_multiplier")).astype(cd)
+        with part("attn_full"):
+            a = blocked.blocked_attention(q, heads_of(wk, kv), heads_of(wv, kv))
+        return jnp.einsum("bntk,nkd->btd", a, wo.astype(cd).reshape(h, hd, d))
+
+    @staticmethod
+    def count(spec: TorsoSpec, op: str, rows: int, tokens: int) -> dict:
+        """One layer's forward over ``rows`` sequences of ``tokens``, under
+        ``laguna_moe.GatedAttention.count``'s names for a causal layer."""
+        visited, total = blocked.blocks_visited(tokens, None)
+        heads = spec.arg("num_attention_heads")
+        return {"pairs_in_mask_full": float(rows * blocked.pairs_in_mask(tokens, None)),
+                "blocks_visited_full": float(rows * heads * visited),
+                "blocks_total_full": float(rows * heads * total)}
+
+
+MIXERS = {"mamba": Mamba2, "attention": NopeAttention}
+
+
+def spec_from_config(cfg: Mapping) -> TorsoSpec:
+    """A ``TorsoSpec`` from the published ``config.json``'s keys, plus what a
+    cut states (``expert_torso.cut_from_config``: ``layers_held``)."""
+    types = list(cfg["layer_types"])
+    held, outputs, experts = cut_from_config(cfg)
+    if outputs or int(cfg.get("num_local_experts", 0)):
+        raise ValueError("this family's expert layers are not built here: num_local_experts "
+                         "must be 0")
+    if int(cfg["mamba_n_groups"]) != 1:
+        raise ValueError("the scan shares B and C over all heads: mamba_n_groups must be 1")
+    if cfg.get("position_embedding_type", "nope") != "nope":
+        raise ValueError("the attention layers have no positional rule: "
+                         "position_embedding_type must be nope")
+    d, heads, kv = (int(cfg[k]) for k in ("hidden_size", "num_attention_heads",
+                                          "num_key_value_heads"))
+    mamba = MambaSizes(heads=int(cfg["mamba_n_heads"]), head_dim=int(cfg["mamba_d_head"]),
+                       state=int(cfg["mamba_d_state"]), conv=int(cfg["mamba_d_conv"]),
+                       chunk=int(cfg["mamba_chunk_size"]))
+    if mamba.inner != int(cfg["mamba_expand"]) * d or heads % kv:
+        raise ValueError(f"mamba_n_heads x mamba_d_head is not mamba_expand x {d}, or "
+                         f"{heads} heads do not divide by {kv} key-value heads")
+    ops = sorted({types[i] for i in held})
+    if not set(ops) <= set(MIXERS):
+        raise ValueError(f"unknown layer types {ops}; {sorted(MIXERS)}")
+    return TorsoSpec(
+        hidden_size=d,
+        intermediate_size=int(cfg["shared_intermediate_size"]),
+        moe_intermediate_size=0,
+        norm_eps=float(cfg["rms_norm_eps"]),
+        router_outputs=0,
+        num_experts_per_tok=0,
+        experts_held=experts,
+        layers=tuple((types[i], "dense") for i in held),
+        mixers=tuple((op, MIXERS[op]) for op in ops),
+        mixer_args=(("mamba", mamba), ("num_attention_heads", heads),
+                    ("num_key_value_heads", kv), ("head_dim", int(cfg.get("head_dim") or d // heads)),
+                    ("attention_multiplier", float(cfg["attention_multiplier"]))),
+        use_expert_bias=False,
+        frame_history=True,
+        residual_multiplier=float(cfg.get("residual_multiplier", 1.0)),
+        token_multiplier=float(cfg.get("embedding_multiplier", 1.0)),
+        float32_leaves=("A_log", "dt_bias", "['D']"),
+    )
+
+
+class GraniteHybridQ(TorsoQ):
+    """Stem, a frame at a time -> a history's tokens -> Granite 4.0-H layers
+    -> norm, mean over tokens -> dueling head."""

@@ -47,7 +47,8 @@ def plan(tokens: int, window: Optional[int]) -> Plan:
     2,048 tokens blocks of 512.  A block's keys are multiplied all at once.
     (Read on a TPU v5e at B=8, 8 key-value heads of 128, T=1,568, forward
     and backward, against smaller and larger blocks: PERF.md section 6, PR
-    32.)"""
+    32.  At heads of 64, 32 over 8, the causal plan is used as it stands and
+    was read at this one block size: PERF.md section 6, PR 34.)"""
     up = lambda n, m: -(-n // m) * m  # noqa: E731
     if window is not None:
         block = min(up(window, _LANES), 512)

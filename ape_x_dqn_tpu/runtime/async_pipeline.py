@@ -306,6 +306,7 @@ class AsyncPipeline:
         # network without such layers.
         self._routing: dict = {}
         self._attention: dict = {}    # StepMetrics.attention, likewise
+        self._scan: dict = {}         # StepMetrics.scan, likewise
         # Per-stage wall-clock accumulators (SURVEY §5 tracing subsystem):
         # µs/step per pipeline stage, exported in every metrics emit.
         self.timers = StageTimer()
@@ -1042,6 +1043,8 @@ class AsyncPipeline:
             out["routing"] = dict(self._routing)
         if self._attention:
             out["attention"] = dict(self._attention)
+        if self._scan:
+            out["scan"] = dict(self._scan)
         return out
 
     def _maybe_eval(self):
@@ -1655,6 +1658,9 @@ class AsyncPipeline:
             if getattr(metrics, "attention", None) is not None:
                 self._attention = {k: float(np.mean(np.asarray(v)))
                                    for k, v in metrics.attention.items()}
+            if getattr(metrics, "scan", None) is not None:
+                self._scan = {k: float(np.mean(np.asarray(v)))
+                              for k, v in metrics.scan.items()}
         return self.logger.emit(
             step=self._learner_step,
             actor_steps=self.worker.actor_steps,
@@ -1668,6 +1674,7 @@ class AsyncPipeline:
             stage_us=self.timers.us_per_call(),
             **({"routing": self._routing} if self._routing else {}),
             **({"attention": self._attention} if self._attention else {}),
+            **({"scan": self._scan} if self._scan else {}),
             final=final,
             **self._transport_extra(),
             **self._ckpt_extra(),

@@ -54,7 +54,7 @@ SCOPE_PREFIX = "stage:"   # device side: jax.named_scope("stage:<name>")
 # prefix would take the network's time out of ``forward`` and lose its
 # backward pass.  A part is read beside its stage, not in its place.
 PARTS = ("stem", "mixer", "router", "experts", "dense_ffn", "head",
-         "attn_window", "attn_full", "shared_expert")
+         "attn_window", "attn_full", "shared_expert", "ssm_scan")
 PART_PREFIX = "torso:"    # device side: jax.named_scope("torso:<name>")
 SPAN_PREFIX = "apex:"     # host side: TraceAnnotation("apex:<name>")
 

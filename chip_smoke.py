@@ -27,6 +27,12 @@ which owns the chip:
             steps at batch 2 on a 1,024-slot ring: beside the learner's 5.9
             GB of state the actors' copy of the parameters (2.95 GB) leaves
             the cell's batch of 8 no room.  Only with --laguna
+  granite   configs/config8_granite4h_q_l10.json (the 749 M parameter torso of
+            nine Mamba-2 layers to one attention layer over the same 32-frame
+            history: the chunked scan and its backward pass under the
+            trainer's loop) with 4 thread actors on the learner's chip, 12
+            learner steps at batch 2 on a 1,024-slot ring, as the laguna
+            leg and for its reason.  Only with --granite
 
 Sets no platform itself.  Exits non-zero, with one line saying why and no
 result, before compiling anything if jax's default backend is not a TPU, and
@@ -292,9 +298,27 @@ def leg_lfm2moe() -> None:
     assert rc == 0, f"lfm2moe: train.main returned {rc}"
 
 
-def leg_laguna() -> None:
+def _train_on_histories(leg: str, config: str, steps: int, inspect) -> None:
+    """``train.main`` on one of the 32-frame-history torsos with 4 thread
+    actors, batch and ring cut to what fits beside the actors' parameters."""
     from ape_x_dqn_tpu import train
 
+    rc = train.main([
+        "--params-file", os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                      "configs", config),
+        "--set", "env.name=fake-atari",
+        "--set", "actor.mode=thread", "--set", "actor.num_actors=4",
+        "--set", "actor.sync_every=1",
+        "--set", "learner.replay_sample_size=2",
+        "--set", "replay.capacity=1024",
+        "--set", "learner.min_replay_mem_size=64",
+        "--set", "learner.publish_every=4",
+        "--log-every", "4", "--steps", str(steps),
+    ], inspect=inspect)
+    assert rc == 0, f"{leg}: train.main returned {rc}"
+
+
+def leg_laguna() -> None:
     steps = 12
 
     def inspect(pipe, final):
@@ -308,20 +332,27 @@ def leg_laguna() -> None:
         say(f"laguna: routing a step {routing}; attention a step {attention}; actors "
             f"adopted param_version {pipe.worker.param_version} of {final['param_version']}")
 
-    rc = train.main([
-        "--params-file", os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                      "configs", "config7_laguna_q_ep32.json"),
-        "--set", "env.name=fake-atari",
-        "--set", "actor.mode=thread", "--set", "actor.num_actors=4",
-        "--set", "actor.sync_every=1",
-        # batch and ring cut to what fits beside the actors' parameters
-        "--set", "learner.replay_sample_size=2",
-        "--set", "replay.capacity=1024",
-        "--set", "learner.min_replay_mem_size=64",
-        "--set", "learner.publish_every=4",
-        "--log-every", "4", "--steps", str(steps),
-    ], inspect=inspect)
-    assert rc == 0, f"laguna: train.main returned {rc}"
+    _train_on_histories("laguna", "config7_laguna_q_ep32.json", steps, inspect)
+
+
+def leg_granite() -> None:
+    steps = 12
+
+    def inspect(pipe, final):
+        check_run("granite", pipe, final, steps)
+        assert type(pipe.comps.network).__name__ == "GraniteHybridQ"
+        assert final["param_version"] >= 1, "granite: nothing was published"
+        scan, attention = final.get("scan") or {}, final.get("attention") or {}
+        assert "routing" not in final, f"granite: routing counters without experts: {final}"
+        # batch 2, three forwards, nine state-space layers, 7 chunks of 256 over 1,568 tokens
+        assert scan.get("chunks") == 2 * 3 * 9 * 7 and 0 < scan.get("tokens", 0) < scan.get(
+            "tokens_padded", 0), f"granite: no scan counters: {final}"
+        assert 0 < attention.get("blocks_visited_full", 0) < attention.get(
+            "blocks_total_full", 0), f"granite: no attention counters: {final}"
+        say(f"granite: scan a step {scan}; attention a step {attention}; actors "
+            f"adopted param_version {pipe.worker.param_version} of {final['param_version']}")
+
+    _train_on_histories("granite", "config8_granite4h_q_l10.json", steps, inspect)
 
 
 def main() -> int:
@@ -376,6 +407,8 @@ def main() -> int:
         legs = [("lfm2moe", leg_lfm2moe)]
     if "--laguna" in sys.argv[1:]:
         legs = [("laguna", leg_laguna)]
+    if "--granite" in sys.argv[1:]:
+        legs = [("granite", leg_granite)]
     for name, fn in legs:
         t0 = time.perf_counter()
         say(f"leg {name}: starts with bytes_in_use per device "

@@ -529,3 +529,65 @@ def test_the_history_torsos_fused_program_fits_the_chip(topo, no_compile_cache, 
     # each backward kernel, not five
     assert kernels.count("splash_mha_dq_no_residuals") == 3, kernels
     assert kernels.count("splash_mha_dkv_no_residuals") == 3, kernels
+
+
+def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cache, monkeypatch):
+    """``benchmark/configs/granite4h_q_l10.json``'s fused program at the cell's
+    shapes (749 M parameters, B=8, 1,568 tokens in 7 chunks of 256, the
+    4,096-slot ring): state, ring and temporaries leave over 0.5 GB of a v5e
+    (under that the configuration's batch would have to be halved); no part
+    of the ring is copied; the scan is a loop over chunks in the executable,
+    forward and backward, that builds nothing of all seven chunks' ``[256,
+    256]`` a head at once; the attention kernels compile at heads of 64, once:
+    one attention layer."""
+    from ape_x_dqn_tpu.learner.train_step import (
+        build_train_step, init_train_state, make_optimizer,
+    )
+    from ape_x_dqn_tpu.models.dueling import build_network
+    from ape_x_dqn_tpu.ops.pallas import blocked_attention
+
+    monkeypatch.setattr(blocked_attention, "INTERPRET", False)   # this process sees the CPU
+    monkeypatch.setitem(globals(), "COMPILE_LIMIT_S", 900.0)     # about 55 s alone
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / "granite4h_q_l10.json").read_text())
+    prec = cfg["precision"]
+    net = build_network(cfg["network"], cfg["num_actions"], torso=cfg,
+                        channels=tuple(cfg["channels"]), hidden=cfg["hidden"],
+                        compute_dtype=jnp.dtype(prec["compute"]),
+                        param_dtype=jnp.dtype(prec["params"]))
+    opt = make_optimizer(cfg["optimizer"], learning_rate=cfg["learning_rate"],
+                         rmsprop_decay=cfg["rmsprop_decay"], rmsprop_eps=cfg["rmsprop_eps"],
+                         max_grad_norm=cfg["max_grad_norm"],
+                         second_moment_dtype=jnp.dtype(prec["second_moment"]))
+    step_fn = build_train_step(net, opt, loss_kind=cfg["loss"], sync_in_step=False, jit=False)
+    fused = build_dedup_fused_learn_step(
+        step_fn, cfg["batch_size"], steps_per_call=cfg["steps_per_call"],
+        priority_exponent=cfg["priority_exponent"], target_sync_freq=cfg["target_sync_freq"],
+        sample_ahead=cfg["sample_ahead"])
+    dev = SingleDeviceSharding(topo.devices[0])
+    obs = tuple(cfg["obs_shape"])
+    state = _with(jax.eval_shape(
+        lambda k: init_train_state(net, opt, k, jnp.zeros((1, *obs), jnp.uint8),
+                                   target_dtype=jnp.dtype(prec["target_params"])),
+        jax.random.PRNGKey(0)), dev)
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(state.params)) == 748_781_171
+    frames = int(cfg["replay_capacity"] * cfg["frame_ratio"])
+    ring = _with(jax.eval_shape(lambda: init_dedup_device_replay(
+        cfg["replay_capacity"], obs, frame_capacity=frames)), dev)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev)
+    compiled = _compile(fused, (state, ring, 0.4, key))
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    hbm = 16_909_336_064                      # a v5e's, PERF.md Open question 11
+    assert hbm - mem.argument_size_in_bytes - mem.temp_size_in_bytes > 0.5e9, mem
+    ring_bytes = frames * 56448 * 4
+    assert_ring_stays_put(text, ring_bytes, 0)
+    assert "mini-gather" not in text
+    # one chunk's decays and products a head, never seven chunks' at once
+    per_chunk = [dims for _, dims, _ in _ARRAY.findall(text) if dims.endswith("256,256")]
+    assert per_chunk and not any(
+        int(np.prod([int(d) for d in dims.split(",")])) > 8 * 64 * 256 * 256 for dims in per_chunk), \
+        sorted(set(per_chunk))
+    kernels = re.findall(r"%(splash_mha_\w+?)[.\d]* = ", text)
+    assert kernels.count("splash_mha_dq_no_residuals") == 1, kernels
+    assert kernels.count("splash_mha_dkv_no_residuals") == 1, kernels

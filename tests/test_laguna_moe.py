@@ -202,7 +202,7 @@ def test_the_train_step_carries_routing_and_attention_counters():
 
 
 def test_the_new_parts_are_scoped():
-    assert profiling.PARTS[6:] == ("attn_window", "attn_full", "shared_expert")
+    assert profiling.PARTS[6:9] == ("attn_window", "attn_full", "shared_expert")
     net = small_net()
     x = obs(jax.random.PRNGKey(8))
     params = net.init(jax.random.PRNGKey(9), x)
