@@ -5,7 +5,11 @@ keys.  ``mamba`` layers are Mamba-2's (Dao & Gu 2024, arXiv:2405.21060): one
 input projection into a gate, the scan's input with its ``B`` and ``C``, and
 a step size per head; a causal depthwise convolution; the selective scan
 (``ops/chunked_scan.py``, in chunks of ``mamba_chunk_size``); a gated
-RMSNorm; the output projection.  ``attention`` layers are grouped-query
+RMSNorm; the output projection.  Between the two projections an activation
+crosses HBM once a pass, in the compute type: the scan reads ``x`` and
+writes ``y`` as ``[chunks, B, H x P, chunk]``, a chunk's tokens in the lanes
+(a head of 64 does not fill them), so the convolution writes that layout
+and the gate and norm read it (``ops/pallas/scan_layout.py``).  ``attention`` layers are grouped-query
 causal attention with no positional rule (``position_embedding_type``
 ``nope``), scores scaled by ``attention_multiplier``, in blocked kernels
 (``ops/pallas/blocked_attention.py``).  Every layer's FFN is the dense SwiGLU
@@ -38,8 +42,9 @@ from flax import linen as nn
 from ape_x_dqn_tpu.models.expert_torso import (
     TorsoQ, TorsoSpec, _bias_init, _lecun, cut_from_config,
 )
-from ape_x_dqn_tpu.ops.chunked_scan import chunked_scan, chunks_of
+from ape_x_dqn_tpu.ops.chunked_scan import chunks_of, cut, scan_chunks
 from ape_x_dqn_tpu.ops.pallas import blocked_attention as blocked
+from ape_x_dqn_tpu.ops.pallas.scan_layout import conv_to_chunks, gated_norm
 from ape_x_dqn_tpu.utils.profiling import part
 
 # Mamba-2's published initialisation: the decay -A ~ U[1, 16], the step size
@@ -73,7 +78,7 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 
 
 class Mamba2(nn.Module):
-    """``W_out norm(scan(conv(W_in u)))``: module docstring."""
+    """``W_out norm(scan(conv(W_in u)) silu(z))``: module docstring."""
 
     spec: TorsoSpec
     op: str
@@ -95,17 +100,16 @@ class Mamba2(nn.Module):
         norm = self.param("norm", nn.initializers.ones, (inner,), pd)
         w_out = self.param("w_out", _lecun(), (inner, d), pd)
 
-        z, xbc, dt = jnp.split(u @ w_in.astype(cd), (inner, inner + mixed), axis=-1)
-        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))   # zeros before t = 0: causal
-        xbc = jax.nn.silu(conv_bias.astype(cd) + sum(
-            padded[:, j:j + u.shape[1], :] * kernel[:, j].astype(cd) for j in range(k)))
-        x, b, c = jnp.split(xbc, (inner, inner + n), axis=-1)
-        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
-        y = chunked_scan(x.reshape(*x.shape[:2], m.heads, m.head_dim), dt, -jnp.exp(a_log),
-                         b, c, skip, m.chunk)
-        g = y.reshape(x.shape).astype(f32) * jax.nn.silu(z.astype(f32))   # gate first
-        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + sp.norm_eps)
-        return (g * norm.astype(f32)).astype(cd) @ w_out.astype(cd)
+        # one parameter, three products: each output is made where it is used
+        w_z, w_x, w_rest = jnp.split(w_in.astype(cd), (inner, 2 * inner), axis=1)
+        bc, dt = jnp.split(u @ w_rest, (2 * n,), axis=-1)
+        # the convolution and its SiLU write x, B, C as the scan reads them
+        x = conv_to_chunks(u @ w_x, kernel[:inner], conv_bias[:inner], m.chunk, True)
+        b, c = jnp.split(conv_to_chunks(bc, kernel[inner:], conv_bias[inner:], m.chunk, False), 2, -1)
+        dt = cut(jax.nn.softplus(dt.astype(f32) + dt_bias), m.chunk, True)
+        y = scan_chunks(x.reshape(*x.shape[:2], m.heads, m.head_dim, -1), dt, -jnp.exp(a_log),
+                        b, c, skip)
+        return gated_norm(y.reshape(x.shape), u @ w_z, norm, sp.norm_eps) @ w_out.astype(cd)
 
     @staticmethod
     def scan_count(spec: TorsoSpec, op: str, rows: int, tokens: int) -> dict:

@@ -23,6 +23,19 @@ product of ``[chunk, chunk]`` by ``[chunk, P]`` a head, and two products with
 the state.  Running sums, decays and the state are float32; the products
 take operands in ``x``'s type and accumulate in float32.
 
+The layout ``x`` and ``y`` cross the walk in is ``[chunks, B, H, P, chunk]``,
+a chunk's tokens last: a head's ``P`` of 64 does not fill a TPU's 128 lanes,
+so the compiler holds these operands with the tokens in the lanes whatever
+order they are written in, and from ``[B, T, H, P]`` it got there by a
+transposing copy, a pad and two copies that bring the chunks to the front,
+each over the whole array, in either direction.  ``scan_chunks`` therefore
+takes the sequence already cut and turned, ``dt`` as ``[chunks, B, H,
+chunk]``, ``B`` and ``C`` as ``[chunks, B, chunk, N]`` (``N`` of 128 fills
+the lanes), and every product of ``_chunk`` contracts over the last axis of
+an operand as it lies; a caller makes that layout where it makes the values
+(``ops/pallas/scan_layout.py``).  ``chunked_scan`` is the same walk for a
+caller that holds ``[B, T, H, P]``.
+
 The backward pass is written by hand (``jax.custom_vjp``): it keeps the
 inputs and each chunk's incoming state (``[chunks, B, H, P, N]`` float32)
 and walks the chunks from the last to the first, computing each again and
@@ -49,89 +62,121 @@ def chunks_of(tokens: int, chunk: int) -> tuple:
     return n, n * q
 
 
-def _cut(v, chunk: int):
-    """[B, T, ...] -> [chunks, B, chunk, ...], zeros past T."""
+def cut(v, chunk: int, tokens_last: bool = False):
+    """[B, T, ...] -> [chunks, B, chunk, ...] (``tokens_last``: [chunks, B,
+    ..., chunk]), zeros past T."""
     n, padded = chunks_of(v.shape[1], chunk)
     v = jnp.pad(v, ((0, 0), (0, padded - v.shape[1])) + ((0, 0),) * (v.ndim - 2))
-    return jnp.moveaxis(v.reshape(v.shape[0], n, padded // n, *v.shape[2:]), 1, 0)
+    v = jnp.moveaxis(v.reshape(v.shape[0], n, padded // n, *v.shape[2:]), 1, 0)
+    return jnp.moveaxis(v, 2, -1) if tokens_last else v
 
 
-def _join(v, tokens: int):
-    """[chunks, B, chunk, ...] -> [B, T, ...]."""
-    v = jnp.moveaxis(v, 0, 1)
+def join(v, tokens: int, tokens_last: bool = False):
+    """``cut``'s inverse: -> [B, T, ...]."""
+    v = jnp.moveaxis(jnp.moveaxis(v, -1, 2) if tokens_last else v, 0, 1)
     return v.reshape(v.shape[0], -1, *v.shape[3:])[:, :tokens]
 
 
 def _chunk(state, x, dt, a, b, c, d):
-    """One chunk: (``state`` [B, H, P, N] float32, the chunk's ``x`` [B, Q, H,
-    P], ``dt`` [B, Q, H] float32, ``b``, ``c`` [B, Q, N], the heads' ``a``,
-    ``d`` [H]) -> (the state after it, ``y`` [B, Q, H, P] in ``x``'s type)."""
+    """One chunk: (``state`` [B, H, P, N] float32, the chunk's ``x`` [B, H, P,
+    Q], ``dt`` [B, H, Q] float32, ``b``, ``c`` [B, Q, N], the heads' ``a``,
+    ``d`` [H]) -> (the state after it, ``y`` [B, H, P, Q] in ``x``'s type)."""
     cd, f32 = x.dtype, jnp.float32
-    run = jnp.cumsum(dt * a, axis=1)                         # L: [B, Q, H]
-    by_head = jnp.moveaxis(run, 2, 1)                        # [B, H, Q]
-    q = x.shape[1]
+    run = jnp.cumsum(dt * a[:, None], axis=-1)               # L: [B, H, Q]
+    q = x.shape[-1]
     earlier = jnp.tril(jnp.ones((q, q), bool))               # j <= i
     # masked before the exponential: a later token's L_i - L_j is positive
-    decay = jnp.exp(jnp.where(earlier, by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+    decay = jnp.exp(jnp.where(earlier, run[..., :, None] - run[..., None, :], -jnp.inf))
     scores = jnp.einsum("bin,bjn->bij", c, b, preferred_element_type=f32)
-    xdt = x.astype(f32) * dt[..., None]                      # dt_j x_j
-    y = jnp.einsum("bhij,bjhp->bihp", (scores[:, None] * decay).astype(cd), xdt.astype(cd),
+    xdt = x.astype(f32) * dt[:, :, None]                     # dt_j x_j
+    y = jnp.einsum("bhpj,bhij->bhpi", xdt.astype(cd), (scores[:, None] * decay).astype(cd),
                    preferred_element_type=f32)
-    y += jnp.exp(run)[..., None] * jnp.einsum(
-        "bin,bhpn->bihp", c, state.astype(cd), preferred_element_type=f32)
-    y += d[:, None] * x.astype(f32)
-    to_end = jnp.exp(run[:, -1:] - run)                      # exp(L_end - L_j)
-    state = jnp.exp(run[:, -1])[..., None, None] * state + jnp.einsum(
-        "bjhp,bjn->bhpn", (xdt * to_end[..., None]).astype(cd), b, preferred_element_type=f32)
+    y += jnp.exp(run)[:, :, None] * jnp.einsum(
+        "bhpn,bin->bhpi", state.astype(cd), c, preferred_element_type=f32)
+    y += d[:, None, None] * x.astype(f32)
+    to_end = jnp.exp(run[..., -1:] - run)                    # exp(L_end - L_j)
+    state = jnp.exp(run[..., -1])[..., None, None] * state + jnp.einsum(
+        "bhpj,bjn->bhpn", (xdt * to_end[:, :, None]).astype(cd), b, preferred_element_type=f32)
     return state, y.astype(cd)
 
 
-def _walk(x, dt, a, b, c, d, chunk: int, keep: bool):
-    """(y [B, T, H, P]; with ``keep`` each chunk's incoming state, [chunks,
-    B, H, P, N], else None)."""
+def _walk(x, dt, a, b, c, d, keep: bool):
+    """(y [chunks, B, H, P, Q]; with ``keep`` each chunk's incoming state,
+    [chunks, B, H, P, N], else None)."""
     with part("ssm_scan"):
-        first = jnp.zeros((x.shape[0], x.shape[2], x.shape[3], b.shape[-1]), jnp.float32)
+        first = jnp.zeros((*x.shape[1:4], b.shape[-1]), jnp.float32)
 
-        def body(state, cut):
-            xc, dtc, bc, cc = cut
+        def body(state, chunk):
+            xc, dtc, bc, cc = chunk
             after, y = _chunk(state, xc, dtc, a, bc, cc, d)
             return after, (y, state if keep else None)
 
-        _, (ys, states) = jax.lax.scan(body, first, tuple(_cut(v, chunk) for v in (x, dt, b, c)))
-        return _join(ys, x.shape[1]), states
+        return jax.lax.scan(body, first, (x, dt, b, c))[1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def chunked_scan(x, dt, a, b, c, d, chunk: int):
-    """``y`` [B, T, H, P] in ``x``'s type of the recurrence above.
+@jax.custom_vjp
+def scan_chunks(x, dt, a, b, c, d):
+    """``y`` [chunks, B, H, P, Q] in ``x``'s type of the recurrence above,
+    over a sequence already cut in chunks, zeros past its end.
 
-    ``x`` [B, T, H, P]; ``dt`` [B, T, H] float32, positive; ``a`` [H]
-    float32, negative; ``b``, ``c`` [B, T, N]; ``d`` [H] float32; ``chunk``
-    the tokens of a chunk (``chunks_of``)."""
-    return _walk(x, dt, a, b, c, d, chunk, keep=False)[0]
-
-
-def _chunked_scan_fwd(x, dt, a, b, c, d, chunk: int):
-    y, states = _walk(x, dt, a, b, c, d, chunk, keep=True)
-    return y, (x, dt, a, b, c, d, states)
+    ``x`` [chunks, B, H, P, Q]; ``dt`` [chunks, B, H, Q] float32, positive;
+    ``a`` [H] float32, negative; ``b``, ``c`` [chunks, B, Q, N]; ``d`` [H]
+    float32."""
+    return _walk(x, dt, a, b, c, d, keep=False)[0]
 
 
-def _chunked_scan_bwd(chunk: int, kept, dy):
+def _pull(kept, dy):
+    """The cotangents of ``scan_chunks``' inputs from ``dy``, all cut."""
     x, dt, a, b, c, d, states = kept
     with part("ssm_scan"):
-        def body(carry, cut):
+        def body(carry, chunk):
             d_after, da, dd = carry
-            state, xc, dtc, bc, cc, dyc = cut
+            state, xc, dtc, bc, cc, dyc = chunk
             _, pull = jax.vjp(_chunk, state, xc, dtc, a, bc, cc, d)   # the chunk, computed again
             d_state, dx, ddt, da_c, db, dc, dd_c = pull((d_after, dyc))
             return (d_state, da + da_c, dd + dd_c), (dx, ddt, db, dc)
 
         zero = jnp.zeros_like
-        (_, da, dd), cuts = jax.lax.scan(
-            body, (zero(states[0]), zero(a), zero(d)),
-            (states, *(_cut(v, chunk) for v in (x, dt, b, c, dy))), reverse=True)
-        dx, ddt, db, dc = (_join(v, x.shape[1]) for v in cuts)
+        (_, da, dd), (dx, ddt, db, dc) = jax.lax.scan(
+            body, (zero(states[0]), zero(a), zero(d)), (states, x, dt, b, c, dy), reverse=True)
         return dx, ddt, da, db, dc, dd
 
 
-chunked_scan.defvjp(_chunked_scan_fwd, _chunked_scan_bwd)
+def _scan_fwd(x, dt, a, b, c, d):
+    y, states = _walk(x, dt, a, b, c, d, keep=True)
+    return y, (x, dt, a, b, c, d, states)
+
+
+scan_chunks.defvjp(_scan_fwd, _pull)
+
+
+def _cuts(x, dt, b, c, chunk: int):
+    return cut(x, chunk, True), cut(dt, chunk, True), cut(b, chunk), cut(c, chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def chunked_scan(x, dt, a, b, c, d, chunk: int):
+    """``scan_chunks`` for a caller that holds the tokens major and uncut:
+    ``x`` [B, T, H, P], ``dt`` [B, T, H], ``b``, ``c`` [B, T, N] -> ``y`` [B,
+    T, H, P]; what it keeps for the backward pass is uncut too."""
+    return _chunked_fwd(x, dt, a, b, c, d, chunk)[0]
+
+
+def _chunked_fwd(x, dt, a, b, c, d, chunk: int):
+    with part("ssm_scan"):
+        xc, dtc, bc, cc = _cuts(x, dt, b, c, chunk)
+        y, states = _walk(xc, dtc, a, bc, cc, d, keep=True)
+        return join(y, x.shape[1], True), (x, dt, a, b, c, d, states)
+
+
+def _chunked_bwd(chunk: int, kept, dy):
+    x, dt, a, b, c, d, states = kept
+    with part("ssm_scan"):
+        xc, dtc, bc, cc = _cuts(x, dt, b, c, chunk)
+        dx, ddt, da, db, dc, dd = _pull((xc, dtc, a, bc, cc, d, states), cut(dy, chunk, True))
+        tokens = x.shape[1]
+        return (join(dx, tokens, True), join(ddt, tokens, True), da,
+                join(db, tokens), join(dc, tokens), dd)
+
+
+chunked_scan.defvjp(_chunked_fwd, _chunked_bwd)

@@ -580,6 +580,8 @@ def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cac
     mem = compiled.memory_analysis()
     hbm = 16_909_336_064                      # a v5e's, PERF.md Open question 11
     assert hbm - mem.argument_size_in_bytes - mem.temp_size_in_bytes > 0.5e9, mem
+    # no more than with the mixers' activations copied around the scan (PR 34; 8,471,350,784 since)
+    assert mem.temp_size_in_bytes <= 8_553_135_616, mem
     ring_bytes = frames * 56448 * 4
     assert_ring_stays_put(text, ring_bytes, 0)
     assert "mini-gather" not in text
@@ -591,3 +593,118 @@ def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cac
     kernels = re.findall(r"%(splash_mha_\w+?)[.\d]* = ", text)
     assert kernels.count("splash_mha_dq_no_residuals") == 1, kernels
     assert kernels.count("splash_mha_dkv_no_residuals") == 1, kernels
+
+
+# ------------------------------- what a Mamba-2 mixer passes around its scan
+
+RELAYOUTS = ("copy", "pad", "slice")
+
+
+def outside_the_walk(hlo_text: str):
+    """(name, opcode, shape text) of the instructions that make a buffer of
+    their own outside every ``while`` body: not inside a fused computation,
+    not inside a loop's body or condition."""
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo_text))
+    loops = set(re.findall(r"(?:body|condition)=%?([\w.\-]+)", hlo_text))
+    inside = None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and inside not in fused and inside not in loops and m.group("op") not in _PASS_THROUGH:
+            yield m.group("name"), m.group("op"), m.group("shape")
+
+
+def relayouts(hlo_text: str, least_bytes: int) -> list:
+    """The ``copy``, ``pad`` and ``slice`` instructions outside the walk whose
+    result is at least ``least_bytes``: an activation written again as it was."""
+    return [name for name, op, shape in outside_the_walk(hlo_text)
+            if op in RELAYOUTS and any(b >= least_bytes for b, _ in _arrays(shape))]
+
+
+def wide_float32(hlo_text: str, least_elements: int) -> list:
+    """The instructions outside the walk with a float32 result of at least
+    ``least_elements`` elements."""
+    return [name for name, _, shape in outside_the_walk(hlo_text)
+            if any(dtype == "f32" and int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+                   >= least_elements for dtype, dims, _ in _ARRAY.findall(shape))]
+
+
+@pytest.mark.parametrize("differentiated", [False, True])
+def test_the_mixer_passes_its_activations_around_the_scan_once(
+        topo, no_compile_cache, monkeypatch, differentiated):
+    """One ``granite_hybrid.Mamba2`` layer at ``granite4h_q_l10``'s shapes (``u``
+    ``bf16[8, 1568, 2048]``, float32 parameters), forward and under
+    ``jax.grad``: outside the chunk walk no ``copy``, ``pad`` or ``slice``
+    writes an activation of 100 MB again (left to the compiler ``x`` was
+    written seven times on its way into the scan: 7 such instructions a
+    forward, 13 a differentiated pass), and nothing is a float32 array of
+    ``[8, 1568, 4096]`` (``y`` was widened in HBM and copied once more
+    before the gate and norm read it)."""
+    from ape_x_dqn_tpu.models import granite_hybrid
+    from ape_x_dqn_tpu.ops.pallas import blocked_attention
+
+    monkeypatch.setattr(blocked_attention, "INTERPRET", False)   # this process sees the CPU
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / "granite4h_q_l10.json").read_text())
+    layer = granite_hybrid.Mamba2(spec=granite_hybrid.spec_from_config(cfg), op="mamba",
+                                  compute_dtype=jnp.bfloat16, param_dtype=jnp.float32)
+    dev = SingleDeviceSharding(topo.devices[0])
+    rows, tokens, hidden = cfg["batch_size"], 32 * 49, cfg["hidden_size"]
+    params = _with(jax.eval_shape(
+        lambda k: layer.init(k, jnp.zeros((1, 8, hidden), jnp.bfloat16)), jax.random.PRNGKey(0)), dev)
+    u = jax.ShapeDtypeStruct((rows, tokens, hidden), jnp.bfloat16, sharding=dev)
+    fn = (jax.grad(lambda p, v: jnp.sum(jnp.square(layer.apply(p, v).astype(jnp.float32))), (0, 1))
+          if differentiated else layer.apply)
+    text = _compile_text(jax.jit(fn), (params, u))
+    assert " while(" in text                                   # the walk is a loop
+    again = relayouts(text, 100_000_000)
+    assert not again, again
+    wide = wide_float32(text, rows * tokens * 4096)
+    assert not wide, wide
+
+
+def test_reader_finds_the_copies_around_the_scan():
+    """The readers on the entry computation the parent of PR 35 compiled one
+    layer's forward to (operands shortened): ``x`` sliced, split, turned,
+    padded and copied twice into the walk, ``y`` widened and copied out."""
+    text = """HloModule jit_fwd
+
+%fused_computation.5 (p: bf16[8,1792,64,64]) -> f32[8,1568,64,64] {
+  %p = bf16[8,1792,64,64]{1,3,2,0:T(8,128)(2,1)} parameter(0)
+  %s = bf16[8,1568,64,64]{1,3,2,0:T(8,128)(2,1)} slice(%p), slice={[0:8], [0:1568], [0:64], [0:64]}
+  ROOT %c = f32[8,1568,64,64]{1,3,2,0:T(8,128)} convert(%s)
+}
+
+%body.1 (t: (s32[], bf16[7,8,256,64,64])) -> (s32[], bf16[7,8,256,64,64]) {
+  %t = (s32[], bf16[7,8,256,64,64]{2,4,3,1,0:T(8,128)(2,1)}) parameter(0)
+  %copy.99 = bf16[7,8,256,64,64]{2,4,3,1,0:T(8,128)(2,1)} copy(%gte.1)
+  ROOT %r = (s32[], bf16[7,8,256,64,64]{2,4,3,1,0:T(8,128)(2,1)}) tuple(%gte.0, %copy.99)
+}
+
+ENTRY %main (u: bf16[8,1568,2048]) -> bf16[8,1568,2048] {
+  %fusion.60 = bf16[8,1568,8512]{2,1,0:T(8,128)(2,1)} fusion(%u, %w), kind=kOutput, calls=%fused_computation.60
+  %slice.21 = bf16[8,1568,4352]{2,1,0:T(8,128)(2,1)} slice(%fusion.60), slice={[0:8], [0:1568], [4096:8448]}
+  %divide_multiply_fusion = bf16[8,1568,4352]{2,1,0:T(8,128)(2,1)S(1)} fusion(%slice.21), kind=kLoop, calls=%fused_computation.61
+  %split.0 = bf16[8,1568,4096]{2,1,0:T(8,128)(2,1)} slice(%divide_multiply_fusion), slice={[0:8], [0:1568], [0:4096]}
+  %copy.22 = bf16[8,1568,4096]{1,2,0:T(8,128)(2,1)S(1)} copy(%split.0)
+  %bitcast.9 = bf16[8,1568,64,64]{1,3,2,0:T(8,128)(2,1)} bitcast(%copy.22)
+  %pad.2 = bf16[8,1792,64,64]{1,3,2,0:T(8,128)(2,1)} pad(%bitcast.9, %constant.1), padding=0_0x0_224x0_0x0_0
+  %reshape.8 = bf16[8,7,256,64,64]{2,1,4,3,0:T(8,128)(2,1)} reshape(%pad.2)
+  %copy.33 = bf16[7,8,64,8,8,256]{5,4,0,3,2,1:T(8,128)(2,1)S(1)} copy(%bitcast.11)
+  %copy.26 = bf16[7,8,256,64,64]{2,4,3,1,0:T(8,128)(2,1)} copy(%bitcast.12)
+  %while.1 = (s32[], bf16[7,8,256,64,64]{2,4,3,1,0:T(8,128)(2,1)}) while(%tuple.1), condition=%cond.1, body=%body.1
+  %slice_convert_fusion = f32[8,1568,64,64]{1,3,2,0:T(8,128)} fusion(%gte.5), kind=kLoop, calls=%fused_computation.5
+  %copy.28 = f32[8,1568,4096]{2,1,0:T(8,128)} copy(%bitcast.10)
+  %copy.24 = bf16[2048,8512]{1,0:T(8,128)(2,1)S(1)} copy(%w)
+  %multiply_reduce_fusion = f32[8,1568]{1,0:T(8,128)S(1)} fusion(%copy.28, %z), kind=kInput, calls=%fused_computation.62
+  ROOT %fusion.55 = bf16[8,1568,2048]{2,1,0:T(8,128)(2,1)} fusion(%copy.28, %w2), kind=kOutput, calls=%fused_computation.63
+}
+"""
+    assert relayouts(text, 100_000_000) == [
+        "slice.21", "split.0", "copy.22", "pad.2", "copy.33", "copy.26", "copy.28"]
+    assert wide_float32(text, 8 * 1568 * 4096) == ["slice_convert_fusion", "copy.28"]
+    # the weights' copy (35 MB), a chunk's copy in the walk and a reshape are none of them
+    assert "copy.24" not in relayouts(text, 100_000_000) and "copy.99" not in relayouts(text, 1)

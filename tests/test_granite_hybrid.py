@@ -20,7 +20,8 @@ for _p in (os.path.join(ROOT, "benchmark"), ROOT):
 from ape_x_dqn_tpu.config import HISTORY_NETWORKS, TORSO_NETWORKS, ApexConfig, load_config, network_kwargs
 from ape_x_dqn_tpu.models import dueling, expert_torso, granite_hybrid, laguna_moe, lfm2_moe
 from ape_x_dqn_tpu.models.dueling import build_network
-from ape_x_dqn_tpu.ops.chunked_scan import chunked_scan, chunks_of
+from ape_x_dqn_tpu.ops.chunked_scan import chunked_scan, chunks_of, cut, join, scan_chunks
+from ape_x_dqn_tpu.ops.pallas.scan_layout import conv_to_chunks, gated_norm
 from ape_x_dqn_tpu.utils import profiling
 
 TORSO = dict(
@@ -101,6 +102,123 @@ def test_the_backward_pass_keeps_the_chunks_incoming_states_alone():
     assert (3, 2, 3, 4, 5) in shapes                      # three chunks' incoming states
     assert not any(s[-2:] == (16, 16) for s in shapes if len(s) >= 2), shapes
     assert sum(int(np.prod(s)) for s in shapes) == sum(int(np.prod(a.shape)) for a in args) + 3 * 2 * 3 * 4 * 5
+
+
+# tokens and chunk: a multiple, not one (the last chunk padded), the cell's 1,568 in 256, a
+# sequence shorter than a chunk
+CUTS = [(48, 16), (40, 16), (1568, 256), (10, 16)]
+
+
+def _close(got, want, name, tol=2e-5):
+    assert got.shape == want.shape and got.dtype == want.dtype, name
+    scale = float(jnp.max(jnp.abs(want))) + 1e-6
+    assert float(jnp.max(jnp.abs(got - want))) <= tol * scale, (name, scale)
+
+
+@pytest.mark.parametrize("turned", [True, False])
+@pytest.mark.parametrize("tokens,chunk", CUTS)
+def test_the_convolution_writes_the_scans_layout(tokens, chunk, turned):
+    """``conv_to_chunks`` (Pallas' interpreter here) against the literal formula,
+    ``silu(bias + sum_j kernel[:, j] v[t - 3 + j])`` cut in chunks: the values,
+    zeros past T, and the gradients of the input, the kernel and the bias from
+    a cotangent that is anything at the padded tokens."""
+    ks = jax.random.split(jax.random.PRNGKey(tokens + turned), 4)
+    v, kernel, bias = (jax.random.normal(k, shape) for k, shape in zip(
+        ks, ((2, tokens, 128), (128, 4), (128,))))
+
+    def literal(v, kernel, bias):
+        padded = jnp.pad(v, ((0, 0), (3, 0), (0, 0)))
+        act = jax.nn.silu(bias + sum(padded[:, j:j + tokens] * kernel[:, j] for j in range(4)))
+        return cut(act, chunk, turned)
+
+    want, pull = jax.vjp(literal, v, kernel, bias)
+    got, pull_kernel = jax.vjp(lambda *a: conv_to_chunks(*a, chunk, turned), v, kernel, bias)
+    n, padded = chunks_of(tokens, chunk)
+    assert got.shape == ((n, 2, 128, padded // n) if turned else (n, 2, padded // n, 128))
+    _close(got, want, "values")
+    own = cut(jnp.ones((2, tokens, 1)), chunk, turned) > 0
+    assert not np.asarray(jnp.where(own, 0.0, got)).any()
+    cot = jax.random.normal(ks[3], want.shape)
+    for name, g, w in zip(("input", "kernel", "bias"), pull_kernel(cot), pull(cot)):
+        _close(g, w, name, 1e-4)
+
+
+@pytest.mark.parametrize("tokens,chunk", CUTS)
+def test_the_gate_and_norm_read_the_scans_layout(tokens, chunk):
+    """``gated_norm`` against ``rmsnorm(y silu(z)) w`` in float32 with ``y`` cut
+    and turned as the scan writes it: values and the gradients of ``y`` (zeros
+    at the padded tokens), ``z`` and the weight."""
+    ks = jax.random.split(jax.random.PRNGKey(tokens), 4)
+    y, z = (jax.random.normal(k, (2, tokens, 128)) for k in ks[:2])
+    w, eps = 1.0 + 0.1 * jax.random.normal(ks[2], (128,)), 1e-5
+
+    def literal(y, z, w):
+        g = y * jax.nn.silu(z)
+        return g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + eps) * w
+
+    want, pull = jax.vjp(literal, y, z, w)
+    cut_y = cut(y, chunk, True)
+    got, pull_kernel = jax.vjp(lambda *a: gated_norm(*a, eps), cut_y, z, w)
+    _close(got, want, "values")
+    cot = jax.random.normal(ks[3], want.shape)
+    (dy, dz, dw), (dy_w, dz_w, dw_w) = pull_kernel(cot), pull(cot)
+    _close(dy, cut(dy_w, chunk, True), "y", 1e-4)                # zeros where the cut pads
+    _close(dz, dz_w, "z", 1e-4)
+    _close(dw, dw_w, "weight", 1e-4)
+    # in the compute type the answer is the float32 one rounded once
+    low = gated_norm(cut_y.astype(jnp.bfloat16), z.astype(jnp.bfloat16), w, eps)
+    assert low.dtype == jnp.bfloat16
+    _close(low.astype(jnp.float32), literal(y.astype(jnp.bfloat16).astype(jnp.float32),
+                                            z.astype(jnp.bfloat16).astype(jnp.float32), w), "bf16", 1e-2)
+
+
+@pytest.mark.parametrize("tokens,chunk", [(40, 16), (10, 16), (1568, 256)])
+def test_the_mixer_is_the_literal_layer(tokens, chunk):
+    """One ``Mamba2`` layer in float32 against the layer written out a token at
+    a time (projection, convolution, SiLU, the recurrence, gate, norm,
+    projection): the output and the gradient of every parameter and of the
+    input, at tokens that do not divide the chunk."""
+    spec = granite_hybrid.spec_from_config(dict(TORSO, mamba_chunk_size=chunk))
+    layer = granite_hybrid.Mamba2(spec=spec, op="mamba", compute_dtype=jnp.float32,
+                                  param_dtype=jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(tokens), (2, tokens, TORSO["hidden_size"]))
+    params = layer.init(jax.random.PRNGKey(1), u)
+    params = jax.tree_util.tree_map(                             # off the initial ones and zeros
+        lambda x: x + 0.1 * jax.random.normal(jax.random.PRNGKey(x.size), x.shape), params)
+    heads, head, state = TORSO["mamba_n_heads"], TORSO["mamba_d_head"], TORSO["mamba_d_state"]
+    inner = heads * head
+
+    def literal_layer(params, u):
+        p = params["params"]
+        z, xbc, dt = jnp.split(u @ p["w_in"], (inner, 2 * inner + 2 * state), axis=-1)
+        padded = jnp.pad(xbc, ((0, 0), (3, 0), (0, 0)))
+        xbc = jax.nn.silu(p["conv_bias"] + sum(
+            padded[:, j:j + tokens] * p["conv_kernel"][:, j] for j in range(4)))
+        x, b, c = jnp.split(xbc, (inner, inner + state), axis=-1)
+        y = literal(x.reshape(2, tokens, heads, head), jax.nn.softplus(dt + p["dt_bias"]),
+                    -jnp.exp(p["A_log"]), b, c, p["D"]).reshape(x.shape)
+        g = y * jax.nn.silu(z)
+        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + TORSO["rms_norm_eps"])
+        return (g * p["norm"]) @ p["w_out"]
+
+    with jax.default_matmul_precision("highest"):
+        want, pull = jax.vjp(literal_layer, params, u)
+        got, pull_layer = jax.vjp(layer.apply, params, u)
+        _close(got, want, "output", 1e-4)
+        cot = jax.random.normal(jax.random.PRNGKey(2), want.shape)
+        (dp, du), (dp_w, du_w) = pull_layer(cot), pull(cot)
+    _close(du, du_w, "input", 1e-3)
+    for name in dp_w["params"]:
+        _close(dp["params"][name], dp_w["params"][name], name, 1e-3)
+
+
+def test_a_sequence_already_cut_scans_as_the_uncut_one():
+    """``scan_chunks`` on ``cut`` inputs and ``join`` are ``chunked_scan``."""
+    (x, dt, a, b, c, d), _ = scan_inputs(40)
+    y = scan_chunks(cut(x, 16, True), cut(dt, 16, True), a, cut(b, 16), cut(c, 16), d)
+    assert y.shape == (3, 2, 3, 4, 16)
+    np.testing.assert_allclose(np.asarray(join(y, 40, True)), np.asarray(chunked_scan(x, dt, a, b, c, d, 16)),
+                               atol=1e-6)
 
 
 def test_the_network_has_the_issues_structure():
