@@ -16,11 +16,20 @@ device-runtime import; the re-exports below resolve on first attribute
 access instead (``from ape_x_dqn_tpu import TrainState`` still works).
 The ``import-light`` checker in ``ape_x_dqn_tpu/analysis`` walks exactly
 this chain.
+
+The one thing done eagerly is the launch log's (``utils/profiling.py``,
+standard library only at module scope): the process's first stamp is taken
+here, and a finder that times the heavy imports that follow goes to the
+front of ``sys.meta_path``.
 """
 
 from __future__ import annotations
 
 import importlib
+
+from ape_x_dqn_tpu.utils import profiling as _profiling
+
+_profiling.install()
 
 __version__ = "0.1.0"
 

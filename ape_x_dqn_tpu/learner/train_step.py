@@ -32,7 +32,7 @@ from flax import struct
 
 from ape_x_dqn_tpu.ops import losses
 from ape_x_dqn_tpu.types import ROUTING, PrioritizedBatch, TrainState
-from ape_x_dqn_tpu.utils.profiling import stage
+from ape_x_dqn_tpu.utils.profiling import launch_span, stage
 
 @struct.dataclass
 class StepMetrics:
@@ -175,6 +175,7 @@ def make_optimizer(
     return opt
 
 
+@launch_span("train_state")
 def init_train_state(
     network: nn.Module,
     optimizer: optax.GradientTransformation,

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ape_x_dqn_tpu.utils.profiling import part
+from ape_x_dqn_tpu.utils.profiling import launch_span, part
 
 
 class DuelingOutput(NamedTuple):
@@ -184,6 +184,7 @@ TORSO_KINDS = {"lfm2_moe": "Lfm2MoeQ", "laguna_moe": "LagunaMoeQ",
                "granite_hybrid": "GraniteHybridQ"}
 
 
+@launch_span("network")
 def build_network(kind: str, num_actions: int, **kwargs) -> nn.Module:
     """Factory keyed by config string: {"conv", "nature", "mlp", "lfm2_moe",
     "laguna_moe", "granite_hybrid"}.  The last three are torsos of blocks

@@ -28,11 +28,15 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ape_x_dqn_tpu.utils import profiling
+
 
 def device_info() -> dict:
     """The backend this process runs on, as jax reports it: what every
-    entry point stamps on its first record."""
-    devs = jax.devices()
+    entry point stamps on its first record.  Where this is the process's
+    first ``jax.devices()`` the span is the chip's start-up."""
+    with profiling.launch.span("backend"):
+        devs = jax.devices()
     return {
         "platform": devs[0].platform,
         "device_kind": devs[0].device_kind,

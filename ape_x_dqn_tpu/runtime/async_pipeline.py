@@ -44,7 +44,7 @@ from ape_x_dqn_tpu.runtime.infeed import PrefetchQueue
 from ape_x_dqn_tpu.runtime.param_store import ParamStore
 from ape_x_dqn_tpu.utils.memory import trim_malloc
 from ape_x_dqn_tpu.utils.metrics import MetricLogger, RateCounter
-from ape_x_dqn_tpu.utils.profiling import StageTimer
+from ape_x_dqn_tpu.utils import profiling
 
 
 class _AsyncPublisher:
@@ -309,7 +309,7 @@ class AsyncPipeline:
         self._scan: dict = {}         # StepMetrics.scan, likewise
         # Per-stage wall-clock accumulators (SURVEY §5 tracing subsystem):
         # µs/step per pipeline stage, exported in every metrics emit.
-        self.timers = StageTimer()
+        self.timers = profiling.StageTimer()
         self._prefetch_depth = prefetch_depth
         self.fused = None
         self.mesh = None
@@ -595,6 +595,15 @@ class AsyncPipeline:
         )
         self.obs_registry.register_provider(
             "stage_us", self.timers.us_per_call
+        )
+        # The launch's partition and the compiles since it ended
+        # (utils/profiling.LaunchLog): whole on /varz, the recompiles on
+        # every periodic record.
+        self.obs_registry.register_provider(
+            "launch", lambda: profiling.launch.varz()
+        )
+        self.register_jsonl_section(
+            "launch", lambda: profiling.launch.since_launch()
         )
         if self._lineage is not None:
             self.obs_registry.register_provider(
@@ -1154,6 +1163,13 @@ class AsyncPipeline:
             # are now TRAINED.
             self._lineage.on_trained(idx)
 
+    def _launch_done(self, step: int) -> None:
+        """The first result the host waited for ends the launch: one
+        ``launch`` event with the partition (``LaunchLog.summary``); a
+        compile from here on is a recompile."""
+        if profiling.launch.done(step):
+            self.logger.event("launch", **profiling.launch.summary(top=12))
+
     def _force_fused(self, metrics) -> None:
         """Force one fused call's completion (a host read of its last
         loss) and credit its steps to the completion-time rate."""
@@ -1242,7 +1258,9 @@ class AsyncPipeline:
                     self._steps_rate.add(1)
                     if pending is not None:
                         self._write_back_priorities(*pending)
+                        self._launch_done(self._learner_step - 1)
                     pending = (host_indices, metrics.priorities)
+                    profiling.launch.step = self._learner_step
                     if self._learner_step % cfg.learner.publish_every == 0:
                         with self.timers.stage("publish"):
                             self._publish(state.params)
@@ -1300,11 +1318,13 @@ class AsyncPipeline:
             # tail of < ingest_block staged rows can strand warmup below the
             # threshold even though enough transitions were collected
             # (round-2 advisor finding).
-            self._wait_for_warmup(
-                warmup_timeout,
-                size_fn=lambda: fused.size,
-                tick=lambda: fused.ingest_staged(drain=self.worker.finished),
-            )
+            with profiling.launch.span("ring_fill"):
+                self._wait_for_warmup(
+                    warmup_timeout,
+                    size_fn=lambda: fused.size,
+                    tick=lambda: fused.ingest_staged(
+                        drain=self.worker.finished),
+                )
             next_log = self._learner_step + self.log_every
             next_ckpt = (
                 self._learner_step + cfg.learner.checkpoint_every
@@ -1329,7 +1349,9 @@ class AsyncPipeline:
                     # dispatch would report steps that haven't executed yet.
                     with self.timers.stage("force_oldest"):
                         self._force_fused(inflight.pop(0))
+                    self._launch_done(self._learner_step)
                 self._learner_step += fused.steps_per_call
+                profiling.launch.step = self._learner_step
                 self.comps.state = fused.state
                 # Publish at most once per fused call — the cap
                 # (publish_every) is finer than K, so every call qualifies;

@@ -21,6 +21,7 @@ from ape_x_dqn_tpu.learner.train_step import init_train_state, make_optimizer
 from ape_x_dqn_tpu.models.dueling import build_network
 from ape_x_dqn_tpu.replay import PrioritizedReplay
 from ape_x_dqn_tpu.types import TrainState
+from ape_x_dqn_tpu.utils.profiling import launch_span
 
 
 @dataclasses.dataclass
@@ -115,6 +116,7 @@ class Components:
 
         return sample
 
+    @launch_span("fused_learner")
     def make_fused_learner(self):
         """The device-resident fused learner (HBM replay + K-step scan) —
         the ``learner.device_replay=True`` throughput mode.  With
@@ -207,6 +209,7 @@ def resolve_spill_dir(cfg: ApexConfig) -> str:
     )
 
 
+@launch_span("components")
 def build_components(cfg: ApexConfig) -> Components:
     cfg.validate()
     env_kwargs = dict(

@@ -26,6 +26,7 @@ import numpy as np
 
 from ape_x_dqn_tpu.learner.train_step import build_train_step
 from ape_x_dqn_tpu.types import DedupChunk, TrainState
+from ape_x_dqn_tpu.utils import profiling
 
 _TXN_FIELDS = ("obs_seq", "next_seq", "action", "reward", "discount", "prio")
 
@@ -297,9 +298,10 @@ class FusedDedupLearner:
         if mesh is None:
             self._n_shards = 1
             self._state = state
-            self._replay = init_dedup_device_replay(
-                capacity, obs_shape, frame_ratio=frame_ratio
-            )
+            with profiling.launch.span("ring_make"):
+                self._replay = init_dedup_device_replay(
+                    capacity, obs_shape, frame_ratio=frame_ratio
+                )
             self._seq_mod = self._replay.seq_modulus
             step_fn = build_train_step(network, optimizer, **step_kwargs)
             self._fused = build_dedup_fused_learn_step(
@@ -346,9 +348,10 @@ class FusedDedupLearner:
             self._state = jax.device_put(
                 jax.device_get(state), NamedSharding(mesh, P())
             )
-            self._replay = init_sharded_dedup_replay(
-                capacity, obs_shape, mesh, frame_ratio=frame_ratio
-            )
+            with profiling.launch.span("ring_make"):
+                self._replay = init_sharded_dedup_replay(
+                    capacity, obs_shape, mesh, frame_ratio=frame_ratio
+                )
             self._seq_mod = shard_seq_modulus(
                 self._replay.frame_capacity, n
             )

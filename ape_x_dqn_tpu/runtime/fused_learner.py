@@ -35,6 +35,7 @@ from ape_x_dqn_tpu.replay.device import (
     init_device_replay,
 )
 from ape_x_dqn_tpu.types import NStepTransition, TrainState
+from ape_x_dqn_tpu.utils import profiling
 
 
 class FusedDeviceLearner:
@@ -66,7 +67,8 @@ class FusedDeviceLearner:
         self._mesh = mesh
         if mesh is None:
             self._state = state
-            self._replay = init_device_replay(capacity, obs_shape)
+            with profiling.launch.span("ring_make"):
+                self._replay = init_device_replay(capacity, obs_shape)
             step_fn = build_train_step(
                 network,
                 optimizer,
@@ -115,9 +117,10 @@ class FusedDeviceLearner:
             self._state = jax.device_put(
                 jax.device_get(state), NamedSharding(mesh, P())
             )
-            self._replay = init_sharded_device_replay(
-                capacity, obs_shape, mesh
-            )
+            with profiling.launch.span("ring_make"):
+                self._replay = init_sharded_device_replay(
+                    capacity, obs_shape, mesh
+                )
             step_fn = build_train_step(
                 network,
                 optimizer,

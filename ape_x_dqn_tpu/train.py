@@ -19,6 +19,7 @@ import argparse
 import sys
 
 from ape_x_dqn_tpu.config import load_config, to_dict
+from ape_x_dqn_tpu.utils import profiling
 from ape_x_dqn_tpu.utils.metrics import MetricLogger
 
 
@@ -86,6 +87,7 @@ def main(argv=None, inspect=None) -> int:
     called after an async run completes — chip_smoke.py uses it to check
     where the train state and the replay ring live."""
     args = build_argparser().parse_args(argv)
+    profiling.launch.begin()
     if args.coordinator:
         # Must run before anything touches the jax backend: after this,
         # jax.devices() is the GLOBAL device set across all participating
@@ -116,14 +118,13 @@ def main(argv=None, inspect=None) -> int:
     logger.event("run_device", **device)
     import contextlib
 
-    from ape_x_dqn_tpu.utils.profiling import trace
-
     if args.profile_port is not None:
         import jax
 
         jax.profiler.start_server(args.profile_port)  # raises if it cannot
     profile_ctx = (
-        trace(args.profile_dir) if args.profile_dir else contextlib.nullcontext()
+        profiling.trace(args.profile_dir) if args.profile_dir
+        else contextlib.nullcontext()
     )
     with profile_ctx:
         return _run(args, cfg, logger, inspect)
@@ -133,10 +134,11 @@ def _run(args, cfg, logger, inspect=None) -> int:
     if args.mode == "async":
         from ape_x_dqn_tpu.runtime import AsyncPipeline
 
-        pipe = AsyncPipeline(
-            cfg, logger=logger, log_every=args.log_every,
-            eval_every=args.eval_every, eval_episodes=args.eval_episodes,
-        )
+        with profiling.launch.span("pipeline"):
+            pipe = AsyncPipeline(
+                cfg, logger=logger, log_every=args.log_every,
+                eval_every=args.eval_every, eval_episodes=args.eval_episodes,
+            )
         final = pipe.run(learner_steps=args.steps)
         print("final:", final, file=sys.stderr)
         if inspect is not None:

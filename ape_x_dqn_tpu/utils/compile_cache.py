@@ -41,9 +41,15 @@ def enable_compile_cache() -> str:
     """Apply the rule to this process; returns the directory in use.
 
     Call before the first compile: jax decides once per process whether the
-    cache is on."""
+    cache is on.  The launch log starts listening to jax's compile events
+    here (``profiling.listen``) and takes a mark: in a process whose entry
+    point touched the backend itself, chip start-up ends at it."""
     import jax
 
+    from ape_x_dqn_tpu.utils import profiling
+
+    profiling.listen()
+    profiling.launch.mark("compile_cache")
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", cache_dir())
     jax.config.update(

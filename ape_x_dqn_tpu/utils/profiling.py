@@ -21,6 +21,16 @@ One vocabulary, three uses:
     so a profiler trace of a live trainer shows the host stages beside the
     device ops, on one clock.
 
+  * ``launch`` — the process's launch log (``LaunchLog``): host spans from
+    the package's import to the first learner step whose result the host
+    waited for.  The imports (a finder at the front of ``sys.meta_path``),
+    the chip's start-up, the builders, every compile by program and phase
+    (listeners on ``jax.monitoring``, registered by
+    ``enable_compile_cache``) and the loop's own ``apex:<stage>`` spans, on
+    ``time.perf_counter``.  ``launch.summary()`` partitions the launch
+    thread's time into nine parts that add up; a compile after
+    ``launch.done(step)`` is a recompile.  Nothing here runs per call.
+
 ``trace(logdir)`` wraps ``jax.profiler`` device tracing (a profiler that
 cannot start raises: a run asked to trace either produces a trace or
 fails); ``summarize_trace(logdir)`` reduces such a trace with
@@ -33,13 +43,16 @@ learner.py:3 solely for ``sleep`` — reference SURVEY §5).
 
 from __future__ import annotations
 
+import atexit
 import bisect
 import collections
 import contextlib
 import functools
 import glob
+import json
 import os
 import re
+import sys
 import threading
 import time
 import types
@@ -91,22 +104,18 @@ class StageTimer:
         self._count: Dict[str, int] = defaultdict(int)
         self._lock = threading.Lock()
 
-    @contextlib.contextmanager
-    def stage(self, name: str) -> Iterator[None]:
+    def stage(self, name: str):
         """Time the section and put the ``apex:<name>`` span on the
         profiler's clock; with no profiler session a TraceAnnotation is a
-        flag test."""
-        import jax
+        flag test.  Until the launch is done the span is in the launch log
+        too (``LaunchLog._span``, the one implementation)."""
+        return launch._span(SPAN_PREFIX + name, SPAN_PREFIX + name, None,
+                            functools.partial(self._add, name))
 
-        t0 = time.perf_counter()
-        try:
-            with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
-                yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self._total_s[name] += dt
-                self._count[name] += 1
+    def _add(self, name: str, dt: float) -> None:
+        with self._lock:
+            self._total_s[name] += dt
+            self._count[name] += 1
 
     def us_per_call(self) -> Dict[str, float]:
         with self._lock:  # readers too: a concurrent first-use of a stage
@@ -116,25 +125,6 @@ class StageTimer:
             name: round(totals[name] / max(1, counts[name]) * 1e6, 1)
             for name in totals
         }
-
-    def snapshot(self) -> Dict[str, dict]:
-        with self._lock:
-            totals, counts = dict(self._total_s), dict(self._count)
-        return {
-            name: {
-                "total_s": round(totals[name], 4),
-                "calls": counts[name],
-                "us_per_call": round(
-                    totals[name] / max(1, counts[name]) * 1e6, 1
-                ),
-            }
-            for name in totals
-        }
-
-    def reset(self) -> None:
-        with self._lock:
-            self._total_s.clear()
-            self._count.clear()
 
 
 @contextlib.contextmanager
@@ -240,8 +230,9 @@ def jit_fused(fn, mesh=None, arg_specs=None, **jit_kwargs):
         prog.text = None
         return fn(*args)
 
-    prog = _FusedProgram(traced, jit_kwargs)
     name = "jit_" + getattr(fn, "__name__", "fused")
+    with launch.span("fused_program", program=name):
+        prog = _FusedProgram(traced, jit_kwargs)
     _fused_programs.setdefault(
         name, collections.deque(maxlen=_KEEP)).append(prog)
     return prog.jitted
@@ -330,7 +321,8 @@ def _merged(intervals) -> list:
 def _own_seconds(events) -> list:
     """(name, start, own seconds) per event: its length less the events
     nested in it (a ``while`` covers its body's ops).  ``events``: (name,
-    start, end)."""
+    start, end).  An event that outlasts the one it starts in ends with
+    it, so the own seconds under one event add up to its length."""
     out, stack = [], []  # stack: [name, start, end, seconds under children]
 
     def close(item):
@@ -341,7 +333,8 @@ def _own_seconds(events) -> list:
         while stack and stack[-1][2] <= s:
             close(stack.pop())
         if stack:
-            stack[-1][3] += min(e, stack[-1][2]) - s
+            e = min(e, stack[-1][2])
+            stack[-1][3] += e - s
         stack.append([name, s, e, 0.0])
     for item in stack:
         close(item)
@@ -438,3 +431,484 @@ def summarize_trace(logdir: str) -> dict:
         "longest_gaps": [[beside(t0, t1), round(length, 6)]
                          for length, t0, t1 in sorted(idle, reverse=True)[:5]],
     }
+
+
+# ------------------------------------------------------------ the launch log
+
+LAUNCH_PREFIX = SPAN_PREFIX + "launch:"  # TraceAnnotation("apex:launch:<name>")
+# What ``LaunchLog.summary`` divides the launch thread's time into; the nine
+# add up to the interval.
+LAUNCH_PARTS = ("import_s", "chip_start_s", "trace_s", "lower_s",
+                "cache_load_s", "compile_s", "build_s", "device_wait_s",
+                "unattributed_s")
+# The loads timed as ``import:<name>`` spans: the libraries a launch pays
+# seconds for, and the package's own modules that pull them in first (their
+# own seconds are what is left under them).
+TIMED_IMPORTS = frozenset((
+    "numpy", "jax", "jaxlib", "jax.experimental.pallas", "flax", "optax",
+    "orbax.checkpoint",
+    "ape_x_dqn_tpu.runtime", "ape_x_dqn_tpu.actors", "ape_x_dqn_tpu.serving",
+    "ape_x_dqn_tpu.parallel", "ape_x_dqn_tpu.learner.train_step",
+    "ape_x_dqn_tpu.models.dueling", "ape_x_dqn_tpu.models.expert_torso",
+    "ape_x_dqn_tpu.models.lfm2_moe", "ape_x_dqn_tpu.models.laguna_moe",
+    "ape_x_dqn_tpu.models.granite_hybrid", "ape_x_dqn_tpu.replay.device",
+    "ape_x_dqn_tpu.replay.device_dedup", "ape_x_dqn_tpu.utils.checkpoint",
+))
+LAUNCH_LOG_ENV = "APEX_LAUNCH_LOG"  # where the log is written at exit, if set
+_MAX_SPANS = 8192       # a launch records one to two thousand (a benchmark
+#                         process, which never ends its launch, up to six);
+#                         past this they are counted (``dropped``), not kept
+_KEEP_RECOMPILES = 32
+_FOLD_TRACE_S = 0.0005  # a trace span shorter than this is counted (``folded``)
+#                         and not kept: a jitted primitive found again in jax's
+#                         trace cache (`add` inside a network's init: 14 us each,
+#                         15,000 of them in a large torso's launch); its seconds
+#                         stay with the span around it
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile:trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile:lower",
+    "/jax/core/compile/backend_compile_duration": "compile:backend",
+}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")  # jit(fused) -> fused, as it is traced
+# where the trainer's loops wait for a result (fused; one step at a time)
+_WAIT_SPANS = (SPAN_PREFIX + "force_oldest", SPAN_PREFIX + "priority_writeback")
+_ROOT, _MARKS = -1, -2  # keys of the interval and of the span read from marks
+
+
+def _annotation(name: str):
+    """``TraceAnnotation(name)`` once jax's profiler is loaded; before that
+    (an import span opens ahead of jax) there is no trace to write into."""
+    cls = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                  "TraceAnnotation", None)
+    return contextlib.nullcontext() if cls is None else cls(name)
+
+
+def _program_row() -> dict:
+    """A row of ``LaunchLog.summary``'s ``programs`` before anything is
+    added to it."""
+    return {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0, "cache": set(),
+            "retrieval_s": 0.0, "compiles": 0}
+
+
+def _part(name: str, attrs: dict) -> str:
+    """The part of ``LAUNCH_PARTS`` a span's own seconds count under."""
+    if name.startswith("import:"):
+        return "import_s"
+    if name == "backend":
+        return "chip_start_s"
+    if name == "compile:trace":
+        return "trace_s"
+    if name == "compile:lower":
+        return "lower_s"
+    if name == "compile:backend":
+        return "cache_load_s" if attrs.get("cache") == "hit" else "compile_s"
+    if name in _WAIT_SPANS:
+        return "device_wait_s"
+    return "build_s"  # builders, ring set-up, the loop's stages before `done`
+
+
+class LaunchLog:
+    """Spans of one process's launch, in memory: rows ``[name, start, end,
+    parent, thread, attrs]`` on ``time.perf_counter``, ``parent`` the row of
+    the innermost span open on the same thread when the row was made (for a
+    compile span, made when it closes, the builder's span that holds it).
+    The process id names the launch.  ``created`` pairs the two clocks once,
+    to place the launch in wall time.
+
+    Recording stops at ``done(step)``; from then a compile is a recompile,
+    kept apart.  ``begin()`` starts a later launch in the same process."""
+
+    def __init__(self):
+        self.pid = os.getpid()
+        self.created = (time.perf_counter(), time.time())
+        self.thread = threading.get_ident()  # the launch thread: the one
+        #                                      whose time `summary` divides
+        self.step = 0  # the learner's step, as its loop last set it
+        self.dropped = 0
+        self.folded = 0
+        self.compiles_after_launch = 0
+        self.recompiles: collections.deque = collections.deque(
+            maxlen=_KEEP_RECOMPILES)
+        self._rows: list = []
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self._t0 = self.created[0]
+        self._done: Optional[tuple] = None  # (perf_counter, step)
+        self._written: Optional[float] = None  # a log read from a file ends here
+
+    # -- recording --
+    def _stack(self) -> list:
+        return self._local.__dict__.setdefault("stack", [])
+
+    def _append(self, name, start, end, attrs) -> Optional[int]:
+        stack = self._stack()
+        with self._lock:
+            if len(self._rows) >= _MAX_SPANS:
+                self.dropped += 1
+                return None
+            self._rows.append([name, start, end, stack[-1] if stack else None,
+                               threading.get_ident(), attrs or {}])
+            return len(self._rows) - 1
+
+    @contextlib.contextmanager
+    def _span(self, name, annotation, attrs=None, closed=None):
+        """Time it, annotate it: the one implementation under ``span`` and
+        ``StageTimer.stage``.  ``closed(seconds)`` is called at the end."""
+        row = None
+        t0 = time.perf_counter()
+        if self._done is None:
+            row = self._append(name, t0, None, attrs)
+            if row is not None:
+                self._stack().append(row)
+        try:
+            with _annotation(annotation):
+                yield
+        finally:
+            t1 = time.perf_counter()
+            if row is not None:
+                self._rows[row][2] = t1
+                self._stack().remove(row)
+            if closed is not None:
+                closed(t1 - t0)
+
+    def span(self, name: str, **attrs):
+        """Record ``name`` over the block and enter
+        ``TraceAnnotation("apex:launch:<name>")``.  Never waits for the
+        device."""
+        return self._span(name, LAUNCH_PREFIX + name, attrs)
+
+    def mark(self, name: str, **attrs) -> None:
+        """A moment: a span of no length."""
+        if self._done is None:
+            t = time.perf_counter()
+            self._append(name, t, t, attrs)
+
+    def _compile_span(self, name, wall_start, wall_end, fun_name) -> None:
+        """One of jax's compile spans, just closed on this thread, moved
+        from ``time.time`` to ``perf_counter`` by the clocks' distance now."""
+        if name == "compile:trace" and wall_end - wall_start < _FOLD_TRACE_S:
+            with self._lock:
+                self.folded += 1
+            return
+        program = _WRAPPED.sub(r"\1", str(fun_name))
+        off = time.perf_counter() - time.time()
+        start, end = wall_start + off, wall_end + off
+        attrs = {"program": program}
+        if name == "compile:backend":
+            note = self._local.__dict__.pop("cache", {})
+            attrs["cache"] = note.get("cache", "off")
+            if "retrieval_s" in note:
+                attrs["retrieval_s"] = note["retrieval_s"]
+        if self._done is None:
+            self._append(name, start, end, attrs)
+            return
+        # After the launch: what this compile made its caller wait, phase
+        # by phase, until the backend's span closes it.
+        waited = self._local.__dict__.setdefault("waited", {})
+        waited[program] = waited.get(program, 0.0) + end - start
+        if name == "compile:backend":
+            with self._lock:
+                self.compiles_after_launch += 1
+                self.recompiles.append({
+                    "program": program, "step": self.step,
+                    "seconds": round(waited[program], 6),
+                    "cache": attrs["cache"],
+                    "thread": threading.current_thread().name})
+            waited.clear()
+
+    def _cache_note(self, **note) -> None:
+        """What the persistent cache said inside the backend span that is
+        open on this thread; the span takes it when it closes."""
+        self._local.__dict__.setdefault("cache", {}).update(note)
+
+    # -- the launch's ends --
+    def begin(self) -> None:
+        """An entry point starts a launch.  The first of a process dates
+        from the log's making, its imports included; after a ``done`` a new
+        one starts here, with no compile after it yet."""
+        with self._lock:
+            if self._done is not None:
+                self._t0, self._done = time.perf_counter(), None
+                self.compiles_after_launch = 0
+                self.recompiles.clear()
+
+    def done(self, step: int = 0) -> bool:
+        """The first call whose result the host waited for is back: the
+        launch is over.  True the first time of a launch."""
+        with self._lock:
+            if self._done is not None:
+                return False
+            self.step = int(step)
+            self._done = (time.perf_counter(), int(step))
+            return True
+
+    # -- the reduction --
+    def summary(self, t0: Optional[float] = None, t1: Optional[float] = None,
+                top: Optional[int] = None) -> dict:
+        """Partition the launch thread's time in ``[t0, t1]`` (default: the
+        launch's start to ``done``, or to now) by the innermost span over
+        each instant, own seconds by ``_own_seconds``: the nine
+        ``LAUNCH_PARTS``, which add up to ``seconds``.  ``cache_load_s`` is
+        the backend spans that ended in a cache hit (key, read,
+        deserialize; jax's own count of the last two is ``retrieval_s``),
+        ``compile_s`` the others.  Beside them ``programs`` (every thread's
+        compiles in the interval, by program; the ``top`` slowest and one
+        row for the rest, if given), ``spans`` (the launch thread's own
+        seconds by span name) and the counts."""
+        with self._lock:
+            rows = [list(r) for r in self._rows]
+            done = self._done
+        t0 = self._t0 if t0 is None else t0
+        if t1 is None:
+            t1 = done[0] if done else self._written or time.perf_counter()
+        by_thread: Dict[int, list] = defaultdict(list)
+        for i, (_name, s, e, _parent, thread, _attrs) in enumerate(rows):
+            s, e = max(s, t0), min(t1 if e is None else e, t1)
+            if s <= e:
+                by_thread[thread].append((i, s, e))
+        notes = []
+        main = by_thread[self.thread]
+        root = [(_ROOT, t0, t1)]
+        if not any(rows[i][0] == "backend" for i, _s, _e in main):
+            # An entry point that is not the program's touched the backend
+            # itself: between jax's import and `enable_compile_cache`.
+            after = [e for i, _s, e in main if rows[i][0] == "import:jax"]
+            mark = [s for i, s, _e in main if rows[i][0] == "compile_cache"
+                    and after and s >= after[0]]
+            if mark:
+                root.append((_MARKS, after[0], mark[0]))
+                notes.append(
+                    "chip_start_s was read between two marks: the launch "
+                    "thread's uncovered time from the end of import:jax to "
+                    "enable_compile_cache()")
+        if not any(rows[i][0] in _WAIT_SPANS for i, _s, _e in main):
+            notes.append(
+                "no apex:force_oldest span in the interval: the waits for "
+                "the device are not the program's, they lie under "
+                "unattributed_s")
+        parts = dict.fromkeys(LAUNCH_PARTS, 0.0)
+        spans: Dict[str, float] = defaultdict(float)
+        programs: Dict[str, dict] = defaultdict(_program_row)
+        caches = collections.Counter()
+        for thread, events in by_thread.items():
+            on_main = thread == self.thread
+            for key, _start, own in _own_seconds(
+                    (root if on_main else []) + events):
+                if key == _ROOT:
+                    parts["unattributed_s"] += own
+                    continue
+                if key == _MARKS:
+                    parts["chip_start_s"] += own
+                    continue
+                name, _s, _e, _parent, _thread, attrs = rows[key]
+                if on_main:
+                    parts[_part(name, attrs)] += own
+                    spans[name] += own
+                if name in _COMPILE_SPANS.values():
+                    p = programs[attrs["program"]]
+                    p[name.split(":")[1] + "_s"] += own
+                    if name == "compile:backend":
+                        p["compiles"] += 1
+                        p["cache"].add(attrs["cache"])
+                        p["retrieval_s"] += attrs.get("retrieval_s", 0.0)
+                        caches[attrs["cache"]] += 1
+        ranked = sorted(programs, key=lambda k: -(
+            programs[k]["trace_s"] + programs[k]["lower_s"]
+            + programs[k]["backend_s"]))
+        if top is not None and len(ranked) > top:
+            rest = programs[f"({len(ranked) - top} other programs)"]
+            for k in ranked[top:]:
+                for f, v in programs[k].items():
+                    rest[f] = rest[f] | v if f == "cache" else rest[f] + v
+            ranked = ranked[:top] + [f"({len(ranked) - top} other programs)"]
+        return {
+            "pid": self.pid,
+            "seconds": t1 - t0,
+            "done": done is not None,
+            "step": done[1] if done else None,
+            **parts,
+            "cache_hits": caches["hit"],
+            "cache_misses": caches["miss"],
+            "compiles_after_launch": self.compiles_after_launch,
+            "programs": {k: {
+                f: ("+".join(sorted(v)) or None) if f == "cache"
+                else round(v, 6) if isinstance(v, float) else v
+                for f, v in programs[k].items()} for k in ranked},
+            "spans": {k: round(v, 6) for k, v in
+                      sorted(spans.items(), key=lambda kv: -kv[1])},
+            "dropped": self.dropped,
+            "folded": self.folded,
+            "notes": notes,
+        }
+
+    def since_launch(self) -> dict:
+        """The compiles after ``done``: how many, and the latest by program,
+        learner step and seconds."""
+        with self._lock:
+            return {"compiles_after_launch": self.compiles_after_launch,
+                    "recompiles": list(self.recompiles)}
+
+    def varz(self) -> dict:
+        """The obs provider ``launch``: the summary (twelve programs) and
+        the recompiles since."""
+        return {**self.summary(top=12), **self.since_launch()}
+
+    # -- a process whose end the program does not own --
+    def write(self, path: str) -> None:
+        """The whole log as one JSON object (``from_file`` reads it)."""
+        with self._lock:
+            doc = {"pid": self.pid, "created": list(self.created),
+                   "thread": self.thread, "t0": self._t0,
+                   "done": list(self._done) if self._done else None,
+                   "written": time.perf_counter(), "dropped": self.dropped,
+                   "folded": self.folded,
+                   "compiles_after_launch": self.compiles_after_launch,
+                   "recompiles": list(self.recompiles),
+                   "spans": [list(r) for r in self._rows]}
+        tmp = f"{path}.{os.getpid()}.tmp"
+        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+        with open(tmp, "w") as f:
+            json.dump(doc, f)
+        os.replace(tmp, path)
+
+    @classmethod
+    def from_file(cls, path: str) -> "LaunchLog":
+        with open(path) as f:
+            doc = json.load(f)
+        log = cls()
+        log.pid, log.created = doc["pid"], tuple(doc["created"])
+        log.thread, log._t0, log._written = (
+            doc["thread"], doc["t0"], doc["written"])
+        log._done = tuple(doc["done"]) if doc["done"] else None
+        log.dropped, log.folded = doc["dropped"], doc["folded"]
+        log.compiles_after_launch = doc["compiles_after_launch"]
+        log.recompiles.extend(doc["recompiles"])
+        # a span still open when the process ended lasts until the writing
+        log._rows = [[n, s, doc["written"] if e is None else e, p, t, a]
+                     for n, s, e, p, t, a in doc["spans"]]
+        return log
+
+
+launch = LaunchLog()
+
+
+def launch_span(name: str):
+    """Decorator: every call of the function under ``launch.span(name)``
+    (the builders'; looked up at the call, so a test may swap the log)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with launch.span(name):
+                return fn(*args, **kwargs)
+        return wrapped
+    return wrap
+
+
+# -- what feeds the log from outside its callers: imports and jax's compiles --
+
+class _TimedLoader:
+    """A module's own loader, its ``exec_module`` under an ``import:<name>``
+    span; the module keeps the real loader afterwards."""
+
+    def __init__(self, loader):
+        self._loader = loader
+
+    def __getattr__(self, name):
+        return getattr(self._loader, name)
+
+    def exec_module(self, module):
+        spec = getattr(module, "__spec__", None)
+        try:
+            with launch.span("import:" + module.__name__):
+                self._loader.exec_module(module)
+        finally:
+            if spec is not None and spec.loader is self:
+                spec.loader = self._loader
+            if getattr(module, "__loader__", None) is self:
+                module.__loader__ = self._loader
+
+
+class _ImportTimer:
+    """The finder at the front of ``sys.meta_path``: for a name of
+    ``TIMED_IMPORTS`` it hands back the spec the other finders find, with
+    the loader timed.  The import system asks a finder only on a miss in
+    ``sys.modules``, so a module is timed once and nothing is added to any
+    later import of it; what the others cannot find, or fail to run, raises
+    as it would without this."""
+
+    def __init__(self, names=TIMED_IMPORTS):
+        self.names = names
+
+    def find_spec(self, fullname, path=None, target=None):
+        if fullname not in self.names:
+            return None
+        for finder in sys.meta_path:
+            find = getattr(finder, "find_spec", None)
+            spec = None if finder is self or find is None else find(
+                fullname, path, target)
+            if spec is not None:
+                if hasattr(spec.loader, "exec_module"):
+                    spec.loader = _TimedLoader(spec.loader)
+                return spec
+        return None
+
+
+_installed = False
+
+
+def install() -> None:
+    """What ``ape_x_dqn_tpu/__init__.py`` runs once a process: the import
+    timer goes to the front of ``sys.meta_path``, and with
+    ``APEX_LAUNCH_LOG`` set the log is written there when the process ends
+    (``{pid}`` in the path is this process's id: children that inherit the
+    variable then write files of their own)."""
+    global _installed
+    if _installed:
+        return
+    _installed = True
+    sys.meta_path.insert(0, _ImportTimer())
+    atexit.register(_write_at_exit)
+
+
+def _write_at_exit() -> None:
+    path = os.environ.get(LAUNCH_LOG_ENV)
+    if path:
+        launch.write(path.replace("{pid}", str(os.getpid())))
+
+
+def _on_time_span(event, start, end, **kw) -> None:
+    name = _COMPILE_SPANS.get(event)
+    if name is not None:
+        launch._compile_span(name, start, end, kw.get("fun_name", "?"))
+
+
+def _on_event(event, **_kw) -> None:
+    cache = _CACHE_EVENTS.get(event)
+    if cache is not None:
+        launch._cache_note(cache=cache)
+
+
+def _on_duration(event, duration, **_kw) -> None:
+    if event == _CACHE_RETRIEVAL:
+        launch._cache_note(retrieval_s=round(float(duration), 6))
+
+
+_listening = False
+
+
+def listen() -> None:
+    """Register, once a process, the listeners that turn jax's compile
+    events into spans of ``launch`` (``enable_compile_cache`` calls this).
+    They fire once per compile, never per call."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    from jax import monitoring
+
+    monitoring.register_event_time_span_listener(_on_time_span)
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)

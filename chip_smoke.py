@@ -110,13 +110,40 @@ def check_run(leg: str, pipe, final: dict, steps: int) -> None:
         f"state_leaves_on_tpu={n_state} ring_leaves_on_tpu={n_ring}")
 
 
+def check_launch(leg: str, t0: float) -> None:
+    """The leg's launch as the program accounts for it (profiling.launch):
+    from ``t0``, the leg's start (what this script did before it, the native
+    build and the ring's footprint, is not the trainer's), to the first fused
+    call the loop waited for.  The nine parts add up to the interval, and
+    what no span covers stays under a tenth."""
+    from ape_x_dqn_tpu.utils import profiling
+
+    s = profiling.launch.summary(t0=t0, top=5)
+    assert s["done"], f"{leg}: the loop never ended its launch"
+    parts = {p: s[p] for p in profiling.LAUNCH_PARTS}
+    say(f"{leg}: launch {s['seconds']:.3f} s to step {s['step']}: "
+        + " ".join(f"{p}={v:.3f}" for p, v in parts.items())
+        + f"; cache hits {s['cache_hits']} misses {s['cache_misses']}; "
+        f"slowest programs {json.dumps(s['programs'])}")
+    for note in s["notes"]:
+        say(f"{leg}: launch: {note}")
+    assert abs(sum(parts.values()) - s["seconds"]) < 1e-6, (
+        f"{leg}: the launch's parts add up to {sum(parts.values())}, "
+        f"the interval is {s['seconds']}")
+    assert parts["unattributed_s"] <= 0.1 * s["seconds"], (
+        f"{leg}: {parts['unattributed_s']:.3f} s of a {s['seconds']:.3f} s "
+        "launch lie under no span")
+
+
 def run_leg(leg: str, argv: list, steps: int, inspect) -> None:
     from ape_x_dqn_tpu import train
 
     def _inspect(pipe, final):
         check_run(leg, pipe, final, steps)
+        check_launch(leg, t0)
         inspect(pipe, final)
 
+    t0 = time.perf_counter()
     rc = train.main(MAIN_PATH + argv + ["--steps", str(steps)],
                     inspect=_inspect)
     assert rc == 0, f"{leg}: train.main returned {rc}"
@@ -275,6 +302,7 @@ def leg_lfm2moe() -> None:
 
     def inspect(pipe, final):
         check_run("lfm2moe", pipe, final, steps)
+        check_launch("lfm2moe", t0)
         assert type(pipe.comps.network).__name__ == "Lfm2MoeQ"
         assert final["param_version"] >= 1, "lfm2moe: nothing was published"
         routing = final.get("routing") or {}
@@ -282,6 +310,7 @@ def leg_lfm2moe() -> None:
         say(f"lfm2moe: routing a step {routing}; actors adopted param_version "
             f"{pipe.worker.param_version} of {final['param_version']}")
 
+    t0 = time.perf_counter()
     rc = train.main([
         "--params-file", os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                       "configs", "config6_lfm2moe_q_ep8.json"),
@@ -303,6 +332,11 @@ def _train_on_histories(leg: str, config: str, steps: int, inspect) -> None:
     actors, batch and ring cut to what fits beside the actors' parameters."""
     from ape_x_dqn_tpu import train
 
+    def _inspect(pipe, final):
+        check_launch(leg, t0)
+        inspect(pipe, final)
+
+    t0 = time.perf_counter()
     rc = train.main([
         "--params-file", os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                       "configs", config),
@@ -314,7 +348,7 @@ def _train_on_histories(leg: str, config: str, steps: int, inspect) -> None:
         "--set", "learner.min_replay_mem_size=64",
         "--set", "learner.publish_every=4",
         "--log-every", "4", "--steps", str(steps),
-    ], inspect=inspect)
+    ], inspect=_inspect)
     assert rc == 0, f"{leg}: train.main returned {rc}"
 
 
@@ -367,9 +401,13 @@ def main() -> int:
         return 2
     import jax
 
-    if jax.default_backend() != "tpu":
+    from ape_x_dqn_tpu.utils import profiling
+
+    with profiling.launch.span("backend"):  # the chip's start-up
+        backend = jax.default_backend()
+    if backend != "tpu":
         print("chip_smoke: needs a TPU, jax's default backend is "
-              f"{jax.default_backend()!r}; nothing compiled, nothing run",
+              f"{backend!r}; nothing compiled, nothing run",
               file=sys.stderr)
         return 2
     enable_compile_cache()

@@ -54,6 +54,7 @@ def snapshot_from_jsonl(path: str) -> dict:
     the /varz sectioned shape (top-level learner scalars → ``learner``;
     ``workers`` / ``lineage`` / ``xp_transport`` ride through)."""
     last = None
+    launch = {}  # the `launch` event: the partition of the run's launch
     with open(path) as f:
         for line in f:
             line = line.strip()
@@ -65,6 +66,8 @@ def snapshot_from_jsonl(path: str) -> dict:
                 continue  # torn tail of a live file
             if "step" in rec and "event" not in rec:
                 last = rec
+            elif rec.get("event") == "launch":
+                launch = rec
     if last is None:
         raise ValueError(f"no periodic records in {path}")
     learner_keys = (
@@ -78,6 +81,9 @@ def snapshot_from_jsonl(path: str) -> dict:
                     "replay_svc"):
         if section in last:
             out[section] = last[section]
+    # the periodic records carry the compiles since the launch ended
+    if launch or "launch" in last:
+        out["launch"] = {**launch, **last.get("launch", {})}
     out["t"] = last.get("t")
     return out
 
@@ -363,6 +369,24 @@ def render(snap: dict) -> str:
         f"replay {ln.get('replay_size', '?')}  "
         f"v{ln.get('param_version', '?')}"
     )
+    launch = snap.get("launch")
+    if launch:
+        lines.append(
+            f"-- launch  {launch.get('seconds', 0):.1f} s to step "
+            f"{launch.get('step', '?')}: "
+            + "  ".join(  # the nine parts: the record's keys in `_s`
+                f"{part[:-2]} {value:.1f}" for part, value in launch.items()
+                if part.endswith("_s"))
+            + f"  cache {launch.get('cache_hits', '?')} hit/"
+            f"{launch.get('cache_misses', '?')} miss  "
+            f"compiles_after_launch {launch.get('compiles_after_launch', 0)}"
+        )
+        for rc in (launch.get("recompiles") or [])[-3:]:
+            lines.append(
+                f"   recompile  {rc.get('program')}  step {rc.get('step')}  "
+                f"{rc.get('seconds', 0):.3f} s  cache {rc.get('cache')}  "
+                f"({rc.get('thread')})"
+            )
     workers = snap.get("workers") or {}
     if workers:
         lines.append(
