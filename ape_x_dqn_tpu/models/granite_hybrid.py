@@ -153,6 +153,7 @@ class NopeAttention(nn.Module):
         visited, total = blocked.blocks_visited(tokens, None)
         heads = spec.arg("num_attention_heads")
         return {"pairs_in_mask_full": float(rows * blocked.pairs_in_mask(tokens, None)),
+                "pairs_computed_full": float(rows * blocked.pairs_computed(tokens, None)),
                 "blocks_visited_full": float(rows * heads * visited),
                 "blocks_total_full": float(rows * heads * total)}
 

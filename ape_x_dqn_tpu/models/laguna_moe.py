@@ -151,12 +151,15 @@ class GatedAttention(nn.Module):
     @staticmethod
     def count(spec: TorsoSpec, op: str, rows: int, tokens: int) -> dict:
         """One layer's forward over ``rows`` sequences of ``tokens``: the
-        pairs in the mask, and the blocks of the kernel's grid that it visits
-        and that exist (a head's, times the heads)."""
+        pairs in the mask and the pairs the kernel computes a score for (a
+        head's), and the blocks of the kernel's grid that it visits and that
+        exist (a head's, times the heads)."""
         kind = kind_of(spec, op)
         visited, total = blocked.blocks_visited(tokens, kind.window)
         return {f"pairs_in_mask_{kind.name}":
                 float(rows * blocked.pairs_in_mask(tokens, kind.window)),
+                f"pairs_computed_{kind.name}":
+                float(rows * blocked.pairs_computed(tokens, kind.window)),
                 f"blocks_visited_{kind.name}": float(rows * kind.heads * visited),
                 f"blocks_total_{kind.name}": float(rows * kind.heads * total)}
 

@@ -1,16 +1,29 @@
 """Blocked masked attention: causal, or causal inside a window of the last
 ``window`` keys, grouped-query, never holding a ``[T, T]`` score tensor.
 
-The kernels are JAX's splash attention (``jax.experimental.pallas.ops.tpu.
-splash_attention``): flash attention over blocks of queries and keys with the
-mask given as data, so that a block the mask empties is never visited, in the
-forward pass, in a recomputation and in the two backward kernels (dq, dk/dv)
-alike; scores and softmax in float32.  What is this module's: the mask of a
-layer kind (``window`` or none), the block sizes and the padded length from
-the shapes (``plan``), the padding (keys past the end lie in no query's
-causal past, the padded queries' rows are cut off and get no gradient), and
-what the shapes alone say of the work: ``pairs_in_mask`` and
-``blocks_visited``.
+Three kernels of this module's own under one ``jax.custom_vjp``: the forward
+pass (flash attention: a running maximum, sum and output in VMEM, float32),
+and the two backward kernels, dq and dk/dv, which compute a block's scores
+again from the kept log-sum.  A block's rows are a key-value head's whole
+group of query heads over ``block_q`` tokens: a block of ``q`` [B, H, T, D]
+is [G, block_q, D], a key-value head's heads being adjacent, and a program
+holds it as [G x block_q, D], so that the keys it loads serve every head
+that reads them and a short run of tokens still fills the array.  Scores and
+softmax are float32, the products in the inputs' type.
+
+The mask is drawn in the kernel from the block's offsets (key ``j`` for
+query ``i`` if ``j <= i`` and, under a window, ``j > i - window``), only in
+the blocks its edges cross; a block wholly outside it is not in the walk at
+all: the grid's last axis is a schedule of the visited blocks, prefetched
+(``_schedule``).  The length is not padded: the last block of queries or keys
+reads past the end, what it reads there is masked or zeroed before it can
+reach a sum, and its rows past the end are not written.  The log-sum and
+``di = rowsum(o x do)`` cross HBM as [B, KV, G, T] float32; ``di`` is made in
+the dq kernel, where ``o`` and ``do`` are in VMEM.  dk/dv sum over the group
+in the kernel.
+
+What the shapes alone say of the work: ``plan``, ``pairs_in_mask``,
+``blocks_visited`` and ``pairs_computed``.
 
 Off the TPU the kernels run in Pallas' interpreter (``INTERPRET`` None); a
 test that compiles for a described TPU sets ``INTERPRET`` False.
@@ -20,43 +33,66 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 INTERPRET: Optional[bool] = None
 _LANES = 128
+_F32 = jnp.float32
+_MASKED = -0.7 * float(np.finfo(np.float32).max)   # finite: a row of it has a maximum to subtract
+_NT = (((1,), (1,)), ((), ()))                      # a @ b^T
+_FIRST, _LAST, _EDGE, _END = 1, 2, 4, 8             # a step's flags in the schedule
 
 
 @dataclasses.dataclass(frozen=True)
 class Plan:
-    """The padded length and the kernels' block sizes for one sequence."""
+    """The kernels' block sizes, all three alike: the tokens of a block's
+    rows (times the group) and the keys of a block."""
 
-    padded: int
     block_q: int
     block_kv: int
-    block_kv_compute: int
 
 
 def plan(tokens: int, window: Optional[int]) -> Plan:
-    """From the shapes.  Under a window: square blocks of the window's size
-    in whole lanes, at most 512 (a query block then sees its own and the
-    block before it), the length padded to whole blocks.  Causal alone: the
-    length padded to a multiple of 256 and cut in two blocks each way; past
-    2,048 tokens blocks of 512.  A block's keys are multiplied all at once.
-    (Read on a TPU v5e at B=8, 8 key-value heads of 128, T=1,568, forward
-    and backward, against smaller and larger blocks: PERF.md section 6, PR
-    32.  At heads of 64, 32 over 8, the causal plan is used as it stands and
-    was read at this one block size: PERF.md section 6, PR 34.)"""
-    up = lambda n, m: -(-n // m) * m  # noqa: E731
-    if window is not None:
-        block = min(up(window, _LANES), 512)
-        return Plan(up(tokens, block), block, block, block)
-    padded = up(tokens, 2 * _LANES)
-    if padded > 2048:
-        return Plan(up(tokens, 512), 512, 512, 512)
-    return Plan(padded, padded // 2, padded // 2, padded // 2)
+    """From the shapes.  Causal alone: 128 tokens by 512 keys.  Under a
+    window: square blocks of half the window in whole lanes, at most 256 (a
+    query block then sees its own key block and up to two before it).
+
+    Read on a TPU v5e (PERF.md section 6, PR 38) at B=8, T=1,568, 8
+    key-value heads, each kernel apart, ms forward / forward keeping the
+    log-sum / dq / dk-dv, by tokens x keys:
+
+    =========  =======================  =======================  =======================
+    block      G=9, D=128, window 512   G=6, D=128, causal       G=4, D=64, causal
+    =========  =======================  =======================  =======================
+    128 x 128  4.06 / 4.23 / 5.85 / 4.62  5.00 / 5.07 / 6.25 / 5.60  4.82 / 5.10 / 6.17 / 5.20
+    128 x 256  3.31 / 3.58 / 4.98 / 4.33  3.61 / 3.73 / 4.91 / 4.54  3.37 / 3.56 / 4.89 / 4.10
+    128 x 512  3.29 / 3.53 / 5.42 / 5.12  2.94 / 3.09 / 4.49 / 4.49  2.61 / 2.77 / 4.35 / 3.89
+    256 x 128  4.04 / 4.22 / 5.95 / 5.28  4.42 / 4.59 / 5.89 / 5.49  3.92 / 4.07 / 5.30 / 4.78
+    256 x 256  3.01 / 3.29 / 4.78 / 4.36  3.17 / 3.40 / 4.66 / 4.58  2.87 / 3.02 / 4.39 / 3.93
+    256 x 512  3.31 / 3.58 / 5.54 / 5.33  2.99 / 3.20 / 4.62 / 4.76  2.58 / 2.70 / 4.23 / 3.97
+    =========  =======================  =======================  =======================
+
+    (the rows of 128 keys an earlier draft's, within 6% of the final
+    kernels where both were read).  Blocks of 128 keys compute the fewest
+    pairs outside the mask (1.34 and 1.21 times the pairs in it) and are the
+    slowest: a step's reductions over the lanes and its fixed cost weigh
+    more than the pairs saved.  The group's size and the head's did not move
+    the choice, so the rule does not read them.  JAX's splash attention, a
+    query head at a time in 512 x 512 and 896 x 896 blocks over a padded
+    length: 6.80 / 24.45 (forward / forward and backward), 5.43 / 18.20 and
+    3.76 / 12.22."""
+    del tokens
+    if window is None:
+        return Plan(128, 512)
+    side = min(max(-(-(window // 2) // _LANES) * _LANES, _LANES), 256)
+    return Plan(side, side)
 
 
 def pairs_in_mask(tokens: int, window: Optional[int]) -> int:
@@ -66,46 +102,314 @@ def pairs_in_mask(tokens: int, window: Optional[int]) -> int:
     return w * (w + 1) // 2 + (tokens - w) * w
 
 
+@functools.lru_cache(maxsize=None)
+def _visits(tokens: int, window: Optional[int], bq: int, bkv: int) -> tuple:
+    """((query block, key block, whether an edge crosses it, whether it
+    reaches past the end), ...) for the blocks that hold a pair in the mask,
+    by query blocks.  An edge is the diagonal, the window's trailing edge or
+    the end of the sequence."""
+    out = []
+    for i in range(-(-tokens // bq)):
+        q0, q1 = i * bq, i * bq + bq - 1
+        for j in range(-(-tokens // bkv)):
+            k0, k1 = j * bkv, j * bkv + bkv - 1
+            # key - query runs from k0 - q1 to k1 - q0 over the block's own tokens
+            reaches = k0 <= min(q1, tokens - 1)
+            inside = window is None or min(k1, tokens - 1) > q0 - window
+            end = max(q1, k1) >= tokens
+            whole = k1 <= q0 and not end and (window is None or k0 > q1 - window)
+            if reaches and inside:
+                out.append((i, j, not whole, end))
+    return tuple(out)
+
+
 def blocks_visited(tokens: int, window: Optional[int]) -> tuple:
     """(blocks of the forward kernel's grid that hold a pair in the mask,
-    blocks of the whole grid), a head, over the padded length."""
+    blocks of the whole grid), a query head."""
     p = plan(tokens, window)
-    visited = 0
-    for r0 in range(0, p.padded, p.block_q):
-        for c0 in range(0, p.padded, p.block_kv):
-            # key - query runs from c0 - r1 to c1 - r0 over the block
-            reaches = c0 - (r0 + p.block_q - 1) <= 0
-            inside = window is None or (c0 + p.block_kv - 1) - r0 >= -(window - 1)
-            visited += reaches and inside
-    return visited, (p.padded // p.block_q) * (p.padded // p.block_kv)
+    return (len(_visits(tokens, window, p.block_q, p.block_kv)),
+            -(-tokens // p.block_q) * -(-tokens // p.block_kv))
+
+
+def pairs_computed(tokens: int, window: Optional[int]) -> int:
+    """(query, key) pairs the forward kernel computes a score for, a query
+    head: the visited blocks' whole extents."""
+    p = plan(tokens, window)
+    return blocks_visited(tokens, window)[0] * p.block_q * p.block_kv
 
 
 @functools.lru_cache(maxsize=None)
-def _kernel(p: Plan, heads: int, window: Optional[int], interpret: bool):
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as kernel, splash_attention_mask as masks,
-    )
+def _schedule(tokens: int, window: Optional[int], bq: int, bkv: int, by_keys: bool):
+    """The walk as three int32 arrays, a step each: its query block, its key
+    block and its flags (first or last of its row of blocks, crossed by an
+    edge, reaching past the end).  A row is a query block's (forward, dq) or
+    a key block's (dk/dv)."""
+    visits = _visits(tokens, window, bq, bkv)
+    row = (lambda v: v[1]) if by_keys else (lambda v: v[0])
+    visits = sorted(visits, key=lambda v: (row(v), v))
+    flags = [(_FIRST if s == 0 or row(visits[s - 1]) != row(v) else 0)
+             | (_LAST if s == len(visits) - 1 or row(visits[s + 1]) != row(v) else 0)
+             | (_EDGE if v[2] else 0) | (_END if v[3] else 0) for s, v in enumerate(visits)]
+    as_array = lambda xs: np.asarray(xs, np.int32)  # noqa: E731
+    return as_array([v[0] for v in visits]), as_array([v[1] for v in visits]), as_array(flags)
 
-    shape = (p.padded, p.padded)
-    mask = (masks.CausalMask(shape) if window is None
-            else masks.LocalMask(shape, (window - 1, 0), 0))
-    sizes = kernel.BlockSizes(
-        block_q=p.block_q, block_kv=p.block_kv, block_kv_compute=p.block_kv_compute,
-        block_q_dkv=p.block_q, block_kv_dkv=p.block_kv,
-        block_kv_dkv_compute=p.block_kv_compute,
-        block_q_dq=p.block_q, block_kv_dq=p.block_kv)
-    with jax.ensure_compile_time_eval():  # the mask's tables are constants of any trace
-        return kernel.make_splash_mha_single_device(
-            masks.MultiHeadMask([mask] * heads), block_sizes=sizes, interpret=interpret)
+
+def _keep(q0, k0, shape, q_axis: int, bq: int, window: Optional[int], tokens: Optional[int]):
+    """The mask over a block of scores whose ``q_axis`` runs over the group's
+    rows (``block_q`` tokens, again for every head of the group) and whose
+    other axis over the block's keys; with ``tokens``, the block reaches past
+    the end and a query there keeps nothing."""
+    i = q0 + (jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) & (bq - 1))
+    j = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    keep = j <= i
+    if window is not None:
+        keep &= j > i - window
+    if tokens is not None:
+        keep &= i < tokens
+    return keep
+
+
+def _own_rows(x, first, tokens: int, period: Optional[int] = None):
+    """``x`` [rows, D] with the rows of tokens past the end zeroed; the rows
+    are tokens from ``first``, starting again every ``period`` rows."""
+    r = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    r = r if period is None else r & (period - 1)
+    return jnp.where(first + r < tokens, x, jnp.zeros_like(x))
+
+
+def _lanes(x, n: int):
+    """``x`` [rows, 128], every lane alike, as [rows, n]."""
+    return x[:, :n] if n <= _LANES else jnp.tile(x, (1, n // _LANES))
+
+
+def _to_lanes(col, ref):
+    """Write ``col`` [G x bq, 128], every lane alike, to ``ref`` [G, bq]: a
+    row's number goes from the sublanes to the lanes."""
+    turned = col.T
+    g, bq = ref.shape
+    for h in range(g):
+        ref[h:h + 1, :] = turned[:1, h * bq:(h + 1) * bq]
+
+
+def _from_lanes(ref):
+    """``ref`` [G, bq] as a column [G x bq, 1]."""
+    return jnp.concatenate([ref[h][:, None] for h in range(ref.shape[0])], 0)
+
+
+def _row(ref):
+    """``ref`` [G, bq] as one row [1, G x bq]."""
+    return jnp.concatenate([ref[h:h + 1, :] for h in range(ref.shape[0])], 1)
+
+
+def _run(flag, step):
+    """``step(edge, end)`` for what the flags say of the block: wholly inside
+    the mask, crossed by an edge, or reaching past the end too."""
+    pl.when(flag & _EDGE == 0)(lambda: step(False, False))
+    pl.when(flag & (_EDGE | _END) == _EDGE)(lambda: step(True, False))
+    pl.when(flag & _END != 0)(lambda: step(True, True))
+
+
+def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *rest,
+                tokens: int, window: Optional[int]):
+    lse_ref = rest[0] if len(rest) == 4 else None
+    m_ref, l_ref, acc_ref = rest[-3:]
+    g, bq, d = q_ref.shape
+    bkv = k_ref.shape[0]
+    s = pl.program_id(2)
+    flag = flag_ref[s]
+
+    @pl.when(flag & _FIRST != 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step(edge: bool, end: bool):
+        q, k, v = q_ref[...].reshape(g * bq, d), k_ref[...], v_ref[...]
+        q0, k0 = qi_ref[s] * bq, kj_ref[s] * bkv
+        scores = jax.lax.dot_general(q, k, _NT, preferred_element_type=_F32)
+        if edge:
+            scores = jnp.where(_keep(q0, k0, scores.shape, 0, bq, window, None), scores, _MASKED)
+        if end:     # keys past the end are masked; what stands in their rows of v is not a number
+            v = _own_rows(v, k0, tokens)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        p = jnp.exp(scores - _lanes(m_next, bkv))
+        alpha = jnp.exp(m_prev - m_next)
+        # the sum stays a lane's own until the row's last block: no reduction over lanes a step
+        l_ref[...] = alpha * l_ref[...] + sum(
+            p[:, c:c + _LANES] for c in range(0, bkv, _LANES))
+        m_ref[...] = m_next
+        acc_ref[...] = _lanes(alpha, d) * acc_ref[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=_F32)
+
+    _run(flag, step)
+
+    @pl.when(flag & _LAST != 0)
+    def _():
+        l = jnp.broadcast_to(l_ref[...].sum(axis=-1, keepdims=True), l_ref.shape)
+        o_ref[...] = (acc_ref[...] / _lanes(l, d)).reshape(g, bq, d).astype(o_ref.dtype)
+        if lse_ref is not None:
+            _to_lanes(m_ref[...] + jnp.log(l), lse_ref)
+
+
+def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+               dq_ref, di_ref, lse_col, di_col, acc_ref, *, tokens: int, window: Optional[int]):
+    g, bq, d = q_ref.shape
+    bkv = k_ref.shape[0]
+    s = pl.program_id(2)
+    flag = flag_ref[s]
+
+    @pl.when(flag & _FIRST != 0)
+    def _():
+        di = jnp.sum(o_ref[...].reshape(g * bq, d).astype(_F32)
+                     * do_ref[...].reshape(g * bq, d).astype(_F32), axis=-1, keepdims=True)
+        di_col[...] = jnp.broadcast_to(di, di_col.shape)
+        _to_lanes(di_col[...], di_ref)
+        lse_col[...] = jnp.broadcast_to(_from_lanes(lse_ref), lse_col.shape)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step(edge: bool, end: bool):
+        q, do = q_ref[...].reshape(g * bq, d), do_ref[...].reshape(g * bq, d)
+        k, v = k_ref[...], v_ref[...]
+        q0, k0 = qi_ref[s] * bq, kj_ref[s] * bkv
+        if end:
+            k, v = _own_rows(k, k0, tokens), _own_rows(v, k0, tokens)
+        scores = jax.lax.dot_general(q, k, _NT, preferred_element_type=_F32)
+        if edge:
+            scores = jnp.where(_keep(q0, k0, scores.shape, 0, bq, window, None), scores, _MASKED)
+        p = jnp.exp(scores - _lanes(lse_col[...], bkv))
+        dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=_F32)
+        ds = p * (dp - _lanes(di_col[...], bkv))
+        acc_ref[...] += jnp.dot(ds.astype(k.dtype), k, preferred_element_type=_F32)
+
+    _run(flag, step)
+
+    @pl.when(flag & _LAST != 0)
+    def _():
+        dq_ref[...] = acc_ref[...].reshape(g, bq, d).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                dk_ref, dv_ref, dk_acc, dv_acc, *, tokens: int, window: Optional[int]):
+    g, bq, d = q_ref.shape
+    bkv = k_ref.shape[0]
+    s = pl.program_id(2)
+    flag = flag_ref[s]
+
+    @pl.when(flag & _FIRST != 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def step(edge: bool, end: bool):
+        # the scores turned, [keys, the group's rows]: the sums over the rows are products
+        q, do = q_ref[...].reshape(g * bq, d), do_ref[...].reshape(g * bq, d)
+        k, v, lse, di = k_ref[...], v_ref[...], _row(lse_ref), _row(di_ref)
+        q0, k0 = qi_ref[s] * bq, kj_ref[s] * bkv
+        if end:     # queries past the end keep nothing; their rows are not numbers
+            q, do = _own_rows(q, q0, tokens, bq), _own_rows(do, q0, tokens, bq)
+            i = q0 + (jax.lax.broadcasted_iota(jnp.int32, di.shape, 1) & (bq - 1))
+            di = jnp.where(i < tokens, di, 0.0)
+        scores = jax.lax.dot_general(k, q, _NT, preferred_element_type=_F32)
+        p = jnp.exp(scores - lse)
+        if edge:
+            p = jnp.where(_keep(q0, k0, scores.shape, 1, bq, window, tokens if end else None),
+                          p, 0.0)
+        dv_acc[...] += jnp.dot(p.astype(do.dtype), do, preferred_element_type=_F32)
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=_F32)
+        ds = p * (dp - di)
+        dk_acc[...] += jnp.dot(ds.astype(q.dtype), q, preferred_element_type=_F32)
+
+    _run(flag, step)
+
+    @pl.when(flag & _LAST != 0)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _call(kernel, name, by_keys: bool, inputs, specs: str, out_shape, out_specs: str, scratch,
+          score_arrays: int, window, interpret: bool):
+    """One kernel over (B, KV, the steps of its walk).  ``inputs``: ``q`` [B,
+    H, T, D], ``k`` [B, KV, T, D] and the rest; ``specs`` names each array's
+    blocks: ``r`` a group's rows of [B, H, T, D], ``k`` a block of keys of
+    [B, KV, T, D], ``l`` a group's tokens, in the lanes, of [B, KV, G, T].
+    ``scratch``: (rows, columns) of its float32 scratch, where ``r`` stands
+    for the rows of a block of queries and ``k`` for a block's keys.  It asks
+    for the VMEM its blocks (twice: one in flight), its scratch and
+    ``score_arrays`` float32 arrays of a block of scores need."""
+    q, k = inputs[:2]
+    tokens, g, d = q.shape[2], q.shape[1] // k.shape[1], q.shape[3]
+    p = plan(tokens, window)
+    bq, bkv = p.block_q, p.block_kv
+    spec = {"r": pl.BlockSpec((None, g, bq, d), lambda b, h, s, qi, kj, fl: (b, h, qi[s], 0)),
+            "k": pl.BlockSpec((None, None, bkv, d), lambda b, h, s, qi, kj, fl: (b, h, kj[s], 0)),
+            "l": pl.BlockSpec((None, None, g, bq), lambda b, h, s, qi, kj, fl: (b, h, 0, qi[s]))}
+    size = {"r": g * bq, "k": bkv}
+    scratch = [pltpu.VMEM((size[r], c), _F32) for r, c in scratch]
+    schedule = _schedule(tokens, window, bq, bkv, by_keys)
+    need = (sum(2 * math.prod(n for n in spec[c].block_shape if n) * x.dtype.itemsize
+                for c, x in zip(specs + out_specs, [*inputs, *out_shape]))
+            + sum(4 * math.prod(x.shape) for x in scratch) + score_arrays * 4 * g * bq * bkv)
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(q.shape[0], k.shape[1], len(schedule[0])),
+        in_specs=[spec[c] for c in specs], out_specs=[spec[c] for c in out_specs],
+        scratch_shapes=scratch)
+    return pl.pallas_call(
+        functools.partial(kernel, tokens=tokens, window=window), grid_spec=grid,
+        out_shape=out_shape, name=name, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=need + (4 << 20)))(*schedule, *inputs)
+
+
+def _like(*arrays):
+    return [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in arrays]
+
+
+def _forward(q, k, v, window, interpret, keep_lse: bool):
+    """The output; with ``keep_lse`` also the log-sum, [B, KV, G, T] float32."""
+    lse = jax.ShapeDtypeStruct((*k.shape[:2], q.shape[1] // k.shape[1], q.shape[2]), _F32)
+    out = _call(_fwd_kernel, "attn_fwd_lse" if keep_lse else "attn_fwd", False, [q, k, v], "rkk",
+                _like(q) + [lse] * keep_lse, "rl"[:1 + keep_lse],
+                [("r", _LANES), ("r", _LANES), ("r", q.shape[3])], 4, window, interpret)
+    return out if keep_lse else out[0]
+
+
+def _dq(q, k, v, o, lse, do, window, interpret):
+    """(dq, and ``di`` [B, KV, G, T] float32 for ``_dkv``)."""
+    return _call(_dq_kernel, "attn_dq", False, [q, k, v, o, do, lse], "rkkrrl", _like(q, lse),
+                 "rl", [("r", _LANES), ("r", _LANES), ("r", q.shape[3])], 5, window, interpret)
+
+
+def _dkv(q, k, v, lse, di, do, window, interpret):
+    return _call(_dkv_kernel, "attn_dkv", True, [q, k, v, do, lse, di], "rkkrll", _like(k, v),
+                 "kk", [("k", q.shape[3])] * 2, 5, window, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attend(q, k, v, window, interpret):
+    return _forward(q, k, v, window, interpret, keep_lse=False)
+
+
+def _attend_fwd(q, k, v, window, interpret):
+    o, lse = _forward(q, k, v, window, interpret, keep_lse=True)
+    return o, (q, k, v, o, lse)
+
+
+def _attend_bwd(window, interpret, kept, do):
+    q, k, v, o, lse = kept
+    dq, di = _dq(q, k, v, o, lse, do, window, interpret)
+    return (dq, *_dkv(q, k, v, lse, di, do, window, interpret))
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def blocked_attention(q, k, v, window: Optional[int] = None):
     """``softmax(q k^T + mask) v``: ``q`` [B, H, T, D], scaled already; ``k``,
     ``v`` [B, KV, T, D], each key-value head serving ``H / KV`` query heads in
     order; -> [B, H, T, D] in ``q``'s type."""
-    heads, tokens = q.shape[1], q.shape[2]
-    p = plan(tokens, window)
     interpret = jax.default_backend() != "tpu" if INTERPRET is None else INTERPRET
-    pad = lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, p.padded - tokens), (0, 0)))  # noqa: E731
-    out = jax.vmap(_kernel(p, heads, window, interpret))(pad(q), pad(k), pad(v))
-    return out[:, :, :tokens]
+    return _attend(q, k, v, window, interpret)

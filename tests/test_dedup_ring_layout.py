@@ -522,13 +522,14 @@ def test_the_history_torsos_fused_program_fits_the_chip(topo, no_compile_cache, 
     square = [dims for _, dims, _ in _ARRAY.findall(text)
               if re.search(r"(?:^|,)(1568|1792|2048),\1(?:,|$)", dims)]
     assert not square, sorted(set(square))[:5]
-    kernels = re.findall(r"%(splash_mha_\w+?)[.\d]* = ", text)
-    assert {"splash_mha_fwd_residuals", "splash_mha_dq_no_residuals",
-            "splash_mha_dkv_no_residuals"} <= set(kernels)
+    kernels = re.findall(r"%(attn_\w+?)[.\d]* = ", text)
+    assert {"attn_fwd", "attn_fwd_lse", "attn_dq", "attn_dkv"} == set(kernels), kernels
     # two full layers apart and one scanned body of sliding layers: three of
     # each backward kernel, not five
-    assert kernels.count("splash_mha_dq_no_residuals") == 3, kernels
-    assert kernels.count("splash_mha_dkv_no_residuals") == 3, kernels
+    assert [kernels.count(k) for k in ("attn_dq", "attn_dkv")] == [3, 3], kernels
+    assert "splash" not in text
+    # the padded copies and the log-sums over 128 lanes are gone: PR 36's program took this much
+    assert mem.temp_size_in_bytes <= 8_334_013_440, mem.temp_size_in_bytes
 
 
 def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cache, monkeypatch):
@@ -580,8 +581,9 @@ def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cac
     mem = compiled.memory_analysis()
     hbm = 16_909_336_064                      # a v5e's, PERF.md Open question 11
     assert hbm - mem.argument_size_in_bytes - mem.temp_size_in_bytes > 0.5e9, mem
-    # no more than with the mixers' activations copied around the scan (PR 34; 8,471,350,784 since)
-    assert mem.temp_size_in_bytes <= 8_553_135_616, mem
+    # PR 35: 8,471,350,784; since PR 38 3.5 MB more, though every buffer of the attention
+    # layer is smaller or gone: the compiler holds one more prefetched activation in flight
+    assert mem.temp_size_in_bytes <= 8_474_811_392, mem
     ring_bytes = frames * 56448 * 4
     assert_ring_stays_put(text, ring_bytes, 0)
     assert "mini-gather" not in text
@@ -590,9 +592,10 @@ def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cac
     assert per_chunk and not any(
         int(np.prod([int(d) for d in dims.split(",")])) > 8 * 64 * 256 * 256 for dims in per_chunk), \
         sorted(set(per_chunk))
-    kernels = re.findall(r"%(splash_mha_\w+?)[.\d]* = ", text)
-    assert kernels.count("splash_mha_dq_no_residuals") == 1, kernels
-    assert kernels.count("splash_mha_dkv_no_residuals") == 1, kernels
+    kernels = re.findall(r"%(attn_\w+?)[.\d]* = ", text)
+    assert "attn_fwd_lse" in kernels
+    assert [kernels.count(k) for k in ("attn_dq", "attn_dkv")] == [1, 1], kernels
+    assert "splash" not in text
 
 
 # ------------------------------- what a Mamba-2 mixer passes around its scan
@@ -600,10 +603,11 @@ def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cac
 RELAYOUTS = ("copy", "pad", "slice")
 
 
-def outside_the_walk(hlo_text: str):
+def outside_the_walk(hlo_text: str, under: str = ""):
     """(name, opcode, shape text) of the instructions that make a buffer of
     their own outside every ``while`` body: not inside a fused computation,
-    not inside a loop's body or condition."""
+    not inside a loop's body or condition; with ``under``, those whose line
+    (its ``op_name``) holds that scope."""
     fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo_text))
     loops = set(re.findall(r"(?:body|condition)=%?([\w.\-]+)", hlo_text))
     inside = None
@@ -613,14 +617,15 @@ def outside_the_walk(hlo_text: str):
             inside = head.group(1)
             continue
         m = _INSTRUCTION.match(line)
-        if m and inside not in fused and inside not in loops and m.group("op") not in _PASS_THROUGH:
+        if (m and inside not in fused and inside not in loops and under in line
+                and m.group("op") not in _PASS_THROUGH):
             yield m.group("name"), m.group("op"), m.group("shape")
 
 
-def relayouts(hlo_text: str, least_bytes: int) -> list:
+def relayouts(hlo_text: str, least_bytes: int, under: str = "") -> list:
     """The ``copy``, ``pad`` and ``slice`` instructions outside the walk whose
     result is at least ``least_bytes``: an activation written again as it was."""
-    return [name for name, op, shape in outside_the_walk(hlo_text)
+    return [name for name, op, shape in outside_the_walk(hlo_text, under)
             if op in RELAYOUTS and any(b >= least_bytes for b, _ in _arrays(shape))]
 
 
@@ -708,3 +713,70 @@ ENTRY %main (u: bf16[8,1568,2048]) -> bf16[8,1568,2048] {
     assert wide_float32(text, 8 * 1568 * 4096) == ["slice_convert_fusion", "copy.28"]
     # the weights' copy (35 MB), a chunk's copy in the walk and a reshape are none of them
     assert "copy.24" not in relayouts(text, 100_000_000) and "copy.99" not in relayouts(text, 1)
+
+
+# ------------------------------- what an attention layer hands its kernels
+
+@pytest.mark.parametrize("differentiated", [False, True])
+def test_an_attention_layer_hands_its_kernels_what_the_projections_wrote(
+        topo, no_compile_cache, monkeypatch, differentiated):
+    """One sliding ``laguna_moe.GatedAttention`` layer at ``laguna_q_ep32``'s
+    shapes (``u`` ``bf16[8, 1568, 3072]``, 72 query heads over 8 key-value
+    heads of 128), forward and pulled back: under ``torso:attn_*`` no ``pad``,
+    ``slice`` or ``copy`` of a whole ``q``, ``k``, ``v`` or output (the
+    length was padded to 2,048 in HBM: four pads a differentiated pass), and
+    nowhere a float32 array of ``[B, H, T, 128]`` (the log-sum, a lane of
+    which was kept, and a float32 copy of the output for ``di``): the
+    log-sum and ``di`` are ``f32[8, 8, 9, 1568]``."""
+    from ape_x_dqn_tpu.models import laguna_moe
+    from ape_x_dqn_tpu.ops.pallas import blocked_attention
+
+    monkeypatch.setattr(blocked_attention, "INTERPRET", False)   # this process sees the CPU
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / "laguna_q_ep32.json").read_text())
+    layer = laguna_moe.GatedAttention(laguna_moe.spec_from_config(cfg), "sliding_attention",
+                                      jnp.bfloat16, jnp.float32)
+    dev = SingleDeviceSharding(topo.devices[0])
+    rows, tokens, hidden, kv, hd = (cfg["batch_size"], 32 * 49, cfg["hidden_size"],
+                                    cfg["num_key_value_heads"], cfg["head_dim"])
+    params = _with(jax.eval_shape(
+        lambda k: layer.init(k, jnp.zeros((1, 8, hidden), jnp.bfloat16)), jax.random.PRNGKey(0)), dev)
+    u = jax.ShapeDtypeStruct((rows, tokens, hidden), jnp.bfloat16, sharding=dev)
+    fn = ((lambda p, v, ct: jax.vjp(layer.apply, p, v)[1](ct)) if differentiated
+          else (lambda p, v, ct: layer.apply(p, v)))
+    text = _compile_text(jax.jit(fn), (params, u, u))
+    kernels = sorted(set(re.findall(r"%(attn_\w+?)[.\d]* = ", text)))
+    assert kernels == (["attn_dkv", "attn_dq", "attn_fwd_lse"] if differentiated else ["attn_fwd"])
+    again = relayouts(text, rows * kv * tokens * hd * 2, under="torso:attn_")
+    assert not again, again
+    wide = wide_float32(text, rows * 72 * tokens * 128)
+    assert not wide, wide
+    sums = {dims for dtype, dims, _ in _ARRAY.findall(text) if dtype == "f32" and "1568" in dims
+            and dims.startswith(f"{rows},{kv},9,")}
+    assert sums == ({f"{rows},{kv},9,{tokens}"} if differentiated else set()), sums
+
+
+def test_reader_finds_the_pads_around_the_kernels():
+    """The readers on the entry computation the parent of PR 38 compiled the
+    same layer's differentiated pass to (operands shortened): ``q``, ``k``
+    and ``v`` padded to 2,048, the cotangent padded, the log-sum written over
+    128 lanes and the float32 copy of the output that ``di`` was summed from."""
+    text = """HloModule jit_pull
+
+ENTRY %main (u: bf16[8,1568,3072]) -> bf16[8,1568,3072] {
+  %pad.4 = bf16[8,8,2048,128]{3,2,1,0:T(8,128)(2,1)} pad(%fusion.3, %c), padding=0_0x0_0x0_480x0_0, metadata={op_name="jit(pull)/jvp(GatedAttention)/torso:attn_window/jit(_pad)/pad"}
+  %pad.2 = bf16[8,8,2048,128]{3,2,1,0:T(8,128)(2,1)} pad(%fusion.2, %c), padding=0_0x0_0x0_480x0_0, metadata={op_name="jit(pull)/jvp(GatedAttention)/torso:attn_window/jit(_pad)/pad"}
+  %pad.0 = bf16[8,72,2048,128]{3,2,1,0:T(8,128)(2,1)} pad(%fusion.1, %c), padding=0_0x0_0x0_480x0_0, metadata={op_name="jit(pull)/jvp(GatedAttention)/torso:attn_window/jit(_pad)/pad"}
+  %splash_mha_fwd_residuals.1 = (f32[8,512,128]{2,1,0:T(8,128)}, bf16[8,72,2048,128]{3,2,1,0:T(8,128)(2,1)}, f32[8,72,2048,128]{3,2,1,0:T(8,128)}) custom-call(%pad.0, %pad.2, %pad.4), custom_call_target="tpu_custom_call"
+  %copy.23 = f32[8,72,2048,128]{2,3,1,0:T(8,128)} copy(%gte.2)
+  %broadcast_in_dim.7 = f32[8,72,8,2048]{3,2,1,0:T(8,128)} broadcast(%fusion.9), dimensions={0,1,3}, metadata={op_name="jit(pull)/transpose(jvp(GatedAttention))/torso:attn_window/vmap(jit(_splash_attention))/broadcast_in_dim"}
+  %pad.9 = bf16[8,72,2048,128]{3,2,1,0:T(8,128)(2,1)} pad(%fusion.8, %c), padding=0_0x0_0x0_480x0_0, metadata={op_name="jit(pull)/transpose(jvp(GatedAttention))/torso:attn_window/pad"}
+  %copy.11 = bf16[8,72,1568,128]{3,2,1,0:T(8,128)(2,1)} copy(%fusion.0), metadata={op_name="jit(pull)/jvp(GatedAttention)/convert_element_type"}
+  ROOT %fusion.55 = bf16[8,1568,3072]{2,1,0:T(8,128)(2,1)} fusion(%pad.9, %w), kind=kOutput, calls=%fused_computation.63
+}
+"""
+    assert relayouts(text, 8 * 8 * 1568 * 128 * 2, under="torso:attn_") == [
+        "pad.4", "pad.2", "pad.0", "pad.9"]
+    assert wide_float32(text, 8 * 72 * 1568 * 128) == ["splash_mha_fwd_residuals.1", "copy.23"]
+    # RoPE's output written in the kernels' layout is the mixer's, not the kernels'
+    assert "copy.11" in relayouts(text, 1) and "copy.11" not in relayouts(text, 1, "torso:attn_")

@@ -4,6 +4,8 @@ what the two torsos of blocks share, the configuration path and the
 trainer's loop, all at small widths on the CPU (the kernels in Pallas'
 interpreter)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -50,43 +52,68 @@ def dense_attention(q, k, v, window):
     return jnp.einsum("bhst,bhtd->bhsd", jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), -1), v)
 
 
-@pytest.mark.parametrize("window", [None, 100, 130])
-def test_blocked_attention_is_the_dense_masked_softmax(window):
-    """Forward and the gradients of q, k and v, 300 tokens: no multiple of a
-    block (padded to 384 under a window, 512 without), a window of 100 inside
-    one block of 128 and crossing its edge, one of 130 reaching two blocks
-    back."""
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("group", [4, 6, 9])
+@pytest.mark.parametrize("window", [None, 100, 400])
+def test_blocked_attention_is_the_dense_masked_softmax(window, group, head_dim):
+    """Forward (with and without the kept log-sum) and the gradients of q, k
+    and v in Pallas' interpreter, 300 tokens: no multiple of a block of
+    either kernel, so the last block of queries and of keys reads past the
+    end (the interpreter fills it with NaN); a window of 100 inside a block,
+    one of 400 longer than the sequence; two key-value heads of ``group``
+    query heads each."""
     tokens = 300
-    plan = blocked.plan(tokens, window)
-    assert plan.padded % plan.block_q == 0 and plan.padded > tokens
+    assert all(tokens % b for b in dataclasses.astuple(blocked.plan(tokens, window)))
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(ks[0], (2, 6, tokens, 32)) / 32 ** 0.5
-    k, v = (jax.random.normal(kk, (2, 2, tokens, 32)) for kk in ks[1:3])
+    q = jax.random.normal(ks[0], (1, 2 * group, tokens, head_dim)) / head_dim ** 0.5
+    k, v = (jax.random.normal(kk, (1, 2, tokens, head_dim)) for kk in ks[1:3])
     cot = jax.random.normal(ks[3], q.shape)
     got, pull = jax.vjp(lambda *a: blocked.blocked_attention(*a, window), q, k, v)
     want, pull_dense = jax.vjp(lambda *a: dense_attention(*a, window), q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(blocked.blocked_attention(q, k, v, window)),
+                                  np.asarray(got))
     for name, a, b in zip("qkv", pull(cot), pull_dense(cot)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name)
 
 
-@pytest.mark.parametrize("tokens,window", [(1568, None), (1568, 512), (300, 100), (40, 8)])
+@pytest.mark.parametrize("tokens,window", [
+    (1568, None), (1568, 512), (300, None), (300, 100), (300, 400), (40, 8)])
 def test_the_counts_from_the_shapes_are_the_masks(tokens, window):
-    """``pairs_in_mask`` against the mask counted pair by pair, and
-    ``blocks_visited`` against the blocks the kernel's own tables keep."""
+    """``pairs_in_mask`` against the mask counted pair by pair;
+    ``blocks_visited`` and ``pairs_computed`` against the dense mask cut in
+    the plan's blocks; and the walks the kernels are handed: every block that
+    holds a pair once, by query blocks and by key blocks, a block that is not
+    marked as crossed by an edge wholly inside the mask."""
     i, j = np.arange(tokens)[:, None], np.arange(tokens)[None, :]
     mask = (j <= i) if window is None else (j <= i) & (j > i - window)
     assert blocked.pairs_in_mask(tokens, window) == int(mask.sum())
-    visited, total = blocked.blocks_visited(tokens, window)
     plan = blocked.plan(tokens, window)
-    kernel = blocked._kernel(plan, 2, window, True)
-    kept = np.asarray(kernel.fwd_mask_info.block_mask)   # like heads share one table
-    assert int((kept[0] != 0).sum()) == visited
-    assert total == (plan.padded // plan.block_q) * (plan.padded // plan.block_kv)
+    bq, bkv = plan.block_q, plan.block_kv
+    nq, nkv = -(-tokens // bq), -(-tokens // bkv)
+    cut = np.zeros((nq * bq, nkv * bkv), bool)
+    cut[:tokens, :tokens] = mask
+    cut = cut.reshape(nq, bq, nkv, bkv)
+    holds, whole = cut.any((1, 3)), cut.all((1, 3))
+    for by_keys in (False, True):
+        qi, kj, flags = blocked._schedule(tokens, window, bq, bkv, by_keys)
+        assert sorted(zip(qi.tolist(), kj.tolist())) == [tuple(b) for b in np.argwhere(holds)]
+        edge = flags & blocked._EDGE != 0
+        assert whole[qi[~edge], kj[~edge]].all() and not whole[qi[edge], kj[edge]].any()
+        row = kj if by_keys else qi
+        starts = np.r_[True, row[1:] != row[:-1]]
+        assert (np.diff(row) >= 0).all()
+        np.testing.assert_array_equal(flags & blocked._FIRST != 0, starts)
+        np.testing.assert_array_equal(flags & blocked._LAST != 0, np.r_[starts[1:], True])
+        past = flags & blocked._END != 0
+        np.testing.assert_array_equal(past, (np.maximum(qi * bq + bq, kj * bkv + bkv) > tokens))
+        assert (edge | ~past).all()
+    assert blocked.blocks_visited(tokens, window) == (int(holds.sum()), nq * nkv)
+    assert blocked.pairs_computed(tokens, window) == int(holds.sum()) * bq * bkv
     if tokens == 1568:
         assert int(mask.sum()) == (1_230_096 if window is None else 672_000)
-        assert (plan.padded, plan.block_q) == ((1792, 896) if window is None else (2048, 512))
-        assert (visited, total) == ((3, 4) if window is None else (7, 16))
+        assert (bq, bkv) == ((128, 512) if window is None else (256, 256))
+        assert blocked.blocks_visited(tokens, window) == ((28, 52) if window is None else (18, 49))
 
 
 def _layer(op):
@@ -182,11 +209,14 @@ def test_the_train_step_carries_routing_and_attention_counters():
     want = net.attention_metrics(x.shape)
     assert want["pairs_in_mask_full"] == 4 * 2 * full
     assert want["pairs_in_mask_window"] == 4 * 3 * window
-    # 40 tokens: the full layers pad to two blocks each way and visit three of
-    # the four, 4 heads on two layers; a window's block holds them all, 6
-    # heads on three layers
-    assert (want["blocks_visited_full"], want["blocks_total_full"]) == (4 * 2 * 4 * 3, 4 * 2 * 4 * 4)
+    # 40 tokens lie in one block of either kind's plan, visited by 4 heads on
+    # two layers and by 6 on three; the kernels compute the whole block
+    assert want["blocks_visited_full"] == want["blocks_total_full"] == 4 * 2 * 4
     assert want["blocks_visited_window"] == want["blocks_total_window"] == 4 * 3 * 6
+    for kind, layers, span in (("full", 2, None), ("window", 3, 8)):
+        plan = blocked.plan(40, span)
+        assert want[f"pairs_computed_{kind}"] == 4 * layers * plan.block_q * plan.block_kv
+        assert want[f"pairs_computed_{kind}"] > want[f"pairs_in_mask_{kind}"]
     assert {k: float(v) for k, v in metrics.attention.items()} == {
         k: 3.0 * v for k, v in want.items()}
     assert float(metrics.routing["held_pairs"]) > 0
