@@ -51,6 +51,8 @@ class StepMetrics:
     # shapes (state-space layers: chunks walked, tokens with and without the
     # padding to whole chunks).
     scan: Optional[dict] = None
+    # A network's ``delta_metrics`` likewise (delta-rule layers).
+    delta: Optional[dict] = None
 
 
 def _scale_by_rms_lowp(
@@ -263,6 +265,7 @@ def build_train_step(
     rebalanced = getattr(network, "rebalanced", None)
     attention_metrics = getattr(network, "attention_metrics", None)
     scan_metrics = getattr(network, "scan_metrics", None)
+    delta_metrics = getattr(network, "delta_metrics", None)
 
     def q_of(params, obs):
         """(Q, what the network's layers sowed)."""
@@ -304,7 +307,8 @@ def build_train_step(
             counted = counter and counter(batch.transition.obs.shape)
             return {k: jnp.float32(3.0 * v) for k, v in counted.items()} if counted else None
 
-        attention, scan = of_three_forwards(attention_metrics), of_three_forwards(scan_metrics)
+        attention, scan, delta_rule = (of_three_forwards(counter) for counter in (
+            attention_metrics, scan_metrics, delta_metrics))
         # Under plain pjit the mean inside loss_fn makes XLA insert the
         # gradient all-reduce over ICI automatically.  Inside shard_map
         # (varying-axes AD semantics): the params enter unvarying while the
@@ -360,6 +364,7 @@ def build_train_step(
             routing=routing,
             attention=attention,
             scan=scan,
+            delta=delta_rule,
         )
         new_state = TrainState(
             params=new_params,

@@ -2,6 +2,11 @@
 and layers, the expert layer, the block and the wrapper.  A torso of blocks
 need not have experts: a spec whose router has no outputs holds none, every
 layer's FFN is the dense SwiGLU, and nothing is routed, sown or counted.
+A spec may hold a share of a layer's heads as well as of its experts
+(``heads_held``, one chip's part of a tensor-parallel mixer): a mixer that
+divides computes its held heads' part of ``W_o``'s sum and that partial
+result goes on, as an expert layer's does; ``None`` holds every head, and
+only a family whose mixers divide (``models/solar_open2.py``) may state one.
 
 The convolutional stem and the dueling head are ``dueling.py``'s; between
 them the positions of the stem's output are tokens (raster order,
@@ -18,8 +23,9 @@ model's is the spec's (``TorsoSpec``): the mixers and their head counts, RoPE
 rules and windows, the router's score function, count and bias, the shared
 expert, whether an observation's tokens are one frame's positions or those
 of a history of frames.  ``models/lfm2_moe.py``, ``models/laguna_moe.py`` and
-``models/granite_hybrid.py`` make a spec from a published ``config.json``'s
-keys and bring their mixers; everything else is here, once.
+``models/granite_hybrid.py`` and ``models/solar_open2.py`` make a spec from
+a published ``config.json``'s keys and bring their mixers; everything else is
+here, once.
 
 The expert layer is one chip's share of an expert-parallel layer: it is told
 how many experts exist (the router's outputs), how many a token takes and
@@ -59,7 +65,8 @@ and ``rebalanced`` (the balancing rule).  ``attention_metrics`` gives what
 the spec's mixers count from the shapes alone (blocked attention's pairs in
 the mask and blocks visited; ``None`` where no mixer counts anything), and
 ``scan_metrics`` what its state-space mixers do (chunks walked, tokens with
-and without their padding).
+and without their padding), ``delta_metrics`` the same of its delta-rule
+mixers.
 
 The expert bias is a model's load-balancing buffer (``use_expert_bias``): a
 parameter no gradient reaches, which the train step moves after every update
@@ -99,7 +106,10 @@ class TorsoSpec:
     ``mixers`` maps a layer type to the module that mixes tokens there,
     ``mixer(spec, op, compute_dtype, param_dtype, name=op)``; a family's own
     sizes (a convolution's taps, an attention's heads, RoPE rule and window)
-    ride in ``mixer_args``, which only its mixers read."""
+    ride in ``mixer_args``, which only its mixers read.  What a chip holds of
+    a layer is the spec's too: ``experts_held`` of the router's outputs, and
+    ``heads_held`` of the published heads where every mixer divides by heads
+    (``divides_heads`` on the mixer's class)."""
 
     hidden_size: int
     intermediate_size: int            # the leading dense layers' SwiGLU
@@ -121,6 +131,7 @@ class TorsoSpec:
     residual_multiplier: float = 1.0  # on both branches of a block
     token_multiplier: float = 1.0     # on the projected tokens
     float32_leaves: Tuple[str, ...] = ()   # a family's, beside the router's (TorsoQ)
+    heads_held: Optional[Tuple[int, int]] = None   # [lo, hi) of the published heads; None: all
 
     def __post_init__(self):
         lo, hi = self.experts_held
@@ -137,6 +148,11 @@ class TorsoSpec:
         for op, ffn in self.layers:
             if op not in ops or ffn not in FFNS:
                 raise ValueError(f"unknown layer ({op!r}, {ffn!r}); ops {ops}, ffns {FFNS}")
+        if self.heads_held is not None:
+            whole = [op for op, mixer in self.mixers if not getattr(mixer, "divides_heads", False)]
+            if whole or not 0 <= self.heads_held[0] < self.heads_held[1]:
+                raise ValueError(f"heads_held {self.heads_held}: no range of heads, or the "
+                                 f"mixers {whole} hold every head")
 
     @property
     def num_held(self) -> int:
@@ -504,6 +520,11 @@ class TorsoQ(nn.Module):
         """What the state-space mixers count of one forward (``scan_count``:
         chunks walked, tokens with and without the padding to whole chunks)."""
         return self._counted(obs_shape, "scan_count")
+
+    def delta_metrics(self, obs_shape) -> Optional[dict]:
+        """What the delta-rule mixers count of one forward (``delta_count``:
+        chunks walked, tokens with and without the padding to whole chunks)."""
+        return self._counted(obs_shape, "delta_count")
 
     def q_values(self, x):
         return self(x)[2]

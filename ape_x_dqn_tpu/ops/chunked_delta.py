@@ -1,0 +1,221 @@
+"""A gated delta-rule recurrence in chunks, with a backward pass that walks the
+chunks in reverse (Kimi Delta Attention, arXiv:2510.26692, in its chunked
+WY / UT form).
+
+Per head, with a state ``S`` in R^{K x V}, a log decay ``g_t <= 0`` per key
+channel and a write strength ``beta_t`` (up to 2: a negative eigenvalue):
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T    S_{-1} = 0
+    o_t = S_t^T q_t
+
+The state is multiplied by a matrix that depends on the token's key, so the
+tokens of a chunk act on each other through a triangular system.  The
+sequence is cut in chunks of ``chunk`` tokens (padded at the end with ``beta
+= 0``, ``g = 0`` and zero ``q``, ``k``, ``v``: no decay, no write; the padded
+rows are cut off and get no gradient) and walked a chunk at a time, carrying
+the state across.  Inside a chunk, with ``G_i`` the running sum of ``g`` from
+the chunk's first token to ``i`` (a vector of K):
+
+    A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)       j < i, strictly lower
+    T    = (I + A)^-1                                    unit lower triangular
+    W    = T (beta k exp(G)),   U = T (beta v)
+    V'   = U - W S_prev
+    o_i  = (q_i exp(G_i)) S_prev + sum_{j <= i} (sum_c q_ic k_jc exp(G_ic - G_jc)) V'_j
+    S    = Diag(exp(G_end)) S_prev + sum_j (k_j exp(G_end - G_j)) V'_j^T
+
+**Every decay that is formed is ``exp`` of a difference ``G_i - G_j`` with
+``j <= i``, or of ``G_i`` alone**: ``exp(-G_j)`` overflows float32 once a
+chunk's decay passes e^-88.  The scores over pairs of tokens are therefore
+made in sub-blocks of ``SUB`` rows, as the published kernels make them: a
+pair in two different sub-blocks is referred to the later one's first row,
+``exp(G_i - G_first) exp(G_first - G_j)``, both factors at most 1, so that the
+sum over the channels is a matrix product; a pair inside one sub-block has
+its difference formed before the exponential, under the mask.  ``T`` is made
+by blocks too: diagonal blocks of ``INVERSE_BASE`` rows inverted by the
+doubling product ``(I - A)(I + A^2)(I + A^4)`` (exact for a nilpotent block,
+and short enough at 8 rows that its alternating powers do not cancel: keys
+that share a direction give entries near ``beta``, and over 64 rows their
+powers reach 1e9), then blocks merged two and two, ``[[T1, 0], [-T2 A21 T1,
+T2]]``, which is block substitution.
+
+Running sums, decays, ``T`` and the state are float32; the products take
+operands in ``q``'s type and accumulate in float32.  ``q``, ``k``, ``v`` and
+``g`` cross the walk as ``[chunks, B, H, chunk, 128]``: a head's 128 fills a
+TPU's lanes, a chunk's tokens the sublanes.
+
+The backward pass is written by hand (``jax.custom_vjp``) in
+``chunked_scan.py``'s manner: it keeps the inputs and each chunk's incoming
+state (``[chunks, B, H, K, V]`` float32) and walks the chunks from the last
+to the first, computing each again and pulling the cotangents of its outputs
+and of its outgoing state back through it; nothing of ``[chunk, chunk]`` a
+head outlives its chunk in either pass.  The two walks stand beside each
+other and are not one: ``chunked_scan._chunk`` takes its heads' parameters
+between the chunk's own operands, and the state-space cell's compiled
+program is held instruction for instruction.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ape_x_dqn_tpu.ops.chunked_scan import cut, join
+from ape_x_dqn_tpu.utils.profiling import part
+
+SUB = 16                         # rows of a sub-block of the pair scores
+INVERSE_BASE = 8                 # rows of a diagonal block inverted by the doubling product
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _divisor(n: int, most: int) -> int:
+    """The largest divisor of ``n`` up to ``most``."""
+    return max(r for r in range(1, min(most, n) + 1) if n % r == 0)
+
+
+def sub_rows(chunk: int) -> int:
+    """Rows of a sub-block of the pair scores in a chunk of ``chunk``."""
+    return _divisor(chunk, SUB)
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for ``a`` [..., C, C] float32, strictly lower
+    triangular: its diagonal blocks (the largest divisor of C up to
+    ``INVERSE_BASE`` rows) by the doubling product, then blocks merged two
+    and two, ``[[T1, 0], [-T2 A21 T1, T2]]``, while their count is even, and
+    by block substitution after that (module docstring)."""
+    c = a.shape[-1]
+    size = _divisor(c, INVERSE_BASE)
+    mm = lambda x, y: jnp.matmul(x, y, precision=_HIGHEST)  # noqa: E731
+    block = lambda i, j, s: a[..., i * s:(i + 1) * s, j * s:(j + 1) * s]  # noqa: E731
+    eye = jnp.eye(size, dtype=a.dtype)
+    x = -jnp.stack([block(i, i, size) for i in range(c // size)], axis=-3)   # [..., n, s, s]
+    inv, power, reach = eye + x, x, 2          # sum of x^k, k < reach
+    while reach < size:
+        power = mm(power, power)
+        inv, reach = mm(inv, eye + power), 2 * reach
+    while inv.shape[-3] % 2 == 0:              # two and two
+        n = inv.shape[-3] // 2
+        first, second = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        below = jnp.stack([block(2 * p + 1, 2 * p, size) for p in range(n)], axis=-3)
+        left = -mm(second, mm(below, first))
+        inv = jnp.concatenate([jnp.concatenate([first, jnp.zeros_like(first)], axis=-1),
+                               jnp.concatenate([left, second], axis=-1)], axis=-2)
+        size *= 2
+    n = inv.shape[-3]
+    out = [inv[..., 0, :, :]]                  # the block rows, each [..., s, (i + 1) s]
+    for i in range(1, n):
+        above = jnp.concatenate(
+            [jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, i * size - row.shape[-1])])
+             for row in out], axis=-2)         # T's first i block rows, [..., i s, i s]
+        left = -mm(inv[..., i, :, :], mm(a[..., i * size:(i + 1) * size, :i * size], above))
+        out.append(jnp.concatenate([left, inv[..., i, :, :]], axis=-1))
+    return jnp.concatenate(
+        [jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, c - row.shape[-1])]) for row in out],
+        axis=-2)
+
+
+def _chunk(state, q, k, v, g, beta):
+    """One chunk: (``state`` [B, H, K, V] float32, the chunk's ``q``, ``k``
+    [B, H, C, K], ``v`` [B, H, C, V], ``g`` [B, H, C, K] float32, ``beta`` [B,
+    H, C] float32) -> (the state after it, ``o`` [B, H, C, V] in ``q``'s type)."""
+    cd, f32 = q.dtype, jnp.float32
+    b, h, c, kw = q.shape
+    rows = sub_rows(c)
+    n = c // rows
+    dot = lambda spec, x, y: jnp.einsum(  # noqa: E731
+        spec, x.astype(cd), y.astype(cd), preferred_element_type=f32)
+    run = jnp.cumsum(g, axis=-2)                                   # G: [B, H, C, K]
+    qf, kf = q.astype(f32), k.astype(f32)
+    by_block = lambda x: x.reshape(b, h, n, rows, *x.shape[3:])    # noqa: E731
+    run_b, q_b, k_b = by_block(run), by_block(qf), by_block(kf)
+    first = run_b[:, :, :, 0]                                      # a sub-block's first row
+
+    # pairs in two sub-blocks: down to the later one's first row, up from it
+    down = jnp.exp(run_b - first[:, :, :, None])                   # exp(G_i - G_first) <= 1
+    up = jnp.exp(jnp.minimum(first[:, :, :, None] - run[:, :, None], 0.0))   # [B, H, n, C, K]
+    across = dot("bhnrk,bhnjk->bhnrj", jnp.concatenate([k_b * down, q_b * down], axis=3),
+                 kf[:, :, None] * up)                              # [B, H, n, 2R, C]
+    earlier = (jnp.arange(c)[None, :] // rows) < jnp.arange(n)[:, None]   # j's block before i's
+    across = jnp.where(earlier[:, None, :], across, 0.0)
+    # pairs in one sub-block: the difference before the exponential
+    same = jnp.tril(jnp.ones((rows, rows), bool))                  # s <= r
+    decay = jnp.exp(jnp.where(same[..., None],
+                              run_b[:, :, :, :, None] - run_b[:, :, :, None, :], -jnp.inf))
+    kk = jnp.sum(k_b[:, :, :, :, None] * k_b[:, :, :, None, :] * decay, axis=-1)   # [B, H, n, R, R]
+    qk = jnp.sum(q_b[:, :, :, :, None] * k_b[:, :, :, None, :] * decay, axis=-1)
+    own = jnp.eye(n, dtype=f32)                                    # a sub-block's own columns
+    place = lambda d: jnp.einsum("bhnrs,nm->bhnrms", d, own).reshape(b, h, c, c)  # noqa: E731
+    strict = jnp.tril(jnp.ones((rows, rows), f32), -1)
+    a = beta[..., None] * (across[:, :, :, :rows].reshape(b, h, c, c) + place(kk * strict))
+    scores = across[:, :, :, rows:].reshape(b, h, c, c) + place(qk)
+
+    t = _unit_lower_inverse(a)
+    decayed = jnp.exp(run)                                         # exp(G_i) <= 1
+    wu = dot("bhij,bhjx->bhix", t,
+             jnp.concatenate([kf * decayed, v.astype(f32)], axis=-1) * beta[..., None])
+    w, u = wu[..., :kw], wu[..., kw:]
+    moved = u - dot("bhik,bhkv->bhiv", w, state)                   # V'
+    o = dot("bhik,bhkv->bhiv", qf * decayed, state) + dot("bhij,bhjv->bhiv", scores, moved)
+    to_end = jnp.exp(run[:, :, -1:] - run)                         # exp(G_end - G_j) <= 1
+    state = decayed[:, :, -1, :, None] * state + dot("bhjk,bhjv->bhkv", kf * to_end, moved)
+    return state, o.astype(cd)
+
+
+def _walk(q, k, v, g, beta, keep: bool):
+    """(o [chunks, B, H, C, V]; with ``keep`` each chunk's incoming state,
+    [chunks, B, H, K, V], else None)."""
+    with part("delta_scan"):
+        first = jnp.zeros((*q.shape[1:3], q.shape[-1], v.shape[-1]), jnp.float32)
+
+        def body(state, chunk):
+            after, o = _chunk(state, *chunk)
+            return after, (o, state if keep else None)
+
+        return jax.lax.scan(body, first, (q, k, v, g, beta))[1]
+
+
+@jax.custom_vjp
+def delta_chunks(q, k, v, g, beta):
+    """``o`` [chunks, B, H, C, V] in ``q``'s type of the recurrence above, over
+    a sequence already cut in chunks, zeros past its end.
+
+    ``q``, ``k`` [chunks, B, H, C, K]; ``v`` [chunks, B, H, C, V]; ``g``
+    [chunks, B, H, C, K] float32, at most 0; ``beta`` [chunks, B, H, C]
+    float32."""
+    return _walk(q, k, v, g, beta, keep=False)[0]
+
+
+def _delta_fwd(q, k, v, g, beta):
+    o, states = _walk(q, k, v, g, beta, keep=True)
+    return o, (q, k, v, g, beta, states)
+
+
+def _delta_bwd(kept, do):
+    """The cotangents of ``delta_chunks``' inputs from ``do``, all cut."""
+    *inputs, states = kept
+    with part("delta_scan"):
+        def body(d_after, chunk):
+            state, *own, doc = chunk
+            _, pull = jax.vjp(_chunk, state, *own)             # the chunk, computed again
+            d_state, *d_own = pull((d_after, doc))
+            return d_state, tuple(d_own)
+
+        _, d_inputs = jax.lax.scan(body, jnp.zeros_like(states[0]), (states, *inputs, do),
+                                   reverse=True)
+        return d_inputs
+
+
+delta_chunks.defvjp(_delta_fwd, _delta_bwd)
+
+
+def chunked_delta(q, k, v, g, beta, chunk: int):
+    """``delta_chunks`` for a caller that holds a head's tokens uncut: ``q``,
+    ``k`` [B, H, T, K], ``v`` [B, H, T, V], ``g`` [B, H, T, K] float32,
+    ``beta`` [B, H, T] float32 -> ``o`` [B, H, T, V].  The cut pads with
+    zeros, which is ``beta = 0`` and ``g = 0``."""
+    with part("delta_scan"):
+        by_chunk = lambda x: cut(jnp.moveaxis(x, 2, 1), chunk)  # noqa: E731  [chunks, B, C, H, ..]
+        heads_first = lambda x: jnp.moveaxis(x, 3, 2)           # noqa: E731  [chunks, B, H, C, ..]
+        o = delta_chunks(*(heads_first(by_chunk(x)) for x in (q, k, v, g, beta)))
+        return jnp.moveaxis(join(jnp.moveaxis(o, 2, 3), q.shape[2]), 1, 2)
+

@@ -84,7 +84,8 @@ def plan(tokens: int, window: Optional[int]) -> Plan:
     pairs outside the mask (1.34 and 1.21 times the pairs in it) and are the
     slowest: a step's reductions over the lanes and its fixed cost weigh
     more than the pairs saved.  The group's size and the head's did not move
-    the choice, so the rule does not read them.  JAX's splash attention, a
+    the choice, so the rule does not read them (G=8, D=128, causal, two
+    key-value heads: the reading of PR 39 is in PERF.md section 6).  JAX's splash attention, a
     query head at a time in 512 x 512 and 896 x 896 blocks over a padded
     length: 6.80 / 24.45 (forward / forward and backward), 5.43 / 18.20 and
     3.76 / 12.22."""

@@ -307,6 +307,7 @@ class AsyncPipeline:
         self._routing: dict = {}
         self._attention: dict = {}    # StepMetrics.attention, likewise
         self._scan: dict = {}         # StepMetrics.scan, likewise
+        self._delta: dict = {}        # StepMetrics.delta, likewise
         # Per-stage wall-clock accumulators (SURVEY §5 tracing subsystem):
         # µs/step per pipeline stage, exported in every metrics emit.
         self.timers = profiling.StageTimer()
@@ -1054,6 +1055,8 @@ class AsyncPipeline:
             out["attention"] = dict(self._attention)
         if self._scan:
             out["scan"] = dict(self._scan)
+        if self._delta:
+            out["delta"] = dict(self._delta)
         return out
 
     def _maybe_eval(self):
@@ -1683,6 +1686,9 @@ class AsyncPipeline:
             if getattr(metrics, "scan", None) is not None:
                 self._scan = {k: float(np.mean(np.asarray(v)))
                               for k, v in metrics.scan.items()}
+            if getattr(metrics, "delta", None) is not None:
+                self._delta = {k: float(np.mean(np.asarray(v)))
+                               for k, v in metrics.delta.items()}
         return self.logger.emit(
             step=self._learner_step,
             actor_steps=self.worker.actor_steps,
@@ -1697,6 +1703,7 @@ class AsyncPipeline:
             **({"routing": self._routing} if self._routing else {}),
             **({"attention": self._attention} if self._attention else {}),
             **({"scan": self._scan} if self._scan else {}),
+            **({"delta": self._delta} if self._delta else {}),
             final=final,
             **self._transport_extra(),
             **self._ckpt_extra(),

@@ -33,6 +33,12 @@ which owns the chip:
             trainer's loop) with 4 thread actors on the learner's chip, 12
             learner steps at batch 2 on a 1,024-slot ring, as the laguna
             leg and for its reason.  Only with --granite
+  solar     configs/config9_solar2_q_ep40.json (the 709 M parameter torso of
+            three gated delta-rule layers to one gated softmax layer, 8 of
+            320 experts and 16 of 64 heads held, over the same history: the
+            chunked delta-rule scan and its backward pass under the
+            trainer's loop) with 4 thread actors, 12 learner steps at batch 2
+            on a 1,024-slot ring, as the laguna leg.  Only with --solar
 
 Sets no platform itself.  Exits non-zero, with one line saying why and no
 result, before compiling anything if jax's default backend is not a TPU, and
@@ -389,6 +395,29 @@ def leg_granite() -> None:
     _train_on_histories("granite", "config8_granite4h_q_l10.json", steps, inspect)
 
 
+def leg_solar() -> None:
+    steps = 12
+
+    def inspect(pipe, final):
+        check_run("solar", pipe, final, steps)
+        assert type(pipe.comps.network).__name__ == "SolarOpen2Q"
+        assert final["param_version"] >= 1, "solar: nothing was published"
+        delta, attention = final.get("delta") or {}, final.get("attention") or {}
+        routing = final.get("routing") or {}
+        assert "scan" not in final, f"solar: state-space counters without such layers: {final}"
+        # batch 2, three forwards, three delta-rule layers, 25 chunks of 64 over 1,568 tokens
+        assert delta.get("chunks") == 2 * 3 * 3 * 25 and 0 < delta.get("tokens", 0) < delta.get(
+            "tokens_padded", 0), f"solar: no delta-rule counters: {final}"
+        assert 0 < attention.get("blocks_visited_full", 0) < attention.get(
+            "blocks_total_full", 0), f"solar: no attention counters: {final}"
+        assert routing.get("held_pairs", 0) > 0, f"solar: no routing counters: {final}"
+        say(f"solar: delta rule a step {delta}; attention a step {attention}; routing a step "
+            f"{routing}; actors adopted param_version {pipe.worker.param_version} of "
+            f"{final['param_version']}")
+
+    _train_on_histories("solar", "config9_solar2_q_ep40.json", steps, inspect)
+
+
 def main() -> int:
     t_start = time.perf_counter()
     try:
@@ -447,6 +476,8 @@ def main() -> int:
         legs = [("laguna", leg_laguna)]
     if "--granite" in sys.argv[1:]:
         legs = [("granite", leg_granite)]
+    if "--solar" in sys.argv[1:]:
+        legs = [("solar", leg_solar)]
     for name, fn in legs:
         t0 = time.perf_counter()
         say(f"leg {name}: starts with bytes_in_use per device "

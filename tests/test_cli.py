@@ -75,7 +75,7 @@ def test_canonical_configs_load_and_validate():
 
     root = os.path.join(os.path.dirname(__file__), "..", "configs")
     paths = sorted(glob.glob(os.path.join(root, "*.json")))
-    assert len(paths) == 9, paths
+    assert len(paths) == 10, paths
     cfgs = {os.path.basename(p): load_config(p) for p in paths}
     assert cfgs["config1_pong_1actor.json"].actor.num_actors == 1
     c6 = cfgs["config6_lfm2moe_q_ep8.json"]
@@ -88,6 +88,9 @@ def test_canonical_configs_load_and_validate():
     c8 = cfgs["config8_granite4h_q_l10.json"]
     assert c8.network == "granite_hybrid" and c8.torso["mamba_d_state"] == 128
     assert c8.env.frame_stack == 32 and c8.learner.replay_sample_size == 8
+    c9 = cfgs["config9_solar2_q_ep40.json"]
+    assert c9.network == "solar_open2" and c9.torso["heads_held"] == [0, 16]
+    assert c9.env.frame_stack == 32 and c9.learner.replay_sample_size == 8
     assert cfgs["config2_breakout_8actors.json"].actor.num_actors == 8
     c3 = cfgs["config3_seaquest_256actors_2m.json"]
     assert c3.replay.capacity == 2_000_000

@@ -532,15 +532,10 @@ def test_the_history_torsos_fused_program_fits_the_chip(topo, no_compile_cache, 
     assert mem.temp_size_in_bytes <= 8_334_013_440, mem.temp_size_in_bytes
 
 
-def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cache, monkeypatch):
-    """``benchmark/configs/granite4h_q_l10.json``'s fused program at the cell's
-    shapes (749 M parameters, B=8, 1,568 tokens in 7 chunks of 256, the
-    4,096-slot ring): state, ring and temporaries leave over 0.5 GB of a v5e
-    (under that the configuration's batch would have to be halved); no part
-    of the ring is copied; the scan is a loop over chunks in the executable,
-    forward and backward, that builds nothing of all seven chunks' ``[256,
-    256]`` a head at once; the attention kernels compile at heads of 64, once:
-    one attention layer."""
+def _cell_fused_program(topo, monkeypatch, name: str, parameters: int):
+    """(``benchmark/configs/<name>.json``, the ring's stored observations, its
+    fused program compiled for v5e at the cell's shapes); the network holds
+    ``parameters``."""
     from ape_x_dqn_tpu.learner.train_step import (
         build_train_step, init_train_state, make_optimizer,
     )
@@ -548,9 +543,9 @@ def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cac
     from ape_x_dqn_tpu.ops.pallas import blocked_attention
 
     monkeypatch.setattr(blocked_attention, "INTERPRET", False)   # this process sees the CPU
-    monkeypatch.setitem(globals(), "COMPILE_LIMIT_S", 900.0)     # about 55 s alone
+    monkeypatch.setitem(globals(), "COMPILE_LIMIT_S", 900.0)     # one to four minutes alone
     cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
-                      / "configs" / "granite4h_q_l10.json").read_text())
+                      / "configs" / f"{name}.json").read_text())
     prec = cfg["precision"]
     net = build_network(cfg["network"], cfg["num_actions"], torso=cfg,
                         channels=tuple(cfg["channels"]), hidden=cfg["hidden"],
@@ -571,12 +566,24 @@ def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cac
         lambda k: init_train_state(net, opt, k, jnp.zeros((1, *obs), jnp.uint8),
                                    target_dtype=jnp.dtype(prec["target_params"])),
         jax.random.PRNGKey(0)), dev)
-    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(state.params)) == 748_781_171
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(state.params)) == parameters
     frames = int(cfg["replay_capacity"] * cfg["frame_ratio"])
     ring = _with(jax.eval_shape(lambda: init_dedup_device_replay(
         cfg["replay_capacity"], obs, frame_capacity=frames)), dev)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev)
-    compiled = _compile(fused, (state, ring, 0.4, key))
+    return cfg, frames, _compile(fused, (state, ring, 0.4, key))
+
+
+def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cache, monkeypatch):
+    """``benchmark/configs/granite4h_q_l10.json``'s fused program at the cell's
+    shapes (749 M parameters, B=8, 1,568 tokens in 7 chunks of 256, the
+    4,096-slot ring): state, ring and temporaries leave over 0.5 GB of a v5e
+    (under that the configuration's batch would have to be halved); no part
+    of the ring is copied; the scan is a loop over chunks in the executable,
+    forward and backward, that builds nothing of all seven chunks' ``[256,
+    256]`` a head at once; the attention kernels compile at heads of 64, once:
+    one attention layer."""
+    _, frames, compiled = _cell_fused_program(topo, monkeypatch, "granite4h_q_l10", 748_781_171)
     text = compiled.as_text()
     mem = compiled.memory_analysis()
     hbm = 16_909_336_064                      # a v5e's, PERF.md Open question 11
@@ -596,6 +603,39 @@ def test_the_state_space_torsos_fused_program_fits_the_chip(topo, no_compile_cac
     assert "attn_fwd_lse" in kernels
     assert [kernels.count(k) for k in ("attn_dq", "attn_dkv")] == [1, 1], kernels
     assert "splash" not in text
+
+
+@pytest.mark.slow    # 130-220 s on six workers: the suite's time limit has no room for a third such compile
+def test_the_delta_rule_torsos_fused_program_fits_the_chip(topo, no_compile_cache, monkeypatch):
+    """``benchmark/configs/solar2_q_ep40.json``'s fused program at the cell's
+    shapes (709 M parameters: 16 of 64 heads and 8 of 320 experts a layer,
+    B=8, 1,568 tokens in 25 chunks of 64, the 4,096-slot ring): state, ring
+    and temporaries leave over 0.5 GB of a v5e (under that the configuration's
+    batch would have to be halved); no part of the ring is copied; the
+    delta-rule scan is a loop over chunks in the executable that builds
+    nothing of all 25 chunks' ``[64, 64]`` a head at once; the attention
+    kernels compile once, at 8 query heads a key-value head."""
+    _, frames, compiled = _cell_fused_program(topo, monkeypatch, "solar2_q_ep40", 708_979_043)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    print(f"solar2_q_ep40 fused program for v5e: arguments {mem.argument_size_in_bytes} B, "
+          f"temporaries {mem.temp_size_in_bytes} B, code {mem.generated_code_size_in_bytes} B")
+    hbm = 16_909_336_064                      # a v5e's, PERF.md Open question 11
+    assert hbm - mem.argument_size_in_bytes - mem.temp_size_in_bytes > 0.5e9, mem
+    # PR 39: 8,690,472,960 (10,155,860,480 with the scan's residuals kept through the expert
+    # layer's backward pass: 86 MB over the chip)
+    assert mem.temp_size_in_bytes <= 8_690_472_960, mem
+    ring_bytes = frames * 56448 * 4
+    assert_ring_stays_put(text, ring_bytes, 0)
+    assert "mini-gather" not in text
+    # one chunk's pair scores and (I + A)^-1 a head, never 25 chunks' at once
+    per_chunk = [dims for _, dims, _ in _ARRAY.findall(text) if dims.endswith("64,64")]
+    assert per_chunk and not any(
+        int(np.prod([int(d) for d in dims.split(",")])) > 2 * 8 * 16 * 64 * 64 for dims in per_chunk), \
+        sorted(set(per_chunk))
+    kernels = re.findall(r"%(attn_\w+?)[.\d]* = ", text)
+    assert "attn_fwd_lse" in kernels
+    assert [kernels.count(k) for k in ("attn_dq", "attn_dkv")] == [1, 1], kernels
 
 
 # ------------------------------- what a Mamba-2 mixer passes around its scan

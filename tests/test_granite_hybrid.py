@@ -427,7 +427,7 @@ def test_a_spec_without_experts():
 
 
 def test_the_scan_is_scoped_inside_the_mixer():
-    assert profiling.PARTS[9:] == ("ssm_scan",)
+    assert profiling.PARTS[9] == "ssm_scan"
     net = small_net()
     x = obs(jax.random.PRNGKey(8))
     params = net.init(jax.random.PRNGKey(9), x)
@@ -445,7 +445,7 @@ def test_the_scan_is_scoped_inside_the_mixer():
 
 
 def test_config_carries_the_torso_and_the_committed_file_is_the_cells():
-    assert TORSO_NETWORKS[-1] == "granite_hybrid" and HISTORY_NETWORKS == ("laguna_moe", "granite_hybrid")
+    assert TORSO_NETWORKS[2] == "granite_hybrid" and HISTORY_NETWORKS[:2] == ("laguna_moe", "granite_hybrid")
     assert tuple(dueling.TORSO_KINDS) == TORSO_NETWORKS
     cfg = ApexConfig()
     cfg.network = "granite_hybrid"
