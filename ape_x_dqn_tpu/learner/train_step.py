@@ -22,6 +22,7 @@ params/opt-state update in place in HBM.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -177,6 +178,68 @@ def make_optimizer(
     return opt
 
 
+def rows_gathered_dense(axis: str):
+    """A ``flax.linen.intercept_methods`` interceptor for the sharded step's
+    differentiated forward: a ``nn.Dense`` whose kernel is large beside the
+    batch gets its kernel's gradient from the *gathered rows* instead of
+    from an all-reduce of every shard's own product.
+
+    Under ``shard_map`` jax sums a kernel's gradient over the axis where the
+    backward pass yields it: ``in x out`` elements through a ring, twice.
+    The same sum is ``X^T D`` over all shards' rows: the layer's input ``X``
+    and its output's cotangent ``D`` are ``B x (in + out)`` elements, gathered
+    once, and the product over all B rows accumulates in float32 what the
+    all-reduce summed in the compute type after each shard had rounded its
+    own.  A layer gathers when that moves fewer elements (``B (in + out) <
+    2 in out``, both in the layer's compute type): the dueling net's two 3136
+    x 512 streams at a global batch of 512, not its heads.  The rule reads the
+    layer's shapes, never its name; every other gradient is summed where jax's
+    transpose sums it, as before.
+    """
+    contract_rows = (((0,), (0,)), ((), ()))
+
+    @jax.custom_vjp
+    def dot(x, w):
+        return jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())))
+
+    def fwd(x, w):
+        return dot(x, w), (x, w)
+
+    def bwd(res, g):
+        x, w = res
+        # ``to="reduced"``: every shard holds all rows, and what is computed
+        # from them alone is typed the same on every shard, as the kernel is.
+        rows = lambda a: jax.lax.all_gather(a, axis, axis=0, tiled=True, to="reduced")  # noqa: E731
+        dx = jax.lax.dot_general(g, w, (((1,), (1,)), ((), ())))
+        dw = jax.lax.dot_general(rows(x), rows(g), contract_rows,
+                                 preferred_element_type=jnp.float32)
+        return dx, dw.astype(w.dtype)
+
+    dot.defvjp(fwd, bwd)
+
+    def dot_general(lhs, rhs, dimension_numbers, precision=None, **kwargs):
+        rows, (n_in, n_out) = lhs.shape[0] * jax.lax.axis_size(axis), rhs.shape
+        if (lhs.ndim != 2 or precision is not None or kwargs
+                or rows * (n_in + n_out) >= 2 * n_in * n_out):
+            return jax.lax.dot_general(lhs, rhs, dimension_numbers, precision=precision, **kwargs)
+        return dot(lhs, rhs)
+
+    def interceptor(next_fun, args, kwargs, context):
+        layer = context.module
+        if not (isinstance(layer, nn.Dense) and context.method_name == "__call__"
+                and layer.dot_general is None and layer.dot_general_cls is None):
+            return next_fun(*args, **kwargs)
+        # ``dot_general`` is the hook ``nn.Dense`` has for its product; the
+        # bound layer is frozen, and is given back as it was
+        object.__setattr__(layer, "dot_general", dot_general)
+        try:
+            return next_fun(*args, **kwargs)
+        finally:
+            object.__setattr__(layer, "dot_general", None)
+
+    return interceptor
+
+
 @launch_span("train_state")
 def init_train_state(
     network: nn.Module,
@@ -251,11 +314,23 @@ def build_train_step(
 
     ``grad_reduce_axis``: set to a mesh axis name when the step runs inside
     ``shard_map`` with the batch sharded over that axis (the sharded fused
-    learner, replay/device_dp.py) — gradients and scalar metrics all-reduce
-    over it explicitly (``pmean``/``pmax`` over ICI), making the optimizer
-    update identical on every shard.  Under plain ``jit``/pjit leave it
-    ``None``: XLA's SPMD partitioner inserts the all-reduce itself from the
-    batch sharding (parallel/dp.py).  Per-row priorities stay per-shard.
+    learners, replay/device_dp.py and replay/device_dedup_dp.py).  Where the
+    gradients are summed over the axis, and in what order: the parameters
+    enter unvarying and the batch varying, so jax's transpose sums each
+    leaf's gradient where the backward pass yields it (head first, stem
+    last), in the type the layer computed in, and XLA joins those sums into
+    one all-reduce per type at the end of the backward pass.  One kind of
+    leaf is kept out of it: the kernel of a ``nn.Dense`` that is large beside
+    the batch takes its gradient from the rows gathered over the axis
+    (``rows_gathered_dense``: the layer's input and its output's cotangent,
+    one product over all the rows, accumulated in float32), which is the same
+    sum and moves fewer bytes.  The sums are divided by the axis extent in one
+    place, below, to the global batch mean; the clip sees the reduced
+    gradient; the update is identical on every shard.  Scalar metrics are
+    ``pmean``/``pmax``ed; per-row priorities stay per-shard.  Under plain
+    ``jit``/pjit leave it ``None``: XLA's SPMD partitioner inserts the
+    all-reduce itself from the batch sharding (parallel/dp.py), and the step
+    holds no collective and no layer with a backward pass of its own.
     """
 
     # A network whose layers sow counts (types.ROUTING) reads them itself:
@@ -267,9 +342,13 @@ def build_train_step(
     scan_metrics = getattr(network, "scan_metrics", None)
     delta_metrics = getattr(network, "delta_metrics", None)
 
-    def q_of(params, obs):
+    gathered = None if grad_reduce_axis is None else rows_gathered_dense(grad_reduce_axis)
+
+    def q_of(params, obs, differentiated: bool = False):
         """(Q, what the network's layers sowed)."""
-        out, sown = network.apply(params, obs, mutable=[ROUTING])
+        with (nn.intercept_methods(gathered) if differentiated and gathered
+              else contextlib.nullcontext()):
+            out, sown = network.apply(params, obs, mutable=[ROUTING])
         return out[2], sown
 
     def loss_fn(params, target_params, batch: PrioritizedBatch):
@@ -277,7 +356,7 @@ def build_train_step(
         # the backward pass's transpose(jvp(stage:forward)).
         with stage("forward"):
             t = batch.transition
-            q_values, s1 = q_of(params, t.obs)
+            q_values, s1 = q_of(params, t.obs, differentiated=True)
             # The bootstrap's online forward runs apart from the
             # differentiated one.  next_obs reaches the loss through an argmax
             # only: joined with obs in one 2B forward, its B rows ride through
@@ -313,11 +392,12 @@ def build_train_step(
         # gradient all-reduce over ICI automatically.  Inside shard_map
         # (varying-axes AD semantics): the params enter unvarying while the
         # batch is varying, so jax's transpose ALREADY psums the param
-        # cotangents over the axis — grads arrive as Σ_shards(local-mean
-        # grads).  Dividing by the axis extent yields the global batch mean
-        # (equal-size shards); an explicit pmean here would double-count
-        # (measured: exactly n× updates).  The scalar loss is still
-        # per-shard varying and needs a real pmean for reporting.
+        # cotangents over the axis, and a gathered layer's product covers
+        # every shard's rows: grads arrive as Σ_shards(local-mean grads).
+        # Dividing by the axis extent, here and nowhere else, yields the
+        # global batch mean (equal-size shards); an explicit pmean here would
+        # double-count (measured: exactly n× updates).  The scalar loss is
+        # still per-shard varying and needs a real pmean for reporting.
         with stage("optimizer"):
             if grad_reduce_axis is not None:
                 n_sh = jax.lax.psum(1, grad_reduce_axis)

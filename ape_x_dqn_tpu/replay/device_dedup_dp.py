@@ -167,8 +167,10 @@ def build_sharded_dedup_fused_learn_step(
     jit: bool = True,
 ):
     """The sharded dedup twin of ``device_dp.build_sharded_fused_learn_step``
-    — same contract (global batch, per-shard B/n sampling, grad all-reduce
-    inside the scan via ``grad_reduce_axis="data"``), dedup gather."""
+    — same contract (global batch, per-shard B/n sampling, the gradients
+    summed over the shards inside the scan by a step built with
+    ``grad_reduce_axis="data"``: where and in what order is
+    ``build_train_step``'s to say), dedup gather."""
     n = mesh.shape[_AXIS]
     if batch_size % n:
         raise ValueError(

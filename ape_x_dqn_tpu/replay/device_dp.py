@@ -153,10 +153,13 @@ def build_sharded_fused_learn_step(
     excluded — the runtime ingests on its own clock via the sharded add).
 
     Args mirror the unsharded builder; ``train_step_fn`` must be built with
-    ``grad_reduce_axis="data"`` and ``sync_in_step=False`` so the gradient
-    all-reduce happens inside the scan body and the target sync hoists to
-    the call boundary.  ``batch_size`` is the GLOBAL batch; each shard
-    samples ``batch_size / n`` rows from its own ring.
+    ``grad_reduce_axis="data"`` and ``sync_in_step=False`` so the gradients
+    are summed over the shards inside the scan body, in the step that
+    applies them (``build_train_step`` says where and in what order: jax's
+    own sums as the backward pass yields each leaf, a large dense kernel's
+    from the gathered rows instead), and the target sync hoists to the call
+    boundary.  ``batch_size`` is the GLOBAL batch; each shard samples
+    ``batch_size / n`` rows from its own ring.
 
     Returns ``fn(train_state, replay_state, beta, rng) -> (train_state,
     replay_state, metrics)``; metrics leaves are stacked [K, ...] with

@@ -308,6 +308,80 @@ def hlo_parts(hlo_text: str) -> Dict[str, str]:
     return out
 
 
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_RESULT = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(")
+_HLO_ARRAY = re.compile(r"\b(pred|[a-z]+\d+)\[([\d,]*)\]")
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_ASYNC_FUSION_DONE = "async-collective-done"  # the TPU compiler's fused form
+
+
+def _hlo_bytes(shape_text: str) -> int:
+    """Bytes of every array in an instruction's result shape."""
+    total = 0
+    for dtype, dims in _HLO_ARRAY.findall(shape_text):
+        bits = 8 if dtype == "pred" else int(re.sub(r"\D", "", dtype))
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        total += n * bits // 8
+    return total
+
+
+def hlo_collectives(hlo_text: str) -> Dict[str, Dict[str, Dict[str, int]]]:
+    """The collectives of an executable's HLO text by kind and by how they
+    run: ``{kind: {"sync": {"count", "bytes"}, "async": {"count", "bytes"}}}``
+    for the kinds in ``COLLECTIVES`` the text holds.  Synchronous: an
+    instruction with the kind as its opcode, outside any fusion's computation
+    (nothing else runs while it does).  Asynchronous: a ``<kind>-start`` /
+    ``<kind>-done`` pair, or the TPU compiler's fused form of one, fusions
+    named ``async-collective-start`` / ``async-collective-done`` whose
+    computations hold the kind's steps; a pair is counted at its ``-done``,
+    whose result is the collective's.  Instructions, not executions: one in a
+    loop's body counts once.  Bytes are the result's, per device.  Computed
+    from the text when asked (``chip_smoke.py``'s ``dp4`` leg); no run calls
+    it."""
+    fused = set()   # computations a fusion calls
+    inside = {}     # computation -> the collective kinds it holds
+    rows, name = [], None
+    for line in hlo_text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            name = head.group(1)
+            continue
+        m = _HLO_RESULT.match(line)
+        if not m:
+            continue
+        instr, shape, op = m.groups()
+        if op == "fusion":
+            called = _HLO_CALLS.search(line)
+            if called:
+                fused.add(called.group(1))
+        if op in COLLECTIVES:
+            inside.setdefault(name, set()).add(op)
+        rows.append((name, instr, shape, op, line))
+    out: Dict[str, Dict[str, Dict[str, int]]] = {}
+
+    def count(kind: str, how: str, shape: str) -> None:
+        cell = out.setdefault(kind, {h: {"count": 0, "bytes": 0} for h in ("sync", "async")})
+        cell[how]["count"] += 1
+        cell[how]["bytes"] += _hlo_bytes(shape)
+
+    for computation, instr, shape, op, line in rows:
+        if computation in fused:
+            continue
+        if op in COLLECTIVES:
+            count(op, "sync", shape)
+        elif op.endswith("-done") and op[:-len("-done")] in COLLECTIVES:
+            count(op[:-len("-done")], "async", shape)
+        elif op == "fusion" and instr.startswith(_ASYNC_FUSION_DONE):
+            called = _HLO_CALLS.search(line)
+            for kind in sorted(inside.get(called.group(1) if called else None, ())):
+                count(kind, "async", shape)
+    return out
+
+
 def _merged(intervals) -> list:
     out: list = []
     for s, e in sorted(intervals):

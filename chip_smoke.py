@@ -292,6 +292,18 @@ def leg_dp4() -> None:
             f"{rows.pop()} rows on {[d.id for d in shard_devs]}; params "
             f"replicated on all 4; bytes_in_use per device {used} "
             f"(spread {spread:.1%})")
+        # The fused program's collectives, from its compiled text: the two
+        # streams' gradients come from gathered rows, so no all-reduce carries
+        # a 3136 x 512 kernel (6.4 MB of them in float32, 3.2 in bfloat16).
+        from ape_x_dqn_tpu.utils import profiling
+
+        name = "jit_" + fused._fused.__wrapped__.__name__
+        collectives = profiling.hlo_collectives(profiling.fused_hlo_text(name))
+        say(f"dp4: collectives of {name}: {json.dumps(collectives)}")
+        total = lambda kind: sum(  # noqa: E731
+            how["bytes"] for how in collectives.get(kind, {}).values())
+        assert total("all-gather") > 0 and total("all-reduce") < 1 << 20, (
+            f"dp4: the streams' kernels are still all-reduced: {collectives}")
 
     run_leg("dp4", [
         "--set", "actor.num_actors=64",

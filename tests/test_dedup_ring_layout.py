@@ -335,6 +335,31 @@ def test_no_array_of_k_batches_at_paper_shapes(topo, no_compile_cache, chips):
         assert temp < 500_000_000, temp
 
 
+def test_the_sharded_step_gathers_the_streams_rows_and_reduces_no_kernel(
+        topo, no_compile_cache):
+    """``apex_b512_dp4``'s fused program, a chip of four: the streams'
+    gradients are products over the 512 gathered rows (one gather of the
+    shared input, ``bf16[512,3136]``, XLA joins the two; one of each stream's
+    cotangent, ``bf16[512,512]``), and what is left to all-reduce is the
+    convolutions, the biases and the head, under 0.2 MB.  The parent's
+    ``all-reduce.132`` carried the two ``bf16[3136,512]`` kernels, 6.6 MB a
+    step through the ring twice."""
+    from ape_x_dqn_tpu.utils.profiling import hlo_collectives
+
+    shapes = _paper()
+    jitted, args = _programs(topo, 4, shapes)["fused"]
+    text = _compile_text(jitted, args)
+    found = hlo_collectives(text)
+    rows, hidden = shapes["batch"], shapes["hidden"]
+    reduced = found["all-reduce"]
+    assert reduced["sync"]["bytes"] + reduced["async"]["bytes"] < 200_000, found
+    gathered = found["all-gather"]
+    assert gathered["sync"]["bytes"] + gathered["async"]["bytes"] == \
+        2 * rows * (3136 + 2 * hidden), found
+    assert gathered["sync"]["count"] + gathered["async"]["count"] == 3, found
+    assert not re.search(rf"bf16\[3136,{hidden}\]\S* all-reduce", text)
+
+
 def test_reader_finds_k_batches_gathered_ahead():
     """The reader on the entry computation the parent of PR 33 compiled to:
     a side's rows gathered for all K batches, copied twice, taken apart."""

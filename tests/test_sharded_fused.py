@@ -548,3 +548,107 @@ class TestShardedDedupFetchesInTheStep:
         assert int(t_a.step) == int(t_b.step) == 2 * K
         np.testing.assert_array_equal(
             np.asarray(dd.frames), frames.reshape(n * cf, *obs_shape))
+
+
+class TestRowsGatheredGradient:
+    """``build_train_step(grad_reduce_axis=...)``: a dense layer whose kernel
+    is large beside the batch takes its kernel's gradient from the gathered
+    rows; every gradient is still the sum over the shards that one ``psum``
+    over all leaves gives."""
+
+    OBS, ACTIONS = (84, 84, 4), 5
+
+    @staticmethod
+    def _one_psum_step(net, opt, axis):
+        """The reference: every parameter cast to varying, local gradients,
+        all leaves summed in one ``psum`` and divided by the axis extent."""
+        import optax
+
+        from ape_x_dqn_tpu.learner.train_step import StepMetrics
+        from ape_x_dqn_tpu.ops import losses
+        from ape_x_dqn_tpu.types import TrainState
+
+        def loss_fn(params, target_params, batch):
+            t = batch.transition
+            q = net.apply(params, t.obs)[2]
+            q_next = net.apply(jax.lax.stop_gradient(params), t.next_obs)[2]
+            targets = losses.double_q_target(
+                q_next, net.apply(target_params, t.next_obs)[2], t.reward, t.discount)
+            delta = losses.td_error(q, t.action, targets)
+            return losses.td_loss(delta, batch.is_weights, kind="squared"), (delta, q)
+
+        def step(state, batch):
+            local = jax.lax.pcast(state.params, axis, to="varying")
+            (loss, (delta, q)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                local, state.target_params, batch)
+            n = jax.lax.axis_size(axis)
+            grads = jax.tree_util.tree_map(lambda g: g / n, jax.lax.psum(grads, axis))
+            updates, opt_state = opt.update(grads, state.opt_state, state.params)
+            metrics = StepMetrics(
+                loss=jax.lax.pmean(loss, axis),
+                mean_abs_td=jax.lax.pmean(jnp.mean(jnp.abs(delta)), axis),
+                max_abs_td=jax.lax.pmax(jnp.max(jnp.abs(delta)), axis),
+                priorities=losses.priorities_from_td(delta, 1e-6),
+                mean_q=jax.lax.pmean(jnp.mean(q), axis))
+            return TrainState(
+                params=optax.apply_updates(state.params, updates),
+                target_params=state.target_params, opt_state=opt_state,
+                step=state.step + 1, rng=state.rng), metrics
+
+        return step
+
+    def test_the_gathered_product_is_the_one_psums_sum(self):
+        """Parameters, second moment, loss, priorities and restamped masses
+        after two calls of K = 3 on four shards.  To 1e-6 relative to each
+        leaf's largest value and not to the last bit: the streams' gradient
+        is one product over the 16 gathered rows where the reference adds
+        four products of 4 rows, so float32 rounds in another order.  SGD,
+        which is linear in the gradient: RMSProp's first steps are its sign,
+        and a last-bit difference at a gradient near zero flips one."""
+        import optax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from ape_x_dqn_tpu.models.dueling import build_network
+
+        n, C_local, K, B = 4, 16, 3, 16
+        mesh = make_mesh(num_devices=n)
+        net = build_network("nature", self.ACTIONS, channels=(4, 4, 4), hidden=128,
+                            compute_dtype=jnp.float32)
+        opt = optax.chain(optax.clip_by_global_norm(10.0), optax.sgd(1e-2))
+        t0 = init_train_state(net, opt, jax.random.PRNGKey(0),
+                              jnp.zeros((1, *self.OBS), jnp.uint8))
+        step = build_train_step(net, opt, loss_kind="squared", sync_in_step=False,
+                                grad_reduce_axis="data", jit=False)
+        r = np.random.default_rng(9)
+        C = n * C_local
+        ring = init_sharded_device_replay(C, self.OBS, mesh)
+        ring = build_sharded_replay_add(mesh)(
+            ring, jax.device_put(np_chunk(C, self.OBS, seed=3)), jnp.ones(C))
+        mass = jnp.asarray(r.integers(1, 30, C), jnp.float32)
+
+        def run(step_fn):
+            fused = build_sharded_fused_learn_step(
+                step_fn, mesh, B, steps_per_call=K, target_sync_freq=None)
+            state = jax.jit(lambda s: s, out_shardings=NamedSharding(mesh, P()))(t0)
+            replay = jax.tree_util.tree_map(jnp.copy, ring).replace(
+                mass=jax.device_put(mass, ring.mass.sharding))
+            out = []
+            for call in range(2):
+                state, replay, metrics = fused(state, replay, 0.4, jax.random.PRNGKey(call))
+                out.append((metrics.loss, metrics.priorities))
+            return jax.device_get((state.params, state.opt_state, out, replay.mass))
+
+        # the streams gather (4 x 4 rows x (196 + 128) < 2 x 196 x 128), the heads do not
+        fused = build_sharded_fused_learn_step(step, mesh, B, steps_per_call=K,
+                                               target_sync_freq=None, jit=False)
+        gathers = str(jax.make_jaxpr(fused)(
+            t0, ring, 0.4, jax.random.PRNGKey(0))).count("all_gather_reduced")
+        assert gathers == 4, gathers  # the input and the cotangent of two streams
+
+        gathered, reference = run(step), run(self._one_psum_step(net, opt, "data"))
+        for a, b in zip(jax.tree_util.tree_leaves(gathered),
+                        jax.tree_util.tree_leaves(reference)):
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-6 * max(1e-30, np.max(np.abs(b))))
+        moved = jax.tree_util.tree_map(
+            lambda a, b: float(np.max(np.abs(a - np.asarray(b)))), gathered[0], t0.params)
+        assert min(jax.tree_util.tree_leaves(moved)) > 0, moved

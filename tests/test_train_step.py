@@ -313,3 +313,150 @@ def test_no_product_covers_two_batches_at_the_cells_shapes(config, rows, tokens)
     seen = product_rows(jax.make_jaxpr(step)(state, batch).jaxpr)
     assert rows in seen and rows * tokens in seen
     assert not seen & {2 * rows, 2 * rows * tokens}, sorted(seen)
+
+
+# ------------------------------------ the sharded step's gradient reduction
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _count(jaxpr, name: str) -> int:
+    return sum(eqn.primitive.name == name for eqn in _eqns(jaxpr))
+
+
+@pytest.mark.parametrize("rows_a_shard,n_in,n_out,gathers", [
+    (128, 3136, 512, True),    # apex_b512_dp4's streams: 512 x 3,648 < 2 x 1,605,632
+    (128, 512, 18, False),     # its advantage head: the kernel is the smaller
+    (4, 196, 128, True),
+    (1024, 3136, 512, False),  # a batch of 4,096: the rows are the larger
+], ids=["streams", "head", "small_streams", "large_batch"])
+def test_a_dense_layer_gathers_its_rows_when_that_moves_less(rows_a_shard, n_in, n_out, gathers):
+    """The rule of ``rows_gathered_dense`` on a layer's shapes, traced on four
+    shards (abstract, nothing computed): the kernel's gradient from the
+    gathered input and cotangent, two gathers and no sum over the axis, or
+    jax's own sum of every shard's product."""
+    from flax import linen as nn
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from ape_x_dqn_tpu.learner.train_step import rows_gathered_dense
+    from ape_x_dqn_tpu.parallel import make_mesh
+
+    layer = nn.Dense(n_out, use_bias=False, dtype=jnp.bfloat16)
+
+    def grad(params, x):
+        def loss(p):
+            with nn.intercept_methods(rows_gathered_dense("data")):
+                return jnp.sum(layer.apply(p, x).astype(jnp.float32) ** 2)
+        return jax.grad(loss)(params)
+
+    fn = shard_map(grad, mesh=make_mesh(4), in_specs=(P(), P("data")), out_specs=P())
+    params = {"params": {"kernel": jax.ShapeDtypeStruct((n_in, n_out), jnp.float32)}}
+    x = jax.ShapeDtypeStruct((4 * rows_a_shard, n_in), jnp.float32)
+    jaxpr = jax.make_jaxpr(fn)(params, x).jaxpr
+    assert _count(jaxpr, "all_gather_reduced") == (2 if gathers else 0)
+    assert _count(jaxpr, "psum_invariant") == (0 if gathers else 1)
+    assert layer.dot_general is None  # the interceptor gave the layer back as it was
+
+
+def test_the_rule_reads_shapes_not_names():
+    """Another network under the axis, the dueling MLP at 64 -> 256 -> 256:
+    its two hidden layers gather (32 rows x 320 and x 512 against 2 x 16,384
+    and 2 x 65,536), its two heads (256 -> 1, 256 -> 5) keep jax's sum, and so
+    do all four biases."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from ape_x_dqn_tpu.parallel import make_mesh
+
+    net = DuelingMLP(num_actions=5, hidden_sizes=(256, 256))
+    opt = make_optimizer("rmsprop", learning_rate=1e-3)
+    state = jax.eval_shape(
+        lambda k: init_train_state(net, opt, k, jnp.zeros((1, 64))), jax.random.PRNGKey(0))
+    rows = 32
+    obs = jax.ShapeDtypeStruct((rows, 64), jnp.float32)
+    vec = lambda dt: jax.ShapeDtypeStruct((rows,), dt)  # noqa: E731
+    batch = PrioritizedBatch(
+        transition=NStepTransition(obs=obs, action=vec(jnp.int32), reward=vec(jnp.float32),
+                                   discount=vec(jnp.float32), next_obs=obs),
+        indices=vec(jnp.int32), is_weights=vec(jnp.float32))
+    step = build_train_step(net, opt, loss_kind="squared", sync_in_step=False,
+                            grad_reduce_axis="data", jit=False)
+    fn = shard_map(lambda s, b: step(s, b)[0].params, mesh=make_mesh(4),
+                   in_specs=(P(), P("data")), out_specs=P())
+    jaxpr = jax.make_jaxpr(fn)(state, batch).jaxpr
+    assert len(jax.tree_util.tree_leaves(state.params)) == 8
+    assert _count(jaxpr, "all_gather_reduced") == 4
+    # six leaves, and the means of the loss, of |TD| and of Q
+    assert _count(jaxpr, "psum_invariant") == (8 - 2) + 3
+
+
+def test_a_step_with_no_axis_holds_no_reduction():
+    """``grad_reduce_axis=None``: the one-chip cells' step is the parent's,
+    no cast to varying, no sum or gather over an axis, no product with a
+    backward pass of its own."""
+    _, state, step = _setup(jit=False)
+    jaxpr = jax.make_jaxpr(step)(state, _make_batch(jax.random.PRNGKey(1))).jaxpr
+    seen = {eqn.primitive.name for eqn in _eqns(jaxpr)}
+    assert not seen & {"pcast", "pvary", "psum", "psum_invariant", "optimization_barrier",
+                       "all_gather", "all_gather_reduced", "custom_vjp_call"}, seen
+
+
+SYNTHETIC_HLO = """\
+HloModule jit_body, is_scheduled=true
+
+%region_1.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add.1 = f32[] add(%a, %b)
+}
+
+%fused_computation.7 (param_0.1: bf16[3136,512]) -> (bf16[3136,512], u32[]) {
+  %param_0.1 = bf16[3136,512]{1,0} parameter(0)
+  %all-reduce.20 = bf16[3136,512]{1,0} all-reduce(%param_0.1), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_1.1
+  ROOT %custom-call.1 = (bf16[3136,512]{1,0}, u32[]) custom-call(%all-reduce.20)
+}
+
+%fused_computation.8 (param_0.2: bf16[3136,512], param_1.2: u32[]) -> bf16[3136,512] {
+  %param_0.2 = bf16[3136,512]{1,0} parameter(0)
+  %param_1.2 = u32[] parameter(1)
+  %all-reduce.21 = bf16[3136,512]{1,0} all-reduce(%param_0.2), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_1.1
+  ROOT %custom-call.2 = bf16[3136,512]{1,0} custom-call(%all-reduce.21, %param_1.2)
+}
+
+%body (p: (f32[8,8,4,32], bf16[3136,512], f32[512])) -> (f32[8,8,4,32], bf16[3136,512], f32[512]) {
+  %p = (f32[8,8,4,32]{3,2,1,0}, bf16[3136,512]{1,0}, f32[512]{0}) parameter(0)
+  %g0 = f32[8,8,4,32]{3,2,1,0} get-tuple-element(%p), index=0
+  %g1 = bf16[3136,512]{1,0} get-tuple-element(%p), index=1
+  %g2 = f32[512]{0} get-tuple-element(%p), index=2
+  %async-collective-start = (bf16[3136,512]{1,0}, u32[]) fusion(%g1), kind=kCustom, calls=%fused_computation.7
+  %e0 = bf16[3136,512]{1,0} get-tuple-element(%async-collective-start), index=0
+  %e1 = u32[] get-tuple-element(%async-collective-start), index=1
+  %async-collective-done = bf16[3136,512]{1,0} fusion(%e0, %e1), kind=kCustom, calls=%fused_computation.8
+  %all-reduce-start.3 = f32[512]{0} all-reduce-start(%g2), channel_id=2, replica_groups={{0,1,2,3}}, to_apply=%region_1.1
+  %all-reduce-done.3 = f32[512]{0} all-reduce-done(%all-reduce-start.3)
+  %all-reduce.5 = (f32[8,8,4,32]{3,2,1,0:T(4,128)S(1)}, f32[]{:T(128)}) all-reduce(%g0, %s), channel_id=3, replica_groups={{0,1,2,3}}, to_apply=%region_1.1
+  %r0 = f32[8,8,4,32]{3,2,1,0} get-tuple-element(%all-reduce.5), index=0
+  ROOT %out = (f32[8,8,4,32]{3,2,1,0}, bf16[3136,512]{1,0}, f32[512]{0}) tuple(%r0, %async-collective-done, %all-reduce-done.3)
+}
+"""
+
+
+def test_the_collectives_reader_tells_synchronous_from_in_flight():
+    """One synchronous all-reduce (8,192 float32 and a scalar), one
+    ``-start``/``-done`` pair and one pair in the TPU compiler's fused form,
+    whose steps inside the fusions' computations are not counted again."""
+    from ape_x_dqn_tpu.utils.profiling import hlo_collectives
+
+    assert hlo_collectives(SYNTHETIC_HLO) == {"all-reduce": {
+        "sync": {"count": 1, "bytes": 4 * 8 * 8 * 4 * 32 + 4},
+        "async": {"count": 2, "bytes": 2 * 3136 * 512 + 4 * 512}}}
+    assert hlo_collectives("HloModule empty\n") == {}
