@@ -39,9 +39,9 @@ from typing import Any, Optional, Sequence
 
 # Network kinds whose torso is a stack of blocks built from ``ApexConfig.torso``
 # (the keys of models/dueling.TORSO_KINDS).
-TORSO_NETWORKS = ("lfm2_moe", "laguna_moe", "granite_hybrid", "solar_open2")
+TORSO_NETWORKS = ("lfm2_moe", "laguna_moe", "granite_hybrid", "solar_open2", "ling_hybrid")
 # Those whose observation is a history of single frames (``frame_history``).
-HISTORY_NETWORKS = ("laguna_moe", "granite_hybrid", "solar_open2")
+HISTORY_NETWORKS = ("laguna_moe", "granite_hybrid", "solar_open2", "ling_hybrid")
 
 
 @dataclasses.dataclass
@@ -829,7 +829,7 @@ class ApexConfig:
     )
     chaos: ChaosConfig = dataclasses.field(default_factory=ChaosConfig)
     network: str = "conv"   # "conv" | "nature" | "mlp" | one of TORSO_NETWORKS
-    # network=lfm2_moe | laguna_moe | granite_hybrid | solar_open2: the torso's block under
+    # network=<one of TORSO_NETWORKS>: the torso's block under
     # the published config.json's keys plus the cut (spec_from_config of
     # models/<network>.py); optionally the
     # stem's ``channels`` and the head's ``hidden``.

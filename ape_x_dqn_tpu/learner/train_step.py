@@ -382,6 +382,8 @@ def build_train_step(
         add = lambda *trees: jax.tree_util.tree_map(lambda *xs: sum(xs), *trees)  # noqa: E731
         routing = None if routing_metrics is None else add(
             *(routing_metrics(s) for s in (*online, sown_target)))
+        if routing:     # a share is the three forwards' mean, every other counter their sum
+            routing = {k: v / 3.0 if k.endswith("_share") else v for k, v in routing.items()}
         def of_three_forwards(counter):
             counted = counter and counter(batch.transition.obs.shape)
             return {k: jnp.float32(3.0 * v) for k, v in counted.items()} if counted else None

@@ -132,6 +132,8 @@ class TorsoSpec:
     token_multiplier: float = 1.0     # on the projected tokens
     float32_leaves: Tuple[str, ...] = ()   # a family's, beside the router's (TorsoQ)
     heads_held: Optional[Tuple[int, int]] = None   # [lo, hi) of the published heads; None: all
+    router_groups: int = 1            # the router's outputs lie in so many groups of consecutive ones
+    router_groups_kept: int = 1       # and a token chooses among the experts of so many (``route``)
 
     def __post_init__(self):
         lo, hi = self.experts_held
@@ -144,6 +146,12 @@ class TorsoSpec:
                 f"{self.router_outputs} outputs")
         if self.score_function not in SCORES:
             raise ValueError(f"unknown score function {self.score_function!r}; {SCORES}")
+        groups, kept = self.router_groups, self.router_groups_kept
+        if not 1 <= kept <= groups or (groups > 1 and (
+                self.router_outputs % groups
+                or self.num_experts_per_tok > kept * (self.router_outputs // groups))):
+            raise ValueError(f"router_groups {groups}, router_groups_kept {kept}: no choice of "
+                             f"{self.num_experts_per_tok} among {self.router_outputs} outputs")
         ops = tuple(op for op, _ in self.mixers)
         for op, ffn in self.layers:
             if op not in ops or ffn not in FFNS:
@@ -220,11 +228,30 @@ class SwiGLU(nn.Module):
         return (jax.nn.silu(u @ w1.astype(cd)) * (u @ w3.astype(cd))) @ w2.astype(cd)
 
 
-def route(scores, bias, spec: TorsoSpec):
+def groups_kept(biased, spec: TorsoSpec):
+    """[T, groups] bool: the ``router_groups_kept`` groups a token keeps of
+    the ``router_groups`` its biased scores [T, E] lie in (``noaux_tc``): a
+    group's score is the sum of its two largest, the largest groups are
+    kept, the earlier of two equal ones first."""
+    groups = spec.router_groups
+    by_group = biased.reshape(biased.shape[0], groups, -1)
+    score = jnp.sum(jax.lax.top_k(by_group, 2)[0], -1)
+    _, kept = jax.lax.top_k(score, spec.router_groups_kept)
+    return jnp.any(kept[:, :, None] == jnp.arange(groups), axis=1)
+
+
+def route(scores, bias, spec: TorsoSpec, kept=None):
     """(chosen experts [T, k], gates [T, k]) from float32 scores [T, E]:
     the top k of ``scores + bias``, gates the chosen scores normalised over
-    all k (``norm_topk_prob``), times ``routed_scaling_factor``."""
-    _, chosen = jax.lax.top_k(scores + bias, spec.num_experts_per_tok)
+    all k (``norm_topk_prob``), times ``routed_scaling_factor``.  With
+    ``router_groups`` over 1 the k are the largest inside the groups the
+    token keeps (``kept``, default ``groups_kept``'s)."""
+    biased = scores + bias
+    if spec.router_groups > 1:
+        kept = groups_kept(biased, spec) if kept is None else kept
+        biased = jnp.where(jnp.repeat(kept, spec.router_outputs // spec.router_groups, axis=-1),
+                           biased, -jnp.inf)
+    _, chosen = jax.lax.top_k(biased, spec.num_experts_per_tok)
     gates = jnp.take_along_axis(scores, chosen, axis=-1)
     if spec.norm_topk_prob:
         gates = gates / (jnp.sum(gates, -1, keepdims=True) + spec.gate_norm_eps)
@@ -393,7 +420,8 @@ class ExpertShare(nn.Module):
             logits = jnp.dot(u.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST)
             scores = (jax.nn.sigmoid(logits) if sp.score_function == "sigmoid"
                       else jax.nn.softmax(logits, axis=-1))
-            chosen, gates = route(scores, bias, sp)
+            kept = groups_kept(scores + bias, sp) if sp.router_groups > 1 else None
+            chosen, gates = route(scores, bias, sp, kept)
             held = (chosen >= lo) & (chosen < hi)
             load = jnp.sum(jax.nn.one_hot(chosen.reshape(-1), sp.router_outputs,
                                           dtype=jnp.int32), axis=0)
@@ -404,6 +432,10 @@ class ExpertShare(nn.Module):
                          tile_rows(rows, n, sp.router_outputs))
         if not self.is_initializing():  # ``init`` returns parameters alone
             self.sow(ROUTING, "load", load)
+            if kept is not None:    # tokens that keep a group with a held expert in it
+                size = sp.router_outputs // sp.router_groups
+                self.sow(ROUTING, "kept", jnp.sum(jnp.any(
+                    kept[:, lo // size:(hi - 1) // size + 1], axis=-1).astype(jnp.int32)))
         return y.reshape(shape)
 
 
@@ -442,6 +474,13 @@ class Block(nn.Module):
                 y = y + SwiGLU(sp.shared_expert_intermediate_size, cd, pd,
                                name="shared_expert")(u)
         return _added(h, y, m), None
+
+
+def _sown(sown, name: str) -> dict:
+    """{the path of an expert layer: what it sowed under ``name``}."""
+    return {jax.tree_util.keystr(path[:-2]): v
+            for path, v in jax.tree_util.tree_leaves_with_path(sown[ROUTING])
+            if getattr(path[-2], "key", None) == name}
 
 
 class TorsoQ(nn.Module):
@@ -540,14 +579,19 @@ class TorsoQ(nn.Module):
         lo, hi = self.spec.experts_held
         if hi == lo:
             return None
-        loads = jnp.concatenate([v.reshape(-1, v.shape[-1])
-                                 for v in jax.tree_util.tree_leaves(sown[ROUTING])])
+        loads = jnp.concatenate([v.reshape(-1, v.shape[-1]) for v in _sown(sown, "load").values()])
         held = loads[:, lo:hi].astype(jnp.float32)
         tile = tile_rows(jnp.sum(loads, -1), hi - lo, self.spec.router_outputs)
         walked = -(-jnp.sum(loads[:, lo:hi], -1) // tile) * tile
-        return {"held_pairs": jnp.sum(held), "load_max": jnp.sum(jnp.max(held, -1)),
-                "load_mean": jnp.sum(jnp.mean(held, -1)),
-                "rows_walked": jnp.sum(walked).astype(jnp.float32)}
+        out = {"held_pairs": jnp.sum(held), "load_max": jnp.sum(jnp.max(held, -1)),
+               "load_mean": jnp.sum(jnp.mean(held, -1)),
+               "rows_walked": jnp.sum(walked).astype(jnp.float32)}
+        if self.spec.router_groups > 1:
+            # a forward's; the train step takes the mean of a name that ends in ``_share``
+            tokens = jnp.sum(loads, -1) / self.spec.num_experts_per_tok
+            kept = jnp.concatenate([v.reshape(-1) for v in _sown(sown, "kept").values()])
+            out["groups_kept_hold_share"] = jnp.mean(kept / tokens)
+        return out
 
     def rebalanced(self, params, sown):
         """``params`` after the balancing rule (module docstring) on the
@@ -556,8 +600,7 @@ class TorsoQ(nn.Module):
         if not (self.spec.use_expert_bias and self.spec.num_held):
             return params
         # .../moe/load/0 in the collection is .../moe/expert_bias in params
-        loads = {jax.tree_util.keystr(path[:-2]): v.astype(jnp.float32)
-                 for path, v in jax.tree_util.tree_leaves_with_path(sown[ROUTING])}
+        loads = {path: v.astype(jnp.float32) for path, v in _sown(sown, "load").items()}
 
         def leaf(path, x):
             if getattr(path[-1], "key", None) != "expert_bias":

@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Mapping
+from typing import Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -67,9 +67,18 @@ class LinearSizes:
     heads: int                        # published, before any share
     head_dim: int
     conv: int                         # the convolutions' taps
-    gate_rank: int                    # of W_f1, W_g1 (kda_use_full_proj false)
+    gate_rank: Optional[int]          # of W_f1, W_g1 (kda_use_full_proj false); None: W_f, W_g
+    #                                   full rank, the output gate without its bias
     beta_scale: float                 # 2 with kda_allow_neg_eigval
     chunk: int = CHUNK
+    gate: str = "softplus"            # the log decay's rule, one of GATES
+    gate_bound: float = 0.0           # ``bounded``: the log decay lies in (gate_bound, 0)
+
+
+# The log decay a key channel, from f = W_f u + dt_bias and a = exp(A_log):
+# ``softplus``: -a softplus(f);  ``bounded``: gate_bound sigmoid(a f)
+# (``kda_safe_gate`` with ``kda_lower_bound``).
+GATES = ("softplus", "bounded")
 
 
 def held_heads(spec: TorsoSpec, heads: int) -> tuple:
@@ -119,14 +128,22 @@ class DeltaAttention(nn.Module):
         w = {name: self.param(name, _lecun(), (d, n * hd), pd) for name in ("w_q", "w_k", "w_v")}
         conv = {name: self.param(name, _lecun(-1), (n * hd, m.conv), pd)
                 for name in ("conv_q", "conv_k", "conv_v")}
-        w_f1 = self.param("w_f1", _lecun(), (d, r), pd)
-        w_f2 = self.param("w_f2", _lecun(), (r, n * hd), pd)
+        if m.gate not in GATES:
+            raise ValueError(f"unknown gate rule {m.gate!r}; {GATES}")
+        if r is None:
+            w_f = self.param("w_f", _lecun(), (d, n * hd), pd)
+        else:
+            w_f1 = self.param("w_f1", _lecun(), (d, r), pd)
+            w_f2 = self.param("w_f2", _lecun(), (r, n * hd), pd)
         a_log = self.param("A_log", _a_log_init, (n,), f32)
         dt_bias = self.param("dt_bias", _dt_bias_init, (n * hd,), f32)
         w_b = self.param("w_b", _lecun(), (d, n), pd)
-        w_g1 = self.param("w_g1", _lecun(), (d, r), pd)
-        w_g2 = self.param("w_g2", _lecun(), (r, n * hd), pd)
-        b_g = self.param("b_g", _bias_init, (n * hd,), pd)
+        if r is None:
+            w_g, b_g = self.param("w_g", _lecun(), (d, n * hd), pd), None
+        else:
+            w_g1 = self.param("w_g1", _lecun(), (d, r), pd)
+            w_g2 = self.param("w_g2", _lecun(), (r, n * hd), pd)
+            b_g = self.param("b_g", _bias_init, (n * hd,), pd)
         norm = self.param("norm", nn.initializers.ones, (hd,), pd)
         w_o = self.param("w_o", _lecun(), (n * hd, d), pd)
 
@@ -137,15 +154,20 @@ class DeltaAttention(nn.Module):
         def operands(raw, f, b, kernels, a_log, dt_bias):
             q, k, v = (_short_conv(x, kernel) for x, kernel in zip(raw, kernels))
             q, k, v = _l2(q, 1.0 / math.sqrt(hd)).astype(cd), _l2(k).astype(cd), v.astype(cd)
-            g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(
-                f.astype(f32) + dt_bias.reshape(n, 1, hd))
+            if m.gate == "softplus":
+                g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(
+                    f.astype(f32) + dt_bias.reshape(n, 1, hd))
+            else:
+                g = m.gate_bound * jax.nn.sigmoid(
+                    jnp.exp(a_log)[:, None, None] * (f.astype(f32) + dt_bias.reshape(n, 1, hd)))
             return q, k, v, g, m.beta_scale * jax.nn.sigmoid(b.astype(f32))
 
         @jax.checkpoint
         def gated(o, z, b_g, norm):
             o = o.astype(f32)
             o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True) + sp.norm_eps)
-            gate = jax.nn.sigmoid(z.astype(f32) + b_g.astype(f32).reshape(n, 1, hd))
+            z = z.astype(f32)
+            gate = jax.nn.sigmoid(z if b_g is None else z + b_g.astype(f32).reshape(n, 1, hd))
             return (o * norm.astype(f32) * gate).astype(cd)
 
         # And the scan between them keeps each chunk's incoming state and its
@@ -159,9 +181,9 @@ class DeltaAttention(nn.Module):
             return gated(chunked_delta(q, k, v, g, beta, m.chunk), z, b_g, norm)
 
         y = mixed(tuple(_heads_of(u, w["w_" + x], n) for x in "qkv"),
-                  _heads_of(u @ w_f1.astype(cd), w_f2, n),
+                  _heads_of(u, w_f, n) if r is None else _heads_of(u @ w_f1.astype(cd), w_f2, n),
                   jnp.einsum("btd,dn->bnt", u, w_b.astype(cd)),
-                  _heads_of(u @ w_g1.astype(cd), w_g2, n),
+                  _heads_of(u, w_g, n) if r is None else _heads_of(u @ w_g1.astype(cd), w_g2, n),
                   tuple(conv["conv_" + x] for x in "qkv"), a_log, dt_bias, b_g, norm)
         return jnp.einsum("bntk,nkd->btd", y, w_o.astype(cd).reshape(n, hd, d))
 
@@ -261,7 +283,9 @@ def spec_from_config(cfg: Mapping) -> TorsoSpec:
     outputs = int(cfg.get("router_outputs", published.get(
         "n_routed_experts", cfg["n_routed_experts"])))
     if cfg.get("use_rope") or cfg.get("kda_use_full_proj") or int(cfg.get("first_k_dense_replace", 0)):
-        raise ValueError("built here: use_rope false, kda_use_full_proj false, no leading dense layer")
+        raise ValueError("this family's spec: use_rope false, kda_use_full_proj false, no leading "
+                         "dense layer (models/ling_hybrid.py builds full-rank gates and a "
+                         "leading dense layer under a linear mixer)")
     if linear.get("num_kv_heads") not in (None, linear_heads) or linear_heads != heads or heads % kv:
         raise ValueError("the linear layers' keys and values have a head each, the two layer "
                          f"kinds one head count: {linear_heads}, {heads} over {kv}")

@@ -80,7 +80,28 @@ def plan(tokens: int, window: Optional[int]) -> Plan:
     =========  =======================  =======================  =======================
 
     (the rows of 128 keys an earlier draft's, within 6% of the final
-    kernels where both were read).  Blocks of 128 keys compute the fewest
+    kernels where both were read).  Read again with a shared key operand
+    (PR 42: latent attention, 8 query heads each with its own keys, G=1,
+    D=128 beside a shared part of 64, causal; the dk/dv time with the sum of
+    the shared key's gradient over the heads):
+
+    =========  =======================
+    block      G=1, D=128+64, causal
+    =========  =======================
+    128 x 128  3.40 / 3.68 / 4.34 / 4.73
+    128 x 256  2.16 / 2.30 / 2.52 / 3.31
+    128 x 512  1.51 / 1.58 / 2.04 / 2.74
+    256 x 128  2.38 / 2.51 / 3.52 / 2.57
+    256 x 256  1.52 / 1.62 / 1.97 / 1.87
+    256 x 512  1.09 / 1.16 / 1.80 / 1.67
+    512 x 512  1.06 / 1.14 / 1.79 / 1.80
+    =========  =======================
+
+    At G=1 a block of 128 tokens is 128 rows and 256 x 512 reads 28% under
+    128 x 512 over the four kernels (4.30 against 5.97 ms forward and
+    backward); the rule still does not read the group: the one layer that
+    has G=1 is 1.3% of its step, and a plan by group would have to reach the
+    counters too (PERF.md section 6, PR 42).  Blocks of 128 keys compute the fewest
     pairs outside the mask (1.34 and 1.21 times the pairs in it) and are the
     slowest: a step's reductions over the lanes and its fixed cost weigh
     more than the pairs saved.  The group's size and the head's did not move
@@ -202,6 +223,15 @@ def _row(ref):
     return jnp.concatenate([ref[h:h + 1, :] for h in range(ref.shape[0])], 1)
 
 
+def _shared_scores(qs_ref, ks):
+    """The second product of a block's scores: the group's shared query parts
+    ``qs_ref`` [G, bq, S] against the one shared key block ``ks`` [bkv, S] (a
+    ref, or its rows as loaded)."""
+    g, bq, width = qs_ref.shape
+    return jax.lax.dot_general(qs_ref[...].reshape(g * bq, width), ks[...], _NT,
+                               preferred_element_type=_F32)
+
+
 def _run(flag, step):
     """``step(edge, end)`` for what the flags say of the block: wholly inside
     the mask, crossed by an edge, or reaching past the end too."""
@@ -210,8 +240,11 @@ def _run(flag, step):
     pl.when(flag & _END != 0)(lambda: step(True, True))
 
 
-def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *rest,
-                tokens: int, window: Optional[int]):
+def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, *rest,
+                tokens: int, window: Optional[int], shared: bool = False):
+    if shared:
+        qs_ref, ks_ref, *rest = rest
+    o_ref, *rest = rest
     lse_ref = rest[0] if len(rest) == 4 else None
     m_ref, l_ref, acc_ref = rest[-3:]
     g, bq, d = q_ref.shape
@@ -229,6 +262,8 @@ def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *rest,
         q, k, v = q_ref[...].reshape(g * bq, d), k_ref[...], v_ref[...]
         q0, k0 = qi_ref[s] * bq, kj_ref[s] * bkv
         scores = jax.lax.dot_general(q, k, _NT, preferred_element_type=_F32)
+        if shared:
+            scores += _shared_scores(qs_ref, ks_ref)
         if edge:
             scores = jnp.where(_keep(q0, k0, scores.shape, 0, bq, window, None), scores, _MASKED)
         if end:     # keys past the end are masked; what stands in their rows of v is not a number
@@ -254,8 +289,13 @@ def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *rest,
             _to_lanes(m_ref[...] + jnp.log(l), lse_ref)
 
 
-def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-               dq_ref, di_ref, lse_col, di_col, acc_ref, *, tokens: int, window: Optional[int]):
+def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, *rest,
+               tokens: int, window: Optional[int], shared: bool = False):
+    if shared:
+        qs_ref, ks_ref, o_ref, do_ref, lse_ref, dq_ref, dqs_ref, di_ref, *rest = rest
+        lse_col, di_col, acc_ref, acc_s = rest
+    else:
+        o_ref, do_ref, lse_ref, dq_ref, di_ref, lse_col, di_col, acc_ref = rest
     g, bq, d = q_ref.shape
     bkv = k_ref.shape[0]
     s = pl.program_id(2)
@@ -269,6 +309,8 @@ def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse
         _to_lanes(di_col[...], di_ref)
         lse_col[...] = jnp.broadcast_to(_from_lanes(lse_ref), lse_col.shape)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if shared:
+            acc_s[...] = jnp.zeros_like(acc_s)
 
     def step(edge: bool, end: bool):
         q, do = q_ref[...].reshape(g * bq, d), do_ref[...].reshape(g * bq, d)
@@ -277,22 +319,33 @@ def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse
         if end:
             k, v = _own_rows(k, k0, tokens), _own_rows(v, k0, tokens)
         scores = jax.lax.dot_general(q, k, _NT, preferred_element_type=_F32)
+        if shared:
+            ks = _own_rows(ks_ref[...], k0, tokens) if end else ks_ref[...]
+            scores += _shared_scores(qs_ref, ks)
         if edge:
             scores = jnp.where(_keep(q0, k0, scores.shape, 0, bq, window, None), scores, _MASKED)
         p = jnp.exp(scores - _lanes(lse_col[...], bkv))
         dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=_F32)
         ds = p * (dp - _lanes(di_col[...], bkv))
         acc_ref[...] += jnp.dot(ds.astype(k.dtype), k, preferred_element_type=_F32)
+        if shared:
+            acc_s[...] += jnp.dot(ds.astype(ks.dtype), ks, preferred_element_type=_F32)
 
     _run(flag, step)
 
     @pl.when(flag & _LAST != 0)
     def _():
         dq_ref[...] = acc_ref[...].reshape(g, bq, d).astype(dq_ref.dtype)
+        if shared:
+            dqs_ref[...] = acc_s[...].reshape(dqs_ref.shape).astype(dqs_ref.dtype)
 
 
-def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, tokens: int, window: Optional[int]):
+def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, *rest,
+                tokens: int, window: Optional[int], shared: bool = False):
+    if shared:
+        qs_ref, ks_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref, dks_ref, dk_acc, dv_acc, dks_acc = rest
+    else:
+        do_ref, lse_ref, di_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     g, bq, d = q_ref.shape
     bkv = k_ref.shape[0]
     s = pl.program_id(2)
@@ -302,6 +355,8 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+        if shared:
+            dks_acc[...] = jnp.zeros_like(dks_acc)
 
     def step(edge: bool, end: bool):
         # the scores turned, [keys, the group's rows]: the sums over the rows are products
@@ -313,6 +368,10 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
             i = q0 + (jax.lax.broadcasted_iota(jnp.int32, di.shape, 1) & (bq - 1))
             di = jnp.where(i < tokens, di, 0.0)
         scores = jax.lax.dot_general(k, q, _NT, preferred_element_type=_F32)
+        if shared:
+            qs = qs_ref[...].reshape(g * bq, qs_ref.shape[-1])
+            qs = _own_rows(qs, q0, tokens, bq) if end else qs
+            scores += jax.lax.dot_general(ks_ref[...], qs, _NT, preferred_element_type=_F32)
         p = jnp.exp(scores - lse)
         if edge:
             p = jnp.where(_keep(q0, k0, scores.shape, 1, bq, window, tokens if end else None),
@@ -321,6 +380,8 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=_F32)
         ds = p * (dp - di)
         dk_acc[...] += jnp.dot(ds.astype(q.dtype), q, preferred_element_type=_F32)
+        if shared:
+            dks_acc[...] += jnp.dot(ds.astype(qs.dtype), qs, preferred_element_type=_F32)
 
     _run(flag, step)
 
@@ -328,6 +389,8 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
     def _():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        if shared:
+            dks_ref[...] = dks_acc[...].astype(dks_ref.dtype)
 
 
 def _call(kernel, name, by_keys: bool, inputs, specs: str, out_shape, out_specs: str, scratch,
@@ -335,7 +398,10 @@ def _call(kernel, name, by_keys: bool, inputs, specs: str, out_shape, out_specs:
     """One kernel over (B, KV, the steps of its walk).  ``inputs``: ``q`` [B,
     H, T, D], ``k`` [B, KV, T, D] and the rest; ``specs`` names each array's
     blocks: ``r`` a group's rows of [B, H, T, D], ``k`` a block of keys of
-    [B, KV, T, D], ``l`` a group's tokens, in the lanes, of [B, KV, G, T].
+    [B, KV, T, D], ``l`` a group's tokens, in the lanes, of [B, KV, G, T];
+    with a shared key, ``R`` a group's rows of [B, H, T, S], ``S`` a block of
+    the one shared key [B, 1, T, S], whatever the head, and ``K`` a block of
+    keys of [B, KV, T, S].
     ``scratch``: (rows, columns) of its float32 scratch, where ``r`` stands
     for the rows of a block of queries and ``k`` for a block's keys.  It asks
     for the VMEM its blocks (twice: one in flight), its scratch and
@@ -347,6 +413,14 @@ def _call(kernel, name, by_keys: bool, inputs, specs: str, out_shape, out_specs:
     spec = {"r": pl.BlockSpec((None, g, bq, d), lambda b, h, s, qi, kj, fl: (b, h, qi[s], 0)),
             "k": pl.BlockSpec((None, None, bkv, d), lambda b, h, s, qi, kj, fl: (b, h, kj[s], 0)),
             "l": pl.BlockSpec((None, None, g, bq), lambda b, h, s, qi, kj, fl: (b, h, 0, qi[s]))}
+    shared = "S" in specs
+    if shared:
+        w = inputs[specs.index("S")].shape[3]
+        spec.update(
+            R=pl.BlockSpec((None, g, bq, w), lambda b, h, s, qi, kj, fl: (b, h, qi[s], 0)),
+            S=pl.BlockSpec((None, None, bkv, w), lambda b, h, s, qi, kj, fl: (b, 0, kj[s], 0)),
+            K=pl.BlockSpec((None, None, bkv, w), lambda b, h, s, qi, kj, fl: (b, h, kj[s], 0)))
+        kernel = functools.partial(kernel, shared=True)
     size = {"r": g * bq, "k": bkv}
     scratch = [pltpu.VMEM((size[r], c), _F32) for r, c in scratch]
     schedule = _schedule(tokens, window, bq, bkv, by_keys)
@@ -369,24 +443,37 @@ def _like(*arrays):
     return [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in arrays]
 
 
-def _forward(q, k, v, window, interpret, keep_lse: bool):
-    """The output; with ``keep_lse`` also the log-sum, [B, KV, G, T] float32."""
+def _forward(q, k, v, window, interpret, keep_lse: bool, shared=()):
+    """The output; with ``keep_lse`` also the log-sum, [B, KV, G, T] float32.
+    ``shared``: () or (the queries' shared part [B, H, T, S], the one shared
+    key [B, 1, T, S])."""
     lse = jax.ShapeDtypeStruct((*k.shape[:2], q.shape[1] // k.shape[1], q.shape[2]), _F32)
-    out = _call(_fwd_kernel, "attn_fwd_lse" if keep_lse else "attn_fwd", False, [q, k, v], "rkk",
+    out = _call(_fwd_kernel, "attn_fwd_lse" if keep_lse else "attn_fwd", False,
+                [q, k, v, *shared], "rkk" + "RS" * bool(shared),
                 _like(q) + [lse] * keep_lse, "rl"[:1 + keep_lse],
                 [("r", _LANES), ("r", _LANES), ("r", q.shape[3])], 4, window, interpret)
     return out if keep_lse else out[0]
 
 
-def _dq(q, k, v, o, lse, do, window, interpret):
-    """(dq, and ``di`` [B, KV, G, T] float32 for ``_dkv``)."""
-    return _call(_dq_kernel, "attn_dq", False, [q, k, v, o, do, lse], "rkkrrl", _like(q, lse),
-                 "rl", [("r", _LANES), ("r", _LANES), ("r", q.shape[3])], 5, window, interpret)
+def _dq(q, k, v, o, lse, do, window, interpret, shared=()):
+    """(dq, with a shared key the shared part's gradient too, and ``di`` [B,
+    KV, G, T] float32 for ``_dkv``)."""
+    also = bool(shared)
+    return _call(_dq_kernel, "attn_dq", False, [q, k, v, *shared, o, do, lse],
+                 "rkk" + "RS" * also + "rrl", _like(q, *shared[:1], lse), "r" + "R" * also + "l",
+                 [("r", _LANES), ("r", _LANES), ("r", q.shape[3])]
+                 + [("r", x.shape[3]) for x in shared[:1]], 5, window, interpret)
 
 
-def _dkv(q, k, v, lse, di, do, window, interpret):
-    return _call(_dkv_kernel, "attn_dkv", True, [q, k, v, do, lse, di], "rkkrll", _like(k, v),
-                 "kk", [("k", q.shape[3])] * 2, 5, window, interpret)
+def _dkv(q, k, v, lse, di, do, window, interpret, shared=()):
+    """(dk, dv; with a shared key also its gradient a key-value head, [B, KV,
+    T, S]: the grid's head axis is parallel, so a head's program cannot add
+    into another's block, and the sum over the heads is the caller's)."""
+    by_head = [jax.ShapeDtypeStruct((*k.shape[:3], x.shape[3]), x.dtype) for x in shared[1:]]
+    return _call(_dkv_kernel, "attn_dkv", True, [q, k, v, *shared, do, lse, di],
+                 "rkk" + "RS" * bool(shared) + "rll", _like(k, v) + by_head, "kk" + "K" * bool(shared),
+                 [("k", q.shape[3])] * 2 + [("k", x.shape[3]) for x in shared[1:]],
+                 5, window, interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -408,9 +495,37 @@ def _attend_bwd(window, interpret, kept, do):
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
-def blocked_attention(q, k, v, window: Optional[int] = None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _attend_shared(q, k, v, q_shared, k_shared, window, interpret):
+    return _forward(q, k, v, window, interpret, False, (q_shared, k_shared))
+
+
+def _attend_shared_fwd(q, k, v, q_shared, k_shared, window, interpret):
+    o, lse = _forward(q, k, v, window, interpret, True, (q_shared, k_shared))
+    return o, (q, k, v, q_shared, k_shared, o, lse)
+
+
+def _attend_shared_bwd(window, interpret, kept, do):
+    q, k, v, q_shared, k_shared, o, lse = kept
+    shared = (q_shared, k_shared)
+    dq, dqs, di = _dq(q, k, v, o, lse, do, window, interpret, shared)
+    dk, dv, dks = _dkv(q, k, v, lse, di, do, window, interpret, shared)
+    dks = jnp.sum(dks.astype(_F32), axis=1, keepdims=True).astype(k_shared.dtype)
+    return dq, dk, dv, dqs, dks
+
+
+_attend_shared.defvjp(_attend_shared_fwd, _attend_shared_bwd)
+
+
+def blocked_attention(q, k, v, window: Optional[int] = None, q_shared=None, k_shared=None):
     """``softmax(q k^T + mask) v``: ``q`` [B, H, T, D], scaled already; ``k``,
     ``v`` [B, KV, T, D], each key-value head serving ``H / KV`` query heads in
-    order; -> [B, H, T, D] in ``q``'s type."""
+    order; -> [B, H, T, D] in ``q``'s type.  With ``q_shared`` [B, H, T, S]
+    (scaled too) and ``k_shared`` [B, 1, T, S], one key for every head, the
+    scores are ``q k^T + q_shared k_shared^T`` (latent attention's rotary
+    part): each program loads its block of the one shared key, which is never
+    laid out a head at a time."""
     interpret = jax.default_backend() != "tpu" if INTERPRET is None else INTERPRET
-    return _attend(q, k, v, window, interpret)
+    if q_shared is None:
+        return _attend(q, k, v, window, interpret)
+    return _attend_shared(q, k, v, q_shared, k_shared, window, interpret)

@@ -39,6 +39,21 @@ which owns the chip:
             chunked delta-rule scan and its backward pass under the
             trainer's loop) with 4 thread actors, 12 learner steps at batch 2
             on a 1,024-slot ring, as the laguna leg.  Only with --solar
+  ling      configs/config10_ling3_q_l7.json (the torso of five bounded-gate
+            delta-rule layers to one latent-attention layer under a router
+            that keeps groups, a leading dense layer, 8 of 32 heads held,
+            over the same history) with 4 thread actors, 12 learner steps at
+            batch 2 on a 1,024-slot ring, as the laguna leg.  Only with --ling,
+            and after ling_kernels
+  ling_kernels  what that cell's comparison does not see on the chip (PERF.md,
+            section 6): the blocked attention kernels with their shared key
+            operand, forward and all five gradients, against plain attention
+            in float32 at the cell's shapes ([8, 8, 1568, 128 + 64], bfloat16
+            in), and the router's choice by groups at 12,544 x 512 against a
+            router by sorting on the host, with the tolerances proven on the
+            spot: plain attention without the shared key's scores, and the
+            top 8 of all 512, must fail them.  With --ling, or alone with
+            --ling-kernels (no network is built: about a minute)
 
 Sets no platform itself.  Exits non-zero, with one line saying why and no
 result, before compiling anything if jax's default backend is not a TPU, and
@@ -430,6 +445,150 @@ def leg_solar() -> None:
     _train_on_histories("solar", "config9_solar2_q_ep40.json", steps, inspect)
 
 
+def leg_ling() -> None:
+    steps = 12
+
+    def inspect(pipe, final):
+        check_run("ling", pipe, final, steps)
+        assert type(pipe.comps.network).__name__ == "LingHybridQ"
+        assert final["param_version"] >= 1, "ling: nothing was published"
+        delta, attention = final.get("delta") or {}, final.get("attention") or {}
+        routing = final.get("routing") or {}
+        assert "scan" not in final, f"ling: state-space counters without such layers: {final}"
+        # batch 2, three forwards, six delta-rule layers, 25 chunks of 64 over 1,568 tokens
+        assert delta.get("chunks") == 2 * 3 * 6 * 25 and 0 < delta.get("tokens", 0) < delta.get(
+            "tokens_padded", 0), f"ling: no delta-rule counters: {final}"
+        assert 0 < attention.get("blocks_visited_latent", 0) < attention.get(
+            "blocks_total_latent", 0), f"ling: no latent attention counters: {final}"
+        assert routing.get("held_pairs", 0) > 0 and 0 < routing.get(
+            "groups_kept_hold_share", 0) <= 1, f"ling: no routing counters: {final}"
+        say(f"ling: delta rule a step {delta}; attention a step {attention}; routing a step "
+            f"{routing}; actors adopted param_version {pipe.worker.param_version} of "
+            f"{final['param_version']}")
+
+    _train_on_histories("ling", "config10_ling3_q_l7.json", steps, inspect)
+
+
+# What the blocked kernels may differ by from plain float32 attention on the
+# same bfloat16 operands, as ||got - want|| / ||want|| over a whole tensor
+# (and how far at least from plain attention that lost the shared key's
+# scores, as ||got - wrong|| / ||got||, where the two gradients it lacks read 1):
+# the kernels round the probabilities and the output to bfloat16 (2**-9 an
+# element, which averages out over a tensor's norm).  Read on the chip at the
+# cell's shapes (my chip run, PR 42): the output 0.00204, the five gradients
+# 0.00284-0.00339 (in Pallas' interpreter on the CPU at 300 tokens 0.0019 and
+# 0.0032-0.0038); without the shared key's scores 0.471-0.489.
+KERNEL_REL = 0.01
+KERNEL_REL_WITHOUT_SHARED_KEY = 0.2
+
+
+def latent_kernels_against_plain(rows: int = 8, heads: int = 8, tokens: int = 1568,
+                                 width: int = 128, shared: int = 64) -> dict:
+    """``blocked_attention`` with its shared key operand against plain causal
+    attention in float32 on the same bfloat16 operands: the output and the
+    gradients of both query parts, the keys, the values and the shared key,
+    each within ``KERNEL_REL`` of plain attention and further than
+    ``KERNEL_REL_WITHOUT_SHARED_KEY`` from plain attention that dropped
+    ``q_shared k_shared^T``.  -> the readings by name, (with, without)."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from ape_x_dqn_tpu.ops.pallas import blocked_attention as blocked
+
+    def plain(q, k, v, qs, ks, with_shared=True):
+        f32 = [x.astype(jnp.float32) for x in (q, k, v, qs, ks)]
+        with jax.default_matmul_precision("highest"):
+            s = jnp.einsum("bhtd,bhsd->bhts", f32[0], f32[1])
+            if with_shared:
+                s = s + jnp.einsum("bhtd,bosd->bhts", f32[3], f32[4])
+            p = jax.nn.softmax(jnp.where(jnp.tril(jnp.ones((tokens, tokens), bool)), s, -jnp.inf), -1)
+            return jnp.einsum("bhts,bhsd->bhtd", p, f32[2])
+
+    key = jax.random.split(jax.random.PRNGKey(42), 6)
+    scale = 1.0 / math.sqrt(width + shared)
+    bf = jnp.bfloat16
+    args = ((jax.random.normal(key[0], (rows, heads, tokens, width)) * scale).astype(bf),
+            jax.random.normal(key[1], (rows, heads, tokens, width)).astype(bf),
+            jax.random.normal(key[2], (rows, heads, tokens, width)).astype(bf),
+            (jax.random.normal(key[3], (rows, heads, tokens, shared)) * scale).astype(bf),
+            jax.random.normal(key[4], (rows, 1, tokens, shared)).astype(bf))
+    cot = jax.random.normal(key[5], (rows, heads, tokens, width))
+
+    def both(fn):       # the output and the five gradients under one cotangent, in float32
+        def run(cot, *args):
+            out, pull = jax.vjp(fn, *args)
+            return [x.astype(jnp.float32) for x in (out, *pull(cot.astype(out.dtype)))]
+        return jax.jit(run)(cot, *args)
+
+    got = both(lambda *a: blocked.blocked_attention(a[0], a[1], a[2], None, a[3], a[4]))
+    want, wrong = both(plain), both(lambda *a: plain(*a, with_shared=False))
+    readings = {}
+    for name, a, b, c in zip(("out", "dq", "dk", "dv", "dq_shared", "dk_shared"), got, want, wrong):
+        assert a.shape == b.shape, f"{name}: {a.shape} for {b.shape}"
+        readings[name] = (float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)),
+                          float(jnp.linalg.norm(a - c) / jnp.linalg.norm(a)))
+        assert readings[name][0] <= KERNEL_REL, f"{name}: {readings[name]} from plain attention"
+        assert readings[name][1] >= KERNEL_REL_WITHOUT_SHARED_KEY, (
+            f"{name}: {readings[name]}: the check would not see a lost shared key")
+    return readings
+
+
+def route_against_sorting(config: str = "config10_ling3_q_l7.json", tokens: int = 12544) -> dict:
+    """``expert_torso.route`` under the configuration's spec (512 outputs in 8
+    groups, 4 kept, 8 a token) against a router by sorting on the host, in
+    float32 as the program's: the same experts for every token, the gates
+    within 1e-5, no expert outside a kept group; and the top 8 of all 512,
+    what a router that forgot its groups gives, differs on most tokens."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from ape_x_dqn_tpu.config import load_config
+    from ape_x_dqn_tpu.models import expert_torso, ling_hybrid
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    torso = load_config(os.path.join(here, "configs", config)).torso
+    spec = ling_hybrid.spec_from_config({k: v for k, v in torso.items() if not k.startswith("_")})
+    outputs, groups, kept, k = (spec.router_outputs, spec.router_groups, spec.router_groups_kept,
+                                spec.num_experts_per_tok)
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(7), (tokens, outputs)))
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(8), (outputs,))
+    chosen, gates = jax.jit(lambda s, b: expert_torso.route(s, b, spec))(scores, bias)
+    plain = jax.jit(lambda s, b: expert_torso.route(s, b, dataclasses.replace(
+        spec, router_groups=1, router_groups_kept=1))[0])(scores, bias)
+    chosen, gates, plain = np.asarray(chosen), np.asarray(gates), np.asarray(plain)
+
+    s, b = np.asarray(scores, np.float32), np.asarray(bias, np.float32)
+    biased = s + b
+    by_group = np.sort(biased.reshape(tokens, groups, -1), -1)
+    group_score = by_group[..., -1] + by_group[..., -2]
+    keep = np.argsort(-group_score, -1, kind="stable")[:, :kept]        # the earlier of two equal first
+    allowed = (keep[:, :, None] == np.arange(groups)).any(1).repeat(outputs // groups, -1)
+    want = np.argsort(-np.where(allowed, biased, -np.inf), -1, kind="stable")[:, :k]
+    want_gates = np.take_along_axis(s, want, -1)
+    want_gates = want_gates / (want_gates.sum(-1, keepdims=True) + spec.gate_norm_eps) * (
+        spec.routed_scaling_factor)
+    differing = int((chosen != want).any(-1).sum())
+    assert differing == 0, f"route: {differing} of {tokens} tokens choose other experts than sorting"
+    np.testing.assert_allclose(gates, want_gates, rtol=1e-5)
+    assert np.take_along_axis(allowed, chosen, -1).all(), "route: an expert outside the kept groups"
+    ungrouped = float((np.sort(plain, -1) != np.sort(chosen, -1)).any(-1).mean())
+    assert ungrouped > 0.5, f"route: the top {k} of all {outputs} agree on {1 - ungrouped:.2f} of tokens"
+    return {"tokens": tokens, "differing": differing, "ungrouped_differs_share": ungrouped,
+            "kept_hold_group_0_share": float(allowed[:, 0].mean())}
+
+
+def leg_ling_kernels() -> None:
+    for name, (near, far) in latent_kernels_against_plain().items():
+        say(f"ling_kernels: {name} {near:.5f} from plain attention (limit {KERNEL_REL}), "
+            f"{far:.4f} from plain attention without the shared key "
+            f"(at least {KERNEL_REL_WITHOUT_SHARED_KEY})")
+    say(f"ling_kernels: route against sorting {route_against_sorting()}")
+
+
 def main() -> int:
     t_start = time.perf_counter()
     try:
@@ -490,6 +649,10 @@ def main() -> int:
         legs = [("granite", leg_granite)]
     if "--solar" in sys.argv[1:]:
         legs = [("solar", leg_solar)]
+    if "--ling" in sys.argv[1:]:
+        legs = [("ling_kernels", leg_ling_kernels), ("ling", leg_ling)]
+    if "--ling-kernels" in sys.argv[1:]:
+        legs = [("ling_kernels", leg_ling_kernels)]
     for name, fn in legs:
         t0 = time.perf_counter()
         say(f"leg {name}: starts with bytes_in_use per device "

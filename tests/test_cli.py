@@ -75,7 +75,7 @@ def test_canonical_configs_load_and_validate():
 
     root = os.path.join(os.path.dirname(__file__), "..", "configs")
     paths = sorted(glob.glob(os.path.join(root, "*.json")))
-    assert len(paths) == 10, paths
+    assert len(paths) == 11, paths
     cfgs = {os.path.basename(p): load_config(p) for p in paths}
     assert cfgs["config1_pong_1actor.json"].actor.num_actors == 1
     c6 = cfgs["config6_lfm2moe_q_ep8.json"]
@@ -91,6 +91,10 @@ def test_canonical_configs_load_and_validate():
     c9 = cfgs["config9_solar2_q_ep40.json"]
     assert c9.network == "solar_open2" and c9.torso["heads_held"] == [0, 16]
     assert c9.env.frame_stack == 32 and c9.learner.replay_sample_size == 8
+    c10 = cfgs["config10_ling3_q_l7.json"]
+    assert c10.network == "ling_hybrid" and c10.torso["kv_lora_rank"] == 512
+    assert c10.torso["heads_held"] == [0, 8] and c10.torso["n_group"] == 8
+    assert c10.env.frame_stack == 32 and c10.learner.replay_sample_size == 8
     assert cfgs["config2_breakout_8actors.json"].actor.num_actors == 8
     c3 = cfgs["config3_seaquest_256actors_2m.json"]
     assert c3.replay.capacity == 2_000_000

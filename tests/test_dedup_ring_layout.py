@@ -663,6 +663,61 @@ def test_the_delta_rule_torsos_fused_program_fits_the_chip(topo, no_compile_cach
     assert [kernels.count(k) for k in ("attn_dq", "attn_dkv")] == [1, 1], kernels
 
 
+@pytest.mark.slow    # about 160 s alone: the suite's time limit has no room for a third such compile
+def test_the_latent_torsos_fused_program_fits_the_chip(topo, no_compile_cache, monkeypatch):
+    """``benchmark/configs/ling3_q_l7.json``'s fused program at the cell's
+    shapes (763 M parameters at 16 held experts: 8 of 32 heads and 16 of 512
+    experts a layer, B=8, 1,568 tokens, the 4,096-slot ring): state, ring and
+    temporaries leave over 0.5 GB of a v5e (under that the issue's rule holds
+    8 experts); no part of the ring is copied; the attention kernels compile
+    once, with the shared key operand ``[8, 1, 1568, 64]`` never laid out a
+    head at a time."""
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / "ling3_q_l7.json").read_text())
+    parameters = {16: 763_253_219, 8: 480_138_723}[cfg["experts_held"][1]]
+    _, frames, compiled = _cell_fused_program(topo, monkeypatch, "ling3_q_l7", parameters)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    print(f"ling3_q_l7 fused program for v5e: arguments {mem.argument_size_in_bytes} B, "
+          f"temporaries {mem.temp_size_in_bytes} B, code {mem.generated_code_size_in_bytes} B")
+    hbm = 16_909_336_064                      # a v5e's, PERF.md Open question 11
+    assert hbm - mem.argument_size_in_bytes - mem.temp_size_in_bytes > 0.5e9, mem
+    assert mem.temp_size_in_bytes <= 9_103_542_784, mem      # PR 42, 16 held experts
+    assert_ring_stays_put(text, frames * 56448 * 4, 0)
+    kernels = re.findall(r"%(attn_\w+?)[.\d]* = ", text)
+    assert "attn_fwd_lse" in kernels
+    assert [kernels.count(k) for k in ("attn_dq", "attn_dkv")] == [1, 1], kernels
+    assert not [dims for dtype, dims, _ in _ARRAY.findall(text)
+                if dtype == "bf16" and dims == "8,8,1568,256"]     # no head padded from 192
+
+
+def test_the_latent_kernels_compile_for_the_chip(topo, no_compile_cache, monkeypatch):
+    """One ``ling_hybrid.LatentAttention`` layer at ``ling3_q_l7``'s shapes
+    (``u`` ``bf16[8, 1568, 2560]``, 8 heads of 128 + 64 against values of
+    128), pulled back: the three kernels take the shared operand through the
+    chip's compiler (blocks of 64 lanes, the second accumulators), the rope
+    key crosses as ``[8, 1, 1568, 64]`` and no ``[8, 8, 1568, 192]`` or
+    ``256`` exists; its gradient a head is summed outside the kernel."""
+    from ape_x_dqn_tpu.models import ling_hybrid
+    from ape_x_dqn_tpu.ops.pallas import blocked_attention
+
+    monkeypatch.setattr(blocked_attention, "INTERPRET", False)   # this process sees the CPU
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / "ling3_q_l7.json").read_text())
+    layer = ling_hybrid.LatentAttention(ling_hybrid.spec_from_config(cfg), "latent_attention",
+                                        jnp.bfloat16, jnp.float32)
+    dev = SingleDeviceSharding(topo.devices[0])
+    params = _with(jax.eval_shape(
+        lambda k: layer.init(k, jnp.zeros((1, 8, 2560), jnp.bfloat16)), jax.random.PRNGKey(0)), dev)
+    u = jax.ShapeDtypeStruct((8, 1568, 2560), jnp.bfloat16, sharding=dev)
+    text = _compile_text(jax.jit(lambda p, v, ct: jax.vjp(layer.apply, p, v)[1](ct)), (params, u, u))
+    kernels = sorted(set(re.findall(r"%(attn_\w+?)[.\d]* = ", text)))
+    assert kernels == ["attn_dkv", "attn_dq", "attn_fwd_lse"]
+    shapes = {dims for dtype, dims, _ in _ARRAY.findall(text) if dtype == "bf16"}
+    assert "8,1,1568,64" in shapes and "8,8,1568,64" in shapes      # the one key; dq's and dk's parts a head
+    assert not shapes & {"8,8,1568,192", "8,8,1568,256"}, shapes
+
+
 # ------------------------------- what a Mamba-2 mixer passes around its scan
 
 RELAYOUTS = ("copy", "pad", "slice")

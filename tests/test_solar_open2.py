@@ -487,7 +487,7 @@ def test_the_other_torsos_hold_every_head_as_they_did():
 
 
 def test_the_delta_scan_is_scoped_inside_the_mixer():
-    assert profiling.PARTS[9:] == ("ssm_scan", "delta_scan")
+    assert profiling.PARTS[9:11] == ("ssm_scan", "delta_scan")
     net = small_net()
     x = obs(jax.random.PRNGKey(8))
     params = net.init(jax.random.PRNGKey(9), x)
@@ -506,7 +506,7 @@ def test_the_delta_scan_is_scoped_inside_the_mixer():
 
 
 def test_config_carries_the_torso_and_the_committed_file_is_the_cells():
-    assert TORSO_NETWORKS[-1] == "solar_open2" and HISTORY_NETWORKS[-1] == "solar_open2"
+    assert TORSO_NETWORKS[3] == "solar_open2" and HISTORY_NETWORKS[2] == "solar_open2"
     assert tuple(dueling.TORSO_KINDS) == TORSO_NETWORKS
     cfg = ApexConfig()
     cfg.network = "solar_open2"
