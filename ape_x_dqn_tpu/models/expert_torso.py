@@ -31,7 +31,13 @@ The expert layer is one chip's share of an expert-parallel layer: it is told
 how many experts exist (the router's outputs), how many a token takes and
 which range ``[lo, hi)`` it holds.  It routes over all of them, normalises
 the gates over all the chosen ones, and computes its own experts' part of
-the sum; nothing stands in for the others.  The token-expert pairs are sorted
+the sum; nothing stands in for the others.  A token's experts are chosen by
+selection, not by sorting (``choose``, ``ops/router_choice.py``): k rounds of
+"the largest biased score not yet taken, the first output that holds it",
+after the same rounds over the groups' scores where the router keeps groups;
+the order, the ties and the gates are a stable ``top_k``'s and a
+``take_along_axis``'s to the bit, and the gates' gradient is a one-hot
+select.  The token-expert pairs are sorted
 by expert, those on held experts first, and the held ones are walked a tile
 of rows at a time (``held_experts``): gather the tile's tokens, multiply by
 groups (``jax.lax.ragged_dot``: on the TPU a grouped kernel that walks the
@@ -86,6 +92,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ape_x_dqn_tpu.models.dueling import STEM_WINDOWS, conv_stem, dueling_head
+from ape_x_dqn_tpu.ops.router_choice import router_choice
 from ape_x_dqn_tpu.types import ROUTING
 from ape_x_dqn_tpu.utils.profiling import part
 
@@ -228,34 +235,70 @@ class SwiGLU(nn.Module):
         return (jax.nn.silu(u @ w1.astype(cd)) * (u @ w3.astype(cd))) @ w2.astype(cd)
 
 
+def _lane_sum(x):
+    """``jnp.sum(x, -1)`` of [T, k] in one order, written out: the order in
+    which the TPU sums the lanes of a row, halves folded onto each other
+    from the widest down (k = 8: ``((x0 + x4) + (x2 + x6)) + ((x1 + x5) +
+    (x3 + x7))``).  That is how the gates' sum was rounded while the gates
+    came from a gather, k in the lanes; the selection hands them over with
+    the tokens in the lanes, where the compiler's own reduction adds one
+    after another, and a third of the rows' sums would move in their last
+    bit (read on the chip, ``PERF.md`` section 6, PR 43).  Its pull-back is
+    ``_over_lanes``."""
+    return _folded(x, x.shape[1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _folded(x, k: int):
+    cols = [x[:, i] for i in range(k)]
+    half = 1 << (k - 1).bit_length() >> 1
+    while half:
+        cols = [c + cols[i + half] if i + half < len(cols) else c for i, c in enumerate(cols[:half])]
+        half >>= 1
+    return cols[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _over_lanes(s, k: int):
+    """[T] -> [T, k], every column ``s``: ``_lane_sum``'s transpose, and
+    ``_lane_sum`` its own: a broadcast's pull-back leaves the order of its
+    sum to the layout."""
+    return jnp.broadcast_to(s[:, None], (s.shape[0], k))
+
+
+_folded.defvjp(lambda x, k: (_folded(x, k), None), lambda k, _, ct: (_over_lanes(ct, k),))
+_over_lanes.defvjp(lambda s, k: (_over_lanes(s, k), None), lambda k, _, ct: (_lane_sum(ct),))
+
+
+def choose(scores, bias, spec: TorsoSpec, kept=None):
+    """(chosen experts [T, k], gates [T, k], the groups kept [T, groups] or
+    None) from float32 scores [T, E]: a selection, not a sort
+    (``ops/router_choice.py``): k rounds of "the largest of ``scores +
+    bias`` not yet taken, the first output that holds it", the gate that
+    output's score; with ``router_groups`` over 1, among the groups the token
+    keeps (``kept``, default the ``router_groups_kept`` largest by the sum
+    of a group's two largest, the earlier of two equal ones first).  The
+    gates are normalised over all k (``norm_topk_prob``), times
+    ``routed_scaling_factor``."""
+    chosen, gates, kept = router_choice(scores, bias, kept, spec.num_experts_per_tok,
+                                        spec.router_groups, spec.router_groups_kept)
+    if spec.norm_topk_prob:
+        gates = gates / _over_lanes(_lane_sum(gates) + spec.gate_norm_eps, gates.shape[1])
+    return chosen, gates * spec.routed_scaling_factor, kept
+
+
 def groups_kept(biased, spec: TorsoSpec):
     """[T, groups] bool: the ``router_groups_kept`` groups a token keeps of
     the ``router_groups`` its biased scores [T, E] lie in (``noaux_tc``): a
     group's score is the sum of its two largest, the largest groups are
     kept, the earlier of two equal ones first."""
-    groups = spec.router_groups
-    by_group = biased.reshape(biased.shape[0], groups, -1)
-    score = jnp.sum(jax.lax.top_k(by_group, 2)[0], -1)
-    _, kept = jax.lax.top_k(score, spec.router_groups_kept)
-    return jnp.any(kept[:, :, None] == jnp.arange(groups), axis=1)
+    return choose(biased, jnp.zeros(biased.shape[-1:], biased.dtype), spec)[2]
 
 
 def route(scores, bias, spec: TorsoSpec, kept=None):
     """(chosen experts [T, k], gates [T, k]) from float32 scores [T, E]:
-    the top k of ``scores + bias``, gates the chosen scores normalised over
-    all k (``norm_topk_prob``), times ``routed_scaling_factor``.  With
-    ``router_groups`` over 1 the k are the largest inside the groups the
-    token keeps (``kept``, default ``groups_kept``'s)."""
-    biased = scores + bias
-    if spec.router_groups > 1:
-        kept = groups_kept(biased, spec) if kept is None else kept
-        biased = jnp.where(jnp.repeat(kept, spec.router_outputs // spec.router_groups, axis=-1),
-                           biased, -jnp.inf)
-    _, chosen = jax.lax.top_k(biased, spec.num_experts_per_tok)
-    gates = jnp.take_along_axis(scores, chosen, axis=-1)
-    if spec.norm_topk_prob:
-        gates = gates / (jnp.sum(gates, -1, keepdims=True) + spec.gate_norm_eps)
-    return chosen, gates * spec.routed_scaling_factor
+    ``choose``'s, for a caller that does not ask which groups were kept."""
+    return choose(scores, bias, spec, kept)[:2]
 
 
 # The grouped kernel's own tile: a walk's tile is a whole number of these.
@@ -420,8 +463,7 @@ class ExpertShare(nn.Module):
             logits = jnp.dot(u.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST)
             scores = (jax.nn.sigmoid(logits) if sp.score_function == "sigmoid"
                       else jax.nn.softmax(logits, axis=-1))
-            kept = groups_kept(scores + bias, sp) if sp.router_groups > 1 else None
-            chosen, gates = route(scores, bias, sp, kept)
+            chosen, gates, kept = choose(scores, bias, sp)
             held = (chosen >= lo) & (chosen < hi)
             load = jnp.sum(jax.nn.one_hot(chosen.reshape(-1), sp.router_outputs,
                                           dtype=jnp.int32), axis=0)

@@ -581,12 +581,143 @@ def route_against_sorting(config: str = "config10_ling3_q_l7.json", tokens: int 
             "kept_hold_group_0_share": float(allowed[:, 0].mean())}
 
 
+# (tokens a step's forward, router outputs, a token's, groups, groups kept): ling3_q_l7,
+# solar2_q_ep40, laguna_q_ep32, lfm2moe_q_ep8; then the rows of a tile of the walk and the width
+ROUTER_SHAPES = ((12544, 512, 8, 8, 4), (12544, 320, 8, 1, 1), (12544, 256, 10, 1, 1),
+                 (25088, 64, 4, 1, 1))
+WALK_TILE = (4608, 2560)
+GATE_NORM_EPS, GATE_SCALE = 1e-6, 2.5
+
+
+def choice_by_sorting(scores, bias, k: int, groups: int, kept: int):
+    """(chosen, gates, groups kept or None, raw gates) as the router chose
+    until PR 43: three ``top_k`` and a ``take_along_axis``, the gates
+    normalised over their ``jnp.sum``.  The oracle."""
+    import jax
+    import jax.numpy as jnp
+
+    biased, held = scores + bias, None
+    if groups > 1:
+        by_group = biased.reshape(biased.shape[0], groups, -1)
+        _, best = jax.lax.top_k(jnp.sum(jax.lax.top_k(by_group, 2)[0], -1), kept)
+        held = jnp.any(best[:, :, None] == jnp.arange(groups), axis=1)
+        biased = jnp.where(jnp.repeat(held, biased.shape[1] // groups, axis=-1), biased, -jnp.inf)
+    _, chosen = jax.lax.top_k(biased, k)
+    raw = jnp.take_along_axis(scores, chosen, axis=-1)
+    gates = raw / (jnp.sum(raw, -1, keepdims=True) + GATE_NORM_EPS) * GATE_SCALE
+    return chosen, gates, held, raw
+
+
+def choice_by_selection(scores, bias, k: int, groups: int, kept: int):
+    """The same four from ``expert_torso.choose`` under a spec of these
+    shapes (the raw gates from ``router_choice`` itself)."""
+    from ape_x_dqn_tpu.models import expert_torso
+    from ape_x_dqn_tpu.ops.router_choice import router_choice
+
+    spec = expert_torso.TorsoSpec(
+        hidden_size=8, intermediate_size=8, moe_intermediate_size=8, norm_eps=1e-5,
+        router_outputs=scores.shape[1], num_experts_per_tok=k, experts_held=(0, 1),
+        layers=(("op", "moe"),), mixers=(("op", None),), gate_norm_eps=GATE_NORM_EPS,
+        routed_scaling_factor=GATE_SCALE, router_groups=groups, router_groups_kept=kept)
+    return (*expert_torso.choose(scores, bias, spec), router_choice(scores, bias, None, k, groups, kept)[1])
+
+
+def _device_microseconds(step, carry, repeats: int) -> float:
+    """One execution of ``step`` (carry -> carry) on the device, from
+    ``repeats`` dependent ones inside one program: a dispatch from this host
+    is 200 us, more than most of what is timed here."""
+    import jax
+
+    run = jax.jit(lambda c: jax.lax.fori_loop(0, repeats, lambda _, c: step(c), c))
+    jax.block_until_ready(run(carry))
+    t0 = time.perf_counter()
+    jax.block_until_ready(run(carry))
+    return (time.perf_counter() - t0) / repeats * 1e6
+
+
+def choice_against_sorting_on_the_chip(shapes=ROUTER_SHAPES, walk_tile=WALK_TILE,
+                                       repeats: int = 20) -> list:
+    """``expert_torso.choose`` against ``choice_by_sorting`` on this device, at
+    the four expert cells' shapes, on random scores and on scores in 1/64ths
+    with no bias (ties on most rows): chosen, kept, raw and normalised gates
+    and the gradient of a weighted sum of the normalised gates equal bit for
+    bit (on a TPU the oracle's ``jnp.sum`` over k runs along the lanes, the
+    order ``expert_torso._lane_sum`` writes out); the microseconds of both
+    (the scores' sigmoid and the bias's dependence on the last execution
+    inside), of the pairs' ``argsort`` and of one tile's scatter-add (the
+    walk's combine), which are not the choice's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    def both(choice, k, groups, kept):
+        def run(scores, bias, weight):
+            out, pull = jax.vjp(lambda s: choice(s, bias, k, groups, kept)[1], scores)
+            return choice(scores, bias, k, groups, kept), pull(weight)[0]
+        return jax.jit(run)
+
+    rows = []
+    for tokens, outputs, k, groups, kept in shapes:
+        key = jax.random.split(jax.random.PRNGKey(tokens + outputs), 3)
+        logits = jax.random.normal(key[0], (tokens, outputs))
+        scores = jax.nn.sigmoid(logits)
+        bias = 0.05 * jax.random.normal(key[1], (outputs,))
+        weight = jax.random.normal(key[2], (tokens, k))
+        new, old = both(choice_by_selection, k, groups, kept), both(choice_by_sorting, k, groups, kept)
+        names = ("chosen", "gates") + (("kept",) if groups > 1 else ()) + ("raw gates", "dscores")
+        for name, (s, b) in (("random", (scores, bias)),
+                             ("ties", (jnp.round(scores * 64) / 64, jnp.zeros_like(bias)))):
+            got, want = jax.tree.leaves(new(s, b, weight)), jax.tree.leaves(old(s, b, weight))
+            assert len(got) == len(want) == len(names)
+            for what, a, c in zip(names, got, want):
+                a, c = np.asarray(a), np.asarray(c)
+                if what in ("gates", "dscores") and jax.default_backend() != "tpu":
+                    # off the chip the oracle's sum over k adds one after another
+                    np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-6 * np.abs(c).max())
+                    continue
+                if a.dtype == np.float32:
+                    a, c = a.view(np.uint32), c.view(np.uint32)
+                differing = int((a != c).reshape(tokens, -1).any(-1).sum())
+                assert a.shape == c.shape and differing == 0, (
+                    f"choice: {what} differs from the sort's on {differing} of {tokens} tokens "
+                    f"({outputs} outputs, {name} scores)")
+
+        def chained(choice, pulled: bool):
+            def step(bias):     # the next execution's bias hangs on this one's gates
+                s = jax.nn.sigmoid(logits + bias[0])
+                if not pulled:
+                    return bias + 0.0 * choice(s, bias, k, groups, kept)[1][0, 0]
+                gates, pull = jax.vjp(lambda s: choice(s, bias, k, groups, kept)[1], s)
+                return bias + 0.0 * (gates[0, 0] + pull(weight)[0][0, 0])
+            return step
+
+        pairs = jax.random.randint(key[2], (tokens * k,), 0, 17)
+        timed = {f"{name}{suffix}_us": _device_microseconds(chained(choice, pulled), bias, repeats)
+                 for name, choice in (("selection", choice_by_selection), ("sorting", choice_by_sorting))
+                 for suffix, pulled in (("", False), ("_with_gradient", True))}
+        rows.append({
+            "tokens": tokens, "outputs": outputs, "k": k, "groups": groups, **timed,
+            "sigmoid_alone_us": _device_microseconds(
+                lambda b: b + 0.0 * jax.nn.sigmoid(logits + b[0])[0, 0], bias, repeats),
+            "pairs_argsort_us": _device_microseconds(
+                lambda p: p + jnp.minimum(jnp.argsort(p, stable=True)[0], 0), pairs, repeats)})
+    tile, width = walk_tile
+    tokens = shapes[0][0]
+    token = jax.random.randint(jax.random.PRNGKey(3), (tile,), 0, tokens)
+    ys = jax.random.normal(jax.random.PRNGKey(4), (tile, width))
+    rows.append({"tile_rows": tile, "width": width, "scatter_add_us": _device_microseconds(
+        lambda y: y.at[token].add(ys), jnp.zeros((tokens, width)), repeats)})
+    return rows
+
+
 def leg_ling_kernels() -> None:
     for name, (near, far) in latent_kernels_against_plain().items():
         say(f"ling_kernels: {name} {near:.5f} from plain attention (limit {KERNEL_REL}), "
             f"{far:.4f} from plain attention without the shared key "
             f"(at least {KERNEL_REL_WITHOUT_SHARED_KEY})")
     say(f"ling_kernels: route against sorting {route_against_sorting()}")
+    for row in choice_against_sorting_on_the_chip():
+        say(f"ling_kernels: the choice against the sort on the chip, bit for bit: {row}")
 
 
 def main() -> int:

@@ -489,6 +489,50 @@ def test_the_expert_layer_builds_no_worst_case_buffer(topo, no_compile_cache):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < WORST_CASE_BLOCK_TEMP_BYTES, temp
 
+@pytest.mark.parametrize("name", ["ling3_q_l7", "lfm2moe_q_ep8"])
+def test_the_router_chooses_without_a_sort_or_a_gather(topo, no_compile_cache, name):
+    """One ``ExpertShare`` at the cell's shapes (12,544 tokens over 512
+    outputs in 8 groups; 25,088 over 64), forward and pulled back, compiled
+    for v5e: the choice is fusions of ``router_choice``'s reductions whose
+    innermost ``torso:`` scope is ``torso:router`` (the parts' readers count
+    them there), no ``sort`` comes from a ``top_k`` and no ``gather`` from a
+    ``take_along_axis``, nothing of the choice is a sort, a gather or a
+    scatter at all; the pairs' ``argsort`` is still a sort of ``tokens x k``
+    keys."""
+    from ape_x_dqn_tpu.models import expert_torso, lfm2_moe, ling_hybrid
+
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / f"{name}.json").read_text())
+    family, rows, tokens = ((ling_hybrid, 8, 1568) if name == "ling3_q_l7" else (lfm2_moe, 512, 49))
+    spec = family.spec_from_config(cfg)
+    assert rows == cfg["batch_size"]
+    layer = expert_torso.ExpertShare(spec, jnp.bfloat16, jnp.float32)
+    dev = SingleDeviceSharding(topo.devices[0])
+    params = _with(jax.eval_shape(
+        lambda key: layer.init(key, jnp.zeros((1, 8, spec.hidden_size), jnp.bfloat16)),
+        jax.random.PRNGKey(0)), dev)
+    u = jax.ShapeDtypeStruct((rows, tokens, spec.hidden_size), jnp.bfloat16, sharding=dev)
+
+    def loss(p, u):
+        out, sown = layer.apply(p, u, mutable=["routing"])
+        return jnp.sum(jnp.square(out.astype(jnp.float32))), sown
+
+    text = _compile_text(jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)), (params, u))
+    named = [(m.group("name"), m.group("op"), re.search(r'op_name="([^"]*)"', line))
+             for line in text.splitlines() for m in [_INSTRUCTION.match(line)] if m]
+    named = [(n, op, scope.group(1) if scope else "") for n, op, scope in named]
+    assert not [(n, scope) for n, op, scope in named if op == "sort" and scope.endswith("top_k")]
+    assert not [(n, scope) for n, op, scope in named if op == "gather" and "take_along_axis" in scope]
+    assert not [(n, scope) for n, op, scope in named if "top_k" in scope or "take_along_axis" in scope]
+    choice = [(op, scope) for n, op, scope in named if "router_choice" in scope]
+    assert choice and all(re.findall(r"torso:\w+", scope)[-1] == "torso:router" for _, scope in choice)
+    assert {op for op, _ in choice}.isdisjoint({"sort", "gather", "scatter", "custom-call"}), choice
+    assert any("transpose(" in scope for _, scope in choice)       # the pull-back's one-hot select
+    pairs = rows * tokens * spec.num_experts_per_tok
+    assert [n for n, op, scope in named if op == "sort" and scope.endswith("argsort)/sort")
+            and f"s32[{pairs}]" in text.split(f"%{n} = ", 1)[1][:80]], "the pairs' argsort went"
+
+
 
 def test_the_history_torsos_fused_program_fits_the_chip(topo, no_compile_cache, monkeypatch):
     """``benchmark/configs/laguna_q_ep32.json``'s fused program at the cell's

@@ -174,7 +174,8 @@ def test_one_group_is_the_plain_top_k_bit_for_bit():
     np.testing.assert_array_equal(np.asarray(gates),
                                   np.asarray(picked / jnp.sum(picked, -1, keepdims=True) * 2.5))
     text = str(jax.make_jaxpr(lambda s, b: expert_torso.route(s, b, spec))(scores, bias))
-    assert text.count("top_k") == 1 and "inf" not in text          # no operation of the groups'
+    # two rounds of selection and no operation of the groups' (their two largest, their rounds)
+    assert "top_k" not in text and text.count("reduce_max") == 2
     for bad in (dict(n_group=3), dict(topk_group=5), dict(n_group=4, topk_group=1, num_experts_per_tok=5)):
         with pytest.raises(ValueError, match="router_groups"):
             ling_hybrid.spec_from_config(dict(TORSO, **bad))
@@ -213,6 +214,34 @@ def test_the_chips_numeric_check_passes_here_and_fails_on_a_lost_mechanism(monke
                for near, far in readings.values())
     routed = chip_smoke.route_against_sorting(tokens=512)
     assert routed["differing"] == 0 and routed["ungrouped_differs_share"] > 0.5
+
+
+@pytest.mark.parametrize("lost", [None, "first_index"])
+def test_the_chips_check_of_the_choice_against_the_sort_runs_here(monkeypatch, lost):
+    """``chip_smoke.choice_against_sorting_on_the_chip`` at small shapes: it
+    passes on the selection as it is (with and without groups, ties
+    included) and returns its timings; a selection that takes the last of
+    equal scores, not the first, fails it."""
+    import chip_smoke
+    from ape_x_dqn_tpu.ops import router_choice
+
+    shapes = ((300, 64, 4, 4, 2), (200, 32, 3, 1, 1))
+    if lost == "first_index":
+        whole = router_choice._rounds
+
+        def last_of_equals(cur, rounds, gates_of=None):
+            n = cur.shape[-1]
+            firsts, gates, left = whole(cur[..., ::-1], rounds,
+                                        None if gates_of is None else gates_of[..., ::-1])
+            return n - 1 - firsts, gates, jnp.where(left[..., ::-1] == n, n, jnp.arange(n))
+
+        monkeypatch.setattr(router_choice, "_rounds", last_of_equals)
+        with pytest.raises(AssertionError, match="differs from the sort's"):
+            chip_smoke.choice_against_sorting_on_the_chip(shapes=shapes, walk_tile=(64, 16), repeats=1)
+        return
+    rows = chip_smoke.choice_against_sorting_on_the_chip(shapes=shapes, walk_tile=(64, 16), repeats=1)
+    assert [r.get("outputs") for r in rows] == [64, 32, None] and rows[-1]["scatter_add_us"] > 0
+    assert all(r[key] > 0 for r in rows[:2] for key in ("selection_us", "sorting_us", "pairs_argsort_us"))
 
 
 # ------------------------------------------- the scan at the bounded gate's floor
