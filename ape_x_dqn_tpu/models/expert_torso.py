@@ -42,11 +42,13 @@ by expert, those on held experts first, and the held ones are walked a tile
 of rows at a time (``held_experts``): gather the tile's tokens, multiply by
 groups (``jax.lax.ragged_dot``: on the TPU a grouped kernel that walks the
 tiles the group sizes name and skips the rows past the last group), add each
-row, by its gate, into its token's sum.  The tiles walked are those the held
-pairs fill, a loop whose count the routing of the call gives: every pair on
-a held expert is multiplied, none is ever dropped, and nothing of the worst
-case's ``tokens x k`` rows by a width is built.  A tile's rows come from the
-shapes (``tile_rows``).  The walk's backward pass is written by hand
+row, by its gate, into its token's float32 sum (``_combined``: the sum lies
+in blocks of 512 columns and every block takes its own scatter-add).  The
+tiles walked are those the held pairs fill, a loop whose count the routing
+of the call gives: every pair on a held expert is multiplied, none is ever
+dropped, and nothing of the worst case's ``tokens x k`` rows by a width is
+built.  A tile's rows come from the shapes (``tile_rows``).  The walk's
+backward pass is written by hand
 (``jax.custom_vjp``): it walks the same tiles, computes each again and keeps
 nothing of a tile, where reverse-mode differentiation of the loop would stack
 every tile's residuals.  Every layer is recomputed in the backward pass
@@ -342,6 +344,33 @@ def _walk(order, sizes, tile: int):
     return order, ends - sizes, ends, -(-ends[-1] // tile)
 
 
+# The columns of one block of a token sum (``_zero_blocks``).
+BLOCK_COLUMNS = 512
+
+
+def _zero_blocks(u) -> tuple:
+    """A zero token sum for ``u`` [tokens, d], float32, as the column blocks
+    ``_combined`` adds into: blocks of ``BLOCK_COLUMNS`` and what is left of
+    ``d``."""
+    whole, rest = divmod(u.shape[1], BLOCK_COLUMNS)
+    return tuple(jnp.zeros((u.shape[0], w), jnp.float32)
+                 for w in [BLOCK_COLUMNS] * whole + [rest] * (rest > 0))
+
+
+def _combined(y, token, rows):
+    """The token sum ``y`` (``_zero_blocks``) with the float32 ``rows`` [R, d]
+    added, each into the sum of its ``token``: one scatter-add a column
+    block.  The sums and the order of their terms are those of one
+    scatter-add of whole rows; what the compiler's scatter-add costs a row
+    hangs on the width it adds into (``PERF.md`` section 6, PR 45)."""
+    with jax.named_scope("combine"):
+        lo, out = 0, []
+        for block in y:
+            out.append(block.at[token].add(rows[:, lo:lo + block.shape[1]]))
+            lo += block.shape[1]
+        return tuple(out)
+
+
 def _products(xs, w13, w2, sizes):
     """(h, a, ys) of a tile's rows ``xs``: ``ys = (silu(h1) * h3) @ w2`` by
     groups, ``[h1, h3] = xs @ w13``."""
@@ -360,8 +389,12 @@ def held_experts(u, w13, w2, gates, order, sizes, tile: int):
     sorted by expert, the held ones first; ``sizes`` [n]: the pairs on each
     held expert.  The sorted pairs are walked ``tile`` rows at a time over the
     tiles that hold a held pair: gather the rows' tokens, multiply by groups,
-    add ``gate * row`` into the token's float32 sum.  The backward pass walks
-    the same tiles and computes each again: nothing of a tile is kept."""
+    add ``gate * row`` into the token's float32 sum, which the walk carries
+    as column blocks (``_zero_blocks``) and adds into a block at a time
+    (``_combined``: the terms and their order are one scatter-add's over
+    whole rows; the blocks are joined before the cast to ``u``'s type).  The
+    backward pass walks the same tiles and computes each again, the tokens'
+    gradient summed the same way: nothing of a tile is kept."""
     return _held_experts_fwd(u, w13, w2, gates, order, sizes, tile)[0]
 
 
@@ -381,11 +414,11 @@ def _held_experts_fwd(u, w13, w2, gates, order, sizes, tile: int):
             # Rows past the last group are the kernel's to leave unwritten.
             ys = jnp.where(live[:, None], ys.astype(jnp.float32) * gate[:, None], 0.0)
         with part("router"):
-            return y.at[token].add(ys)
+            return _combined(y, token, ys)
 
     with part("router"):
-        y = jax.lax.fori_loop(0, tiles, body, jnp.zeros(u.shape, jnp.float32))
-        return y.astype(cd), (u, w13, w2, gates, order, sizes)
+        y = jax.lax.fori_loop(0, tiles, body, _zero_blocks(u))
+        return jnp.concatenate(y, axis=1).astype(cd), (u, w13, w2, gates, order, sizes)
 
 
 def _held_experts_bwd(tile: int, kept, dy):
@@ -421,14 +454,14 @@ def _held_experts_bwd(tile: int, kept, dy):
             dxs = jax.lax.ragged_dot(dh, w13t, group)
             dxs = jnp.where(live[:, None], dxs, 0).astype(jnp.float32)
         with part("router"):
-            return du.at[token].add(dxs), dw13, dw2, dgates.at[pair].add(dgate)
+            return _combined(du, token, dxs), dw13, dw2, dgates.at[pair].add(dgate)
 
     with part("router"):
         zeros = lambda x: jnp.zeros(x.shape, jnp.float32)  # noqa: E731
         du, dw13, dw2, dgates = jax.lax.fori_loop(
-            0, tiles, body, (zeros(u), zeros(w13), zeros(w2), zeros(gates.reshape(-1))))
-        return (du.astype(cd), dw13.astype(w13.dtype), dw2.astype(w2.dtype),
-                dgates.reshape(gates.shape).astype(gates.dtype), None, None)
+            0, tiles, body, (_zero_blocks(u), zeros(w13), zeros(w2), zeros(gates.reshape(-1))))
+        return (jnp.concatenate(du, axis=1).astype(cd), dw13.astype(w13.dtype),
+                dw2.astype(w2.dtype), dgates.reshape(gates.shape).astype(gates.dtype), None, None)
 
 
 held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)

@@ -52,8 +52,12 @@ which owns the chip:
             in), and the router's choice by groups at 12,544 x 512 against a
             router by sorting on the host, with the tolerances proven on the
             spot: plain attention without the shared key's scores, and the
-            top 8 of all 512, must fail them.  With --ling, or alone with
-            --ling-kernels (no network is built: about a minute)
+            top 8 of all 512, must fail them; the router's choice by
+            selection against the sort, and the walk's combine by column
+            blocks against one scatter-add of whole rows at a tile of each of
+            the four expert cells, with the microseconds of each.  With
+            --ling, or alone with --ling-kernels (no network is built: about
+            two minutes)
 
 Sets no platform itself.  Exits non-zero, with one line saying why and no
 result, before compiling anything if jax's default backend is not a TPU, and
@@ -710,6 +714,65 @@ def choice_against_sorting_on_the_chip(shapes=ROUTER_SHAPES, walk_tile=WALK_TILE
     return rows
 
 
+# (tokens a step's forward, router outputs, a token's, experts held, width): the four expert
+# cells in ``ROUTER_SHAPES``' order; a tile of the walk is ``tile_rows`` of these
+WALK_SHAPES = ((12544, 512, 8, 16, 2560), (12544, 320, 8, 8, 4096), (12544, 256, 10, 8, 3072),
+               (25088, 64, 4, 8, 2048))
+
+
+def combine_against_whole_rows_on_the_chip(shapes=WALK_SHAPES, repeats: int = 20) -> list:
+    """The walk's combine, ``expert_torso._combined`` (a token sum in column
+    blocks, one scatter-add a block), against one scatter-add of whole rows
+    on this device at a tile of each expert cell: the held pairs of a random
+    choice of experts sorted by expert as the walk has them, float32 rows,
+    zero past the last pair, onto sums that hold something.  Both within
+    float32's rounding of a float64 sum by token (they add the same terms in
+    the same order: ``equal_bits``), and the microseconds of both, dependent
+    executions inside one program."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ape_x_dqn_tpu.models import expert_torso
+
+    def chained(add):       # the next execution's indices hang on this one's sums
+        def step(carry):
+            y, token, ys = carry
+            moved = (jax.tree.leaves(y)[0][0, 0] < -1e30).astype(token.dtype)
+            return add(y, token + moved, ys), token, ys
+        return step
+
+    whole_rows = lambda y, token, ys: y.at[token].add(ys)  # noqa: E731
+    rows = []
+    for tokens, outputs, k, held, width in shapes:
+        rng = np.random.default_rng(tokens + outputs)
+        chosen = np.argsort(rng.random((tokens, outputs)), -1)[:, :k]
+        keys = np.where(chosen < held, chosen, held).reshape(-1)
+        order = np.argsort(keys, kind="stable")
+        tile = int(expert_torso.tile_rows(tokens * k, held, outputs))
+        live = min(int((keys < held).sum()), tile)
+        token = jnp.asarray(np.pad(order, (0, -order.size % tile))[:tile] // k, jnp.int32)
+        key = jax.random.split(jax.random.PRNGKey(tokens + width), 2)
+        ys = jnp.where((jnp.arange(tile) < live)[:, None], jax.random.normal(key[0], (tile, width)), 0.0)
+        y0 = jax.random.normal(key[1], (tokens, width))
+        widths = [b.shape[1] for b in jax.eval_shape(expert_torso._zero_blocks, y0)]
+        blocks0 = tuple(jnp.split(y0, np.cumsum(widths)[:-1], axis=1))
+        got = np.asarray(jnp.concatenate(jax.jit(expert_torso._combined)(blocks0, token, ys), axis=1))
+        want = np.asarray(jax.jit(whole_rows)(y0, token, ys))
+        exact = np.asarray(y0, np.float64)
+        np.add.at(exact, np.asarray(token), np.asarray(ys, np.float64))
+        for name, sums in (("column blocks", got), ("whole rows", want)):
+            np.testing.assert_allclose(sums, exact, rtol=0, atol=1e-5, err_msg=(
+                f"combine: {name} differ from the float64 sum by token ({tokens} x {width})"))
+        rows.append({
+            "tokens": tokens, "tile_rows": tile, "live_rows": live, "width": width, "blocks": len(widths),
+            "equal_bits": bool(np.array_equal(got, want)),
+            "column_blocks_us": _device_microseconds(
+                chained(expert_torso._combined), (blocks0, token, ys), repeats),
+            "whole_rows_us": _device_microseconds(chained(whole_rows), (y0, token, ys), repeats)})
+    return rows
+
+
 def leg_ling_kernels() -> None:
     for name, (near, far) in latent_kernels_against_plain().items():
         say(f"ling_kernels: {name} {near:.5f} from plain attention (limit {KERNEL_REL}), "
@@ -718,6 +781,8 @@ def leg_ling_kernels() -> None:
     say(f"ling_kernels: route against sorting {route_against_sorting()}")
     for row in choice_against_sorting_on_the_chip():
         say(f"ling_kernels: the choice against the sort on the chip, bit for bit: {row}")
+    for row in combine_against_whole_rows_on_the_chip():
+        say(f"ling_kernels: the walk's combine against one scatter-add of whole rows: {row}")
 
 
 def main() -> int:

@@ -489,18 +489,15 @@ def test_the_expert_layer_builds_no_worst_case_buffer(topo, no_compile_cache):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < WORST_CASE_BLOCK_TEMP_BYTES, temp
 
-@pytest.mark.parametrize("name", ["ling3_q_l7", "lfm2moe_q_ep8"])
-def test_the_router_chooses_without_a_sort_or_a_gather(topo, no_compile_cache, name):
-    """One ``ExpertShare`` at the cell's shapes (12,544 tokens over 512
-    outputs in 8 groups; 25,088 over 64), forward and pulled back, compiled
-    for v5e: the choice is fusions of ``router_choice``'s reductions whose
-    innermost ``torso:`` scope is ``torso:router`` (the parts' readers count
-    them there), no ``sort`` comes from a ``top_k`` and no ``gather`` from a
-    ``take_along_axis``, nothing of the choice is a sort, a gather or a
-    scatter at all; the pairs' ``argsort`` is still a sort of ``tokens x k``
-    keys."""
+@pytest.fixture(scope="module", params=["ling3_q_l7", "lfm2moe_q_ep8"])
+def expert_share(request, topo, no_compile_cache):
+    """(the cell's name, spec, rows, tokens a row, one ``ExpertShare`` of
+    ``benchmark/configs/<name>.json`` at the cell's shapes (12,544 tokens
+    over 512 outputs in 8 groups; 25,088 over 64), forward and pulled back,
+    compiled for v5e): one compile a cell for the tests that read it."""
     from ape_x_dqn_tpu.models import expert_torso, lfm2_moe, ling_hybrid
 
+    name = request.param
     cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
                       / "configs" / f"{name}.json").read_text())
     family, rows, tokens = ((ling_hybrid, 8, 1568) if name == "ling3_q_l7" else (lfm2_moe, 512, 49))
@@ -517,10 +514,27 @@ def test_the_router_chooses_without_a_sort_or_a_gather(topo, no_compile_cache, n
         out, sown = layer.apply(p, u, mutable=["routing"])
         return jnp.sum(jnp.square(out.astype(jnp.float32))), sown
 
-    text = _compile_text(jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)), (params, u))
+    return name, spec, rows, tokens, _compile(
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)), (params, u))
+
+
+def _named(text: str) -> list:
+    """[(an instruction's name, its opcode, its ``op_name``)] of a program's text."""
     named = [(m.group("name"), m.group("op"), re.search(r'op_name="([^"]*)"', line))
              for line in text.splitlines() for m in [_INSTRUCTION.match(line)] if m]
-    named = [(n, op, scope.group(1) if scope else "") for n, op, scope in named]
+    return [(n, op, scope.group(1) if scope else "") for n, op, scope in named]
+
+
+def test_the_router_chooses_without_a_sort_or_a_gather(expert_share):
+    """In one ``ExpertShare`` at the cell's shapes the choice is fusions of
+    ``router_choice``'s reductions whose innermost ``torso:`` scope is
+    ``torso:router`` (the parts' readers count them there), no ``sort`` comes
+    from a ``top_k`` and no ``gather`` from a ``take_along_axis``, nothing of
+    the choice is a sort, a gather or a scatter at all; the pairs' ``argsort``
+    is still a sort of ``tokens x k`` keys."""
+    _, spec, rows, tokens, compiled = expert_share
+    text = compiled.as_text()
+    named = _named(text)
     assert not [(n, scope) for n, op, scope in named if op == "sort" and scope.endswith("top_k")]
     assert not [(n, scope) for n, op, scope in named if op == "gather" and "take_along_axis" in scope]
     assert not [(n, scope) for n, op, scope in named if "top_k" in scope or "take_along_axis" in scope]
@@ -532,6 +546,31 @@ def test_the_router_chooses_without_a_sort_or_a_gather(topo, no_compile_cache, n
     assert [n for n, op, scope in named if op == "sort" and scope.endswith("argsort)/sort")
             and f"s32[{pairs}]" in text.split(f"%{n} = ", 1)[1][:80]], "the pairs' argsort went"
 
+
+# One ``ExpertShare``'s temporaries, forward and pulled back, compiled for v5e
+# by the fixture above at the parent of PR 45, whose combine was one
+# scatter-add of whole rows into ``f32[tokens, d]``.
+WHOLE_ROW_COMBINE_TEMP_BYTES = {"ling3_q_l7": 993_377_792, "lfm2moe_q_ep8": 1_284_998_656}
+
+
+def test_the_combine_adds_a_column_block_at_a_time(expert_share):
+    """The walk's combine in one ``ExpertShare`` at the cell's shapes: no
+    scatter-add takes a token sum of whole rows (``f32[12544, 2560]``,
+    ``f32[25088, 2048]``); the forward's walk and the backward's each add
+    into blocks of 512 columns, five at Ling's width and four at LFM2's.
+    Everything of the combine sits under ``torso:router`` (the parts' readers
+    count it there), and the program takes no more temporaries than with one
+    scatter-add of whole rows."""
+    name, _, rows, tokens, compiled = expert_share
+    text = compiled.as_text()
+    blocks = ["512"] * {"ling3_q_l7": 5, "lfm2moe_q_ep8": 4}[name]
+    sums = re.findall(rf"= f32\[{rows * tokens},(\d+)\]\S* scatter\(", text)
+    assert sums == 2 * blocks, sums
+    combine = [(op, scope) for _, op, scope in _named(text) if "/combine/" in scope]
+    assert "scatter" in {op for op, _ in combine}
+    assert all(re.findall(r"torso:\w+", scope)[-1] == "torso:router" for _, scope in combine), combine
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= WHOLE_ROW_COMBINE_TEMP_BYTES[name], temp
 
 
 def test_the_history_torsos_fused_program_fits_the_chip(topo, no_compile_cache, monkeypatch):
