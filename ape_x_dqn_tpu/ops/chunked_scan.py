@@ -33,8 +33,8 @@ takes the sequence already cut and turned, ``dt`` as ``[chunks, B, H,
 chunk]``, ``B`` and ``C`` as ``[chunks, B, chunk, N]`` (``N`` of 128 fills
 the lanes), and every product of ``_chunk`` contracts over the last axis of
 an operand as it lies; a caller makes that layout where it makes the values
-(``ops/pallas/scan_layout.py``).  ``chunked_scan`` is the same walk for a
-caller that holds ``[B, T, H, P]``.
+(``ops/pallas/scan_layout.py``); one that holds ``[B, T, H, P]`` cuts and
+joins with ``cut`` and ``join``.
 
 The backward pass is written by hand (``jax.custom_vjp``): it keeps the
 inputs and each chunk's incoming state (``[chunks, B, H, P, N]`` float32)
@@ -45,8 +45,6 @@ where reverse-mode differentiation of the walk would stack every chunk's.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -148,35 +146,3 @@ def _scan_fwd(x, dt, a, b, c, d):
 
 
 scan_chunks.defvjp(_scan_fwd, _pull)
-
-
-def _cuts(x, dt, b, c, chunk: int):
-    return cut(x, chunk, True), cut(dt, chunk, True), cut(b, chunk), cut(c, chunk)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def chunked_scan(x, dt, a, b, c, d, chunk: int):
-    """``scan_chunks`` for a caller that holds the tokens major and uncut:
-    ``x`` [B, T, H, P], ``dt`` [B, T, H], ``b``, ``c`` [B, T, N] -> ``y`` [B,
-    T, H, P]; what it keeps for the backward pass is uncut too."""
-    return _chunked_fwd(x, dt, a, b, c, d, chunk)[0]
-
-
-def _chunked_fwd(x, dt, a, b, c, d, chunk: int):
-    with part("ssm_scan"):
-        xc, dtc, bc, cc = _cuts(x, dt, b, c, chunk)
-        y, states = _walk(xc, dtc, a, bc, cc, d, keep=True)
-        return join(y, x.shape[1], True), (x, dt, a, b, c, d, states)
-
-
-def _chunked_bwd(chunk: int, kept, dy):
-    x, dt, a, b, c, d, states = kept
-    with part("ssm_scan"):
-        xc, dtc, bc, cc = _cuts(x, dt, b, c, chunk)
-        dx, ddt, da, db, dc, dd = _pull((xc, dtc, a, bc, cc, d, states), cut(dy, chunk, True))
-        tokens = x.shape[1]
-        return (join(dx, tokens, True), join(ddt, tokens, True), da,
-                join(db, tokens), join(dc, tokens), dd)
-
-
-chunked_scan.defvjp(_chunked_fwd, _chunked_bwd)

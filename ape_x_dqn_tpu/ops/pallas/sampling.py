@@ -1,41 +1,22 @@
-"""Pallas TPU kernel: stratified inverse-CDF sampling over priorities.
+"""Stratified inverse-CDF sampling over priorities.
 
 The device replay's hot op is "given priorities p[0..C) and B stratified
 target masses, find the B leaf indices whose prefix-sum intervals contain
-them".  The XLA spelling (``cumsum`` + ``searchsorted``) materializes the
-full C-length prefix array in HBM — a write + re-read of 4·C bytes the
-kernel below avoids: it streams the priority array through VMEM **once**
-(sequential grid over (R,128) tiles, running carry in SMEM — TPU grid
-programs execute in order, which is what makes the carry legal), builds the
-tile's inclusive prefix with an unrolled Hillis-Steele shift-add (``cumsum``
-has no Mosaic lowering), and resolves each target with a monotone count
-``pos = Σ[prefix ≤ rel]`` — no argmax, no reshape, nothing the TPU
-lowering lacks.  HBM traffic drops from ~3 passes to 1.
-
-Written per /opt/skills/guides/pallas_guide.md idioms (sequential-grid
-carry, SMEM scratch, ``@pl.when`` predication).
-
-Hardware measurement (round 2, real v5e at C=2M) found the streaming
-kernel's sequential grid pays ~1 µs/tile of grid overhead and the flat
-spellings pay O(C) HBM traffic per call, so the production default in
-``sample_indices`` is now ``_two_level_sample`` — a radix-√C two-level
-inverse-CDF (the TPU-native sum-tree) that does O(C/chunk)+O(B·chunk)
-work.  The Pallas kernel and flat XLA spelling remain as explicitly
-selectable paths (`use_pallas=True/False`): the kernel documents the
-single-pass bandwidth experiment and runs under interpret mode on CPU;
-the flat spelling is the test oracle.
+them".  The flat XLA spelling (``cumsum`` + ``searchsorted``) writes and
+reads again the whole C-length prefix array in HBM: measured on a real v5e
+at C=2M it pays O(C) of traffic a call.  ``sample_indices`` is
+``_two_level_sample``, a radix-sqrt(C) two-level inverse-CDF (the TPU-native
+sum-tree) that does O(C/chunk) + O(B chunk) work; the flat spelling stays as
+the tests' oracle.  A streaming Pallas kernel (one pass over the priorities,
+a running carry in SMEM) was measured on the same chip, paid about 1 us a
+tile of grid overhead, two orders behind the two-level sampler, and is gone
+(PR 46); the module keeps its place under ``ops/pallas/`` for its importers.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-
-LANES = 128
-ROWS = 8
-BLOCK = ROWS * LANES  # priorities per grid step; 4 KB f32 in VMEM
 
 
 def _xla_sample(priorities: jax.Array, targets: jax.Array) -> jax.Array:
@@ -44,90 +25,6 @@ def _xla_sample(priorities: jax.Array, targets: jax.Array) -> jax.Array:
     cdf = jnp.cumsum(priorities)
     idx = jnp.searchsorted(cdf, targets, side="right")
     return jnp.clip(idx, 0, priorities.shape[0] - 1).astype(jnp.int32)
-
-
-def _tile_inclusive_prefix(x: jax.Array) -> jax.Array:
-    """Inclusive prefix over a (ROWS, LANES) tile in row-major order.
-
-    The TPU-native prefix sum: a triangular matmul on the MXU.
-    ``cumsum`` has no Mosaic lowering and shifted-concat Hillis-Steele trips
-    offset constraints, but prefix[r, j] = Σ_{k≤j} x[r, k] is exactly
-    ``x @ U`` with U upper-triangular ones — one 128×128 systolic pass.
-    Row offsets are the same trick on the (tiny) row-total vector with a
-    strictly-lower-triangular matrix.
-    """
-    upper = jnp.triu(jnp.ones((LANES, LANES), jnp.float32))       # k<=j
-    prefix = jax.lax.dot(x, upper, precision=jax.lax.Precision.HIGHEST)
-    row_tot = x @ jnp.ones((LANES, 1), jnp.float32)               # (ROWS, 1)
-    strictly_lower = jnp.tril(jnp.ones((ROWS, ROWS), jnp.float32), k=-1)
-    row_excl = jax.lax.dot(
-        strictly_lower, row_tot, precision=jax.lax.Precision.HIGHEST
-    )                                                             # (ROWS, 1)
-    return prefix + row_excl
-
-
-def _kernel(p_ref, t_ref, out_ref, carry_ref):
-    """One grid step: resolve all targets landing in this priority tile.
-
-    carry_ref (SMEM, (1,)) holds the total mass of all previous tiles —
-    valid because TPU grid steps run sequentially.
-    """
-    from jax.experimental import pallas as pl
-
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _():
-        carry_ref[0] = 0.0
-        # Initialize before the first read of out_ref below: a target past
-        # the total mass (callers clamp, but belt-and-braces) resolves to
-        # the last leaf instead of uninitialized memory.
-        out_ref[:] = jnp.full_like(out_ref, pl.num_programs(0) * BLOCK - 1)
-
-    base = carry_ref[0]
-    prefix = _tile_inclusive_prefix(p_ref[:])   # (ROWS, LANES)
-    tile_sum = prefix[ROWS - 1, LANES - 1]
-    targets = t_ref[:]                          # (1, B) — B on the lane dim
-    rel = targets - base
-    in_tile = (targets >= base) & (targets < base + tile_sum)
-    # Monotone count: index of first prefix entry > rel (== #entries <= rel).
-    # Layout: (ROWS, LANES, B) with B in lanes; reduce the tile axes.
-    le = (prefix[:, :, None] <= rel[0][None, None, :]).astype(jnp.int32)
-    pos = jnp.sum(le, axis=(0, 1))[None, :]     # (1, B)
-    pos = jnp.minimum(pos, BLOCK - 1)
-    global_idx = (step * BLOCK + pos).astype(jnp.int32)
-    out_ref[:] = jnp.where(in_tile, global_idx, out_ref[:])
-    carry_ref[0] = base + tile_sum
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _pallas_sample(priorities: jax.Array, targets: jax.Array,
-                   interpret: bool = False) -> jax.Array:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    C = priorities.shape[0]
-    if C % BLOCK != 0:
-        pad = BLOCK - C % BLOCK
-        priorities = jnp.concatenate([priorities, jnp.zeros((pad,), priorities.dtype)])
-    C_padded = priorities.shape[0]
-    B = targets.shape[0]
-    grid = C_padded // BLOCK
-    p2d = priorities.astype(jnp.float32).reshape(grid * ROWS, LANES)
-    t2d = targets.astype(jnp.float32)[None, :]
-    out = pl.pallas_call(
-        _kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((ROWS, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, B), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, B), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
-        interpret=interpret,
-    )(p2d, t2d)
-    return jnp.clip(out[0], 0, C - 1)
 
 
 def _two_level_sample(priorities: jax.Array, targets: jax.Array,
@@ -168,23 +65,7 @@ def _two_level_sample(priorities: jax.Array, targets: jax.Array,
     return jnp.clip(r * chunk + pos, 0, C - 1).astype(jnp.int32)
 
 
-def sample_indices(
-    priorities: jax.Array,
-    targets: jax.Array,
-    use_pallas: bool | None = None,
-) -> jax.Array:
-    """Stratified inverse-CDF lookup: indices [B] for target masses [B].
-
-    Default is the two-level sampler everywhere: on a real v5e it beats both
-    the flat-cumsum XLA spelling and the streaming Pallas kernel by ~2
-    orders of magnitude at large C (all three were measured on hardware;
-    the Pallas kernel's sequential grid pays ~1 µs/tile of grid overhead).
-    ``use_pallas=True`` forces the Pallas kernel (kept for the bandwidth
-    experiment it documents); ``use_pallas=False`` forces the flat XLA
-    spelling (the oracle for tests).
-    """
-    if use_pallas is None:
-        return _two_level_sample(priorities, targets)
-    if use_pallas:
-        return _pallas_sample(priorities, targets)
-    return _xla_sample(priorities, targets)
+def sample_indices(priorities: jax.Array, targets: jax.Array) -> jax.Array:
+    """Stratified inverse-CDF lookup: indices [B] for target masses [B], by
+    the two-level sampler."""
+    return _two_level_sample(priorities, targets)

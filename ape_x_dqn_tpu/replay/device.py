@@ -10,11 +10,10 @@ ingest + K train steps into ONE dispatch (`lax.scan` over sampled batches),
 amortizing host dispatch overhead — the single-chip path to the north-star
 steps/sec (SURVEY §7 hard parts #1-2 collapse into on-device ops).
 
-Sampling is flat prefix-sum inverse-CDF, not a tree: on TPU a cumsum over
-the priority vector is one bandwidth-bound pass that the VPU eats (and the
-pallas kernel in ops/pallas/sampling.py does it without materializing the
-prefix array); an O(log N) pointer-chasing tree would serialize on exactly
-the hardware that hates it.  Same math as the host sum-tree: mass ∝ p^α,
+Sampling is a two-level prefix-sum inverse-CDF (ops/pallas/sampling.py), not
+a pointer-chasing tree: row sums, a small cumsum over them and one cumsum of
+the picked rows are bandwidth-bound passes that the VPU eats; an O(log N)
+tree walk would serialize on exactly the hardware that hates it.  Same math as the host sum-tree: mass ∝ p^α,
 stratified targets, β-annealed IS weights (reference replay.py:24-30
 semantics, reference defects excluded per SURVEY §2.8).
 

@@ -109,3 +109,20 @@ def test_native_unknown_key_rejected(tmp_path, section, field, value):
     native.write_text(json.dumps({section: {field: value}}))
     with pytest.raises(ValueError, match="unknown config keys"):
         load_config(str(native))
+
+
+def test_every_torso_network_has_a_row_of_the_contract_and_a_file_that_runs_it():
+    """``tests/torso_contract.py`` states what a torso's network is held to; a
+    name in ``TORSO_NETWORKS`` with no row there, or no test file whose
+    ``TestContract`` is ``contract.of("<name>")``, is held to nothing."""
+    import pathlib
+    import re
+
+    from ape_x_dqn_tpu.config import TORSO_NETWORKS
+    from tests import torso_contract
+
+    assert set(torso_contract.ROWS) == set(TORSO_NETWORKS)
+    tests = pathlib.Path(__file__).parent
+    run = {m for f in tests.glob("test_*.py") for m in re.findall(
+        r'^class TestContract\(contract\.of\("(\w+)"\)\):', f.read_text(), re.M)}
+    assert run == set(TORSO_NETWORKS), set(TORSO_NETWORKS) ^ run

@@ -2,61 +2,30 @@
 a shared key operand against plain attention, the router that keeps groups
 against a sort in numpy, the delta-rule scan at the bounded gate's floor, the
 two mixers' shares of heads and the expert layer's 32 shares against the uncut
-layers, the counters, the configuration path and the trainer's loop, all at
-small widths on the CPU (the attention kernels in Pallas' interpreter).  The
-network against ``benchmark/reference/ling3_q.py`` on seeded weights is
-``tests/test_ling_hybrid_reference.py``: a file of its own, so that another
-worker of the test run takes those two and a half minutes."""
+layers, at small widths on the CPU (the attention kernels in Pallas'
+interpreter); what every torso is held to (structure,
+``benchmark/reference/ling3_q.py`` on seeded weights, the float32 leaves,
+scopes, counters, the configuration path, the trainer's loop) is the
+contract's, ``tests/torso_contract.py``, on this torso's row."""
 import dataclasses
-import json
 import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-for _p in (os.path.join(ROOT, "benchmark"), ROOT):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
-
-from ape_x_dqn_tpu.config import HISTORY_NETWORKS, TORSO_NETWORKS, ApexConfig, load_config, network_kwargs
-from ape_x_dqn_tpu.models import dueling, expert_torso, ling_hybrid, solar_open2
-from ape_x_dqn_tpu.models.dueling import build_network
+from ape_x_dqn_tpu.models import expert_torso, ling_hybrid, solar_open2
 from ape_x_dqn_tpu.ops.chunked_delta import chunked_delta as delta
 from ape_x_dqn_tpu.ops.pallas import blocked_attention as blocked
-from ape_x_dqn_tpu.utils import profiling
+from tests import torso_contract as contract
+from tests.torso_contract import built, init_of, pulled  # noqa: F401 - built: the module's fixture
 
-TORSO = dict(
-    model_type="bailing_hybrid", hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
-    moe_shared_expert_intermediate_size=32, num_shared_experts=1, num_attention_heads=4,
-    num_key_value_heads=4, head_dim=16, kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
-    v_head_dim=16, q_lora_rank=None, rope_theta=6000000, rope_interleave=True,
-    short_conv_kernel_size=4, rms_norm_eps=1e-6, layer_group_size=3, num_hidden_layers=4,
-    first_k_dense_replace=1, published=dict(num_hidden_layers=12, first_k_dense_replace=2),
-    layers_held=[1, 2, 3, 4], no_kda_lora=True, kda_safe_gate=True, kda_lower_bound=-5,
-    num_kv_heads_for_linear_attn=0, num_experts=4, router_outputs=16, experts_held=[4, 8],
-    n_group=4, topk_group=2, norm_topk_prob=True, routed_scaling_factor=2.5,
-    num_experts_per_tok=2, score_function="sigmoid", moe_router_enable_expert_bias=True,
-    expert_swiglu_limit_list=[0] * 10 + [4, 4], share_expert_swiglu_limit_list=[0] * 11 + [5],
-    kda_chunk_size=16, channels=[8, 8, 8], hidden=32, expert_bias_update_rate=0.05,
-)
-# what the benchmark's driver adds to the torso's keys for its reference
-CFG = dict(TORSO, obs_shape=[44, 60, 5], num_actions=6, batch_size=4, optimizer="rmsprop",
-           learning_rate=6.25e-5, rmsprop_decay=0.95, rmsprop_eps=1.5e-7, max_grad_norm=40.0,
-           loss="squared")
+TORSO = contract.LING
 
 
-def small_net(compute=jnp.float32, **over):
-    return build_network("ling_hybrid", 6, torso=dict(TORSO, **over), channels=(8, 8, 8),
-                         hidden=32, compute_dtype=compute)
-
-
-def obs(key, rows=2, shape=(44, 60, 5)):   # 5 frames of 2 x 4 positions: 40 tokens
-    return jax.random.randint(key, (rows, *shape), 0, 256).astype(jnp.uint8)
+class TestContract(contract.of("ling_hybrid")):
+    """The contract's cases on this torso (``tests/torso_contract.py``)."""
 
 
 # ------------------------------------------------- the kernels' shared operand
@@ -87,10 +56,10 @@ def test_the_kernels_add_the_shared_keys_scores_forward_and_in_all_five_gradient
     gradients of both query parts, the keys, the shared key (summed over the
     heads) and the values against autodiff of plain attention."""
     args, cot = _operands(700)
-    got, pull = jax.vjp(lambda *a: blocked.blocked_attention(a[0], a[1], a[2], None, a[3], a[4]), *args)
-    want, pull_plain = jax.vjp(_plain, *args)
+    got, gots = pulled(lambda *a: blocked.blocked_attention(a[0], a[1], a[2], None, a[3], a[4]))(cot, *args)
+    want, wanted = pulled(_plain)(cot, *args)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-    for name, a, b in zip(("q", "k", "v", "q_shared", "k_shared"), pull(cot), pull_plain(cot)):
+    for name, a, b in zip(("q", "k", "v", "q_shared", "k_shared"), gots, wanted):
         assert a.shape == b.shape and a.dtype == b.dtype, name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=1e-4, err_msg=name)
 
@@ -100,12 +69,11 @@ def test_a_group_of_query_heads_shares_the_shared_key_too():
     heads a key-value head, a shared part of 32 beside heads of 64."""
     (q, k, v, qs, ks), cot = _operands(200, heads=4, group=2, width=64, shared=32)
     rep = lambda x: jnp.repeat(x, 2, axis=1)  # noqa: E731
-    got, pull = jax.vjp(lambda *a: blocked.blocked_attention(a[0], a[1], a[2], None, a[3], a[4]),
-                        q, k, v, qs, ks)
-    want, pull_plain = jax.vjp(lambda q, k, v, qs, ks: _plain(q, rep(k), rep(v), qs, ks),
-                               q, k, v, qs, ks)
+    got, gots = pulled(lambda *a: blocked.blocked_attention(a[0], a[1], a[2], None, a[3], a[4]))(
+        cot, q, k, v, qs, ks)
+    want, wanted = pulled(lambda q, k, v, qs, ks: _plain(q, rep(k), rep(v), qs, ks))(cot, q, k, v, qs, ks)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-    for a, b in zip(pull(cot), pull_plain(cot)):
+    for a, b in zip(gots, wanted):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=1e-4)
 
 
@@ -258,11 +226,11 @@ def test_the_scan_at_a_decay_of_e_to_the_minus_five_a_step_is_the_literal_recurr
     g = jnp.full_like(g, -5.0 + 1e-3)
     assert float(jnp.sum(g[0, 0, :64, 0])) < -319.0
     with jax.default_matmul_precision("highest"):
-        want, pull = jax.vjp(literal, q, k, v, g, beta)
-        got, pull_chunked = jax.vjp(lambda *z: delta(*z, 64), q, k, v, g, beta)
+        want, wanted = pulled(literal)(cot, q, k, v, g, beta)
+        got, gots = pulled(lambda *z: delta(*z, 64))(cot, q, k, v, g, beta)
         assert bool(jnp.all(jnp.isfinite(got)))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
-        for name, a, b in zip(("q", "k", "v", "g", "beta"), pull_chunked(cot), pull(cot)):
+        for name, a, b in zip(("q", "k", "v", "g", "beta"), gots, wanted):
             assert bool(jnp.all(jnp.isfinite(a))), name
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4, rtol=5e-4,
                                        err_msg=name)
@@ -277,7 +245,7 @@ def test_the_gate_is_bounded_and_told_to_the_one_delta_module():
     assert (m.gate_rank, m.gate, m.gate_bound, m.beta_scale, m.chunk) == (None, "bounded", -5.0, 1.0, 16)
     layer = solar_open2.DeltaAttention(spec, "linear_attention", jnp.float32, jnp.float32)
     u = 30.0 * jax.random.normal(jax.random.PRNGKey(0), (2, 40, 64))     # drives the sigmoid to both ends
-    params = layer.init(jax.random.PRNGKey(1), u)["params"]
+    params = init_of(layer, jax.random.PRNGKey(1), u)["params"]
     assert set(params) == {"w_q", "w_k", "w_v", "conv_q", "conv_k", "conv_v", "w_f", "A_log",
                            "dt_bias", "w_b", "w_g", "norm", "w_o"}
     assert params["w_f"].shape == params["w_g"].shape == (64, 64)
@@ -338,7 +306,7 @@ def test_the_four_head_shares_add_up_to_the_uncut_mixer(op):
     u = jax.random.normal(jax.random.PRNGKey(0), (2, 40, 64))
     mixer = dict(spec.mixers)[op]
     whole = mixer(spec, op, jnp.float32, jnp.float32)
-    params = whole.init(jax.random.PRNGKey(1), u)["params"]
+    params = init_of(whole, jax.random.PRNGKey(1), u)["params"]
     want = whole.apply({"params": params}, u)
     total = 0.0
     for lo in range(0, 8, 2):
@@ -363,9 +331,9 @@ def test_the_32_expert_shares_add_up_to_the_uncut_expert_layer():
         num_experts_per_tok=8))
     u = jax.random.normal(jax.random.PRNGKey(3), (2, 40, 64))
     shared = expert_torso.SwiGLU(base.shared_expert_intermediate_size, jnp.float32, jnp.float32)
-    sp = shared.init(jax.random.PRNGKey(4), u)
+    sp = init_of(shared, jax.random.PRNGKey(4), u)
     whole = expert_torso.ExpertShare(base, jnp.float32, jnp.float32)
-    params = whole.init(jax.random.PRNGKey(5), u)["params"]
+    params = init_of(whole, jax.random.PRNGKey(5), u)["params"]
     out, sown = whole.apply({"params": params}, u, mutable=["routing"])
     assert int(sown["routing"]["kept"][0]) == 80                 # every token keeps some group
     want = out + shared.apply(sp, u)
@@ -382,51 +350,6 @@ def test_the_32_expert_shares_add_up_to_the_uncut_expert_layer():
     assert all(len(set(kept[g * 4:(g + 1) * 4])) == 1 for g in range(8)) and sum(kept[::4]) == 4 * 80
 
 
-# ----------------------------------------------------------------- the network
-
-def test_the_network_has_the_issues_structure():
-    net = small_net()
-    x = obs(jax.random.PRNGKey(2))
-    assert net.tokens_of(x.shape) == 40
-    params = net.init(jax.random.PRNGKey(3), x)["params"]
-    assert set(params) >= {"layer_0", "layer_1", "layers_2_3", "w_tok", "final_norm"}
-    assert set(params["layer_0"]) == {"operator_norm", "ffn_norm", "linear_attention", "dense"}
-    assert params["layer_0"]["dense"]["w1"].shape == (64, 128)      # the leading dense layer
-    assert set(params["layer_1"]) == {"operator_norm", "ffn_norm", "latent_attention", "moe",
-                                      "shared_expert"}
-    assert {k: v.shape for k, v in params["layer_1"]["latent_attention"].items()} == {
-        "w_q": (64, 4 * 24), "w_dkv": (64, 24 + 8), "kv_norm": (24,), "w_ukv": (24, 4 * 32),
-        "w_g": (64, 4), "w_o": (64, 64)}
-    assert params["layers_2_3"]["moe"]["router"].shape == (2, 64, 16)
-    assert params["layers_2_3"]["moe"]["w13"].shape == (2, 4, 64, 64)
-    assert params["layers_2_3"]["shared_expert"]["w1"].shape == (2, 64, 32)
-    spec = net.spec
-    assert spec.layers == (("linear_attention", "dense"), ("latent_attention", "moe"),
-                           ("linear_attention", "moe"), ("linear_attention", "moe"))
-    assert (spec.router_outputs, spec.experts_held, spec.num_experts_per_tok, spec.score_function,
-            spec.use_expert_bias, spec.shared_expert_intermediate_size, spec.routed_scaling_factor,
-            spec.router_groups, spec.router_groups_kept, spec.norm_eps, spec.frame_history) == (
-                16, (4, 8), 2, "sigmoid", True, 32, 2.5, 4, 2, 1e-6, True)
-    m = spec.arg("latent")
-    assert (m.heads, m.kv_rank, m.nope, m.rope, m.v, m.theta) == (4, 24, 16, 8, 16, 6e6)
-    assert ling_hybrid.layer_types(TORSO) == (["linear_attention"] * 2 + ["latent_attention"]) * 4
-    out, sown = net.apply({"params": params}, x, mutable=["routing"])
-    assert out[2].shape == (2, 6) and bool(jnp.all(jnp.isfinite(out[2])))
-    routing = net.routing_metrics(sown)
-    assert float(routing["held_pairs"]) > 0 and 0.0 < float(routing["groups_kept_hold_share"]) < 1.0
-    # a non-zero swiglu limit on a held layer raises; on a layer not held it does not
-    for name in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
-        with pytest.raises(ValueError, match="no clamp"):
-            ling_hybrid.spec_from_config(dict(TORSO, **{name: [0, 0, 0, 4] + [0] * 8}))
-    ling_hybrid.spec_from_config(dict(TORSO, expert_swiglu_limit_list=[4] + [0] * 11))
-    for bad in (dict(no_kda_lora=False), dict(kda_safe_gate=False), dict(q_lora_rank=128),
-                dict(num_kv_heads_for_linear_attn=2), dict(score_function="softmax"),
-                dict(layer_types=["linear_attention"] * 12),
-                dict(heads_held=[0, 2], published=dict(TORSO["published"], num_attention_heads=8))):
-        with pytest.raises(ValueError):
-            ling_hybrid.spec_from_config(dict(TORSO, **bad))
-
-
 def test_rope_turns_pairs_and_scores_depend_on_the_distance_alone():
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 9, 8))
     got = np.asarray(ling_hybrid.rope_pairs(x, 100.0), np.float64)
@@ -438,92 +361,3 @@ def test_rope_turns_pairs_and_scores_depend_on_the_distance_alone():
     turned = ling_hybrid.rope_pairs(same, 100.0)
     dots = np.asarray(jnp.einsum("bhtd,bhsd->bhts", turned, turned))
     np.testing.assert_allclose(dots[0, 0, 2, 0], dots[0, 0, 7, 5], rtol=1e-5)
-
-
-def test_the_other_torsos_sow_and_count_as_they_did():
-    """One group is no group: the four older families' specs carry the
-    default, sow ``load`` alone and count no ``groups_kept_hold_share``."""
-    from tests.test_solar_open2 import small_net as solar_net
-
-    net = solar_net()
-    assert (net.spec.router_groups, net.spec.router_groups_kept) == (1, 1)
-    x = obs(jax.random.PRNGKey(2))
-    params = net.init(jax.random.PRNGKey(3), x)
-    _, sown = net.apply(params, x, mutable=["routing"])
-    names = {p[-2].key for p, _ in jax.tree_util.tree_leaves_with_path(sown["routing"])}
-    assert names == {"load"} and set(net.routing_metrics(sown)) == {
-        "held_pairs", "load_max", "load_mean", "rows_walked"}
-    assert net.attention_metrics(x.shape).keys() == {"pairs_in_mask_full", "pairs_computed_full",
-                                                     "blocks_visited_full", "blocks_total_full"}
-
-
-def test_the_latent_kernels_are_scoped_inside_the_mixer():
-    assert profiling.PARTS[-1] == "attn_latent"
-    net = small_net()
-    x = obs(jax.random.PRNGKey(8))
-    params = net.init(jax.random.PRNGKey(9), x)
-    text = jax.jit(jax.grad(lambda p: jnp.sum(net.apply(p, x)[2] ** 2))).lower(params).as_text(
-        debug_info=True)
-    for part in ("delta_scan", "attn_latent", "mixer", "router", "experts", "shared_expert",
-                 "dense_ffn", "stem", "head"):
-        assert f"torso:{part}" in text, part
-    assert "torso:mixer/latent_attention/torso:attn_latent" in text
-    assert "torso:mixer/linear_attention/" in text and "transpose(" in text
-    for part in ("ssm_scan", "attn_full", "attn_window"):
-        assert f"torso:{part}" not in text, part
-
-
-def test_config_carries_the_torso_and_the_committed_file_is_the_cells():
-    assert TORSO_NETWORKS[-1] == "ling_hybrid" and HISTORY_NETWORKS[-1] == "ling_hybrid"
-    assert tuple(dueling.TORSO_KINDS) == TORSO_NETWORKS
-    cfg = ApexConfig()
-    cfg.network = "ling_hybrid"
-    cfg.torso = dict(TORSO)
-    with pytest.raises(ValueError, match="frame_stack"):
-        cfg.validate()                      # a history needs more than one frame
-    cfg.env.frame_stack = 5
-    kw = network_kwargs(cfg.validate())
-    assert kw["channels"] == (8, 8, 8) and kw["hidden"] == 32
-    assert build_network(cfg.network, 6, **kw).spec.num_held == 4
-    committed = load_config(os.path.join(ROOT, "configs", "config10_ling3_q_l7.json"))
-    spec = build_network(committed.network, 18, **network_kwargs(committed)).spec
-    assert committed.env.frame_stack == 32 and spec.frame_history
-    assert committed.learner.replay_sample_size == 8 and committed.learner.steps_per_call == 1
-    cell = json.load(open(os.path.join(ROOT, "benchmark", "configs", "ling3_q_l7.json")))
-    assert spec == ling_hybrid.spec_from_config(cell)
-    assert [kinds for kinds in spec.layers] == [("linear_attention", "dense")] + [
-        ("linear_attention", "moe")] * 3 + [("latent_attention", "moe")] + [("linear_attention", "moe")] * 2
-    m, n = spec.arg("linear"), spec.arg("latent")
-    assert (spec.hidden_size, spec.intermediate_size, spec.moe_intermediate_size,
-            spec.shared_expert_intermediate_size, m.heads, m.head_dim, m.conv, m.gate_rank, m.chunk,
-            m.gate, m.gate_bound) == (2560, 6144, 768, 768, 32, 128, 4, None, 64, "bounded", -5.0)
-    assert (n.heads, n.kv_rank, n.nope, n.rope, n.v, n.theta) == (32, 512, 128, 64, 128, 6e6)
-    assert (spec.router_outputs, spec.num_experts_per_tok, spec.router_groups, spec.router_groups_kept,
-            spec.heads_held, spec.routed_scaling_factor) == (512, 8, 8, 4, (0, 8), 2.5)
-    lo, hi = spec.experts_held
-    assert lo == 0 and hi in (8, 16) and hi <= 64                  # all in router group 0
-    assert expert_torso.tile_rows(12544 * 8, hi, 512) == {16: 4608, 8: 2560}[hi]
-
-
-def test_the_trainers_loop_runs_the_network():
-    """``runtime/single_process.py``'s loop, a few learner steps, through
-    ``build_components``: the normal path builds and trains the network on
-    histories of ``env.frame_stack`` frames."""
-    from ape_x_dqn_tpu.runtime import SingleProcessDriver
-
-    cfg = ApexConfig()
-    cfg.env.name = "fake-atari"
-    cfg.env.frame_stack = 4
-    cfg.network = "ling_hybrid"
-    cfg.torso = dict(TORSO)
-    cfg.actor.num_actors = 2
-    cfg.actor.flush_every = 8
-    cfg.learner.min_replay_mem_size = 32
-    cfg.learner.replay_sample_size = 4
-    cfg.replay.capacity = 256
-    driver = SingleProcessDriver(cfg.validate())
-    results = driver.run(learner_steps=3)
-    assert driver.learner_step >= 3
-    learned = [r.loss for r in results if r.learner_step > 0]
-    assert len(learned) >= 3 and all(np.isfinite(v) for v in learned), learned
-    assert type(driver.network).__name__ == "LingHybridQ"
