@@ -341,6 +341,9 @@ def build_train_step(
     attention_metrics = getattr(network, "attention_metrics", None)
     scan_metrics = getattr(network, "scan_metrics", None)
     delta_metrics = getattr(network, "delta_metrics", None)
+    # A network whose nets share a first layer on the same observations
+    # computes the bootstrap's pair itself: ``q_of_two(online, target, obs)``.
+    q_of_two = getattr(network, "q_of_two", None)
 
     gathered = None if grad_reduce_axis is None else rows_gathered_dense(grad_reduce_axis)
 
@@ -357,15 +360,24 @@ def build_train_step(
         with stage("forward"):
             t = batch.transition
             q_values, s1 = q_of(params, t.obs, differentiated=True)
-            # The bootstrap's online forward runs apart from the
-            # differentiated one.  next_obs reaches the loss through an argmax
-            # only: joined with obs in one 2B forward, its B rows ride through
-            # the whole backward pass with cotangents of zero (neither jax nor
-            # XLA drops them) and the forward keeps their activations.  Joining
-            # saves one read of the parameters and never paid for that on a
-            # v5e, not even at B=32 (PERF.md section 6, PR 29).
-            q_next_online, s2 = q_of(jax.lax.stop_gradient(params), t.next_obs)
-            q_next_target, s3 = q_of(target_params, t.next_obs)
+            # The bootstrap's forwards run apart from the differentiated one.
+            # next_obs reaches the loss through an argmax only: joined with
+            # obs in one 2B forward, its B rows ride through the whole
+            # backward pass with cotangents of zero (neither jax nor XLA drops
+            # them) and the forward keeps their activations; a product over 2B
+            # rows never paid for the saved read of the parameters on a v5e
+            # (PERF.md section 6, PR 29).  The two of them read the same
+            # next_obs: a network that offers the pair joins them along the
+            # first layer's output channels, which the array has room for
+            # (DuelingDQN; PERF.md section 6, PR 49), and sows nothing.
+            online_params = jax.lax.stop_gradient(params)
+            if q_of_two is not None:
+                q_next_online, q_next_target = q_of_two(
+                    online_params, target_params, t.next_obs)
+                s2 = s3 = {}
+            else:
+                q_next_online, s2 = q_of(online_params, t.next_obs)
+                q_next_target, s3 = q_of(target_params, t.next_obs)
             targets = losses.double_q_target(
                 q_next_online, q_next_target, t.reward, t.discount
             )

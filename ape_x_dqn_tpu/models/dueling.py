@@ -49,13 +49,8 @@ def _dueling_aggregate(value: jax.Array, advantage: jax.Array) -> jax.Array:
 STEM_WINDOWS = ((8, 4), (4, 2), (3, 1))
 
 
-def conv_stem(x: jax.Array, channels: Sequence[int], compute_dtype, param_dtype,
-              out_dtype=None) -> jax.Array:
-    """Conv(8x8/4) -> Conv(4x4/2) -> Conv(3x3/1), VALID, ReLU, on NHWC uint8
-    or float observations: [B, H, W, C] -> [B, h, w, channels[-1]].  Called
-    inside a module's ``@nn.compact`` method; the convolutions are that
-    module's ``Conv_0..2``.  ``out_dtype`` is the type the last convolution
-    sums and returns in (default ``compute_dtype``)."""
+def _observations(x: jax.Array, compute_dtype) -> jax.Array:
+    """NHWC uint8 or float observations in the compute type, bytes as [0, 1]."""
     # Guard against the reference's NCHW layout, which otherwise fails deep
     # inside flax.
     if x.ndim != 4:
@@ -68,23 +63,56 @@ def conv_stem(x: jax.Array, channels: Sequence[int], compute_dtype, param_dtype,
             f"observations look NCHW (shape {x.shape}); this framework uses "
             "NHWC [B, H, W, C] — transpose with x.transpose(0, 2, 3, 1)"
         )
-    kernels = tuple((k, k) for k, _ in STEM_WINDOWS)
-    strides = tuple((s, s) for _, s in STEM_WINDOWS)
-    if len(channels) != len(kernels):
-        raise ValueError(
-            f"channels must have exactly {len(kernels)} entries, got {channels}"
-        )
     with part("stem"):
         if x.dtype == jnp.uint8:
-            x = x.astype(compute_dtype) / 255.0
-        else:
-            x = x.astype(compute_dtype)
-        dtypes = [compute_dtype] * (len(channels) - 1) + [out_dtype or compute_dtype]
-        for ch, k, s, dtype in zip(channels, kernels, strides, dtypes):
-            x = nn.Conv(ch, k, s, padding="VALID", dtype=dtype,
-                        param_dtype=param_dtype)(x)
+            return x.astype(compute_dtype) / 255.0
+        return x.astype(compute_dtype)
+
+
+def conv_stem(x: jax.Array, channels: Sequence[int], compute_dtype, param_dtype,
+              out_dtype=None, after_first: bool = False) -> jax.Array:
+    """Conv(8x8/4) -> Conv(4x4/2) -> Conv(3x3/1), VALID, ReLU, on NHWC uint8
+    or float observations: [B, H, W, C] -> [B, h, w, channels[-1]].  Called
+    inside a module's ``@nn.compact`` method; the convolutions are that
+    module's ``Conv_0..2``.  ``out_dtype`` is the type the last convolution
+    sums and returns in (default ``compute_dtype``).  ``after_first``: ``x``
+    is ``Conv_0``'s output with its ReLU taken (``first_conv_of_two`` computes
+    it), and the layers after it run."""
+    if len(channels) != len(STEM_WINDOWS):
+        raise ValueError(
+            f"channels must have exactly {len(STEM_WINDOWS)} entries, got {channels}"
+        )
+    if not after_first:
+        x = _observations(x, compute_dtype)
+    dtypes = [compute_dtype] * (len(channels) - 1) + [out_dtype or compute_dtype]
+    with part("stem"):
+        for i in range(int(after_first), len(channels)):
+            k, s = STEM_WINDOWS[i]
+            x = nn.Conv(channels[i], (k, k), (s, s), padding="VALID", dtype=dtypes[i],
+                        param_dtype=param_dtype, name=f"Conv_{i}")(x)
             x = nn.relu(x)
     return x
+
+
+def first_conv_of_two(params_a, params_b, x: jax.Array, compute_dtype
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """``Conv_0`` and its ReLU of two parameter trees on the same
+    observations, as one convolution: the two filter banks side by side along
+    the output channels, so ``x`` is cast and unfolded once and the product is
+    ``2 x channels[0]`` wide.  Per output channel the sum is ``nn.Conv``'s own:
+    operands cast as it casts them, its dimension numbers, stride and
+    padding."""
+    x = _observations(x, compute_dtype)
+    _, stride = STEM_WINDOWS[0]
+    with part("stem"):
+        convs = [p["params"]["Conv_0"] for p in (params_a, params_b)]
+        kernel, bias = (
+            jnp.concatenate([c[leaf].astype(compute_dtype) for c in convs], axis=-1)
+            for leaf in ("kernel", "bias"))
+        y = jax.lax.conv_general_dilated(
+            x, kernel, (stride, stride), "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        y_a, y_b = jnp.split(y + bias, 2, axis=-1)
+        return nn.relu(y_a), nn.relu(y_b)
 
 
 def dueling_head(x: jax.Array, num_actions: int, hidden: int, compute_dtype,
@@ -123,13 +151,27 @@ class DuelingDQN(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        x = conv_stem(x, self.channels, self.compute_dtype, self.param_dtype)
+    def __call__(self, x: jax.Array, after_first: bool = False
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """``after_first``: ``x`` is ``Conv_0``'s output with its ReLU taken
+        (``q_of_two`` computes it) and not an observation."""
+        x = conv_stem(x, self.channels, self.compute_dtype, self.param_dtype,
+                      after_first=after_first)
         return dueling_head(x.reshape((x.shape[0], -1)), self.num_actions,
                             self.hidden, self.compute_dtype, self.param_dtype)
 
     def q_values(self, x: jax.Array) -> jax.Array:
         return self(x)[2]
+
+    def q_of_two(self, params_a, params_b, obs: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """Q of two parameter trees on the same observations (the double-Q
+        bootstrap's online and target nets on ``next_obs``): one first
+        convolution for both (``first_conv_of_two``), then each net's own
+        layers on its half.  What ``apply(params_a, obs)[2]`` and
+        ``apply(params_b, obs)[2]`` compute; no gradient is meant to flow."""
+        firsts = first_conv_of_two(params_a, params_b, obs, self.compute_dtype)
+        return tuple(self.apply(p, y, after_first=True)[2]
+                     for p, y in zip((params_a, params_b), firsts))
 
 
 class DuelingMLP(nn.Module):

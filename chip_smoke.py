@@ -58,6 +58,14 @@ which owns the chip:
             the four expert cells, with the microseconds of each.  With
             --ling, or alone with --ling-kernels (no network is built: about
             two minutes)
+  first_conv  the bootstrap's first convolution apart, at the three conv
+            cells' shapes: the online and the target net's convolutions of N
+            outputs on the same bytes against one of 2N with the two filter
+            banks side by side (``dueling.first_conv_of_two``), equal bits,
+            and the microseconds of both, alone and with each net's second
+            convolution behind its half (PERF.md section 6, PR 49: the cell
+            gains more than the part).  Only with --first-conv (no network
+            is trained: a minute); it runs in no cell
 
 Sets no platform itself.  Exits non-zero, with one line saying why and no
 result, before compiling anything if jax's default backend is not a TPU, and
@@ -773,6 +781,85 @@ def combine_against_whole_rows_on_the_chip(shapes=WALK_SHAPES, repeats: int = 20
     return rows
 
 
+# (rows a chip, frames an observation, the first convolution's outputs): the three conv cells,
+# ``apex_b512``, a chip of ``apex_b512_dp4`` and ``ref_b32``
+FIRST_CONV_SHAPES = ((512, 4, 32), (128, 4, 32), (32, 1, 64))
+
+
+def first_conv_of_two_on_the_chip(shapes=FIRST_CONV_SHAPES, repeats: int = 50) -> list:
+    """The bootstrap's first convolution apart, on this device, at the conv
+    cells' shapes: two convolutions of N outputs (an online bank in float32
+    and a target bank in bfloat16 on the same bytes, each with the cast, the
+    bias and the ReLU, as two ``nn.Conv`` make them) against
+    ``dueling.first_conv_of_two`` (one of 2N), equal bits, and the
+    microseconds of both: alone, the outputs written out, and with each
+    net's second convolution behind its half, which is what reads them in
+    the step.  The observations are the loop's to lay out, as the step's
+    batch is the scan's."""
+    import jax
+    import jax.numpy as jnp
+
+    from ape_x_dqn_tpu.models import dueling
+
+    def conv(x, leaves, window):     # nn.Conv's own call, operands cast as it casts them
+        (k, s), cd = window, x.dtype
+        y = jax.lax.conv_general_dilated(x, leaves["kernel"].astype(cd), (s, s), "VALID",
+                                         dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        return jax.nn.relu(y + leaves["bias"].astype(cd))
+
+    rows = []
+    for batch, frames, outputs in shapes:
+        channels, cd = (outputs, 64, 64), jnp.bfloat16
+        net = dueling.DuelingDQN(num_actions=18, channels=channels, compute_dtype=cd)
+        key = jax.random.split(jax.random.PRNGKey(batch + outputs), 3)
+        obs = jax.random.randint(key[0], (batch, 84, 84, frames), 0, 256).astype(jnp.uint8)
+        init = jax.jit(net.init)
+        online = init(key[1], obs)
+        target = jax.tree.map(lambda p: p.astype(cd), init(key[2], obs))
+        nets = (online, target)
+
+        def apart(obs):
+            x = obs.astype(cd) / 255.0
+            return tuple(conv(x, p["params"]["Conv_0"], dueling.STEM_WINDOWS[0]) for p in nets)
+
+        def joined(obs):
+            return dueling.first_conv_of_two(online, target, obs, cd)
+
+        def then_second(firsts):
+            def run(obs):
+                return tuple(conv(y, p["params"]["Conv_1"], dueling.STEM_WINDOWS[1])
+                             for y, p in zip(firsts(obs), nets))
+            return run
+
+        def chained(outputs_of):    # the next execution's bytes hang on this one's outputs
+            def step(carry):
+                obs, moved, _ = carry
+                ys = outputs_of(obs ^ moved)    # fused into the cast: no pass of its own
+                return obs, sum(jnp.isnan(y[0, 0, 0, 0]) for y in ys).astype(jnp.uint8), ys
+            return step
+
+        want, got = jax.jit(apart)(obs), jax.jit(joined)(obs)
+        equal = all(bool(jnp.array_equal(w, g)) for w, g in zip(want, got))
+        assert equal, f"first convolution: one of {2 * outputs} differs from two of {outputs} at {obs.shape}"
+        timed = {}
+        for name, fn in (("apart", apart), ("joined", joined),
+                         ("apart_then_second", then_second(apart)),
+                         ("joined_then_second", then_second(joined))):
+            carry = (obs, jnp.uint8(0), jax.jit(fn)(obs))
+            timed[name + "_us"] = round(_device_microseconds(chained(fn), carry, repeats), 2)
+        flop = 2.0 * batch * 20 * 20 * 64 * frames * outputs     # a bank's
+        rows.append({
+            "obs": list(obs.shape), "outputs": outputs, "equal_bits": equal, **timed,
+            "apart_tflops": round(2 * flop / timed["apart_us"] / 1e6, 2),
+            "joined_tflops": round(2 * flop / timed["joined_us"] / 1e6, 2)})
+    return rows
+
+
+def leg_first_conv() -> None:
+    for row in first_conv_of_two_on_the_chip():
+        say(f"first_conv: two first convolutions of N against one of 2N: {row}")
+
+
 def leg_ling_kernels() -> None:
     for name, (near, far) in latent_kernels_against_plain().items():
         say(f"ling_kernels: {name} {near:.5f} from plain attention (limit {KERNEL_REL}), "
@@ -849,6 +936,8 @@ def main() -> int:
         legs = [("ling_kernels", leg_ling_kernels), ("ling", leg_ling)]
     if "--ling-kernels" in sys.argv[1:]:
         legs = [("ling_kernels", leg_ling_kernels)]
+    if "--first-conv" in sys.argv[1:]:
+        legs = [("first_conv", leg_first_conv)]
     for name, fn in legs:
         t0 = time.perf_counter()
         say(f"leg {name}: starts with bytes_in_use per device "

@@ -168,3 +168,25 @@ def test_replica_spawn_assigns_the_cpu_whatever_the_parent_says(
     )
     rep.spawn()
     assert _FakePopen.captured["JAX_PLATFORMS"] == "cpu"
+
+
+@pytest.mark.parametrize("lost", [None, "the_target_bank"])
+def test_the_chips_timing_of_the_first_convolution_runs_here(monkeypatch, lost):
+    """``chip_smoke.first_conv_of_two_on_the_chip`` at two rows: it passes on
+    ``dueling.first_conv_of_two`` as it is and returns the four timings; a
+    joined convolution that hands the online half to both nets fails it."""
+    import chip_smoke
+    from ape_x_dqn_tpu.models import dueling
+
+    shapes = ((2, 1, 8),)
+    if lost:
+        whole = dueling.first_conv_of_two
+        monkeypatch.setattr(dueling, "first_conv_of_two",
+                            lambda *args: (whole(*args)[0],) * 2)
+        with pytest.raises(AssertionError, match="one of 16 differs from two of 8"):
+            chip_smoke.first_conv_of_two_on_the_chip(shapes=shapes, repeats=1)
+        return
+    (row,) = chip_smoke.first_conv_of_two_on_the_chip(shapes=shapes, repeats=1)
+    assert row["obs"] == [2, 84, 84, 1] and row["outputs"] == 8 and row["equal_bits"]
+    assert all(row[f"{name}_us"] > 0 for name in (
+        "apart", "joined", "apart_then_second", "joined_then_second"))

@@ -319,8 +319,36 @@ def test_no_convolution_at_paper_shapes_covers_two_batches(compiled, chips):
     convs = convolution_dims(compiled(chips, "paper", "fused").as_text())
     doubled = {name: sorted(dims) for name, dims in convs.items() if 2 * rows in dims}
     assert not doubled, doubled
-    # 7 a forward and 12 backward, less what the compiler merges
+    # 7 the differentiated forward, 13 the bootstrap's pair (the first
+    # convolution is one for both nets) and 12 backward, less what the
+    # compiler merges
     assert sum(rows in dims for dims in convs.values()) >= 20, sorted(convs)
+
+
+def first_window_convolutions(hlo_text: str) -> list:
+    """The result's dimensions of every convolution of the optimized module
+    with the stem's first window (8 x 8, stride 4): the forward first
+    convolutions, a weight gradient's window being its cotangent's 20 x 20."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group("op") == "convolution" and "window={size=8x8 stride=4x4}" in line:
+            found += [tuple(int(d) for d in dims.split(","))
+                      for _, dims, _ in _ARRAY.findall(m.group("shape"))]
+    return sorted(found)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_the_bootstraps_two_nets_share_one_first_convolution(compiled, chips):
+    """``apex_b512``'s fused program reads ``next_obs`` through one first
+    convolution: a chip's rows, the online and the target filters side by
+    side in 64 output features, and none of 32 beside the differentiated
+    forward's on ``obs`` (there were three of 32: two held the two banks
+    apart on the same bytes, each at a fifth of the array's rate)."""
+    shapes = _paper()
+    rows, features = shapes["batch"] // chips, shapes["channels"][0]
+    firsts = first_window_convolutions(compiled(chips, "paper", "fused").as_text())
+    assert firsts == [(rows, 20, 20, features), (rows, 20, 20, 2 * features)], firsts
 
 
 @pytest.mark.parametrize("chips", [1, 4])
