@@ -9,22 +9,28 @@ footprint to ~frame_ratio/2 of the double-store (2M slots ≈ 17.9 GB →
 feasible per-chip at dp≥2 with the sharded builder in
 replay/device_dedup_dp.py).
 
-Stored format.  The ring is ``rows``: one row an observation,
-``uint32[frame_capacity, row_stride]``, the observation's bytes as
-little-endian 32-bit words (four stacked uint8 pixels a word) zero-padded to
-``row_stride`` = the words rounded up to a multiple of 128; ``RowFormat``
-computes it from the observation's shape and dtype alone (84×84×4: 7,056
-words in 7,168, +1.6%; 84×84×1: 1,764 in 1,792, +1.6%; a 6×6×1 toy row: 9 in
-128).  Why: the chip's compact layout puts a dimension that fills its tile
-in the lanes, and of ``[Cf, 84, 84, 4]`` only ``Cf`` does, so the ring index
-became the MINOR-most dimension and every program that scatters or gathers
-rows first copied the whole ring; where every trailing extent fills its
-tile (a multiple of 128 words) the ring is held row-major and a row is a
-row.  HBM sizing: ``frame_capacity × row_stride × 4`` bytes (153,600
-observations of 84×84×4 = 4.40 GB).  ``DedupDeviceReplayState(frames=...)``
-packs a logical ``[Cf, *obs_shape]`` block, ``.frames`` unpacks one (a copy:
-not for a hot path); ingest packs the incoming block (U rows), the fused
-scan unpacks the B rows of the step it is in; checkpoints hold logical rows.
+Stored format.  The ring is ``rows``: one row an observation, the
+observation's bytes as little-endian 32-bit words (four stacked uint8 pixels
+a word) zero-padded to ``row_stride`` = the words rounded up to a multiple of
+128; ``RowFormat`` computes it from the observation's shape and dtype alone
+(84×84×4: 7,056 words in 7,168, +1.6%; 84×84×1: 1,764 in 1,792, +1.6%; a
+6×6×1 toy row: 9 in 128).  Why: the chip's compact layout puts a dimension
+that fills its tile in the lanes, and of ``[Cf, 84, 84, 4]`` only ``Cf``
+does, so the ring index became the MINOR-most dimension and every program
+that scatters or gathers rows first copied the whole ring; where every
+trailing extent fills its tile (a multiple of 128 words) the ring is held
+row-major and a row is a row.  A row has one of two forms, by its stride alone
+(``RowFormat.row_shape``): ``uint32[Cf, row_stride]`` in general, where a
+tile of the chip holds 128 words of EIGHT rows and a row is ``stride / 128``
+pieces of 512 B, 4 KB apart; and ``uint32[Cf, row_stride / 128, 128]`` where
+the stride is whole (8, 128) tiles (``row_stride % 1024 == 0``; 84×84×4:
+``[Cf, 56, 128]``, seven tiles), the same words in the same order and no byte
+more, where a row is ONE piece of HBM that a kernel can fetch with one DMA.
+HBM sizing: ``frame_capacity × row_stride × 4`` bytes (153,600 observations
+of 84×84×4 = 4.40 GB).  ``DedupDeviceReplayState(frames=...)`` packs a
+logical ``[Cf, *obs_shape]`` block, ``.frames`` unpacks one (a copy: not for
+a hot path); ingest packs the incoming block (U rows); checkpoints hold
+logical rows.
 
 Reference addressing under XLA's int32 world:
   * frame sequence numbers live modulo ``Q = (2^30 // frame_capacity) ·
@@ -53,13 +59,29 @@ all K batches at once, from call-entry masses): the draw, the weights, the
 small per-transition fields and, in place of each observation, the ring slot
 that holds it (``ref % Cf``).  ``dedup_fetch`` is done in the scan's body, a
 step at a time: the B rows of each side are fetched from the ring the body
-closes over (``gather_rows``) and taken apart (``RowFormat.unpack``), so the
-program makes no array of K·B observations (at 84×84×4, B=512, K=64 such
-an array is 0.93 GB, and gathering ahead made four a side, each written to
-HBM and read back).  The ring's rows are not written inside
+closes over, so the program makes no array of K·B observations (at 84×84×4,
+B=512, K=64 such an array is 0.93 GB, and gathering ahead made four a side,
+each written to HBM and read back).  The ring's rows are not written inside
 the scan (only ``mass`` is restamped), so a row fetched in step t is the row
 that would have been fetched ahead: same slots, same rows, same bits.
 ``dedup_sample_many`` is the two halves one after the other.
+
+How a side is fetched is decided from what ``dedup_fetch`` can see in its
+input (``turned_fetch_applies``; no option).  Where a stored word is a
+pixel's four byte channels, a ring row is whole tiles and the batch fills
+whole lane tiles (84×84×4 at B = 128, 512: the word the first convolution
+reads, batch-minor with the channels packed, IS the stored word), a side is
+one kernel, ``ops/pallas/row_fetch.fetch_turned``: a row a DMA from the ring
+as it lies, turned in VMEM while the next rows arrive, left where the
+convolution reads it, with nothing but a bitcast between them (24 us a side
+at 512 rows on a v5e, 600 GB/s, where the compiler's row gather at 427 GB/s,
+a copy that turned the batch to the lanes and a fusion that took the words
+apart into bytes followed one another in 85).  Everywhere else (a batch of 8
+or 96, 16-bit observations, one frame a pixel, a row that is not whole
+tiles: the 32-frame histories' 56,448 words) the compiler's gather
+(``gather_rows``) and ``RowFormat.unpack``, as before.  Each traced side
+leaves a ``gather_path`` span in the launch log (``path`` kernel or plain,
+``rows``, ``words``; ``tools/launch_report.py`` prints them).
 """
 
 from __future__ import annotations
@@ -75,10 +97,11 @@ import numpy as np
 
 from ape_x_dqn_tpu.replay.device import fused_scan_body, sample_slots
 from ape_x_dqn_tpu.types import NStepTransition, PrioritizedBatch
-from ape_x_dqn_tpu.utils.profiling import jit_fused, stage
+from ape_x_dqn_tpu.utils.profiling import jit_fused, launch, stage
 
 
 _LANES = 128          # the chip's minor tile extent, in 32-bit words
+_TILE = 8 * _LANES    # the words of one whole (8, 128) tile
 _PACK_BLOCK = 256    # rows packed at a time where a whole ring is packed
 # The widest row, in words, that the chip's row gather fetches whole (eight
 # rows, double-buffered, in its 2 MiB of scoped memory).  A wider row (a
@@ -122,12 +145,20 @@ class RowFormat:
         words = -(-self.row_elems // self.per_word)
         return -(-words // _LANES) * _LANES
 
+    @property
+    def row_shape(self) -> Tuple[int, ...]:
+        """A stored row's own dimensions: ``[row_stride / 128, 128]`` where
+        the stride is whole (8, 128) tiles, so that a row is one piece of
+        HBM (module docstring), else ``[row_stride]``."""
+        stride = self.row_stride
+        return (stride // _LANES, _LANES) if stride % _TILE == 0 else (stride,)
+
     def zeros(self, n: int) -> jax.Array:
         """``n`` empty stored rows."""
-        return jnp.zeros((n, self.row_stride), self.stored_dtype)
+        return jnp.zeros((n, *self.row_shape), self.stored_dtype)
 
     def pack(self, frames):
-        """[..., *obs_shape] -> [..., row_stride] stored rows (zero padded).
+        """[..., *obs_shape] -> [..., *row_shape] stored rows (zero padded).
         numpy in, numpy out; anything else goes through jnp, a long block
         ``_PACK_BLOCK`` rows at a time (XLA widens sub-word elements to
         whole words on the way to a word: four times the block's bytes)."""
@@ -157,16 +188,17 @@ class RowFormat:
         pad = self.row_stride * self.per_word - self.row_elems
         if pad:
             flat = xp.pad(flat, [(0, 0)] * len(lead) + [(0, pad)])
-        if self.per_word == 1:
-            return flat
-        if xp is np:
-            return np.ascontiguousarray(flat).view(np.uint32)
-        return jax.lax.bitcast_convert_type(
-            flat.reshape(*lead, self.row_stride, self.per_word), jnp.uint32)
+        if self.per_word > 1 and xp is np:
+            flat = np.ascontiguousarray(flat).view(np.uint32)
+        elif self.per_word > 1:
+            flat = jax.lax.bitcast_convert_type(
+                flat.reshape(*lead, self.row_stride, self.per_word), jnp.uint32)
+        return flat.reshape(*lead, *self.row_shape)
 
     def unpack(self, rows):
-        """[..., row_stride] stored rows -> [..., *obs_shape]."""
-        lead = rows.shape[:-1]
+        """[..., *row_shape] stored rows -> [..., *obs_shape]."""
+        lead = rows.shape[:rows.ndim - len(self.row_shape)]
+        rows = rows.reshape(*lead, self.row_stride)
         if self.per_word > 1:
             # Drop the padding while the elements are still words, so that
             # what is widened on the way apart is the observation alone.
@@ -208,7 +240,7 @@ class DedupDeviceReplayState:
     ``rows=``/``fmt=``, from stored rows; ``frames`` reads the logical view
     back and is not a leaf.
 
-    rows      stored dtype [Cf, row_stride] — each unique frame once
+    rows      stored dtype [Cf, *fmt.row_shape] — each unique frame once
     obs_ref   int32 [C] — S_t frame seq (mod Q)
     next_ref  int32 [C] — S_{t+n} frame seq (mod Q)
     action    int32 [C]
@@ -376,13 +408,22 @@ def dedup_device_add_transitions(
 
 
 def gather_rows(rows: jax.Array, slots: jax.Array) -> jax.Array:
-    """``rows[slots]``: [Cf, stride] and [...] -> [..., stride].  Rows the
-    chip's gather takes whole go through it; wider ones are read one after
+    """``rows[slots]``: [Cf, *row_shape] and [...] -> [..., *row_shape].  Rows
+    the chip's gather takes whole go through it; wider ones are read one after
     the other, each a dynamic slice of the ring (``_GATHER_WORDS``)."""
-    if rows.shape[1] <= _GATHER_WORDS:
+    if math.prod(rows.shape[1:]) <= _GATHER_WORDS:
         return rows[slots]
     one = lambda slot: jax.lax.dynamic_index_in_dim(rows, slot, 0, keepdims=False)  # noqa: E731
-    return jax.lax.map(one, slots.reshape(-1)).reshape(*slots.shape, rows.shape[1])
+    return jax.lax.map(one, slots.reshape(-1)).reshape(*slots.shape, *rows.shape[1:])
+
+
+def turned_fetch_applies(fmt: RowFormat, slots_shape) -> bool:
+    """Whether a side is one ``fetch_turned`` (``ops/pallas/row_fetch.py``):
+    the stored word holds an observation's last axis, four bytes (a word is
+    a pixel's channels), the batch fills whole lane tiles (that is when the
+    compiler puts it in the lanes) and a ring row is whole tiles."""
+    return (fmt.per_word == 4 and fmt.obs_shape[-1] == 4 and len(fmt.row_shape) == 2
+            and len(slots_shape) == 1 and slots_shape[0] % _LANES == 0)
 
 
 def dedup_sample_slots(
@@ -419,12 +460,23 @@ def dedup_fetch(
     state: DedupDeviceReplayState, sampled: PrioritizedBatch
 ) -> PrioritizedBatch:
     """The half done where a batch is used: the sampled slots' rows fetched
-    from the ring and taken apart into observations (any leading shape; the
-    fused scan hands it one step's [B])."""
+    from the ring as observations (any leading shape; the fused scan hands
+    it one step's [B]): one kernel a side where ``turned_fetch_applies``,
+    else the compiler's gather and ``unpack``.  The same bits either way."""
+    fmt, transition = state.fmt, sampled.transition
+    kernel = turned_fetch_applies(fmt, transition.obs.shape)
+
+    def take(slots):
+        # once a compile and side, in the launch log: which path was traced
+        with launch.span("gather_path", path="kernel" if kernel else "plain",
+                         rows=slots.size, words=fmt.row_stride):
+            if kernel:
+                from ape_x_dqn_tpu.ops.pallas import row_fetch  # Pallas' import, paid where a kernel is traced
+
+                return row_fetch.fetch_turned(state.rows, slots, fmt.obs_shape, fmt.dtype)
+            return fmt.unpack(gather_rows(state.rows, slots))
+
     with stage("gather"):
-        take = lambda slots: state.fmt.unpack(  # noqa: E731
-            gather_rows(state.rows, slots))
-        transition = sampled.transition
         return sampled.replace(transition=transition.replace(
             obs=take(transition.obs), next_obs=take(transition.next_obs)))
 

@@ -662,6 +662,11 @@ class LaunchLog:
             t = time.perf_counter()
             self._append(name, t, t, attrs)
 
+    def attrs_of(self, name: str) -> list:
+        """The attributes of every span or mark called ``name``, in order."""
+        with self._lock:
+            return [dict(r[5]) for r in self._rows if r[0] == name]
+
     def _compile_span(self, name, wall_start, wall_end, fun_name) -> None:
         """One of jax's compile spans, just closed on this thread, moved
         from ``time.time`` to ``perf_counter`` by the clocks' distance now."""

@@ -66,6 +66,14 @@ which owns the chip:
             convolution behind its half (PERF.md section 6, PR 49: the cell
             gains more than the part).  Only with --first-conv (no network
             is trained: a minute); it runs in no cell
+  fetch     a side of the dedup ring's gather stage apart, at 512 and 128
+            rows of 84x84x4: ``dedup_fetch`` on a ring whose rows are whole
+            tiles (one kernel a side, ``ops/pallas/row_fetch.py``) beside the
+            compiler's gather, copy and unpack on the same words, each with
+            a first convolution behind it, equal bits, the microseconds of
+            both and the ``gather_path`` spans of the launch log (PERF.md
+            section 6, PR 50).  Only with --fetch (a minute); it runs in no
+            cell
 
 Sets no platform itself.  Exits non-zero, with one line saying why and no
 result, before compiling anything if jax's default backend is not a TPU, and
@@ -634,17 +642,51 @@ def choice_by_selection(scores, bias, k: int, groups: int, kept: int):
     return (*expert_torso.choose(scores, bias, spec), router_choice(scores, bias, None, k, groups, kept)[1])
 
 
-def _device_microseconds(step, carry, repeats: int) -> float:
-    """One execution of ``step`` (carry -> carry) on the device, from
+def _device_microseconds(step, carry, repeats: int, *held) -> float:
+    """One execution of ``step`` (carry, *held -> carry) on the device, from
     ``repeats`` dependent ones inside one program: a dispatch from this host
-    is 200 us, more than most of what is timed here."""
+    is 200 us, more than most of what is timed here.  ``held`` is read and
+    not returned: an array too large to close over or to copy out."""
     import jax
 
-    run = jax.jit(lambda c: jax.lax.fori_loop(0, repeats, lambda _, c: step(c), c))
-    jax.block_until_ready(run(carry))
+    run = _repeated(step, repeats)
+    jax.block_until_ready(run(carry, *held))
     t0 = time.perf_counter()
-    jax.block_until_ready(run(carry))
+    jax.block_until_ready(run(carry, *held))
     return (time.perf_counter() - t0) / repeats * 1e6
+
+
+def _repeated(step, repeats: int):
+    import jax
+
+    return jax.jit(lambda c, *held: jax.lax.fori_loop(0, repeats, lambda _, c: step(c, *held), c))
+
+
+def _device_op_microseconds(step, carry, repeats: int, *held, top: int = 6) -> dict:
+    """{instruction: us an execution} of the ``top`` longest device ops of
+    ``step``, from a trace of ``repeats`` dependent executions in one
+    program (``_device_microseconds``' program, run once more under the
+    profiler)."""
+    import collections
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    run = _repeated(step, repeats)
+    jax.block_until_ready(run(carry, *held))
+    seconds = collections.Counter()
+    with tempfile.TemporaryDirectory() as logdir:
+        with jax.profiler.trace(logdir):
+            jax.block_until_ready(run(carry, *held))
+        (found,) = glob.glob(os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb"))
+        for plane in ProfileData.from_file(found).planes:
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    for e in line.events:
+                        seconds[e.name.split(" = ", 1)[0].lstrip("%").strip()] += e.duration_ns * 1e-3
+    return {name: round(us / repeats, 2) for name, us in seconds.most_common(top)}
 
 
 def choice_against_sorting_on_the_chip(shapes=ROUTER_SHAPES, walk_tile=WALK_TILE,
@@ -860,6 +902,102 @@ def leg_first_conv() -> None:
         say(f"first_conv: two first convolutions of N against one of 2N: {row}")
 
 
+# (rows a chip): ``apex_b512`` and ``lfm2moe_q_ep8``, and a chip of ``apex_b512_dp4``
+FETCH_BATCHES = (512, 128)
+
+
+def fetch_against_three_ops_on_the_chip(batches=FETCH_BATCHES, obs_shape=(84, 84, 4),
+                                        frames: int = 65536, repeats: int = 50) -> list:
+    """A side of the gather stage apart, on this device: ``dedup_fetch`` on a
+    ring of ``frames`` rows stored as whole tiles (the kernel,
+    ``ops/pallas/row_fetch.py``) beside the three ops it replaced on the same
+    words stored a run a row (the compiler's row gather, the copy that turns
+    the batch to the lanes, the unpack into bytes), each with the first
+    convolution behind it, which is what decides the layout of both; the bytes
+    of both sides must be the host's.  The microseconds of each and of the
+    convolution alone on bytes the loop lays out, each program's longest
+    device ops from a trace, and the kernel's own time and rate from there."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ape_x_dqn_tpu.replay.device_dedup import DedupDeviceReplayState, RowFormat, dedup_fetch
+    from ape_x_dqn_tpu.types import NStepTransition, PrioritizedBatch
+
+    fmt = RowFormat.of(obs_shape, np.uint8)
+    words = fmt.row_elems // 4
+    key = jax.random.split(jax.random.PRNGKey(50), 3)
+    tiled = jax.jit(lambda k: jax.random.bits(k, (frames, *fmt.row_shape), jnp.uint32))(key[0])
+    runs = jax.jit(lambda r: r.reshape(frames, fmt.row_stride))(tiled)
+    kernel_w = (jax.random.normal(key[1], (8, 8, obs_shape[-1], 32)) * 0.05).astype(jnp.bfloat16)
+
+    def conv(obs):
+        return jax.lax.conv_general_dilated(obs.astype(jnp.bfloat16) / 255.0, kernel_w, (4, 4), "VALID",
+                                            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+    def shipped(slots, tiled):     # both sides through the program's own entry point
+        sampled = PrioritizedBatch(
+            transition=NStepTransition(obs=slots, action=None, reward=None, discount=None,
+                                       next_obs=slots[::-1]), indices=None, is_weights=None)
+        got = dedup_fetch(DedupDeviceReplayState(rows=tiled, fmt=fmt), sampled).transition
+        return got.obs, got.next_obs
+
+    def three_ops(slots, runs):
+        taken = runs[slots][:, :words]
+        return jax.lax.bitcast_convert_type(taken, jnp.uint8).reshape(slots.shape[0], *obs_shape)
+
+    def chained(fetch, then):     # the next execution's slots hang on this one's bytes
+        def step(carry, ring):
+            slots, _ = carry
+            y = then(fetch(slots, ring))
+            moved = jnp.isnan(y.reshape(-1)[0].astype(jnp.float32)).astype(jnp.int32)
+            return (slots + moved) % frames, y
+        return step
+
+    rows = []
+    for batch in batches:
+        slots = jax.random.randint(key[2], (batch,), 0, frames, jnp.int32)
+        slots = slots.at[:4].set(jnp.array([0, frames - 1, 7, 7]))
+        got, got_next = jax.jit(shipped)(slots, tiled)
+        want = jax.jit(three_ops)(slots, runs)
+        host = np.asarray(runs[slots[:16]])[:, :words].view(np.uint8).reshape(16, *obs_shape)
+        equal = (bool(jnp.array_equal(got, want)) and bool(jnp.array_equal(got_next, want[::-1]))
+                 and bool(np.array_equal(np.asarray(got[:16]), host)))
+        assert equal, f"the kernel's bytes are not the gather's at {batch} rows of {obs_shape}"
+        kernel_side = lambda s, ring: shipped(s, ring)[0]  # noqa: E731
+        laid_out = lambda s, obs: obs ^ (s[0] >> 30).astype(jnp.uint8)  # noqa: E731
+        timed = {}
+        for name, fetch, then, ring in (
+                ("kernel_then_conv", kernel_side, conv, tiled),
+                ("three_ops_then_conv", three_ops, conv, runs), ("conv", laid_out, conv, want)):
+            step = chained(fetch, then)
+            carry = (slots, jax.jit(lambda s, r, step=step: step((s, None), r)[1])(slots, ring))
+            timed[name + "_us"] = round(_device_microseconds(step, carry, repeats, ring), 2)
+            if name != "conv":
+                timed[name + "_ops_us"] = _device_op_microseconds(step, carry, repeats, ring)
+        kernel_us = next((us for name, us in timed["kernel_then_conv_ops_us"].items()
+                          if name.startswith("fetch_turned")), None)    # none off the chip: no device trace
+        rows.append({
+            "rows": batch, "obs": list(obs_shape), "ring": list(tiled.shape), "equal_bits": equal, **timed,
+            "kernel_side_us": round(timed["kernel_then_conv_us"] - timed["conv_us"], 2),
+            "three_ops_side_us": round(timed["three_ops_then_conv_us"] - timed["conv_us"], 2),
+            "kernel_us": kernel_us,
+            "kernel_gb_per_s": kernel_us and round(batch * fmt.row_stride * 4 / kernel_us / 1e3, 1)})
+    return rows
+
+
+def leg_fetch() -> None:
+    import collections
+
+    from ape_x_dqn_tpu.utils import profiling
+
+    for row in fetch_against_three_ops_on_the_chip():
+        say(f"fetch: a side in one kernel against the gather, the copy and the unpack: {row}")
+    traced = collections.Counter(tuple(side.items()) for side in profiling.launch.attrs_of("gather_path"))
+    for side, times in traced.items():
+        say(f"fetch: gather_path {dict(side)}: {times} traced sides")
+
+
 def leg_ling_kernels() -> None:
     for name, (near, far) in latent_kernels_against_plain().items():
         say(f"ling_kernels: {name} {near:.5f} from plain attention (limit {KERNEL_REL}), "
@@ -938,6 +1076,8 @@ def main() -> int:
         legs = [("ling_kernels", leg_ling_kernels)]
     if "--first-conv" in sys.argv[1:]:
         legs = [("first_conv", leg_first_conv)]
+    if "--fetch" in sys.argv[1:]:
+        legs = [("fetch", leg_fetch)]
     for name, fn in legs:
         t0 = time.perf_counter()
         say(f"leg {name}: starts with bytes_in_use per device "

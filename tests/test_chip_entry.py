@@ -190,3 +190,26 @@ def test_the_chips_timing_of_the_first_convolution_runs_here(monkeypatch, lost):
     assert row["obs"] == [2, 84, 84, 1] and row["outputs"] == 8 and row["equal_bits"]
     assert all(row[f"{name}_us"] > 0 for name in (
         "apart", "joined", "apart_then_second", "joined_then_second"))
+
+
+@pytest.mark.parametrize("lost", [None, "the_byte_order"])
+def test_the_chips_timing_of_a_gathered_side_runs_here(monkeypatch, lost):
+    """``chip_smoke.fetch_against_three_ops_on_the_chip`` at a row of one
+    tile: it passes on ``row_fetch.fetch_turned`` as it is, through
+    ``dedup_fetch``, and returns the timings; a kernel whose packed word
+    holds its first channel last fails it."""
+    import chip_smoke
+    from ape_x_dqn_tpu.ops.pallas import row_fetch
+
+    run = lambda: chip_smoke.fetch_against_three_ops_on_the_chip(  # noqa: E731
+        batches=(128,), obs_shape=(32, 32, 4), frames=16, repeats=1)
+    if lost:
+        whole = row_fetch.fetch_turned
+        monkeypatch.setattr(row_fetch, "fetch_turned", lambda *args: whole(*args)[..., ::-1])
+        with pytest.raises(AssertionError, match="bytes are not the gather's at 128 rows"):
+            run()
+        return
+    (row,) = run()
+    assert row["rows"] == 128 and row["ring"] == [16, 8, 128] and row["equal_bits"]
+    assert all(row[f"{name}_us"] > 0 for name in (
+        "kernel_then_conv", "three_ops_then_conv", "conv"))

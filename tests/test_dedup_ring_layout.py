@@ -14,7 +14,9 @@ The fused program at the paper's shapes (``benchmark/configs/apex_b512.json``,
 one chip and the four-chip shard) holds no convolution or product over two
 batches of rows: the backward pass covers the rows that carry a gradient.
 It makes no array of K batches of observations either: the scan is handed
-the sampled slots and fetches and takes apart a batch's rows in its body.
+the sampled slots and fetches a batch's rows in its body, a side one kernel
+on a ring whose rows are whole tiles (``ops/pallas/row_fetch.py``), with
+nothing but bitcasts between the kernel and the first convolutions.
 
 One expert block of ``benchmark/configs/lfm2moe_q_ep8.json`` at its published
 widths, forward and backward: no array of the worst case's ``tokens x k``
@@ -246,13 +248,19 @@ def _compile(jitted, args, limit_s: float = COMPILE_LIMIT_S):
     """The executable, or a failure once ``limit_s`` have gone (the compile
     runs on a thread of this process, which holds the TPU's library; a stuck
     one is left behind as a daemon)."""
+    from ape_x_dqn_tpu.ops.pallas import blocked_attention
+
     box = {}
 
     def work():
+        # this process sees the CPU: a kernel traced here is for the chip
+        before, blocked_attention.INTERPRET = blocked_attention.INTERPRET, False
         try:
             box["done"] = jitted.lower(*args).compile()
         except BaseException as e:  # noqa: BLE001 - re-raised below
             box["error"] = e
+        finally:
+            blocked_attention.INTERPRET = before
 
     t = threading.Thread(target=work, daemon=True)
     t.start()
@@ -365,19 +373,81 @@ def test_no_array_of_k_batches_at_paper_shapes(compiled, chips):
     k_batches = shapes["k"] * rows * int(np.prod(OBS))
     big = ring_sized_instructions(text, k_batches)
     assert not big, "\n".join(line[:200] for _, _, line in big)
-    # the row fetch of both sides: gathers of a batch's rows, in a loop body
+    # the row fetch of both sides: a kernel each, in a loop body
     bodies = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
-    inside, fetches = None, []
-    for line in text.splitlines():
-        head = _COMPUTATION.match(line)
-        if head:
-            inside = head.group(1)
-        elif re.search(rf" = u32\[{rows},7168\]\S* fusion\(.*stage:gather/gather", line):
-            fetches.append(inside)
+    fetches = [inside for _, _, inside, _ in side_fetches(text)]
     assert len(fetches) == 2 and set(fetches) <= bodies, fetches
     if chips == 1:
         temp = fused.memory_analysis().temp_size_in_bytes
         assert temp < 500_000_000, temp
+
+
+def side_fetches(hlo_text: str) -> list:
+    """[(name, operand names, computation, line)] of the optimized module's
+    ``fetch_turned`` kernel calls (``ops/pallas/row_fetch.py``)."""
+    inside, found = None, []
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if (m and m.group("op") == "custom-call" and "tpu_custom_call" in line
+                and "stage:gather/fetch_turned" in line):
+            operands = re.findall(r"%([\w.\-]+)", m.group("args").split(")")[0])
+            found.append((m.group("name"), operands, inside, line))
+    return found
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_a_side_is_one_kernel_on_a_ring_of_whole_tiles(compiled, chips):
+    """``apex_b512``'s fused program: the ring is ``u32[Cf, 56, 128]``, index
+    major, a row seven whole tiles; inside the ``while`` each side is one
+    ``fetch_turned`` call whose operand is the ring as it lies (no copy, no
+    slice of it), and between a call and the convolutions that read it stand
+    bitcasts alone: no gather of rows, no copy of ``u32[B, 7056]`` that turns
+    the batch to the lanes, no fusion that takes words apart into
+    ``u8[B, 84, 84, 4]``, no pass that casts the bytes ahead of the
+    convolution (the parent's stage was those three a side: 169 of its 171
+    us a step at 512 rows)."""
+    shapes = _paper()
+    rows, frames = shapes["batch"] // chips, shapes["frames"]
+    text = compiled(chips, "paper", "fused").as_text()
+    rings = ring_parameters(text, frames * int(np.prod(OBS)))
+    assert len(rings) == 1, rings
+    entry = text[text.index("\nENTRY "):]
+    assert re.search(rf" = u32\[{frames},56,128\]\{{2,1,0:T\(8,128\)\}} parameter\(", entry), rings
+
+    made, consumers, called = {}, {}, {}     # name -> (op, shape); name -> [names]; fusion -> computation
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            made[m.group("name")] = (m.group("op"), m.group("shape"))
+            for operand in re.findall(r"%([\w.\-]+)", m.group("args").split("), ")[0]):
+                consumers.setdefault(operand, []).append(m.group("name"))
+            if m.group("op") == "fusion":
+                called[m.group("name")] = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+    bodies = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
+    fetches = side_fetches(text)
+    assert len(fetches) == 2 and {inside for _, _, inside, _ in fetches} <= bodies, fetches
+    convolving = {name for name in re.findall(
+        r"^%?([\w.\-]+) \([^\n]*\{\n(?:[^}\n][^\n]*\n)*?[^\n]* convolution\(", text, re.M)}
+    for name, operands, _, line in fetches:
+        assert f"u32[{frames},56,128]{{2,1,0}}" in line, line[:400]
+        assert made[operands[1]][0] in _PASS_THROUGH, (operands[1], made[operands[1]])
+        assert made[name][1].startswith(f"u8[{rows // 128 * 7056 * 4},128]"), made[name]
+        readers = consumers[name]
+        assert readers and all(made[r][0] == "bitcast" for r in readers), [(r, made[r]) for r in readers]
+        for reader in readers:
+            assert made[reader][1].startswith(f"u8[{rows},84,84,4]"), made[reader]
+            fusions = consumers[reader]
+            assert fusions and all(called.get(f) in convolving for f in fusions), [
+                (f, made[f][0], called.get(f)) for f in fusions]
+    gather_lines = [line for line in text.splitlines() if "stage:gather" in line]
+    assert not [line[:200] for line in gather_lines
+                if re.search(rf" = u32\[{rows},(7168|7056|56,128)\]\S* (gather|copy|fusion)\(", line)]
+    assert not [line[:200] for line in text.splitlines()
+                if re.search(rf" = u8\[{rows},84,84,4\]\S* (?!bitcast|parameter)[\w\-]+\(", line)]
 
 
 def test_the_sharded_step_gathers_the_streams_rows_and_reduces_no_kernel(compiled):

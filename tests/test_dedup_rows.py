@@ -1,5 +1,6 @@
 """The dedup frame ring's stored rows (replay/device_dedup.py RowFormat):
-whatever the observation's shape, the ring built, ingested into, sampled
+whatever the observation's shape, and whether a row is stored as one run of
+words or as whole (8, 128) tiles, the ring built, ingested into, sampled
 from and checkpointed holds byte for byte what a plain numpy ring holds."""
 
 import jax
@@ -24,9 +25,11 @@ from ape_x_dqn_tpu.replay.device_dedup_dp import (
     dedup_replay_specs,
 )
 
-# Paper rows, config3's rows, the suite's toy rows, a row of exactly 128 B
-# and one that fills its 128 words exactly.
-OBS_SHAPES = [(84, 84, 4), (84, 84, 1), (6, 6, 1), (8, 16, 1), (16, 8, 4)]
+# Paper rows (seven whole tiles a row: stored [Cf, 56, 128]), config3's rows,
+# the suite's toy rows, a row of exactly 128 B, one that fills its 128 words
+# exactly and one that fills one whole tile exactly (stored [Cf, 8, 128]).
+OBS_SHAPES = [(84, 84, 4), (84, 84, 1), (6, 6, 1), (8, 16, 1), (16, 8, 4), (32, 32, 4)]
+TILED = {(84, 84, 4): (56, 128), (32, 32, 4): (8, 128)}  # the others: [row_stride]
 ids = lambda shapes: ["x".join(map(str, s)) for s in shapes]  # noqa: E731
 
 
@@ -64,7 +67,8 @@ def test_state_built_in_jit_reads_back(obs_shape):
     state = jax.jit(lambda f: full_state(f, 32))(jnp.asarray(x))
     fmt = RowFormat.of(obs_shape, np.uint8)
     assert state.fmt == fmt
-    assert state.rows.shape == (40, fmt.row_stride)
+    assert fmt.row_shape == TILED.get(obs_shape, (fmt.row_stride,))
+    assert state.rows.shape == (40, *fmt.row_shape)
     assert fmt.row_stride % 128 == 0 and state.rows.dtype == np.uint32
     assert 4 * fmt.row_stride - x[0].size < 512  # under one tile row of padding
     assert state.frame_capacity == 40 and state.capacity == 32
@@ -75,6 +79,10 @@ def test_state_built_in_jit_reads_back(obs_shape):
     np.testing.assert_array_equal(jax.device_get(state).frames, x)
     np.testing.assert_array_equal(fmt.unpack(fmt.pack(x)), x)
     np.testing.assert_array_equal(np.asarray(state.rows), fmt.pack(x))
+    # Either form holds the same words in the same order.
+    words = np.ascontiguousarray(x.reshape(40, -1)).view(np.uint32)
+    np.testing.assert_array_equal(
+        np.asarray(state.rows).reshape(40, -1)[:, :words.shape[1]], words)
 
 
 def test_long_block_packs_in_pieces(monkeypatch):
@@ -261,7 +269,8 @@ def _feed(fused, n, seqs, obs_shape):
         seqs[src] = seq + 1
 
 
-CKPT_CASES = [((84, 84, 1), 1), ((6, 6, 1), 1), ((8, 16, 1), 1), ((6, 6, 1), 2)]
+CKPT_CASES = [((84, 84, 1), 1), ((6, 6, 1), 1), ((8, 16, 1), 1), ((6, 6, 1), 2),
+              ((32, 32, 4), 1), ((32, 32, 4), 2)]   # the last two: tiled rows
 
 
 @pytest.mark.parametrize(
@@ -322,9 +331,11 @@ def test_incremental_checkpoint_round_trips_logical_rows(tmp_path, obs_shape, n)
 
 def test_footprint_is_rows_times_stride():
     """HBM sizing: ``frame_capacity x row_stride`` stored elements, within
-    1.6% of the observations' own bytes at the package's real rows."""
+    1.6% of the observations' own bytes at the package's real rows; a row
+    stored as whole tiles is no byte larger."""
     for obs_shape in ((84, 84, 4), (84, 84, 1)):
         st = init_dedup_device_replay(64, obs_shape, frame_ratio=1.25)
+        assert st.rows.shape == (80, *TILED.get(obs_shape, (st.fmt.row_stride,)))
         assert st.rows.nbytes == st.frame_capacity * st.fmt.row_stride * 4
         assert st.rows.nbytes == pytest.approx(
             80 * int(np.prod(obs_shape)), rel=0.016)

@@ -321,7 +321,7 @@ def test_stage_timer_and_launch_span_share_one_implementation(log, monkeypatch):
 
 @pytest.mark.parametrize("named", [False, True])
 def test_the_log_is_written_at_exit_only_where_the_variable_names_a_file(
-        tmp_path, monkeypatch, log, named):
+        tmp_path, monkeypatch, capsys, log, named):
     monkeypatch.chdir(tmp_path)
     if named:
         monkeypatch.setenv(profiling.LAUNCH_LOG_ENV, str(tmp_path / "out" / "l-{pid}.json"))
@@ -329,6 +329,9 @@ def test_the_log_is_written_at_exit_only_where_the_variable_names_a_file(
         monkeypatch.delenv(profiling.LAUNCH_LOG_ENV, raising=False)
     with log.span("network", kind="conv"):
         jax.jit(lambda x: x + 41)(jnp.ones(2)).block_until_ready()
+    with log.span("gather_path", path="kernel", rows=512, words=7168):
+        pass
+    assert log.attrs_of("gather_path") == [{"path": "kernel", "rows": 512, "words": 7168}]
     open_row = log._append("pipeline", time.perf_counter(), None, None)
     profiling._write_at_exit()
     written = [str(p.relative_to(tmp_path)) for p in tmp_path.rglob("*") if p.is_file()]
@@ -344,6 +347,7 @@ def test_the_log_is_written_at_exit_only_where_the_variable_names_a_file(
     from tools import launch_report
 
     assert launch_report.main([str(tmp_path / written[0]), "--seconds", "0.5"]) == 0
+    assert "gather_path: kernel, 512 rows of 7168 words" in capsys.readouterr().out
 
 
 TOY = ["--set", "network=conv", "--set", "env.name=fake-atari",

@@ -8,7 +8,9 @@ A process that ran with ``APEX_LAUNCH_LOG`` set leaves its launch log there
 compile by program and phase).  This prints the partition of the log's first
 ``--seconds`` seconds (default: up to the launch's ``done``, or to the
 writing) into the nine parts of ``LaunchLog.summary``, the launch thread's
-own seconds by span, and the table of programs, slowest first.  The log's
+own seconds by span, the table of programs, slowest first, and which path
+each traced side of the dedup ring's gather stage took (``gather_path``:
+the kernel or the compiler's gather).  The log's
 first stamp is the package's import: what the process did before it (the
 interpreter's start, the entry point's own first imports) is not in it.
 """
@@ -67,6 +69,8 @@ def main(argv=None) -> int:
         t0=t0, t1=None if args.seconds is None else t0 + args.seconds,
         top=PROGRAMS)
     print(report(summary))
+    for side in log.attrs_of("gather_path"):  # replay/device_dedup.dedup_fetch, a traced side
+        print(f"gather_path: {side['path']}, {side['rows']} rows of {side['words']} words")
     return 0
 
 
