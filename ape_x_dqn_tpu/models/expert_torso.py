@@ -18,13 +18,18 @@ torso's width and run through the layers the spec names, each
 
 (``m`` the spec's ``residual_multiplier``, 1 unless a model states one; the
 projected tokens are likewise multiplied by its ``token_multiplier``)
+or, where the spec says ``post_norm`` (the Olmo family's reordered norm, OLMo
+2, arXiv:2501.00656), a sublayer reads the stream as it is and its output is
+normed, ``h <- h + m RMSNorm(Op(h))``, ``h <- h + m RMSNorm(FFN(h))``, under
+the same two parameter names;
 then RMSNorm, the mean over the tokens and the dueling head.  What is a
 model's is the spec's (``TorsoSpec``): the mixers and their head counts, RoPE
 rules and windows, the router's score function, count and bias, the shared
 expert, whether an observation's tokens are one frame's positions or those
-of a history of frames.  ``models/lfm2_moe.py``, ``models/laguna_moe.py`` and
-``models/granite_hybrid.py`` and ``models/solar_open2.py`` make a spec from
-a published ``config.json``'s keys and bring their mixers; everything else is
+of a history of frames.  ``models/lfm2_moe.py``, ``models/laguna_moe.py``,
+``models/granite_hybrid.py``, ``models/solar_open2.py``,
+``models/ling_hybrid.py`` and ``models/olmo_hybrid.py`` make a spec from a
+published ``config.json``'s keys and bring their mixers; everything else is
 here, once.
 
 The expert layer is one chip's share of an expert-parallel layer: it is told
@@ -143,6 +148,7 @@ class TorsoSpec:
     heads_held: Optional[Tuple[int, int]] = None   # [lo, hi) of the published heads; None: all
     router_groups: int = 1            # the router's outputs lie in so many groups of consecutive ones
     router_groups_kept: int = 1       # and a token chooses among the experts of so many (``route``)
+    post_norm: bool = False           # a block norms a sublayer's output, not its input
 
     def __post_init__(self):
         lo, hi = self.experts_held
@@ -165,6 +171,9 @@ class TorsoSpec:
         for op, ffn in self.layers:
             if op not in ops or ffn not in FFNS:
                 raise ValueError(f"unknown layer ({op!r}, {ffn!r}); ops {ops}, ffns {FFNS}")
+        if self.post_norm and any(ffn == "moe" for _, ffn in self.layers):
+            raise ValueError("post_norm is built for dense layers: no family here norms an "
+                             "expert layer's output")
         if self.heads_held is not None:
             whole = [op for op, mixer in self.mixers if not getattr(mixer, "divides_heads", False)]
             if whole or not 0 <= self.heads_held[0] < self.heads_held[1]:
@@ -533,9 +542,16 @@ class Block(nn.Module):
         """(h, None) -> (the layer's output, None): a ``scan``'s body."""
         sp, cd, pd = self.spec, self.compute_dtype, self.param_dtype
         m = sp.residual_multiplier
+        mixer = dict(sp.mixers)[self.op](sp, self.op, cd, pd, name=self.op)
+        if sp.post_norm:      # dense layers alone (the spec)
+            with part("mixer"):
+                h = _added(h, RMSNorm(sp.norm_eps, cd, pd, name="operator_norm")(mixer(h)), m)
+            with part("dense_ffn"):
+                y = SwiGLU(sp.intermediate_size, cd, pd, name="dense")(h)
+                return _added(h, RMSNorm(sp.norm_eps, cd, pd, name="ffn_norm")(y), m), None
         with part("mixer"):
             u = RMSNorm(sp.norm_eps, cd, pd, name="operator_norm")(h)
-            h = _added(h, dict(sp.mixers)[self.op](sp, self.op, cd, pd, name=self.op)(u), m)
+            h = _added(h, mixer(u), m)
         if self.ffn == "dense":
             with part("dense_ffn"):
                 u = RMSNorm(sp.norm_eps, cd, pd, name="ffn_norm")(h)

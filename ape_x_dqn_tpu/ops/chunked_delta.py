@@ -43,6 +43,22 @@ operands in ``q``'s type and accumulate in float32.  ``q``, ``k``, ``v`` and
 ``g`` cross the walk as ``[chunks, B, H, chunk, 128]``: a head's 128 fills a
 TPU's lanes, a chunk's tokens the sublanes.
 
+**The scalar-gate form** (Gated DeltaNet, arXiv:2412.06464): where the log
+decay is one scalar a head and token (``g`` [B, H, T], not [B, H, T, K]) it
+leaves the sums over the key channels,
+
+    A_ij = beta_i (k_i . k_j) exp(G_i - G_j),   scores_ij = (q_i . k_j) exp(G_i - G_j)
+
+so a chunk's pair scores are one product of ``[2 C, K]`` by ``[K, C]`` and a
+``[C, C]`` mask of decays, each the exponential of a difference formed before
+it and under the mask: no sub-blocks, and ``g`` crosses the walk as 4 B a
+token and head.  ``W``, ``U``, ``V'``, ``o`` and the state are the lines above
+with ``exp(G)`` a scalar a row (``_chunk_scalar``); the inverse, the cut, the
+padding and the carry are shared.  The same entry takes both forms and tells
+them apart by ``g``'s rank; keys and values need not have one width (the
+state is [K, V]), and a head's K and V lie in the lanes as they are, padded
+by the compiler's tiles to whole lanes (96 of 128, 192 of 256).
+
 The backward pass is written by hand (``jax.custom_vjp``) in
 ``chunked_scan.py``'s manner: it keeps the inputs and each chunk's incoming
 state (``[chunks, B, H, K, V]`` float32) and walks the chunks from the last
@@ -60,7 +76,7 @@ import jax
 import jax.numpy as jnp
 
 from ape_x_dqn_tpu.ops.chunked_scan import cut, join
-from ape_x_dqn_tpu.utils.profiling import part
+from ape_x_dqn_tpu.utils.profiling import launch, part
 
 SUB = 16                         # rows of a sub-block of the pair scores
 INVERSE_BASE = 8                 # rows of a diagonal block inverted by the doubling product
@@ -161,14 +177,53 @@ def _chunk(state, q, k, v, g, beta):
     return state, o.astype(cd)
 
 
+def _chunk_scalar(state, q, k, v, g, beta):
+    """``_chunk`` under one log decay a head and token: ``g`` [B, H, C]
+    float32; ``state`` [B, H, K, V], ``q``, ``k`` [B, H, C, K], ``v`` [B, H,
+    C, V], K and V any two widths (module docstring)."""
+    cd, f32 = q.dtype, jnp.float32
+    c, kw = q.shape[2], q.shape[3]
+    dot = lambda spec, x, y: jnp.einsum(  # noqa: E731
+        spec, x.astype(cd), y.astype(cd), preferred_element_type=f32)
+    with jax.named_scope("scalar_gate"):
+        run = jnp.cumsum(g, axis=-1)                                   # G: [B, H, C]
+        earlier = jnp.tril(jnp.ones((c, c), bool))                     # j <= i
+        # masked before the exponential: a later token's G_i - G_j is positive
+        decay = jnp.exp(jnp.where(earlier, run[..., :, None] - run[..., None, :], -jnp.inf))
+        # k.k over q.k, one product: [B, H, 2C, C]
+        pairs = dot("bhrk,bhjk->bhrj", jnp.concatenate([k, q], axis=2), k)
+        a = beta[..., None] * jnp.tril(pairs[:, :, :c] * decay, -1)
+        scores = pairs[:, :, c:] * decay
+
+        t = _unit_lower_inverse(a)
+        decayed = jnp.exp(run)[..., None]                              # exp(G_i) <= 1
+        kf = k.astype(f32)
+        wu = dot("bhij,bhjx->bhix", t,
+                 jnp.concatenate([kf * decayed, v.astype(f32)], axis=-1) * beta[..., None])
+        w, u = wu[..., :kw], wu[..., kw:]
+        moved = u - dot("bhik,bhkv->bhiv", w, state)                   # V'
+        o = (dot("bhik,bhkv->bhiv", q.astype(f32) * decayed, state)
+             + dot("bhij,bhjv->bhiv", scores, moved))
+        to_end = jnp.exp(run[..., -1:] - run)[..., None]               # exp(G_end - G_j) <= 1
+        state = decayed[:, :, -1:] * state + dot("bhjk,bhjv->bhkv", kf * to_end, moved)
+        return state, o.astype(cd)
+
+
+def _chunk_of(q, g):
+    """The chunk's function for ``g``: a decay a key channel, or one a head."""
+    return _chunk if g.ndim == q.ndim else _chunk_scalar
+
+
 def _walk(q, k, v, g, beta, keep: bool):
     """(o [chunks, B, H, C, V]; with ``keep`` each chunk's incoming state,
     [chunks, B, H, K, V], else None)."""
     with part("delta_scan"):
         first = jnp.zeros((*q.shape[1:3], q.shape[-1], v.shape[-1]), jnp.float32)
 
+        step = _chunk_of(q, g)
+
         def body(state, chunk):
-            after, o = _chunk(state, *chunk)
+            after, o = step(state, *chunk)
             return after, (o, state if keep else None)
 
         return jax.lax.scan(body, first, (q, k, v, g, beta))[1]
@@ -180,7 +235,8 @@ def delta_chunks(q, k, v, g, beta):
     a sequence already cut in chunks, zeros past its end.
 
     ``q``, ``k`` [chunks, B, H, C, K]; ``v`` [chunks, B, H, C, V]; ``g``
-    [chunks, B, H, C, K] float32, at most 0; ``beta`` [chunks, B, H, C]
+    [chunks, B, H, C, K] float32, at most 0, or [chunks, B, H, C] where a
+    head has one decay (the scalar-gate form); ``beta`` [chunks, B, H, C]
     float32."""
     return _walk(q, k, v, g, beta, keep=False)[0]
 
@@ -193,10 +249,11 @@ def _delta_fwd(q, k, v, g, beta):
 def _delta_bwd(kept, do):
     """The cotangents of ``delta_chunks``' inputs from ``do``, all cut."""
     *inputs, states = kept
+    step = _chunk_of(inputs[0], inputs[3])
     with part("delta_scan"):
         def body(d_after, chunk):
             state, *own, doc = chunk
-            _, pull = jax.vjp(_chunk, state, *own)             # the chunk, computed again
+            _, pull = jax.vjp(step, state, *own)               # the chunk, computed again
             d_state, *d_own = pull((d_after, doc))
             return d_state, tuple(d_own)
 
@@ -210,10 +267,14 @@ delta_chunks.defvjp(_delta_fwd, _delta_bwd)
 
 def chunked_delta(q, k, v, g, beta, chunk: int):
     """``delta_chunks`` for a caller that holds a head's tokens uncut: ``q``,
-    ``k`` [B, H, T, K], ``v`` [B, H, T, V], ``g`` [B, H, T, K] float32,
-    ``beta`` [B, H, T] float32 -> ``o`` [B, H, T, V].  The cut pads with
+    ``k`` [B, H, T, K], ``v`` [B, H, T, V], ``g`` [B, H, T, K] float32 (or
+    [B, H, T]: the scalar-gate form), ``beta`` [B, H, T] float32 -> ``o``
+    [B, H, T, V].  The cut pads with
     zeros, which is ``beta = 0`` and ``g = 0``."""
-    with part("delta_scan"):
+    # once a trace and layer kind, in the launch log: which form was traced
+    with part("delta_scan"), launch.span(
+            "scan_path", path="per_channel" if g.ndim == q.ndim else "scalar", heads=q.shape[1],
+            key=q.shape[-1], value=v.shape[-1]):
         by_chunk = lambda x: cut(jnp.moveaxis(x, 2, 1), chunk)  # noqa: E731  [chunks, B, C, H, ..]
         heads_first = lambda x: jnp.moveaxis(x, 3, 2)           # noqa: E731  [chunks, B, H, C, ..]
         o = delta_chunks(*(heads_first(by_chunk(x)) for x in (q, k, v, g, beta)))

@@ -58,6 +58,14 @@ which owns the chip:
             the four expert cells, with the microseconds of each.  With
             --ling, or alone with --ling-kernels (no network is built: about
             two minutes)
+  olmo_kernels  the scalar-gate walk (``ops/chunked_delta.py``, a decay a
+            head and token) against the recurrence stepped a token at a time
+            in float32, at ``olmoh_q_l4``'s head sizes (30 heads, keys of 96,
+            values of 192, 1,568 tokens, chunk 64, bfloat16 in), forward and
+            the five gradients, with the microseconds of a forward and of a
+            forward and backward, and the ``scan_path`` spans; the same walk
+            with ``beta`` held to 1 must fail the limit.  Only with
+            --olmo-kernels (no network is built: about two minutes)
   first_conv  the bootstrap's first convolution apart, at the three conv
             cells' shapes: the online and the target net's convolutions of N
             outputs on the same bytes against one of 2N with the two filter
@@ -998,6 +1006,88 @@ def leg_fetch() -> None:
         say(f"fetch: gather_path {dict(side)}: {times} traced sides")
 
 
+OLMO_WALK_REL = 0.03    # of the largest |value|: bfloat16 operands against a float32 recurrence
+
+
+def scalar_walk_against_the_recurrence(rows=2, heads=30, tokens=1568, kw=96, vw=192, chunk=64,
+                                       repeats=5):
+    """{name: (relative distance, the same with ``beta`` held to 1)} of the
+    scalar-gate walk's output and five gradients from the literal
+    recurrence's (float32, a token a step), and the walk's microseconds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ape_x_dqn_tpu.ops.chunked_delta import chunked_delta
+
+    ks = jax.random.split(jax.random.PRNGKey(51), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (rows, heads, tokens, kw))) / np.sqrt(kw)
+    k = unit(jax.random.normal(ks[1], (rows, heads, tokens, kw)) + 0.5)
+    v = jax.random.normal(ks[2], (rows, heads, tokens, vw))
+    g = -0.1 * jax.random.uniform(ks[3], (rows, heads, tokens))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (rows, heads, tokens)))
+    cot = jax.random.normal(ks[5], v.shape)
+    low = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
+
+    def literal(q, k, v, g, beta):
+        def step(state, token):
+            qt, kt, vt, gt, bt = token
+            state = jnp.exp(gt)[..., None, None] * state
+            read = jnp.sum(kt[..., None] * state, axis=-2)
+            state = state + (bt[..., None] * kt)[..., None] * (vt - read)[..., None, :]
+            return state, jnp.sum(qt[..., None] * state, axis=-2)
+
+        # the backward pass keeps a state a segment and steps the segment again:
+        # a state a token would be 6.9 GB at these sizes
+        seg = max(s for s in range(1, 57) if tokens % s == 0)
+        segment = jax.checkpoint(lambda state, part: jax.lax.scan(step, state, part))
+        by_time = tuple(jnp.moveaxis(x, 2, 0).reshape(tokens // seg, seg, *x.shape[:2], *x.shape[3:])
+                        for x in (q, k, v, g, beta))
+        first = jnp.zeros((*q.shape[:2], q.shape[-1], v.shape[-1]), jnp.float32)
+        _, o = jax.lax.scan(segment, first, by_time)
+        return jnp.moveaxis(o.reshape(tokens, *o.shape[2:]), 0, 2)
+
+    def pulled(fn):
+        def run(*args):
+            out, pull = jax.vjp(fn, *args)
+            return (out, *pull(cot.astype(out.dtype)))
+        return jax.jit(run)
+
+    walk = lambda q, k, v, g, beta: chunked_delta(low(q), low(k), low(v), g, beta, chunk)  # noqa: E731
+    held = lambda q, k, v, g, beta: walk(q, k, v, g, jnp.minimum(beta, 1.0))  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = pulled(lambda *z: literal(*(low(x).astype(jnp.float32) for x in z[:3]), *z[3:]))(
+            q, k, v, g, beta)
+    got, lost = pulled(walk)(q, k, v, g, beta), pulled(held)(q, k, v, g, beta)
+    rel = lambda a, b: float(jnp.max(jnp.abs(a.astype(jnp.float32) - b)) / jnp.max(jnp.abs(b)))  # noqa: E731
+    out = {name: (rel(a, w), rel(b, w)) for name, a, b, w in
+           zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, lost, want)}
+    forward, both = jax.jit(walk), pulled(walk)
+    times = {}
+    for name, fn in (("forward_us", forward), ("forward_and_backward_us", both)):
+        jax.block_until_ready(fn(q, k, v, g, beta))
+        t0 = time.perf_counter()
+        for _ in range(repeats):
+            done = fn(q, k, v, g, beta)
+        jax.block_until_ready(done)
+        times[name] = round((time.perf_counter() - t0) / repeats * 1e6, 1)
+    return out, times
+
+
+def leg_olmo_kernels() -> None:
+    from ape_x_dqn_tpu.utils import profiling
+
+    near, times = scalar_walk_against_the_recurrence()
+    for name, (rel, lost) in near.items():
+        say(f"olmo_kernels: {name} {rel:.5f} from the token-by-token recurrence (limit "
+            f"{OLMO_WALK_REL}), {lost:.4f} with beta held to 1")
+        assert rel <= OLMO_WALK_REL, f"the scalar-gate walk's {name} is {rel} from the recurrence"
+    assert near["o"][1] > 3 * OLMO_WALK_REL, "a walk with beta held to 1 passes the limit"
+    say(f"olmo_kernels: the walk at [2, 30, 1568, 96 | 192], chunk 64, host clock: {times}")
+    say(f"olmo_kernels: scan_path {profiling.launch.attrs_of('scan_path')[:1]}")
+
+
 def leg_ling_kernels() -> None:
     for name, (near, far) in latent_kernels_against_plain().items():
         say(f"ling_kernels: {name} {near:.5f} from plain attention (limit {KERNEL_REL}), "
@@ -1074,6 +1164,8 @@ def main() -> int:
         legs = [("ling_kernels", leg_ling_kernels), ("ling", leg_ling)]
     if "--ling-kernels" in sys.argv[1:]:
         legs = [("ling_kernels", leg_ling_kernels)]
+    if "--olmo-kernels" in sys.argv[1:]:
+        legs = [("olmo_kernels", leg_olmo_kernels)]
     if "--first-conv" in sys.argv[1:]:
         legs = [("first_conv", leg_first_conv)]
     if "--fetch" in sys.argv[1:]:

@@ -71,6 +71,9 @@ def main(argv=None) -> int:
     print(report(summary))
     for side in log.attrs_of("gather_path"):  # replay/device_dedup.dedup_fetch, a traced side
         print(f"gather_path: {side['path']}, {side['rows']} rows of {side['words']} words")
+    for walk in log.attrs_of("scan_path"):    # ops/chunked_delta.chunked_delta, a traced layer kind
+        print(f"scan_path: {walk['path']}, {walk['heads']} heads, keys of {walk['key']}, "
+              f"values of {walk['value']}")
     return 0
 
 
