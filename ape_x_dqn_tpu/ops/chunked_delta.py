@@ -53,11 +53,25 @@ so a chunk's pair scores are one product of ``[2 C, K]`` by ``[K, C]`` and a
 ``[C, C]`` mask of decays, each the exponential of a difference formed before
 it and under the mask: no sub-blocks, and ``g`` crosses the walk as 4 B a
 token and head.  ``W``, ``U``, ``V'``, ``o`` and the state are the lines above
-with ``exp(G)`` a scalar a row (``_chunk_scalar``); the inverse, the cut, the
-padding and the carry are shared.  The same entry takes both forms and tells
+with ``exp(G)`` a scalar a row (``_chunk_scalar``: ``_state_free``, which
+makes everything up to ``W``, ``U``, the scores and the decayed ``q`` and
+``k``, then ``_carry``, the four products that read the state); the cut, the
+padding and the walk are shared.  The same entry takes both forms and tells
 them apart by ``g``'s rank; keys and values need not have one width (the
 state is [K, V]), and a head's K and V lie in the lanes as they are, padded
 by the compiler's tiles to whole lanes (96 of 128, 192 of 256).
+
+**This form's inverse is made where it lies** (``_unit_lower_inverse_of``):
+the same diagonal blocks, doubling product and merges, on whole ``[C, C]``
+matrices under block masks, ten ``[64, 64]`` products a chunk of 64 where the
+gathered blocks' form is ten products of ``[8, 8, 8]`` to ``[32, 32]`` with a
+gather, a pad, a join and a change of layout round each: on a TPU v5e the
+gathered form was three fifths of a chunk's time, lanes an eighth full.  It
+is pulled back by ``dA = -T^T dT T^T``, two products and ``T`` alone kept,
+where autodiff through the blocks kept some forty small arrays a chunk and
+made the backward's loop 246 instructions.  The per-channel form keeps the
+gathered blocks (``_unit_lower_inverse``): its cells' programs are held to
+their text until the benchmark can judge a change to them.
 
 The backward pass is written by hand (``jax.custom_vjp``) in
 ``chunked_scan.py``'s manner: it keeps the inputs and each chunk's incoming
@@ -130,6 +144,54 @@ def _unit_lower_inverse(a):
         axis=-2)
 
 
+def _inverse_in_place(a):
+    """``_unit_lower_inverse``'s blocks, products and order on whole ``[C,
+    C]`` matrices under block masks: a diagonal block stays where it lies,
+    so nothing is gathered, padded or joined and every product is ``[C, C]``
+    by ``[C, C]`` with the chunk's tokens in the lanes (a product with the
+    zeros round a block adds nothing to its sums)."""
+    c = a.shape[-1]
+    size = _divisor(c, INVERSE_BASE)
+    mm = lambda x, y: jnp.matmul(x, y, precision=_HIGHEST)  # noqa: E731
+    block_of = lambda s: jnp.arange(c) // s  # noqa: E731
+    within = lambda s: block_of(s)[:, None] == block_of(s)[None, :]  # noqa: E731
+    eye = jnp.eye(c, dtype=a.dtype)
+    x = -jnp.where(within(size), a, 0.0)       # the diagonal blocks
+    inv, power, reach = eye + x, x, 2          # sum of x^k, k < reach
+    while reach < size:
+        power = mm(power, power)
+        inv, reach = mm(inv, eye + power), 2 * reach
+    while (c // size) % 2 == 0:                # two and two: [[T1, 0], [-T2 A21 T1, T2]]
+        below = jnp.where(within(2 * size) & ~within(size), a, 0.0)
+        inv, size = inv - mm(inv, mm(below, inv)), 2 * size
+    rows = block_of(size)
+    for i in range(1, c // size):              # block substitution, a block row at a time
+        own = (rows == i)[:, None]
+        left = jnp.where(own & (rows < i)[None, :], a, 0.0)
+        inv = inv - mm(jnp.where(own, inv, 0.0), mm(left, inv))
+    return inv
+
+
+@jax.custom_vjp
+def _unit_lower_inverse_of(a):
+    """``T = (I + a)^-1`` by ``_inverse_in_place``, pulled back by ``dA = -T^T
+    dT T^T``: ``T`` alone is kept for the backward pass."""
+    return _inverse_in_place(a)
+
+
+def _inverse_fwd(a):
+    t = _inverse_in_place(a)
+    return t, t
+
+
+def _inverse_bwd(t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    return (-jnp.matmul(tt, jnp.matmul(dt, tt, precision=_HIGHEST), precision=_HIGHEST),)
+
+
+_unit_lower_inverse_of.defvjp(_inverse_fwd, _inverse_bwd)
+
+
 def _chunk(state, q, k, v, g, beta):
     """One chunk: (``state`` [B, H, K, V] float32, the chunk's ``q``, ``k``
     [B, H, C, K], ``v`` [B, H, C, V], ``g`` [B, H, C, K] float32, ``beta`` [B,
@@ -177,36 +239,64 @@ def _chunk(state, q, k, v, g, beta):
     return state, o.astype(cd)
 
 
+def _dot_in(cd):
+    """``einsum`` of operands cast to ``cd``, summed in float32."""
+    return lambda spec, x, y: jnp.einsum(
+        spec, x.astype(cd), y.astype(cd), preferred_element_type=jnp.float32)
+
+
+def _state_free(q, k, v, g, beta):
+    """The half of a scalar-gate chunk that does not read the carried state,
+    over any leading dimensions (``[B, H]`` in the walk): ``q``, ``k`` [...,
+    C, K], ``v`` [..., C, V], ``g``, ``beta`` [..., C] float32 -> what
+    ``_carry`` takes after the state: ``W`` [..., C, K], ``U`` [..., C, V]
+    float32, the scores [..., C, C], ``q exp(G)`` and ``k exp(G_end - G)``
+    [..., C, K], ``exp(G_end)`` [..., 1, 1] float32; the four that are
+    operands of products alone in ``q``'s type, as the products take them.
+    It stays in the walk's loop: made for a group of chunks at once its
+    arrays leave the chip's fast memory and the walk is slower at every
+    group's size (PERF.md section 6, PR 52)."""
+    cd, f32 = q.dtype, jnp.float32
+    c, kw = q.shape[-2], q.shape[-1]
+    dot = _dot_in(cd)
+    with jax.named_scope("scalar_gate"):
+        run = jnp.cumsum(g, axis=-1)                                   # G: [..., C]
+        earlier = jnp.tril(jnp.ones((c, c), bool))                     # j <= i
+        # masked before the exponential: a later token's G_i - G_j is positive
+        decay = jnp.exp(jnp.where(earlier, run[..., :, None] - run[..., None, :], -jnp.inf))
+        # k.k over q.k, one product: [..., 2C, C]
+        pairs = dot("...rk,...jk->...rj", jnp.concatenate([k, q], axis=-2), k)
+        a = beta[..., None] * jnp.tril(pairs[..., :c, :] * decay, -1)
+        scores = pairs[..., c:, :] * decay
+
+        t = _unit_lower_inverse_of(a)
+        decayed = jnp.exp(run)[..., None]                              # exp(G_i) <= 1
+        kf = k.astype(f32)
+        wu = dot("...ij,...jx->...ix", t,
+                 jnp.concatenate([kf * decayed, v.astype(f32)], axis=-1) * beta[..., None])
+        to_end = jnp.exp(run[..., -1:] - run)[..., None]               # exp(G_end - G_j) <= 1
+        return (wu[..., :kw].astype(cd), wu[..., kw:], scores.astype(cd),
+                (q.astype(f32) * decayed).astype(cd), (kf * to_end).astype(cd),
+                decayed[..., -1:, :])
+
+
+def _carry(state, w, u, scores, reads, writes, kept):
+    """What of a scalar-gate chunk reads the state: (``state`` [B, H, K, V]
+    float32, a chunk of ``_state_free``'s outputs) -> (the state after the
+    chunk, ``o`` [B, H, C, V] in ``q``'s type)."""
+    cd, dot = w.dtype, _dot_in(w.dtype)
+    with jax.named_scope("scalar_gate"):
+        moved = u - dot("bhik,bhkv->bhiv", w, state)                   # V'
+        o = dot("bhik,bhkv->bhiv", reads, state) + dot("bhij,bhjv->bhiv", scores, moved)
+        state = kept * state + dot("bhjk,bhjv->bhkv", writes, moved)
+        return state, o.astype(cd)
+
+
 def _chunk_scalar(state, q, k, v, g, beta):
     """``_chunk`` under one log decay a head and token: ``g`` [B, H, C]
     float32; ``state`` [B, H, K, V], ``q``, ``k`` [B, H, C, K], ``v`` [B, H,
     C, V], K and V any two widths (module docstring)."""
-    cd, f32 = q.dtype, jnp.float32
-    c, kw = q.shape[2], q.shape[3]
-    dot = lambda spec, x, y: jnp.einsum(  # noqa: E731
-        spec, x.astype(cd), y.astype(cd), preferred_element_type=f32)
-    with jax.named_scope("scalar_gate"):
-        run = jnp.cumsum(g, axis=-1)                                   # G: [B, H, C]
-        earlier = jnp.tril(jnp.ones((c, c), bool))                     # j <= i
-        # masked before the exponential: a later token's G_i - G_j is positive
-        decay = jnp.exp(jnp.where(earlier, run[..., :, None] - run[..., None, :], -jnp.inf))
-        # k.k over q.k, one product: [B, H, 2C, C]
-        pairs = dot("bhrk,bhjk->bhrj", jnp.concatenate([k, q], axis=2), k)
-        a = beta[..., None] * jnp.tril(pairs[:, :, :c] * decay, -1)
-        scores = pairs[:, :, c:] * decay
-
-        t = _unit_lower_inverse(a)
-        decayed = jnp.exp(run)[..., None]                              # exp(G_i) <= 1
-        kf = k.astype(f32)
-        wu = dot("bhij,bhjx->bhix", t,
-                 jnp.concatenate([kf * decayed, v.astype(f32)], axis=-1) * beta[..., None])
-        w, u = wu[..., :kw], wu[..., kw:]
-        moved = u - dot("bhik,bhkv->bhiv", w, state)                   # V'
-        o = (dot("bhik,bhkv->bhiv", q.astype(f32) * decayed, state)
-             + dot("bhij,bhjv->bhiv", scores, moved))
-        to_end = jnp.exp(run[..., -1:] - run)[..., None]               # exp(G_end - G_j) <= 1
-        state = decayed[:, :, -1:] * state + dot("bhjk,bhjv->bhkv", kf * to_end, moved)
-        return state, o.astype(cd)
+    return _carry(state, *_state_free(q, k, v, g, beta))
 
 
 def _chunk_of(q, g):
@@ -272,9 +362,10 @@ def chunked_delta(q, k, v, g, beta, chunk: int):
     [B, H, T, V].  The cut pads with
     zeros, which is ``beta = 0`` and ``g = 0``."""
     # once a trace and layer kind, in the launch log: which form was traced
+    wide = g.ndim == q.ndim
     with part("delta_scan"), launch.span(
-            "scan_path", path="per_channel" if g.ndim == q.ndim else "scalar", heads=q.shape[1],
-            key=q.shape[-1], value=v.shape[-1]):
+            "scan_path", path="per_channel" if wide else "scalar", heads=q.shape[1],
+            key=q.shape[-1], value=v.shape[-1], inverse="by_blocks" if wide else "in_place"):
         by_chunk = lambda x: cut(jnp.moveaxis(x, 2, 1), chunk)  # noqa: E731  [chunks, B, C, H, ..]
         heads_first = lambda x: jnp.moveaxis(x, 3, 2)           # noqa: E731  [chunks, B, H, C, ..]
         o = delta_chunks(*(heads_first(by_chunk(x)) for x in (q, k, v, g, beta)))

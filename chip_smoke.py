@@ -1084,7 +1084,8 @@ def leg_olmo_kernels() -> None:
             f"{OLMO_WALK_REL}), {lost:.4f} with beta held to 1")
         assert rel <= OLMO_WALK_REL, f"the scalar-gate walk's {name} is {rel} from the recurrence"
     assert near["o"][1] > 3 * OLMO_WALK_REL, "a walk with beta held to 1 passes the limit"
-    say(f"olmo_kernels: the walk at [2, 30, 1568, 96 | 192], chunk 64, host clock: {times}")
+    say(f"olmo_kernels: the walk at [2, 30, 1568, 96 | 192], chunk 64, host clock: {times} "
+        "(4,050 and 15,100 with the inverse by gathered blocks: PERF.md section 6, PR 51)")
     say(f"olmo_kernels: scan_path {profiling.launch.attrs_of('scan_path')[:1]}")
 
 

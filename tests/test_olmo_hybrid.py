@@ -189,9 +189,123 @@ def test_bfloat16_operands_float32_sums():
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) < 0.05 * float(jnp.max(jnp.abs(want)))
 
 
+# ------------------------------------------------ the inverse where it lies
+
+def _strictly_lower(c, seed=0, scale=0.5):
+    """[2, 3, C, C] strictly lower triangular, entries near ``beta k.k``'s."""
+    return jnp.tril(scale * jax.random.normal(jax.random.PRNGKey(seed + c), (2, 3, c, c)), -1)
+
+
+@pytest.mark.parametrize("c", [
+    64,    # eight blocks of 8 merged two and two three times: the cell's chunk
+    16,    # one merge
+    8,     # one block: the doubling product alone
+    40,    # five blocks of 8: block substitution, no merge
+    48,    # six blocks: one merge, then three blocks by substitution
+    50,    # blocks of 5 rows, ten of them
+    20,    # blocks of 5, four of them
+    11,    # a prime count: blocks of one row, substitution alone
+    7,     # one block of 7 rows
+])
+def test_the_inverse_in_place_is_the_inverse_by_blocks(c):
+    """The scalar-gate chunk's inverse on whole ``[C, C]`` matrices under
+    block masks against ``_unit_lower_inverse`` (the same blocks gathered,
+    which the per-channel form keeps) and against numpy's in float64, over
+    every way a chunk divides: both are as far from the exact inverse."""
+    a = _strictly_lower(c)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(chunked_delta._unit_lower_inverse_of)(a)
+        blocks = jax.jit(chunked_delta._unit_lower_inverse)(a)
+    exact = np.linalg.inv(np.eye(c) + np.asarray(a, np.float64))
+    top = float(np.max(np.abs(exact)))
+    far = lambda x: float(np.max(np.abs(np.asarray(x, np.float64) - exact))) / top  # noqa: E731
+    assert far(got) < 2e-6 and far(got) < 2 * far(blocks) + 1e-7
+    np.testing.assert_allclose(np.asarray(got), np.asarray(blocks), atol=4e-6 * top)
+    assert not np.triu(np.asarray(got), 1).any() and (np.diagonal(got, axis1=-2, axis2=-1) == 1).all()
+
+
+@pytest.mark.parametrize("c", [64, 40, 20, 11])
+def test_the_inverse_is_pulled_back_through_itself_alone(c):
+    """``dA = -T^T dT T^T`` against autodiff through the gathered blocks'
+    products, on the strictly lower part (all of ``a`` that a chunk makes);
+    the backward pass keeps ``T`` and nothing of its making."""
+    a, dt = _strictly_lower(c), jax.random.normal(jax.random.PRNGKey(c), (2, 3, c, c))
+    with jax.default_matmul_precision("highest"):
+        (want,), (got,) = (pulled(fn)(dt, a)[1] for fn in (
+            chunked_delta._unit_lower_inverse, chunked_delta._unit_lower_inverse_of))
+    np.testing.assert_allclose(np.asarray(jnp.tril(got, -1)), np.asarray(jnp.tril(want, -1)),
+                               atol=2e-6 * float(jnp.max(jnp.abs(want))))
+    kept = jax.tree_util.tree_leaves(jax.eval_shape(
+        lambda a: jax.vjp(chunked_delta._unit_lower_inverse_of, a)[1], a))
+    assert [x.shape for x in kept] == [(2, 3, c, c)]
+
+
+def _loops(fn, *args):
+    """[(reverse, the body's equations, every primitive under the body)] of
+    each ``scan`` in ``fn``'s jaxpr whose carry is the walk's state."""
+    def under(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for value in eqn.params.values():
+                for inner in (value if isinstance(value, (list, tuple)) else [value]):
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        yield from under(inner)
+
+    found = []
+    for eqn in under(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name == "scan":
+            body = eqn.params["jaxpr"].jaxpr
+            found.append((eqn.params["reverse"], len(body.eqns), list(under(body))))
+    return found
+
+
+def _both_ways(chunk):
+    def run(*args):
+        out, pull = jax.vjp(lambda *z: delta(*z, chunk), *args)
+        return pull(out)
+    return run
+
+
+def test_the_scalar_walks_loop_multiplies_whole_chunks_and_gathers_nothing():
+    """The chunk loop of the scalar-gate walk: no ``gather``, ``scatter-add``
+    or ``pad`` (the gathered blocks' inverse made nine a chunk), every product
+    at the highest precision is ``[C, C]`` by ``[C, C]`` (the inverse where it
+    lies), four products read the state (``_carry``), and the backward's loop
+    holds 183 equations where the gathered blocks' autodiff held 231: what
+    keeps a later edit from putting the blocks back in the loop."""
+    (q, k, v, g, beta), _ = scan_inputs(150)
+    (reverse, top, eqns), = _loops(lambda *z: delta(*z, 16), q, k, v, g, beta)
+    names = [e.primitive.name for e in eqns]
+    assert not reverse and top <= 42 and not {"gather", "scatter-add", "pad"} & set(names)
+    products = [e for e in eqns if e.primitive.name == "dot_general"]
+    highest = [e for e in products if e.params["precision"] is not None
+               and jax.lax.Precision.HIGHEST in tuple(e.params["precision"])]
+    assert len(products) == 12 and len(highest) == 6            # 16 rows: a doubling of 8, one merge
+    assert all(tuple(x.aval.shape[-2:]) == (16, 16) for e in highest for x in e.invars)
+    carry = jax.make_jaxpr(chunked_delta._carry)(
+        jnp.zeros((2, 3, 12, 24)), *chunked_delta._state_free(*(x[:, :, :16] for x in (q, k, v, g, beta))))
+    assert [e.primitive.name for e in carry.jaxpr.eqns].count("dot_general") == 4
+    forward, backward = _loops(_both_ways(16), q, k, v, g, beta)
+    assert not forward[0] and backward[0] and backward[1] <= 183
+    assert not {"gather", "scatter-add"} & {e.primitive.name for e in backward[2]}
+
+
+def test_the_per_channel_walks_loops_are_what_they_were():
+    """A decay a key channel walks ``_chunk`` with the gathered blocks'
+    inverse: 140 equations a chunk forward and 356 in the backward's loop,
+    the counts of the program before the scalar form's inverse moved."""
+    (q, k, v, g, beta), _ = scan_inputs(150)
+    wide = jnp.broadcast_to(g[..., None], q.shape)
+    forward, backward = _loops(_both_ways(16), q, k, v, wide, beta)
+    assert (forward[0], forward[1], backward[0], backward[1]) == (False, 140, True, 356)
+    assert "gather" in {e.primitive.name for e in forward[2]}
+
+
 def test_each_form_leaves_its_scan_path_in_the_launch_log(monkeypatch, capsys, tmp_path):
     """A trace of the walk records one ``scan_path`` span: ``scalar`` with
-    the two head sizes, ``per_channel`` for a decay a key channel; and
+    the two head sizes and the inverse made where it lies, ``per_channel``
+    for a decay a key channel, its inverse by gathered blocks; and
     ``tools/launch_report.py`` prints them."""
     log = profiling.LaunchLog()
     monkeypatch.setattr(chunked_delta, "launch", log)
@@ -199,15 +313,15 @@ def test_each_form_leaves_its_scan_path_in_the_launch_log(monkeypatch, capsys, t
     jax.make_jaxpr(lambda *z: delta(*z, 16))(q, k, v, g, beta)
     jax.make_jaxpr(lambda *z: delta(*z, 16))(q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta)
     assert log.attrs_of("scan_path") == [
-        {"path": "scalar", "heads": 3, "key": 12, "value": 24},
-        {"path": "per_channel", "heads": 3, "key": 12, "value": 24}]
+        {"path": "scalar", "heads": 3, "key": 12, "value": 24, "inverse": "in_place"},
+        {"path": "per_channel", "heads": 3, "key": 12, "value": 24, "inverse": "by_blocks"}]
     from tools import launch_report
 
     log.write(str(tmp_path / "l.json"))
     assert launch_report.main([str(tmp_path / "l.json")]) == 0
     out = capsys.readouterr().out
-    assert "scan_path: scalar, 3 heads, keys of 12, values of 24" in out
-    assert "scan_path: per_channel, 3 heads, keys of 12, values of 24" in out
+    assert "scan_path: scalar, 3 heads, keys of 12, values of 24, the inverse in_place" in out
+    assert "scan_path: per_channel, 3 heads, keys of 12, values of 24, the inverse by_blocks" in out
 
 
 # ------------------------------------------------------------------ the block

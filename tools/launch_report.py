@@ -73,7 +73,7 @@ def main(argv=None) -> int:
         print(f"gather_path: {side['path']}, {side['rows']} rows of {side['words']} words")
     for walk in log.attrs_of("scan_path"):    # ops/chunked_delta.chunked_delta, a traced layer kind
         print(f"scan_path: {walk['path']}, {walk['heads']} heads, keys of {walk['key']}, "
-              f"values of {walk['value']}")
+              f"values of {walk['value']}, the inverse {walk['inverse']}")
     return 0
 
 
