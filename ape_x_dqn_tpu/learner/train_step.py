@@ -33,7 +33,7 @@ from flax import struct
 
 from ape_x_dqn_tpu.ops import losses
 from ape_x_dqn_tpu.types import ROUTING, PrioritizedBatch, TrainState
-from ape_x_dqn_tpu.utils.profiling import launch_span, stage
+from ape_x_dqn_tpu.utils.profiling import launch_span, pass_, stage
 
 @struct.dataclass
 class StepMetrics:
@@ -370,14 +370,16 @@ def build_train_step(
             # next_obs: a network that offers the pair joins them along the
             # first layer's output channels, which the array has room for
             # (DuelingDQN; PERF.md section 6, PR 49), and sows nothing.
+            # Named as a pass of their own (``pass.bootstrap_step_us``).
             online_params = jax.lax.stop_gradient(params)
-            if q_of_two is not None:
-                q_next_online, q_next_target = q_of_two(
-                    online_params, target_params, t.next_obs)
-                s2 = s3 = {}
-            else:
-                q_next_online, s2 = q_of(online_params, t.next_obs)
-                q_next_target, s3 = q_of(target_params, t.next_obs)
+            with pass_("bootstrap"):
+                if q_of_two is not None:
+                    q_next_online, q_next_target = q_of_two(
+                        online_params, target_params, t.next_obs)
+                    s2 = s3 = {}
+                else:
+                    q_next_online, s2 = q_of(online_params, t.next_obs)
+                    q_next_target, s3 = q_of(target_params, t.next_obs)
             targets = losses.double_q_target(
                 q_next_online, q_next_target, t.reward, t.discount
             )

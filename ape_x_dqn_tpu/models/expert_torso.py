@@ -101,7 +101,7 @@ from flax import linen as nn
 from ape_x_dqn_tpu.models.dueling import STEM_WINDOWS, conv_stem, dueling_head
 from ape_x_dqn_tpu.ops.router_choice import router_choice
 from ape_x_dqn_tpu.types import ROUTING
-from ape_x_dqn_tpu.utils.profiling import part
+from ape_x_dqn_tpu.utils.profiling import part, pass_
 
 FFNS = ("dense", "moe")
 SCORES = ("sigmoid", "softmax")
@@ -433,19 +433,25 @@ def _held_experts_fwd(u, w13, w2, gates, order, sizes, tile: int):
 def _held_experts_bwd(tile: int, kept, dy):
     u, w13, w2, gates, order, sizes = kept
     cd, k, f = u.dtype, gates.shape[1], w2.shape[1]
-    with part("router"):
+    # What the forward walk made and did not keep is made again, under a pass
+    # of its own: the walk's bounds, the cast weights, a tile's rows and products.
+    with part("router"), pass_("again"):
         padded, starts, ends, tiles = _walk(order, sizes, tile)
     with part("experts"):
-        w13c, w2c = w13.astype(cd), w2.astype(cd)
+        with pass_("again"):
+            w13c, w2c = w13.astype(cd), w2.astype(cd)
         w13t, w2t = jnp.swapaxes(w13c, 1, 2), jnp.swapaxes(w2c, 1, 2)
 
     def body(t, carry):
         du, dw13, dw2, dgates = carry
         with part("router"):
-            pair, token, live, gate, group = _tile(t, tile, k, padded, starts, ends, gates)
-            xs, dy_rows = u[token], dy[token]
+            with pass_("again"):
+                pair, token, live, gate, group = _tile(t, tile, k, padded, starts, ends, gates)
+                xs = u[token]
+            dy_rows = dy[token]
         with part("experts"):
-            h, a, ys = _products(xs, w13c, w2c, group)
+            with pass_("again"):
+                h, a, ys = _products(xs, w13c, w2c, group)
             dy_rows = dy_rows.astype(jnp.float32)
             dgate = jnp.where(live, jnp.sum(dy_rows * ys.astype(jnp.float32), -1), 0.0)
             dys = jnp.where(live[:, None], dy_rows * gate[:, None], 0.0).astype(cd)

@@ -90,7 +90,7 @@ import jax
 import jax.numpy as jnp
 
 from ape_x_dqn_tpu.ops.chunked_scan import cut, join
-from ape_x_dqn_tpu.utils.profiling import launch, part
+from ape_x_dqn_tpu.utils.profiling import launch, part, pass_
 
 SUB = 16                         # rows of a sub-block of the pair scores
 INVERSE_BASE = 8                 # rows of a diagonal block inverted by the doubling product
@@ -343,7 +343,8 @@ def _delta_bwd(kept, do):
     with part("delta_scan"):
         def body(d_after, chunk):
             state, *own, doc = chunk
-            _, pull = jax.vjp(step, state, *own)               # the chunk, computed again
+            with pass_("again"):                               # the chunk, computed again
+                _, pull = jax.vjp(step, state, *own)
             d_state, *d_own = pull((d_after, doc))
             return d_state, tuple(d_own)
 

@@ -49,7 +49,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ape_x_dqn_tpu.utils.profiling import part
+from ape_x_dqn_tpu.utils.profiling import part, pass_
 
 
 def chunks_of(tokens: int, chunk: int) -> tuple:
@@ -130,7 +130,8 @@ def _pull(kept, dy):
         def body(carry, chunk):
             d_after, da, dd = carry
             state, xc, dtc, bc, cc, dyc = chunk
-            _, pull = jax.vjp(_chunk, state, xc, dtc, a, bc, cc, d)   # the chunk, computed again
+            with pass_("again"):                                      # the chunk, computed again
+                _, pull = jax.vjp(_chunk, state, xc, dtc, a, bc, cc, d)
             d_state, dx, ddt, da_c, db, dc, dd_c = pull((d_after, dyc))
             return (d_state, da + da_c, dd + dd_c), (dx, ddt, db, dc)
 

@@ -69,6 +69,18 @@ SCOPE_PREFIX = "stage:"   # device side: jax.named_scope("stage:<name>")
 PARTS = ("stem", "mixer", "router", "experts", "dense_ffn", "head",
          "attn_window", "attn_full", "shared_expert", "ssm_scan", "delta_scan", "attn_latent")
 PART_PREFIX = "torso:"    # device side: jax.named_scope("torso:<name>")
+# The passes of a step, a third axis beside stages and parts and under a
+# prefix of its own for the same reason: the stage readers take the innermost
+# ``stage:``, the part readers the innermost ``torso:``, and a pass nested
+# under either prefix would move them.  Two scopes are written by hand,
+# ``pass:bootstrap`` (the forwards on ``next_obs``) and ``pass:again`` (a
+# hand-written backward's forward, computed again); AD writes the rest:
+# ``rematted_computation`` is jax's own segment for what a checkpoint computes
+# again, ``transpose(`` its mark of a pull-back.
+PASSES = ("bootstrap", "forward", "recompute", "backward")
+PASS_SCOPES = ("bootstrap", "again")
+PASS_PREFIX = "pass:"     # device side: jax.named_scope("pass:<name>")
+REMAT_SEGMENT = "rematted_computation"  # jax's name-stack segment (ad_checkpoint.py)
 SPAN_PREFIX = "apex:"     # host side: TraceAnnotation("apex:<name>")
 
 
@@ -88,6 +100,15 @@ def part(name: str):
     import jax
 
     return jax.named_scope(PART_PREFIX + name)
+
+
+def pass_(name: str):
+    """``jax.named_scope("pass:<name>")`` for a name in ``PASS_SCOPES``."""
+    if name not in PASS_SCOPES:
+        raise ValueError(f"unknown pass scope {name!r}; PASS_SCOPES = {PASS_SCOPES}")
+    import jax
+
+    return jax.named_scope(PASS_PREFIX + name)
 
 
 class StageTimer:
@@ -167,9 +188,14 @@ class _FusedProgram:
         """The optimized HLO text of the program, lowered from the recorded
         signature: jit answers with the executable that ran.  The persistent
         compile cache's key leaves metadata out, so an executable loaded
-        from an entry that a build without the scopes wrote runs the same
-        instructions and names no stage; then the same function is jitted
-        and compiled once more under a key that holds the metadata (a real
+        from an entry that a build with other scopes wrote runs the same
+        instructions under that build's names: none from a build before the
+        stages, ``stage:`` and no ``pass:`` from one before the passes (a
+        parent commit measured before the change on one machine and one
+        cache: its entry answers the change's compile).  A fused program
+        holds a train step, whose loss emits both prefixes, so a text that
+        lacks either is such a load; then the same function is jitted and
+        compiled once more under a key that holds the metadata (a real
         compile the first time, a cache load after).  The flag is set for
         this thread alone (jax's config states are thread-local inside
         their context managers): a compile another thread starts meanwhile
@@ -181,7 +207,7 @@ class _FusedProgram:
 
         if self.text is None:
             text = self.jitted.lower(*self.signature).compile().as_text()
-            if SCOPE_PREFIX not in text:
+            if SCOPE_PREFIX not in text or PASS_PREFIX not in text:
                 again = jax.jit(  # a new function: nothing in memory answers
                     functools.wraps(self.traced)(lambda *a: self.traced(*a)),
                     **self.jit_kwargs)
@@ -267,6 +293,27 @@ _SCOPE = re.compile(re.escape(SCOPE_PREFIX) + r"(\w+)")
 _BACKWARD = re.compile(r"transpose\([^/]*" + re.escape(SCOPE_PREFIX) + "forward")
 
 
+def _own_op_names(hlo_text: str) -> Dict[str, str]:
+    """{instruction: its own ``op_name``, "" without one} for every
+    instruction of an executable's HLO text."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m and m.group(1) not in out:
+            op = _HLO_OP_NAME.search(m.group(2))
+            out[m.group(1)] = op.group(1) if op else ""
+    return out
+
+
+def _op_stage(op_name: str) -> str:
+    found = _SCOPE.findall(op_name)
+    if not found:
+        return OTHER
+    if found[-1] == "forward" and _BACKWARD.search(op_name):
+        return "backward"
+    return found[-1]
+
+
 def hlo_stages(hlo_text: str) -> Dict[str, str]:
     """{instruction: stage} for every instruction of an executable's HLO
     text: the innermost ``stage:<name>`` of its own ``op_name`` (``forward``
@@ -275,18 +322,7 @@ def hlo_stages(hlo_text: str) -> Dict[str, str]:
     ``other`` here, and the one rule that hands each to the stage that
     consumes it is the benchmark's (``benchmark/stage_times.py``), whose
     test holds the two to the same answer on every scoped instruction."""
-    out: Dict[str, str] = {}
-    for line in hlo_text.splitlines():
-        m = _HLO_INSTRUCTION.match(line)
-        if not m or m.group(1) in out:
-            continue
-        op = _HLO_OP_NAME.search(m.group(2))
-        found = _SCOPE.findall(op.group(1)) if op else []
-        out[m.group(1)] = (
-            OTHER if not found else
-            "backward" if found[-1] == "forward" and _BACKWARD.search(op.group(1))
-            else found[-1])
-    return out
+    return {name: _op_stage(op) for name, op in _own_op_names(hlo_text).items()}
 
 
 _PART = re.compile(re.escape(PART_PREFIX) + r"(\w+)")
@@ -296,16 +332,41 @@ def hlo_parts(hlo_text: str) -> Dict[str, str]:
     """{instruction: part} for the instructions whose own ``op_name`` holds a
     ``torso:<name>`` (the innermost), forward, recomputation and backward
     alike."""
-    out: Dict[str, str] = {}
-    for line in hlo_text.splitlines():
-        m = _HLO_INSTRUCTION.match(line)
-        if not m or m.group(1) in out:
-            continue
-        op = _HLO_OP_NAME.search(m.group(2))
-        found = _PART.findall(op.group(1)) if op else []
-        if found:
-            out[m.group(1)] = found[-1]
-    return out
+    found = ((name, _PART.findall(op)) for name, op in _own_op_names(hlo_text).items())
+    return {name: parts[-1] for name, parts in found if parts}
+
+
+# ``pass:again`` as a segment of its own: the pull-back of what ran under it
+# reads ``transpose(pass:again)``, and is the backward pass proper.
+_AGAIN = re.compile(r"(?:^|/)(?:" + re.escape(REMAT_SEGMENT) + "|"
+                    + re.escape(PASS_PREFIX) + r"again)(?:/|$)")
+
+
+def op_pass(op_name: str) -> Optional[str]:
+    """The pass of ``PASSES`` that an ``op_name`` names, or None where its
+    stage is neither ``forward`` nor ``backward``: ``bootstrap`` under
+    ``pass:bootstrap``; under the pull-back ``recompute`` where jax's
+    ``rematted_computation`` or ``pass:again`` is a segment (nested
+    recomputation is ``recompute`` once), else ``backward``; else
+    ``forward``."""
+    stage = _op_stage(op_name)
+    if stage not in ("forward", "backward"):
+        return None
+    if PASS_PREFIX + "bootstrap" in op_name:
+        return "bootstrap"
+    if stage == "forward":
+        return "forward"
+    return "recompute" if _AGAIN.search(op_name) else "backward"
+
+
+def hlo_passes(hlo_text: str) -> Dict[str, str]:
+    """{instruction: pass} for the instructions whose own ``op_name`` puts
+    them in ``forward`` or ``backward`` (``hlo_stages``' rule), by
+    ``op_pass``.  As ``hlo_stages``, the operator's summary stops at an
+    instruction's own metadata; the benchmark's ``pass_times.py`` hands the
+    unscoped ones on, and its test holds the two to one answer here."""
+    found = ((name, op_pass(op)) for name, op in _own_op_names(hlo_text).items())
+    return {name: p for name, p in found if p}
 
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -418,8 +479,10 @@ def _own_seconds(events) -> list:
 def summarize_trace(logdir: str) -> dict:
     """Reduce the newest ``*.xplane.pb`` under ``logdir`` with
     ``jax.profiler.ProfileData``: the share of the traced span with an op
-    running on the device; seconds per stage (and per part of the network,
-    ``part_s``, where the program names parts), from the ops inside the runs
+    running on the device; seconds per stage, ``stage_s`` (and per part of
+    the network, ``part_s``, where the program names parts; per pass of the
+    step, ``pass_s``, the ``PASSES`` of ``forward`` and ``backward`` apart:
+    how much of the step is work done a second time), from the ops inside the runs
     of every fused program whose text is known (``fused_hlo_texts``; ops of
     any other program, the actors' action selection say, are
     ``other_programs``), with the share of that time on instructions the
@@ -437,10 +500,12 @@ def summarize_trace(logdir: str) -> dict:
         raise FileNotFoundError(f"no *.xplane.pb under {logdir}")
     stages: Dict[str, Dict[str, str]] = {}  # program -> instruction -> stage
     parts: Dict[str, Dict[str, str]] = {}   # program -> instruction -> part
+    passes: Dict[str, Dict[str, str]] = {}  # program -> instruction -> pass
     for program in list(_fused_programs):
         for text in fused_hlo_texts(program):
             stages.setdefault(program, {}).update(hlo_stages(text))
             parts.setdefault(program, {}).update(hlo_parts(text))
+            passes.setdefault(program, {}).update(hlo_passes(text))
 
     def events(line) -> list:
         return [(e.name, e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
@@ -460,6 +525,7 @@ def summarize_trace(logdir: str) -> dict:
     busy = span = fused_s = named_s = 0.0
     stage_s: Dict[str, float] = defaultdict(float)
     part_s: Dict[str, float] = defaultdict(float)
+    pass_s: Dict[str, float] = defaultdict(float)
     idle: list = []
     for ops, modules in devices:
         if not ops:
@@ -478,6 +544,8 @@ def summarize_trace(logdir: str) -> dict:
                 stage = stages[runs[i][2]].get(name, OTHER)
                 if name in parts[runs[i][2]]:
                     part_s[parts[runs[i][2]][name]] += own / len(devices)
+                if name in passes[runs[i][2]]:
+                    pass_s[passes[runs[i][2]][name]] += own / len(devices)
                 fused_s += own
                 named_s += own if name in stages[runs[i][2]] else 0.0
             else:
@@ -500,6 +568,8 @@ def summarize_trace(logdir: str) -> dict:
         # seconds on instructions a part of the network names (PARTS),
         # forward and backward together; a part is read beside its stage
         "part_s": {k: round(v, 6) for k, v in sorted(part_s.items())},
+        # seconds of ``forward`` and ``backward`` by pass of the step (PASSES)
+        "pass_s": {k: round(v, 6) for k, v in sorted(pass_s.items())},
         "stage_named_share": named_s / fused_s if fused_s else None,
         "host_spans": len(spans),
         "longest_gaps": [[beside(t0, t1), round(length, 6)]
