@@ -36,7 +36,15 @@ doubling product ``(I - A)(I + A^2)(I + A^4)`` (exact for a nilpotent block,
 and short enough at 8 rows that its alternating powers do not cancel: keys
 that share a direction give entries near ``beta``, and over 64 rows their
 powers reach 1e9), then blocks merged two and two, ``[[T1, 0], [-T2 A21 T1,
-T2]]``, which is block substitution.
+T2]]``, while their count is even, and by block substitution after that.
+
+**The inverse is made where it lies** (``_unit_lower_inverse_of``, one for
+both forms): the blocks, the doubling product and the merges on whole ``[C,
+C]`` matrices under block masks, ten ``[64, 64]`` products a chunk of 64 and
+nothing gathered, padded or joined (blocks gathered into ``[.., 8, 8, 8]`` to
+``[.., 32, 32]`` were three fifths of a chunk's time on a TPU v5e, lanes an
+eighth full, and autodiff through them some forty small arrays a chunk).  It
+is pulled back by ``dA = -T^T dT T^T``: two products, ``T`` alone kept.
 
 Running sums, decays, ``T`` and the state are float32; the products take
 operands in ``q``'s type and accumulate in float32.  ``q``, ``k``, ``v`` and
@@ -60,18 +68,6 @@ padding and the walk are shared.  The same entry takes both forms and tells
 them apart by ``g``'s rank; keys and values need not have one width (the
 state is [K, V]), and a head's K and V lie in the lanes as they are, padded
 by the compiler's tiles to whole lanes (96 of 128, 192 of 256).
-
-**This form's inverse is made where it lies** (``_unit_lower_inverse_of``):
-the same diagonal blocks, doubling product and merges, on whole ``[C, C]``
-matrices under block masks, ten ``[64, 64]`` products a chunk of 64 where the
-gathered blocks' form is ten products of ``[8, 8, 8]`` to ``[32, 32]`` with a
-gather, a pad, a join and a change of layout round each: on a TPU v5e the
-gathered form was three fifths of a chunk's time, lanes an eighth full.  It
-is pulled back by ``dA = -T^T dT T^T``, two products and ``T`` alone kept,
-where autodiff through the blocks kept some forty small arrays a chunk and
-made the backward's loop 246 instructions.  The per-channel form keeps the
-gathered blocks (``_unit_lower_inverse``): its cells' programs are held to
-their text until the benchmark can judge a change to them.
 
 The backward pass is written by hand (``jax.custom_vjp``) in
 ``chunked_scan.py``'s manner: it keeps the inputs and each chunk's incoming
@@ -107,49 +103,16 @@ def sub_rows(chunk: int) -> int:
     return _divisor(chunk, SUB)
 
 
-def _unit_lower_inverse(a):
+def _inverse_in_place(a):
     """``(I + a)^-1`` for ``a`` [..., C, C] float32, strictly lower
     triangular: its diagonal blocks (the largest divisor of C up to
     ``INVERSE_BASE`` rows) by the doubling product, then blocks merged two
-    and two, ``[[T1, 0], [-T2 A21 T1, T2]]``, while their count is even, and
-    by block substitution after that (module docstring)."""
-    c = a.shape[-1]
-    size = _divisor(c, INVERSE_BASE)
-    mm = lambda x, y: jnp.matmul(x, y, precision=_HIGHEST)  # noqa: E731
-    block = lambda i, j, s: a[..., i * s:(i + 1) * s, j * s:(j + 1) * s]  # noqa: E731
-    eye = jnp.eye(size, dtype=a.dtype)
-    x = -jnp.stack([block(i, i, size) for i in range(c // size)], axis=-3)   # [..., n, s, s]
-    inv, power, reach = eye + x, x, 2          # sum of x^k, k < reach
-    while reach < size:
-        power = mm(power, power)
-        inv, reach = mm(inv, eye + power), 2 * reach
-    while inv.shape[-3] % 2 == 0:              # two and two
-        n = inv.shape[-3] // 2
-        first, second = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
-        below = jnp.stack([block(2 * p + 1, 2 * p, size) for p in range(n)], axis=-3)
-        left = -mm(second, mm(below, first))
-        inv = jnp.concatenate([jnp.concatenate([first, jnp.zeros_like(first)], axis=-1),
-                               jnp.concatenate([left, second], axis=-1)], axis=-2)
-        size *= 2
-    n = inv.shape[-3]
-    out = [inv[..., 0, :, :]]                  # the block rows, each [..., s, (i + 1) s]
-    for i in range(1, n):
-        above = jnp.concatenate(
-            [jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, i * size - row.shape[-1])])
-             for row in out], axis=-2)         # T's first i block rows, [..., i s, i s]
-        left = -mm(inv[..., i, :, :], mm(a[..., i * size:(i + 1) * size, :i * size], above))
-        out.append(jnp.concatenate([left, inv[..., i, :, :]], axis=-1))
-    return jnp.concatenate(
-        [jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, c - row.shape[-1])]) for row in out],
-        axis=-2)
-
-
-def _inverse_in_place(a):
-    """``_unit_lower_inverse``'s blocks, products and order on whole ``[C,
-    C]`` matrices under block masks: a diagonal block stays where it lies,
-    so nothing is gathered, padded or joined and every product is ``[C, C]``
-    by ``[C, C]`` with the chunk's tokens in the lanes (a product with the
-    zeros round a block adds nothing to its sums)."""
+    and two while their count is even, and by block substitution after that
+    (module docstring), on whole ``[C, C]`` matrices under block masks: a
+    diagonal block stays where it lies, so nothing is gathered, padded or
+    joined and every product is ``[C, C]`` by ``[C, C]`` with the chunk's
+    tokens in the lanes (a product with the zeros round a block adds nothing
+    to its sums)."""
     c = a.shape[-1]
     size = _divisor(c, INVERSE_BASE)
     mm = lambda x, y: jnp.matmul(x, y, precision=_HIGHEST)  # noqa: E731
@@ -213,21 +176,21 @@ def _chunk(state, q, k, v, g, beta):
     up = jnp.exp(jnp.minimum(first[:, :, :, None] - run[:, :, None], 0.0))   # [B, H, n, C, K]
     across = dot("bhnrk,bhnjk->bhnrj", jnp.concatenate([k_b * down, q_b * down], axis=3),
                  kf[:, :, None] * up)                              # [B, H, n, 2R, C]
-    earlier = (jnp.arange(c)[None, :] // rows) < jnp.arange(n)[:, None]   # j's block before i's
-    across = jnp.where(earlier[:, None, :], across, 0.0)
     # pairs in one sub-block: the difference before the exponential
     same = jnp.tril(jnp.ones((rows, rows), bool))                  # s <= r
     decay = jnp.exp(jnp.where(same[..., None],
                               run_b[:, :, :, :, None] - run_b[:, :, :, None, :], -jnp.inf))
     kk = jnp.sum(k_b[:, :, :, :, None] * k_b[:, :, :, None, :] * decay, axis=-1)   # [B, H, n, R, R]
     qk = jnp.sum(q_b[:, :, :, :, None] * k_b[:, :, :, None, :] * decay, axis=-1)
-    own = jnp.eye(n, dtype=f32)                                    # a sub-block's own columns
-    place = lambda d: jnp.einsum("bhnrs,nm->bhnrms", d, own).reshape(b, h, c, c)  # noqa: E731
     strict = jnp.tril(jnp.ones((rows, rows), f32), -1)
-    a = beta[..., None] * (across[:, :, :, :rows].reshape(b, h, c, c) + place(kk * strict))
-    scores = across[:, :, :, rows:].reshape(b, h, c, c) + place(qk)
+    own = jnp.concatenate([jnp.concatenate([kk * strict, qk], axis=3)] * n, axis=-1)   # [B, H, n, 2R, C]
+    # a sub-block's rows: the earlier blocks' columns, its own, nothing later
+    later = (jnp.arange(c)[None, :] // rows - jnp.arange(n)[:, None])[:, None, :]      # j's block less i's
+    pairs = jnp.where(later < 0, across, jnp.where(later == 0, own, 0.0))
+    a = beta[..., None] * pairs[:, :, :, :rows].reshape(b, h, c, c)
+    scores = pairs[:, :, :, rows:].reshape(b, h, c, c)
 
-    t = _unit_lower_inverse(a)
+    t = _unit_lower_inverse_of(a)
     decayed = jnp.exp(run)                                         # exp(G_i) <= 1
     wu = dot("bhij,bhjx->bhix", t,
              jnp.concatenate([kf * decayed, v.astype(f32)], axis=-1) * beta[..., None])
@@ -366,7 +329,7 @@ def chunked_delta(q, k, v, g, beta, chunk: int):
     wide = g.ndim == q.ndim
     with part("delta_scan"), launch.span(
             "scan_path", path="per_channel" if wide else "scalar", heads=q.shape[1],
-            key=q.shape[-1], value=v.shape[-1], inverse="by_blocks" if wide else "in_place"):
+            key=q.shape[-1], value=v.shape[-1], inverse="in_place"):
         by_chunk = lambda x: cut(jnp.moveaxis(x, 2, 1), chunk)  # noqa: E731  [chunks, B, C, H, ..]
         heads_first = lambda x: jnp.moveaxis(x, 3, 2)           # noqa: E731  [chunks, B, H, C, ..]
         o = delta_chunks(*(heads_first(by_chunk(x)) for x in (q, k, v, g, beta)))

@@ -58,14 +58,16 @@ which owns the chip:
             the four expert cells, with the microseconds of each.  With
             --ling, or alone with --ling-kernels (no network is built: about
             two minutes)
-  olmo_kernels  the scalar-gate walk (``ops/chunked_delta.py``, a decay a
-            head and token) against the recurrence stepped a token at a time
-            in float32, at ``olmoh_q_l4``'s head sizes (30 heads, keys of 96,
-            values of 192, 1,568 tokens, chunk 64, bfloat16 in), forward and
-            the five gradients, with the microseconds of a forward and of a
-            forward and backward, and the ``scan_path`` spans; the same walk
-            with ``beta`` held to 1 must fail the limit.  Only with
-            --olmo-kernels (no network is built: about two minutes)
+  olmo_kernels  the delta walk (``ops/chunked_delta.py``) against the
+            recurrence stepped a token at a time in float32, in both forms:
+            a decay a head and token at ``olmoh_q_l4``'s head sizes (30
+            heads, keys of 96, values of 192) and a decay a key channel at
+            ``solar2_q_ep40``'s (16 heads of 128); 1,568 tokens, chunk 64,
+            bfloat16 in, forward and the five gradients, with the
+            microseconds of a forward and of a forward and backward, and the
+            ``scan_path`` spans; the same walk with ``beta`` held to 1 must
+            fail the limit.  Only with --olmo-kernels (no network is built:
+            about three minutes)
   first_conv  the bootstrap's first convolution apart, at the three conv
             cells' shapes: the online and the target net's convolutions of N
             outputs on the same bytes against one of 2N with the two filter
@@ -1009,11 +1011,12 @@ def leg_fetch() -> None:
 OLMO_WALK_REL = 0.03    # of the largest |value|: bfloat16 operands against a float32 recurrence
 
 
-def scalar_walk_against_the_recurrence(rows=2, heads=30, tokens=1568, kw=96, vw=192, chunk=64,
-                                       repeats=5):
+def walk_against_the_recurrence(rows=2, heads=30, tokens=1568, kw=96, vw=192, chunk=64,
+                                repeats=5, per_channel=False):
     """{name: (relative distance, the same with ``beta`` held to 1)} of the
-    scalar-gate walk's output and five gradients from the literal
-    recurrence's (float32, a token a step), and the walk's microseconds."""
+    delta walk's output and five gradients from the literal recurrence's
+    (float32, a token a step), and the walk's microseconds: the scalar-gate
+    form, or with ``per_channel`` a log decay a key channel."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1025,7 +1028,7 @@ def scalar_walk_against_the_recurrence(rows=2, heads=30, tokens=1568, kw=96, vw=
     q = unit(jax.random.normal(ks[0], (rows, heads, tokens, kw))) / np.sqrt(kw)
     k = unit(jax.random.normal(ks[1], (rows, heads, tokens, kw)) + 0.5)
     v = jax.random.normal(ks[2], (rows, heads, tokens, vw))
-    g = -0.1 * jax.random.uniform(ks[3], (rows, heads, tokens))
+    g = -0.1 * jax.random.uniform(ks[3], (rows, heads, tokens, *([kw] if per_channel else [])))
     beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (rows, heads, tokens)))
     cot = jax.random.normal(ks[5], v.shape)
     low = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
@@ -1033,7 +1036,7 @@ def scalar_walk_against_the_recurrence(rows=2, heads=30, tokens=1568, kw=96, vw=
     def literal(q, k, v, g, beta):
         def step(state, token):
             qt, kt, vt, gt, bt = token
-            state = jnp.exp(gt)[..., None, None] * state
+            state = jnp.exp(gt).reshape(*state.shape[:2], -1, 1) * state     # a head's decay, or a channel's
             read = jnp.sum(kt[..., None] * state, axis=-2)
             state = state + (bt[..., None] * kt)[..., None] * (vt - read)[..., None, :]
             return state, jnp.sum(qt[..., None] * state, axis=-2)
@@ -1078,15 +1081,16 @@ def scalar_walk_against_the_recurrence(rows=2, heads=30, tokens=1568, kw=96, vw=
 def leg_olmo_kernels() -> None:
     from ape_x_dqn_tpu.utils import profiling
 
-    near, times = scalar_walk_against_the_recurrence()
-    for name, (rel, lost) in near.items():
-        say(f"olmo_kernels: {name} {rel:.5f} from the token-by-token recurrence (limit "
-            f"{OLMO_WALK_REL}), {lost:.4f} with beta held to 1")
-        assert rel <= OLMO_WALK_REL, f"the scalar-gate walk's {name} is {rel} from the recurrence"
-    assert near["o"][1] > 3 * OLMO_WALK_REL, "a walk with beta held to 1 passes the limit"
-    say(f"olmo_kernels: the walk at [2, 30, 1568, 96 | 192], chunk 64, host clock: {times} "
-        "(4,050 and 15,100 with the inverse by gathered blocks: PERF.md section 6, PR 51)")
-    say(f"olmo_kernels: scan_path {profiling.launch.attrs_of('scan_path')[:1]}")
+    for form, sizes in (("scalar", {}), ("per_channel", dict(per_channel=True, heads=16, kw=128, vw=128))):
+        near, times = walk_against_the_recurrence(**sizes)
+        for name, (rel, lost) in near.items():
+            say(f"olmo_kernels: {form} {name} {rel:.5f} from the token-by-token recurrence (limit "
+                f"{OLMO_WALK_REL}), {lost:.4f} with beta held to 1")
+            assert rel <= OLMO_WALK_REL, f"the {form} walk's {name} is {rel} from the recurrence"
+        assert near["o"][1] > 3 * OLMO_WALK_REL, "a walk with beta held to 1 passes the limit"
+        say(f"olmo_kernels: the {form} walk, 2 rows, chunk 64, host clock: {times}")
+    paths = sorted({(walk["path"], walk["inverse"]) for walk in profiling.launch.attrs_of("scan_path")})
+    say(f"olmo_kernels: scan_path {paths}")
 
 
 def leg_ling_kernels() -> None:

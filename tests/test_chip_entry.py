@@ -192,30 +192,32 @@ def test_the_chips_timing_of_the_first_convolution_runs_here(monkeypatch, lost):
         "apart", "joined", "apart_then_second", "joined_then_second"))
 
 
-@pytest.mark.parametrize("lost", [None, "the_decay"])
-def test_the_chips_check_of_the_scalar_gate_walk_runs_here(monkeypatch, lost):
+@pytest.mark.parametrize("lost", [None, "_chunk_scalar", "_chunk"])
+def test_the_chips_check_of_the_delta_walk_runs_here(monkeypatch, lost):
     """``chip_smoke.leg_olmo_kernels`` at a small size (3 heads, keys of 12,
-    values of 24, 40 tokens in chunks of 16): the walk is inside the limit of
-    the token-by-token recurrence and the same walk with ``beta`` held to 1
-    is not; a walk that lost its decay fails the leg."""
+    values of 24, 40 tokens in chunks of 16): each form of the walk is inside
+    the limit of the token-by-token recurrence and the same walk with
+    ``beta`` held to 1 is not; a form that lost its decay fails the leg."""
     import chip_smoke
     from ape_x_dqn_tpu.ops import chunked_delta
 
-    small = chip_smoke.scalar_walk_against_the_recurrence
-    monkeypatch.setattr(chip_smoke, "scalar_walk_against_the_recurrence",
-                        lambda: small(rows=2, heads=3, tokens=40, kw=12, vw=24, chunk=16, repeats=1))
+    small = chip_smoke.walk_against_the_recurrence
+    monkeypatch.setattr(
+        chip_smoke, "walk_against_the_recurrence", lambda per_channel=False, **cell: small(
+            rows=2, heads=3, tokens=40, kw=12, vw=24, chunk=16, repeats=1, per_channel=per_channel))
     if lost:
-        walk = chunked_delta._chunk_scalar
-        monkeypatch.setattr(chunked_delta, "_chunk_scalar",
+        walk = getattr(chunked_delta, lost)
+        monkeypatch.setattr(chunked_delta, lost,
                             lambda state, q, k, v, g, beta: walk(state, q, k, v, 0.0 * g, beta))
         with pytest.raises(AssertionError, match="from the recurrence"):
             chip_smoke.leg_olmo_kernels()
         return
     chip_smoke.leg_olmo_kernels()
-    near, times = chip_smoke.scalar_walk_against_the_recurrence()
-    assert set(near) == {"o", "dq", "dk", "dv", "dg", "dbeta"}
-    assert all(rel <= chip_smoke.OLMO_WALK_REL for rel, _ in near.values())
-    assert times["forward_us"] > 0 and times["forward_and_backward_us"] > 0
+    for per_channel in (False, True):
+        near, times = chip_smoke.walk_against_the_recurrence(per_channel=per_channel)
+        assert set(near) == {"o", "dq", "dk", "dv", "dg", "dbeta"}
+        assert all(rel <= chip_smoke.OLMO_WALK_REL for rel, _ in near.values())
+        assert times["forward_us"] > 0 and times["forward_and_backward_us"] > 0
 
 
 @pytest.mark.parametrize("lost", [None, "the_byte_order"])

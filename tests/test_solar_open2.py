@@ -71,13 +71,15 @@ def _agrees(tokens, chunk, atol=3e-5, gtol=2e-4, **kw):
     (40, 64),    # one chunk, of the sequence's own length
     (64, 16),    # another chunk size, the same answer
     (50, 20),    # a chunk that is no multiple of the sub-block's 16 rows: sub-blocks of 10
+    (150, 64),   # the cells' chunk: eight inverse blocks merged three times, four sub-blocks
 ])
 def test_chunked_delta_is_the_literal_recurrence(tokens, chunk):
     """The output and, through the hand-walked backward pass, the gradient of
     every input, against autodiff of the recurrence stepped a token at a time."""
     assert chunks_of(tokens, chunk) == {(64, 32): (2, 64), (40, 32): (2, 64), (40, 64): (1, 40),
-                                        (64, 16): (4, 64), (50, 20): (3, 60)}[tokens, chunk]
-    assert chunked_delta.sub_rows(min(chunk, tokens)) == {32: 16, 40: 10, 16: 16, 20: 10}[
+                                        (64, 16): (4, 64), (50, 20): (3, 60),
+                                        (150, 64): (3, 192)}[tokens, chunk]
+    assert chunked_delta.sub_rows(min(chunk, tokens)) == {32: 16, 40: 10, 16: 16, 20: 10, 64: 16}[
         min(chunk, tokens)]
     _agrees(tokens, chunk)
 
@@ -143,7 +145,7 @@ def test_the_inverse_is_made_by_blocks(entry):
     by blocks of 8 merged two and two it is right to 1e-4 of its largest entry."""
     a = entry * jnp.tril(jnp.ones((64, 64)), -1)
     want = np.linalg.inv(np.eye(64) + np.asarray(a, np.float64))
-    inverse = jax.jit(chunked_delta._unit_lower_inverse)
+    inverse = jax.jit(chunked_delta._unit_lower_inverse_of)
     got = np.asarray(inverse(a[None])[0])
     np.testing.assert_allclose(got, want, atol=1e-4 * np.abs(want).max())
     assert np.allclose(np.triu(got, 1), 0) and np.allclose(np.diag(got), 1)
