@@ -28,9 +28,10 @@ rules and windows, the router's score function, count and bias, the shared
 expert, whether an observation's tokens are one frame's positions or those
 of a history of frames.  ``models/lfm2_moe.py``, ``models/laguna_moe.py``,
 ``models/granite_hybrid.py``, ``models/solar_open2.py``,
-``models/ling_hybrid.py`` and ``models/olmo_hybrid.py`` make a spec from a
-published ``config.json``'s keys and bring their mixers; everything else is
-here, once.
+``models/ling_hybrid.py``, ``models/olmo_hybrid.py`` and
+``models/kanana_moe.py`` make a spec from a published ``config.json``'s keys
+and bring their mixers (the last takes ``ling_hybrid``'s latent mixer, told
+that no head is gated); everything else is here, once.
 
 The expert layer is one chip's share of an expert-parallel layer: it is told
 how many experts exist (the router's outputs), how many a token takes and

@@ -1,6 +1,8 @@
 """Ling-3.0-flash's block (inclusionAI/Ling-3.0-flash, ``config.json``,
 ``model_type`` ``bailing_hybrid``) as a Q-network's torso over a history of
-frames: its latent-attention mixer and the spec made from the published keys.
+frames: the spec made from the published keys, and the latent-attention mixer
+(``LatentAttention``), which this family shares with ``models/kanana_moe.py``
+(``deepseek_v3``: the same mixer in every layer, without the head gate).
 Five layers of six (``layer_group_size``) are gated delta-rule linear
 attention, ``solar_open2.DeltaAttention`` told this model's gate: full-rank
 ``W_f`` and ``W_g`` (``no_kda_lora``), a log decay bounded below
@@ -10,7 +12,8 @@ multi-head latent attention (``LatentAttention``): keys and values expanded
 from one ``kv_lora_rank``-wide latent a token, a query and key of
 ``qk_nope_head_dim`` a head with no positional rule beside a rotary part of
 ``qk_rope_head_dim`` whose key is one for every head, values of
-``v_head_dim``, a sigmoid gate a head.  The first ``first_k_dense_replace``
+``v_head_dim``, a sigmoid gate a head (``LatentSizes.gated``: this family's;
+``deepseek_v3`` has none).  The first ``first_k_dense_replace``
 layers carry the dense SwiGLU, every other layer routes over ``num_experts``
 sigmoid scores with a balancing bias, ``n_group`` groups of which a token
 keeps ``topk_group`` before it chooses (``expert_torso.route``), and adds a
@@ -66,6 +69,7 @@ class LatentSizes:
     rope: int                         # the rotary part: a query a head, one key for all
     v: int                            # a head's value
     theta: float
+    gated: bool = True                # a head's output times sigmoid(w_g . u) (Ling); False: no gate
 
 
 def rope_pairs(x, theta: float, scale: float = 1.0):
@@ -88,7 +92,10 @@ def rope_pairs(x, theta: float, scale: float = 1.0):
 
 class LatentAttention(nn.Module):
     """Multi-head latent attention over the held heads, causal: module
-    docstring."""
+    docstring.  Two families use it: ``ling_hybrid`` (one layer in six, a
+    share of the heads, a sigmoid gate a head) and ``kanana_moe`` (every
+    layer, every head, no gate: ``LatentSizes.gated`` false, and the module
+    has no ``w_g``)."""
 
     spec: TorsoSpec
     op: str
@@ -106,7 +113,7 @@ class LatentAttention(nn.Module):
         w_dkv = self.param("w_dkv", _lecun(), (d, m.kv_rank + m.rope), pd)
         kv_norm = self.param("kv_norm", nn.initializers.ones, (m.kv_rank,), pd)
         w_ukv = self.param("w_ukv", _lecun(), (m.kv_rank, h * (m.nope + m.v)), pd)
-        w_g = self.param("w_g", _lecun(), (d, h), pd)
+        w_g = self.param("w_g", _lecun(), (d, h), pd) if m.gated else None
         w_o = self.param("w_o", _lecun(), (h * m.v, d), pd)
         # a head's columns are [nope; rope] of W_q and [nope; value] of W_ukv:
         # the weights are cut, no activation is sliced along the lanes
@@ -122,8 +129,9 @@ class LatentAttention(nn.Module):
         k, v = _heads_of(c, w_ukv[..., :m.nope], h), _heads_of(c, w_ukv[..., m.nope:], h)
         with part("attn_latent"):
             a = blocked.blocked_attention(q, k, v, q_shared=q_rope, k_shared=k_rope)
-        gate = jax.nn.sigmoid(jnp.einsum("btd,dn->bnt", u, w_g.astype(cd)).astype(f32))
-        a = a * gate[..., None].astype(cd)
+        if m.gated:
+            gate = jax.nn.sigmoid(jnp.einsum("btd,dn->bnt", u, w_g.astype(cd)).astype(f32))
+            a = a * gate[..., None].astype(cd)
         return jnp.einsum("bntk,nkd->btd", a, w_o.astype(cd).reshape(h, m.v, d))
 
     @staticmethod

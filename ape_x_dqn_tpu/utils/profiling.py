@@ -597,6 +597,7 @@ TIMED_IMPORTS = frozenset((
     "ape_x_dqn_tpu.models.lfm2_moe", "ape_x_dqn_tpu.models.laguna_moe",
     "ape_x_dqn_tpu.models.granite_hybrid", "ape_x_dqn_tpu.models.solar_open2",
     "ape_x_dqn_tpu.models.ling_hybrid", "ape_x_dqn_tpu.models.olmo_hybrid",
+    "ape_x_dqn_tpu.models.kanana_moe",
     "ape_x_dqn_tpu.replay.device",
     "ape_x_dqn_tpu.replay.device_dedup", "ape_x_dqn_tpu.utils.checkpoint",
 ))

@@ -58,6 +58,25 @@ which owns the chip:
             the four expert cells, with the microseconds of each.  With
             --ling, or alone with --ling-kernels (no network is built: about
             two minutes)
+  kanana    configs/config12_kanana2_q_ep8.json (the 624 M parameter torso of
+            latent attention in every layer, all 32 heads held, a leading
+            dense layer and five expert layers in one scanned body, 16 of 128
+            experts held, over the same history) with 4 thread actors, 12
+            learner steps at batch 2 on a 1,024-slot ring, as the laguna leg.
+            Only with --kanana, and after kanana_kernels
+  kanana_kernels  what that cell's comparison leaves to the chip (PERF.md,
+            section 6, PR 56): the blocked attention kernels with their
+            shared key operand at all 32 heads ([2, 32, 1568, 128 + 64],
+            bfloat16 in: the shared key's gradient summed over 32 heads),
+            forward and all five gradients against plain attention in
+            float32; the ungated latent mixer whole at the published widths
+            (``ling_hybrid.LatentAttention`` under this family's spec, two
+            rows) against the mixer written out in float32, where one that
+            lost the latent's norm must fail; and the router's 6 of 128 with
+            its gates (normalised, times 2.448) against a sort on the host,
+            where gates without the factor must fail.  With --kanana, or
+            alone with --kanana-kernels (no network is built: about two
+            minutes)
   olmo_kernels  the delta walk (``ops/chunked_delta.py``) against the
             recurrence stepped a token at a time in float32, in both forms:
             a decay a head and token at ``olmoh_q_l4``'s head sizes (30
@@ -1078,6 +1097,153 @@ def walk_against_the_recurrence(rows=2, heads=30, tokens=1568, kw=96, vw=192, ch
     return out, times
 
 
+# The whole ungated latent mixer in bfloat16 against the mixer written out in
+# float32 on the same weights, ||got - want|| / ||want||: its five projections
+# round to bfloat16 (read in Pallas' interpreter on the CPU at 4 heads and 300
+# tokens: 0.006; on the chip at the published widths: the leg prints it), and
+# how far at least a mixer that lost the latent's norm lies (the input's RMS is
+# 1.5 and the norm's weights spread 0.3 round 1, so that a lost norm is no
+# rounding).
+MIXER_REL = 0.03
+MIXER_REL_WITHOUT_LATENT_NORM = 0.2
+# The gates against the host's: float32 on both sides; without the scaling
+# factor they are off by 1 - 1/2.448.
+GATE_ABS = 1e-5
+
+
+def _kanana_spec(config: str = "config12_kanana2_q_ep8.json", **over):
+    from ape_x_dqn_tpu.config import load_config
+    from ape_x_dqn_tpu.models import kanana_moe
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    torso = load_config(os.path.join(here, "configs", config)).torso
+    return kanana_moe.spec_from_config(
+        dict({k: v for k, v in torso.items() if not k.startswith("_")}, **over))
+
+
+def latent_mixer_against_plain(rows: int = 2, tokens: int = 1568, **over) -> dict:
+    """``ling_hybrid.LatentAttention`` under the Kanana spec (no head gate),
+    bfloat16 compute, against the mixer of ISSUE 56's section 1 written out in
+    float32 on the same weights and input: within ``MIXER_REL``; and the same
+    mixer written out without the latent's norm lies further than
+    ``MIXER_REL_WITHOUT_LATENT_NORM`` from the program.  -> {"mixer": (with,
+    without)}."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from ape_x_dqn_tpu.models.ling_hybrid import LatentAttention
+
+    spec = _kanana_spec(**over)
+    m, d, eps = spec.arg("latent"), spec.hidden_size, spec.norm_eps
+    h, dn, dr, dv, r = m.heads, m.nope, m.rope, m.v, m.kv_rank
+    mixer = LatentAttention(spec, "latent_attention", jnp.bfloat16, jnp.float32)
+    ku, kp, kn = jax.random.split(jax.random.PRNGKey(56), 3)
+    u = (1.5 * jax.random.normal(ku, (rows, tokens, d))).astype(jnp.bfloat16)
+    params = jax.jit(mixer.init)(kp, u)["params"]
+    assert "w_g" not in params, "kanana's latent mixer has no head gate"
+    params = dict(params, kv_norm=1.0 + 0.3 * jax.random.normal(kn, (r,)))
+
+    def turned(x):          # [B, T, n, R], pairs (2j, 2j + 1) by t theta^(-2j / R)
+        ang = (jnp.arange(tokens, dtype=jnp.float32)[:, None]
+               * m.theta ** (-jnp.arange(0, dr, 2, dtype=jnp.float32) / dr)[None, :])
+        cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+        even, odd = x[..., 0::2], x[..., 1::2]
+        return jnp.stack([even * cos - odd * sin, odd * cos + even * sin], -1).reshape(x.shape)
+
+    def plain(p, u, normed=True):
+        with jax.default_matmul_precision("highest"):
+            u = u.astype(jnp.float32)
+            q = (u @ p["w_q"]).reshape(rows, tokens, h, dn + dr)
+            down = u @ p["w_dkv"]
+            c = down[..., :r]
+            if normed:
+                c = c / jnp.sqrt(jnp.mean(c * c, -1, keepdims=True) + eps) * p["kv_norm"]
+            kv = (c @ p["w_ukv"]).reshape(rows, tokens, h, dn + dv)
+            score = (jnp.einsum("bthd,bshd->bhts", q[..., :dn], kv[..., :dn])
+                     + jnp.einsum("bthd,bsd->bhts", turned(q[..., dn:]),
+                                  turned(down[..., None, r:])[:, :, 0])) / math.sqrt(dn + dr)
+            prob = jax.nn.softmax(
+                jnp.where(jnp.tril(jnp.ones((tokens, tokens), bool)), score, -jnp.inf), -1)
+            a = jnp.einsum("bhts,bshd->bthd", prob, kv[..., dn:]).reshape(rows, tokens, h * dv)
+            return a @ p["w_o"]
+
+    got = jax.jit(lambda p, u: mixer.apply({"params": p}, u))(params, u).astype(jnp.float32)
+    want = jax.jit(plain)(params, u)
+    wrong = jax.jit(lambda p, u: plain(p, u, normed=False))(params, u)
+    near = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    far = float(jnp.linalg.norm(got - wrong) / jnp.linalg.norm(got))
+    assert near <= MIXER_REL, f"mixer: {near} from the mixer written out"
+    assert far >= MIXER_REL_WITHOUT_LATENT_NORM, (
+        f"mixer: {far}: the check would not see a lost latent norm")
+    return {"mixer": (near, far)}
+
+
+def gates_against_sorting(tokens: int = 12544, **over) -> dict:
+    """``expert_torso.route`` under the Kanana spec (6 of 128 sigmoid scores
+    by ``score + bias``, gates the chosen scores over their sum, times 2.448)
+    against a sort on the host: the same experts for every token, the gates
+    within ``GATE_ABS``; the gates without the factor are not."""
+    import jax
+    import numpy as np
+
+    from ape_x_dqn_tpu.models import expert_torso
+
+    spec = _kanana_spec(**over)
+    outputs, k = spec.router_outputs, spec.num_experts_per_tok
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(7), (tokens, outputs)))
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(8), (outputs,))
+    chosen, gates = jax.jit(lambda s, b: expert_torso.route(s, b, spec))(scores, bias)
+    s, b = np.asarray(scores, np.float32), np.asarray(bias, np.float32)
+    want = np.argsort(-(s + b), axis=-1, kind="stable")[:, :k]
+    taken = np.take_along_axis(s, want, -1)
+    unscaled = taken / (taken.sum(-1, keepdims=True) + 1e-20)
+    differing = int(np.sum(np.any(np.asarray(chosen) != want, axis=-1)))
+    off = float(np.max(np.abs(np.asarray(gates) - unscaled * spec.routed_scaling_factor)))
+    off_unscaled = float(np.max(np.abs(np.asarray(gates) - unscaled)))
+    assert differing == 0, f"{differing} of {tokens} tokens choose other experts than sorting"
+    assert off <= GATE_ABS, f"gates {off} from the host's"
+    assert off_unscaled > 100 * GATE_ABS, "the check would not see gates without their factor"
+    return {"tokens": tokens, "differing": differing, "gates_max_abs": off,
+            "without_the_factor": off_unscaled}
+
+
+def leg_kanana_kernels() -> None:
+    for name, (near, far) in latent_kernels_against_plain(rows=2, heads=32).items():
+        say(f"kanana_kernels: {name} {near:.5f} from plain attention at 32 heads (limit "
+            f"{KERNEL_REL}), {far:.4f} from plain attention without the shared key "
+            f"(at least {KERNEL_REL_WITHOUT_SHARED_KEY})")
+    near, far = latent_mixer_against_plain()["mixer"]
+    say(f"kanana_kernels: the ungated mixer {near:.5f} from the mixer written out in float32 "
+        f"(limit {MIXER_REL}), {far:.4f} from one without the latent's norm (at least "
+        f"{MIXER_REL_WITHOUT_LATENT_NORM})")
+    say(f"kanana_kernels: route against sorting {gates_against_sorting()}")
+
+
+def leg_kanana() -> None:
+    steps = 12
+
+    def inspect(pipe, final):
+        check_run("kanana", pipe, final, steps)
+        assert type(pipe.comps.network).__name__ == "KananaMoeQ"
+        assert final["param_version"] >= 1, "kanana: nothing was published"
+        attention, routing = final.get("attention") or {}, final.get("routing") or {}
+        assert "scan" not in final and "delta" not in final, (
+            f"kanana: recurrent counters without such layers: {final}")
+        # batch 2, three forwards, six latent layers of 32 heads
+        assert 0 < attention.get("blocks_visited_latent", 0) < attention.get(
+            "blocks_total_latent", 0) and abs(attention.get(
+                "pairs_in_mask_latent", 0) - 2 * 3 * 6 * (1568 * 1569 // 2)) <= 64, (
+            f"kanana: no latent attention counters: {final}")
+        assert routing.get("held_pairs", 0) > 0 and "groups_kept_hold_share" not in routing, (
+            f"kanana: no routing counters: {final}")
+        say(f"kanana: attention a step {attention}; routing a step {routing}; actors adopted "
+            f"param_version {pipe.worker.param_version} of {final['param_version']}")
+
+    _train_on_histories("kanana", "config12_kanana2_q_ep8.json", steps, inspect)
+
+
 def leg_olmo_kernels() -> None:
     from ape_x_dqn_tpu.utils import profiling
 
@@ -1169,6 +1335,10 @@ def main() -> int:
         legs = [("ling_kernels", leg_ling_kernels), ("ling", leg_ling)]
     if "--ling-kernels" in sys.argv[1:]:
         legs = [("ling_kernels", leg_ling_kernels)]
+    if "--kanana" in sys.argv[1:]:
+        legs = [("kanana_kernels", leg_kanana_kernels), ("kanana", leg_kanana)]
+    if "--kanana-kernels" in sys.argv[1:]:
+        legs = [("kanana_kernels", leg_kanana_kernels)]
     if "--olmo-kernels" in sys.argv[1:]:
         legs = [("olmo_kernels", leg_olmo_kernels)]
     if "--first-conv" in sys.argv[1:]:

@@ -75,7 +75,7 @@ def test_canonical_configs_load_and_validate():
 
     root = os.path.join(os.path.dirname(__file__), "..", "configs")
     paths = sorted(glob.glob(os.path.join(root, "*.json")))
-    assert len(paths) == 12, paths
+    assert len(paths) == 13, paths
     cfgs = {os.path.basename(p): load_config(p) for p in paths}
     assert cfgs["config1_pong_1actor.json"].actor.num_actors == 1
     c6 = cfgs["config6_lfm2moe_q_ep8.json"]
@@ -99,6 +99,10 @@ def test_canonical_configs_load_and_validate():
     assert c11.network == "olmo_hybrid" and c11.torso["linear_value_head_dim"] == 192
     assert c11.torso["layers_held"] == [0, 1, 2, 3] and c11.torso["num_attention_heads"] == 30
     assert c11.env.frame_stack == 32 and c11.learner.replay_sample_size == 4
+    c12 = cfgs["config12_kanana2_q_ep8.json"]
+    assert c12.network == "kanana_moe" and c12.torso["kv_lora_rank"] == 512
+    assert c12.torso["experts_held"] == [0, 16] and "heads_held" not in c12.torso
+    assert c12.env.frame_stack == 32 and c12.learner.replay_sample_size == 8
     assert cfgs["config2_breakout_8actors.json"].actor.num_actors == 8
     c3 = cfgs["config3_seaquest_256actors_2m.json"]
     assert c3.replay.capacity == 2_000_000

@@ -40,7 +40,7 @@ from ape_x_dqn_tpu.learner.train_step import (
     StepMetrics, build_train_step, init_train_state, make_optimizer,
 )
 from ape_x_dqn_tpu.models import (
-    dueling, expert_torso, granite_hybrid, lfm2_moe, ling_hybrid, olmo_hybrid, solar_open2,
+    dueling, expert_torso, granite_hybrid, kanana_moe, lfm2_moe, ling_hybrid, olmo_hybrid, solar_open2,
 )
 from ape_x_dqn_tpu.models.dueling import build_greedy_apply, build_network
 from ape_x_dqn_tpu.ops.pallas import blocked_attention as blocked
@@ -122,6 +122,19 @@ OLMO = dict(
     linear_num_value_heads=3, linear_key_head_dim=12, linear_value_head_dim=24,
     linear_conv_kernel_dim=4, linear_allow_neg_eigval=True, rope_parameters={"rope_theta": None},
     linear_chunk_size=16, channels=[8, 8, 8], hidden=32,
+)
+
+# four heads of 16 + 8 / 16 on a latent of 24, every layer; eight outputs, two held, three a token
+KANANA = dict(
+    model_type="deepseek_v3", hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+    n_shared_experts=2, num_attention_heads=4, num_key_value_heads=4, head_dim=8, kv_lora_rank=24,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, q_lora_rank=None, rope_theta=1000000,
+    rope_interleave=True, rope_scaling=None, rms_norm_eps=1e-6, num_hidden_layers=4,
+    first_k_dense_replace=1, moe_layer_freq=1, published=dict(num_hidden_layers=12, n_routed_experts=8),
+    layers_held=[0, 1, 2, 3], n_routed_experts=2, router_outputs=8, experts_held=[2, 4], n_group=1,
+    topk_group=1, norm_topk_prob=True, routed_scaling_factor=2.448, num_experts_per_tok=3,
+    scoring_func="sigmoid", topk_method="noaux_tc", attention_bias=False, channels=[8, 8, 8],
+    hidden=32, expert_bias_update_rate=0.05,
 )
 
 
@@ -989,7 +1002,7 @@ def _olmo_others():
 
 
 def _olmo_config(row, spec, committed, committed_spec):
-    assert TORSO_NETWORKS[-1] == "olmo_hybrid" and HISTORY_NETWORKS[-1] == "olmo_hybrid"
+    assert TORSO_NETWORKS[5] == "olmo_hybrid" and HISTORY_NETWORKS[4] == "olmo_hybrid"
     assert spec.post_norm and spec.num_held == 0
     spec = committed_spec
     assert committed.learner.replay_sample_size == 4 and committed.learner.steps_per_call == 1
@@ -1001,6 +1014,82 @@ def _olmo_config(row, spec, committed, committed_spec):
             m.beta_scale, m.chunk, spec.arg("num_attention_heads"), spec.arg("head_dim"),
             spec.norm_eps, spec.post_norm, spec.heads_held) == (
                 3840, 11008, 30, 96, 192, 4, 2.0, 64, 30, 128, 1e-6, True, None)
+
+
+# ---------------------------------------------------------------- kanana_moe
+
+def _kanana_structure(b: Built):
+    net, params = b.net(), b.params["params"]
+    assert params["Conv_0"]["kernel"].shape == (8, 8, 1, 8)     # one frame at a time
+    assert set(params) >= {"layer_0", "layers_1_3", "w_tok", "final_norm"}
+    assert set(params["layer_0"]) == {"operator_norm", "ffn_norm", "latent_attention", "dense"}
+    assert params["layer_0"]["dense"]["w1"].shape == (64, 128)      # the leading dense layer
+    assert set(params["layers_1_3"]) == {"operator_norm", "ffn_norm", "latent_attention", "moe",
+                                         "shared_expert"}
+    assert {k: v.shape for k, v in params["layer_0"]["latent_attention"].items()} == {
+        "w_q": (64, 4 * 24), "w_dkv": (64, 24 + 8), "kv_norm": (24,), "w_ukv": (24, 4 * 32),
+        "w_o": (64, 64)}                                          # no head gate
+    assert params["layers_1_3"]["moe"]["router"].shape == (3, 64, 8)
+    assert params["layers_1_3"]["moe"]["w13"].shape == (3, 2, 64, 64)
+    assert params["layers_1_3"]["shared_expert"]["w1"].shape == (3, 64, 64)   # two of 32 as one
+    spec = net.spec
+    assert spec.layers == (("latent_attention", "dense"),) + (("latent_attention", "moe"),) * 3
+    assert dict(spec.mixers) == {"latent_attention": ling_hybrid.LatentAttention}
+    assert (spec.router_outputs, spec.experts_held, spec.heads_held, spec.norm_eps,
+            spec.frame_history, spec.post_norm) == (8, (2, 4), None, 1e-6, True, False)
+    out, sown = b.applied
+    assert out[2].shape == (2, 6) and bool(jnp.all(jnp.isfinite(out[2])))
+    routing = net.routing_metrics(sown)
+    assert float(routing["held_pairs"]) > 0 and "groups_kept_hold_share" not in routing
+    assert net.scan_metrics(b.x.shape) is None and net.delta_metrics(b.x.shape) is None
+
+
+def _kanana_counters(s):
+    metrics = s.metrics
+    assert "expert_bias" not in s.got_w["layer_0"]
+    # four latent layers of four heads: a block of 128 x 512 a head and row, 4 rows, 3 forwards
+    assert {k: float(v) for k, v in metrics.attention.items()} == {
+        "pairs_in_mask_latent": 4 * 3 * 4 * (40 * 41 // 2), "pairs_computed_latent": 4 * 3 * 4 * 128 * 512.0,
+        "blocks_visited_latent": 4 * 3 * 4 * 4 * 1.0, "blocks_total_latent": 4 * 3 * 4 * 4 * 1.0}
+    assert float(metrics.routing["held_pairs"]) > 0
+    assert metrics.scan is None and metrics.delta is None
+    assert set(metrics.routing) == {"held_pairs", "load_max", "load_mean", "rows_walked"}
+
+
+def _kanana_others():
+    """``LatentSizes.gated`` defaults to Ling's gate: ``ling_hybrid``'s spec
+    carries it, its latent layer keeps ``w_g`` in its place among the six
+    parameters, and the other families' specs hold no latent sizes at all."""
+    ling = network("ling_hybrid")
+    assert ling.spec.arg("latent").gated is True
+    x = obs(jax.random.PRNGKey(2))
+    shapes = jax.eval_shape(ling.init, jax.random.PRNGKey(3), x)["params"]
+    assert list(shapes["layer_1"]["latent_attention"]) == [
+        "kv_norm", "w_dkv", "w_g", "w_o", "w_q", "w_ukv"]
+    for kind in ("granite_hybrid", "solar_open2", "laguna_moe", "olmo_hybrid"):
+        assert "latent" not in dict(network(kind).spec.mixer_args), kind
+
+
+def _kanana_config(row, spec, committed, committed_spec):
+    assert TORSO_NETWORKS[-1] == "kanana_moe" and HISTORY_NETWORKS[-1] == "kanana_moe"
+    assert spec.num_held == 2
+    spec = committed_spec
+    assert committed.learner.replay_sample_size == 8 and committed.learner.steps_per_call == 1
+    cell = json.load(open(os.path.join(ROOT, "benchmark", "configs", "kanana2_q_ep8.json")))
+    assert spec == kanana_moe.spec_from_config(cell)
+    assert spec.layers == (("latent_attention", "dense"),) + (("latent_attention", "moe"),) * 5
+    n = spec.arg("latent")
+    assert (spec.hidden_size, spec.intermediate_size, spec.moe_intermediate_size,
+            spec.shared_expert_intermediate_size, n.heads, n.kv_rank, n.nope, n.rope, n.v, n.theta,
+            n.gated) == (2048, 6144, 768, 1536, 32, 512, 128, 64, 128, 1e6, False)
+    assert (spec.router_outputs, spec.num_experts_per_tok, spec.router_groups, spec.router_groups_kept,
+            spec.heads_held, spec.routed_scaling_factor, spec.experts_held, spec.gate_norm_eps) == (
+                128, 6, 1, 1, None, 2.448, (0, 16), 1e-20)
+    assert expert_torso.tile_rows(12544 * 6, 16, 128) == 12800      # the walk's tile at 128 outputs
+
+
+def _kanana_loads(loads):
+    assert loads.shape == (4, 8) and [float(v) for v in jnp.sum(loads, -1)] == [0.0] + [4 * 40 * 3.0] * 3
 
 
 def _solar_loads(loads):
@@ -1073,4 +1162,15 @@ ROWS = {row.name: row for row in (
                      "torso:mixer/full_attention/torso:attn_full", "transpose("),
         scopes_absent=("ssm_scan", "router", "experts", "shared_expert", "attn_window", "attn_latent"),
         walked_back="delta_scan", compiled_part="delta_scan"),
+    Row("kanana_moe", KANANA, "KananaMoeQ", "config12_kanana2_q_ep8.json", _kanana_config,
+        _kanana_counters, kept_float32=("router", "expert_bias"),
+        structure=_kanana_structure, reference="kanana2_q", bf16_tolerance=0.5,
+        flags=("reference_drops_shared_key", "reference_skips_latent_norm",
+               "reference_unscaled_gates"), loads=_kanana_loads, bias_moved=(1, 2, 3),
+        others=_kanana_others, parts_at=slice(-1, None), parts=("attn_latent",),
+        scopes=("attn_latent", "mixer", "router", "experts", "shared_expert", "dense_ffn", "stem",
+                "head"),
+        scope_paths=("torso:mixer/latent_attention/torso:attn_latent", "transpose("),
+        scopes_absent=("ssm_scan", "delta_scan", "attn_full", "attn_window"),
+        compiled_part="attn_latent"),
 )}
