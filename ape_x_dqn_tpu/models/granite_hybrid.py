@@ -150,10 +150,11 @@ class NopeAttention(nn.Module):
     def count(spec: TorsoSpec, op: str, rows: int, tokens: int) -> dict:
         """One layer's forward over ``rows`` sequences of ``tokens``, under
         ``laguna_moe.GatedAttention.count``'s names for a causal layer."""
-        visited, total = blocked.blocks_visited(tokens, None)
         heads = spec.arg("num_attention_heads")
+        group = heads // spec.arg("num_key_value_heads")
+        visited, total = blocked.blocks_visited(tokens, None, group)
         return {"pairs_in_mask_full": float(rows * blocked.pairs_in_mask(tokens, None)),
-                "pairs_computed_full": float(rows * blocked.pairs_computed(tokens, None)),
+                "pairs_computed_full": float(rows * blocked.pairs_computed(tokens, None, group)),
                 "blocks_visited_full": float(rows * heads * visited),
                 "blocks_total_full": float(rows * heads * total)}
 

@@ -155,11 +155,12 @@ class GatedAttention(nn.Module):
         head's), and the blocks of the kernel's grid that it visits and that
         exist (a head's, times the heads)."""
         kind = kind_of(spec, op)
-        visited, total = blocked.blocks_visited(tokens, kind.window)
+        group = kind.heads // spec.arg("num_key_value_heads")
+        visited, total = blocked.blocks_visited(tokens, kind.window, group)
         return {f"pairs_in_mask_{kind.name}":
                 float(rows * blocked.pairs_in_mask(tokens, kind.window)),
                 f"pairs_computed_{kind.name}":
-                float(rows * blocked.pairs_computed(tokens, kind.window)),
+                float(rows * blocked.pairs_computed(tokens, kind.window, group)),
                 f"blocks_visited_{kind.name}": float(rows * kind.heads * visited),
                 f"blocks_total_{kind.name}": float(rows * kind.heads * total)}
 

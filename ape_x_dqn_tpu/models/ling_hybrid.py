@@ -138,11 +138,11 @@ class LatentAttention(nn.Module):
     def count(spec: TorsoSpec, op: str, rows: int, tokens: int) -> dict:
         """One layer's forward over ``rows`` sequences of ``tokens``, under
         ``laguna_moe.GatedAttention.count``'s names for the kind ``latent``,
-        over the heads held."""
-        visited, total = blocked.blocks_visited(tokens, None)
+        over the heads held; every head has keys of its own, a group of 1."""
+        visited, total = blocked.blocks_visited(tokens, None, 1)
         lo, hi = held_heads(spec, spec.arg("latent").heads)
         return {"pairs_in_mask_latent": float(rows * blocked.pairs_in_mask(tokens, None)),
-                "pairs_computed_latent": float(rows * blocked.pairs_computed(tokens, None)),
+                "pairs_computed_latent": float(rows * blocked.pairs_computed(tokens, None, 1)),
                 "blocks_visited_latent": float(rows * (hi - lo) * visited),
                 "blocks_total_latent": float(rows * (hi - lo) * total)}
 

@@ -193,7 +193,7 @@ def spec_from_config(cfg: Mapping) -> TorsoSpec:
         experts_held=experts,
         layers=tuple((types[i], "dense") for i in held),
         mixers=tuple((op, MIXERS[op]) for op in ops),
-        mixer_args=(("linear", sizes), ("num_attention_heads", heads),
+        mixer_args=(("linear", sizes), ("num_attention_heads", heads), ("num_key_value_heads", kv),
                     ("head_dim", int(cfg.get("head_dim") or d // heads))),
         use_expert_bias=False,
         frame_history=True,

@@ -241,10 +241,11 @@ class GatedNopeAttention(nn.Module):
         """One layer's forward over ``rows`` sequences of ``tokens``, under
         ``laguna_moe.GatedAttention.count``'s names for a causal layer, over
         the heads held."""
-        visited, total = blocked.blocks_visited(tokens, None)
-        heads, _ = GatedNopeAttention.held(spec)
+        heads, kv = GatedNopeAttention.held(spec)
+        group = heads // kv
+        visited, total = blocked.blocks_visited(tokens, None, group)
         return {"pairs_in_mask_full": float(rows * blocked.pairs_in_mask(tokens, None)),
-                "pairs_computed_full": float(rows * blocked.pairs_computed(tokens, None)),
+                "pairs_computed_full": float(rows * blocked.pairs_computed(tokens, None, group)),
                 "blocks_visited_full": float(rows * heads * visited),
                 "blocks_total_full": float(rows * heads * total)}
 

@@ -48,6 +48,7 @@ _F32 = jnp.float32
 _MASKED = -0.7 * float(np.finfo(np.float32).max)   # finite: a row of it has a maximum to subtract
 _NT = (((1,), (1,)), ((), ()))                      # a @ b^T
 _FIRST, _LAST, _EDGE, _END = 1, 2, 4, 8             # a step's flags in the schedule
+_ROWS = 256                                         # a causal block's rows reach this: ``plan``
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,10 +60,13 @@ class Plan:
     block_kv: int
 
 
-def plan(tokens: int, window: Optional[int]) -> Plan:
-    """From the shapes.  Causal alone: 128 tokens by 512 keys.  Under a
-    window: square blocks of half the window in whole lanes, at most 256 (a
-    query block then sees its own key block and up to two before it).
+def plan(tokens: int, window: Optional[int], group: int) -> Plan:
+    """From the shapes.  Causal alone: 512 keys by the fewest tokens, whole
+    lanes and a power of two, whose rows over the key-value head's ``group``
+    of query heads reach 256: 256 tokens where a key-value head has one query
+    head, 128 under any larger group.  Under a window: square blocks of half
+    the window in whole lanes, at most 256 (a query block then sees its own
+    key block and up to two before it), whatever the group.
 
     Read on a TPU v5e (PERF.md section 6, PR 38) at B=8, T=1,568, 8
     key-value heads, each kernel apart, ms forward / forward keeping the
@@ -97,22 +101,48 @@ def plan(tokens: int, window: Optional[int]) -> Plan:
     512 x 512  1.06 / 1.14 / 1.79 / 1.80
     =========  =======================
 
-    At G=1 a block of 128 tokens is 128 rows and 256 x 512 reads 28% under
-    128 x 512 over the four kernels (4.30 against 5.97 ms forward and
-    backward); the rule still does not read the group: the one layer that
-    has G=1 is 1.3% of its step, and a plan by group would have to reach the
-    counters too (PERF.md section 6, PR 42).  Blocks of 128 keys compute the fewest
+    And at the two cells whose every key-value head has one query head (PR
+    57; bfloat16, T=1,568, causal, 20 executions on the host's clock:
+    ``kanana2_q_ep8``'s latent layers, B=8, 32 heads, the shared part of 64
+    and the sum of its gradient over the heads; ``olmoh_q_l4``'s full layer,
+    B=4, 30 heads, no shared operand):
+
+    ==========  =========================  =========================
+    block       G=1, D=128+64, 8 x 32      G=1, D=128, 4 x 30
+    ==========  =========================  =========================
+    128 x 256   8.48 / 9.01 / 10.56 / 13.40  3.67 / 3.91 / 4.40 / 4.26
+    128 x 512   5.73 / 6.01 / 8.37 / 10.94   2.43 / 2.56 / 3.01 / 3.06
+    128 x 1024  5.05 / 5.27 / 8.06 / 11.15   2.02 / 2.10 / 2.69 / 2.74
+    256 x 256   5.88 / 6.16 / 7.95 / 7.24    2.40 / 2.54 / 3.09 / 3.46
+    256 x 512   4.09 / 4.29 / 7.11 / 6.47    1.58 / 1.66 / 2.36 / 2.67
+    256 x 1024  4.16 / 4.32 / 7.32 / 6.86    1.46 / 1.55 / 2.29 / 2.53
+    512 x 256   5.37 / 5.61 / 7.69 / 7.71    2.05 / 2.18 / 2.77 / 2.79
+    512 x 512   3.97 / 4.15 / 6.96 / 6.96    1.40 / 1.50 / 2.20 / 2.34
+    512 x 1024  4.41 / 4.57 / 7.42 / 7.53    1.54 / 1.62 / 2.25 / 2.44
+    ==========  =========================  =========================
+
+    At G=1 a block of 128 tokens is 128 rows.  A learner step's six calls a
+    layer (two forwards, two keeping the log-sum, dq, dk/dv) read 42.79 ms
+    at 128 x 512, 30.33 at 256 x 512 and 30.18 at 512 x 512 with the shared
+    operand, 16.04, 11.51 and 10.35 without: 256 rows take 28-29% off either,
+    512 rows nothing more of the first and a tenth more of the second, which
+    is 0.2% of its cell's step, so the rule stops at 256.  The groups of the
+    other cells (4, 6, 8, 9) fill 512 to 1,152 rows at 128 tokens, and their
+    256-token rows above read no faster.  Blocks of 128 keys compute the fewest
     pairs outside the mask (1.34 and 1.21 times the pairs in it) and are the
     slowest: a step's reductions over the lanes and its fixed cost weigh
-    more than the pairs saved.  The group's size and the head's did not move
-    the choice, so the rule does not read them (G=8, D=128, causal, two
-    key-value heads: the reading of PR 39 is in PERF.md section 6).  JAX's splash attention, a
-    query head at a time in 512 x 512 and 896 x 896 blocks over a padded
-    length: 6.80 / 24.45 (forward / forward and backward), 5.43 / 18.20 and
-    3.76 / 12.22."""
+    more than the pairs saved; 1,024 keys gain only at 128 rows.  The head's
+    size did not move the choice, so the rule does not read it (G=8, D=128,
+    causal, two key-value heads: the reading of PR 39 is in PERF.md section
+    6).  JAX's splash attention, a query head at a time in 512 x 512 and 896
+    x 896 blocks over a padded length: 6.80 / 24.45 (forward / forward and
+    backward), 5.43 / 18.20 and 3.76 / 12.22."""
     del tokens
     if window is None:
-        return Plan(128, 512)
+        block_q = _LANES
+        while group * block_q < _ROWS:
+            block_q *= 2
+        return Plan(block_q, 512)
     side = min(max(-(-(window // 2) // _LANES) * _LANES, _LANES), 256)
     return Plan(side, side)
 
@@ -145,19 +175,19 @@ def _visits(tokens: int, window: Optional[int], bq: int, bkv: int) -> tuple:
     return tuple(out)
 
 
-def blocks_visited(tokens: int, window: Optional[int]) -> tuple:
+def blocks_visited(tokens: int, window: Optional[int], group: int) -> tuple:
     """(blocks of the forward kernel's grid that hold a pair in the mask,
-    blocks of the whole grid), a query head."""
-    p = plan(tokens, window)
+    blocks of the whole grid), a query head of a key-value head's ``group``."""
+    p = plan(tokens, window, group)
     return (len(_visits(tokens, window, p.block_q, p.block_kv)),
             -(-tokens // p.block_q) * -(-tokens // p.block_kv))
 
 
-def pairs_computed(tokens: int, window: Optional[int]) -> int:
+def pairs_computed(tokens: int, window: Optional[int], group: int) -> int:
     """(query, key) pairs the forward kernel computes a score for, a query
-    head: the visited blocks' whole extents."""
-    p = plan(tokens, window)
-    return blocks_visited(tokens, window)[0] * p.block_q * p.block_kv
+    head of a key-value head's ``group``: the visited blocks' whole extents."""
+    p = plan(tokens, window, group)
+    return blocks_visited(tokens, window, group)[0] * p.block_q * p.block_kv
 
 
 @functools.lru_cache(maxsize=None)
@@ -408,7 +438,7 @@ def _call(kernel, name, by_keys: bool, inputs, specs: str, out_shape, out_specs:
     ``score_arrays`` float32 arrays of a block of scores need."""
     q, k = inputs[:2]
     tokens, g, d = q.shape[2], q.shape[1] // k.shape[1], q.shape[3]
-    p = plan(tokens, window)
+    p = plan(tokens, window, g)
     bq, bkv = p.block_q, p.block_kv
     spec = {"r": pl.BlockSpec((None, g, bq, d), lambda b, h, s, qi, kj, fl: (b, h, qi[s], 0)),
             "k": pl.BlockSpec((None, None, bkv, d), lambda b, h, s, qi, kj, fl: (b, h, kj[s], 0)),

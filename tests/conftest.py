@@ -73,6 +73,17 @@ STALE_MANIFEST_PINS = {
     "[test_the_manifests_new_entries]":
         "cuts seven lists to their first cell and expects every latent.* list to be Ling's alone; "
         "PR 56 appended its cell to seven more",
+    # PR 57: ``blocked_attention.plan`` reads the group, and a latent layer's
+    # block is 256 tokens.  These two hold, as their last assertion, the
+    # published networks' ``attention_metrics`` to the blocks every causal
+    # layer had before (28 of 52 of 128 x 512 a head; now 16 of 28 of 256 x
+    # 512).  ``tests/test_ling_hybrid.py::test_what_the_two_pinned_tests_hold_beside_the_plan``
+    # runs both as they stand with the plan told a group of 2, and holds the
+    # same networks' counters to the plan they get.
+    "test_benchmark_ling_reference.py::test_published_configuration_builds_abstractly":
+        "pins the latent layer's counters to blocks of 128 tokens; PR 57's plan gives a group of 1 blocks of 256",
+    "test_benchmark_kanana_reference.py::test_published_configuration_builds_abstractly":
+        "pins the six latent layers' counters to blocks of 128 tokens; PR 57's plan gives a group of 1 blocks of 256",
 }
 
 

@@ -217,7 +217,7 @@ def test_the_like_expert_layers_are_one_scanned_body_with_the_kernels_inside(bui
     assert "layer_0/torso:mixer/latent_attention/torso:attn_latent" in debug
     assert "layers_1_3/torso:mixer/latent_attention/torso:attn_latent" in debug
     counted = net.attention_metrics(built.x.shape)
-    visited, total = blocked.blocks_visited(40, None)
+    visited, total = blocked.blocks_visited(40, None, 1)
     assert counted["blocks_visited_latent"] == 2 * 4 * 4 * visited       # rows x layers x heads
     assert counted["blocks_total_latent"] == 2 * 4 * 4 * total
     assert counted["pairs_in_mask_latent"] == 2 * 4 * blocked.pairs_in_mask(40, None)

@@ -38,7 +38,7 @@ def dense_attention(q, k, v, window):
 
 
 @pytest.mark.parametrize("head_dim", [64, 128])
-@pytest.mark.parametrize("group", [4, 6, 9])
+@pytest.mark.parametrize("group", [1, 4, 6, 9])
 @pytest.mark.parametrize("window", [None, 100, 400])
 def test_blocked_attention_is_the_dense_masked_softmax(window, group, head_dim):
     """Forward (with and without the kept log-sum) and the gradients of q, k
@@ -46,9 +46,11 @@ def test_blocked_attention_is_the_dense_masked_softmax(window, group, head_dim):
     either kernel, so the last block of queries and of keys reads past the
     end (the interpreter fills it with NaN); a window of 100 inside a block,
     one of 400 longer than the sequence; two key-value heads of ``group``
-    query heads each."""
+    query heads each (a group of 1 walks causal blocks of 256 tokens, and
+    300 cross one)."""
     tokens = 300
-    assert all(tokens % b for b in dataclasses.astuple(blocked.plan(tokens, window)))
+    plan = blocked.plan(tokens, window, group)
+    assert all(tokens % b for b in dataclasses.astuple(plan)) and plan.block_q < tokens
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (1, 2 * group, tokens, head_dim)) / head_dim ** 0.5
     k, v = (jax.random.normal(kk, (1, 2, tokens, head_dim)) for kk in ks[1:3])
@@ -62,18 +64,20 @@ def test_blocked_attention_is_the_dense_masked_softmax(window, group, head_dim):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name)
 
 
+@pytest.mark.parametrize("group", [1, 6])
 @pytest.mark.parametrize("tokens,window", [
     (1568, None), (1568, 512), (300, None), (300, 100), (300, 400), (40, 8)])
-def test_the_counts_from_the_shapes_are_the_masks(tokens, window):
+def test_the_counts_from_the_shapes_are_the_masks(tokens, window, group):
     """``pairs_in_mask`` against the mask counted pair by pair;
     ``blocks_visited`` and ``pairs_computed`` against the dense mask cut in
-    the plan's blocks; and the walks the kernels are handed: every block that
-    holds a pair once, by query blocks and by key blocks, a block that is not
-    marked as crossed by an edge wholly inside the mask."""
+    the blocks of the plan the group gets; and the walks the kernels are
+    handed: every block that holds a pair once, by query blocks and by key
+    blocks, a block that is not marked as crossed by an edge wholly inside
+    the mask."""
     i, j = np.arange(tokens)[:, None], np.arange(tokens)[None, :]
     mask = (j <= i) if window is None else (j <= i) & (j > i - window)
     assert blocked.pairs_in_mask(tokens, window) == int(mask.sum())
-    plan = blocked.plan(tokens, window)
+    plan = blocked.plan(tokens, window, group)
     bq, bkv = plan.block_q, plan.block_kv
     nq, nkv = -(-tokens // bq), -(-tokens // bkv)
     cut = np.zeros((nq * bq, nkv * bkv), bool)
@@ -93,12 +97,34 @@ def test_the_counts_from_the_shapes_are_the_masks(tokens, window):
         past = flags & blocked._END != 0
         np.testing.assert_array_equal(past, (np.maximum(qi * bq + bq, kj * bkv + bkv) > tokens))
         assert (edge | ~past).all()
-    assert blocked.blocks_visited(tokens, window) == (int(holds.sum()), nq * nkv)
-    assert blocked.pairs_computed(tokens, window) == int(holds.sum()) * bq * bkv
+    assert blocked.blocks_visited(tokens, window, group) == (int(holds.sum()), nq * nkv)
+    assert blocked.pairs_computed(tokens, window, group) == int(holds.sum()) * bq * bkv
     if tokens == 1568:
         assert int(mask.sum()) == (1_230_096 if window is None else 672_000)
-        assert (bq, bkv) == ((128, 512) if window is None else (256, 256))
-        assert blocked.blocks_visited(tokens, window) == ((28, 52) if window is None else (18, 49))
+        causal = ((256, 512), (16, 28)) if group == 1 else ((128, 512), (28, 52))
+        assert ((bq, bkv), blocked.blocks_visited(tokens, window, group)) == (
+            causal if window is None else ((256, 256), (18, 49)))
+
+
+@pytest.mark.parametrize("group", [1, 4, 6, 8, 9])
+@pytest.mark.parametrize("window", [None, 100, 512])
+def test_the_plan_reads_the_group(window, group):
+    """A causal block of queries is the fewest whole lanes of tokens, a power
+    of two, whose rows over the group reach ``_ROWS``: 256 tokens where a
+    key-value head has one query head, 128 under every group of the cells
+    (what every layer had before the rule read the group).  Under a window
+    the group changes nothing.  The counters follow the same plan: at the
+    cells' length they are the forward walk's steps and their extents."""
+    plan = blocked.plan(1568, window, group)
+    before = {None: (128, 512), 100: (128, 128), 512: (256, 256)}[window]
+    assert dataclasses.astuple(plan) == ((256, 512) if window is None and group == 1 else before)
+    assert all(b % 128 == 0 and b & (b - 1) == 0 for b in dataclasses.astuple(plan))
+    if window is None:      # the rows are reached, and by the fewest tokens
+        assert group * plan.block_q >= blocked._ROWS
+        assert plan.block_q == 128 or group * plan.block_q // 2 < blocked._ROWS
+    steps = len(blocked._schedule(1568, window, plan.block_q, plan.block_kv, False)[0])
+    assert blocked.blocks_visited(1568, window, group)[0] == steps
+    assert blocked.pairs_computed(1568, window, group) == steps * plan.block_q * plan.block_kv
 
 
 def _layer(op):

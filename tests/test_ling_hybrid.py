@@ -8,7 +8,9 @@ interpreter); what every torso is held to (structure,
 scopes, counters, the configuration path, the trainer's loop) is the
 contract's, ``tests/torso_contract.py``, on this torso's row."""
 import dataclasses
+import importlib
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -51,8 +53,9 @@ def _operands(tokens, rows=2, heads=3, group=1, width=128, shared=64):
 
 def test_the_kernels_add_the_shared_keys_scores_forward_and_in_all_five_gradients():
     """192 = 128 + 64 against values of 128, one rope key for every head, a
-    length that is no multiple of a block (700 = 5 query blocks of 128 and a
-    rest of 60, one key block of 512 and a rest of 188): the output and the
+    length that is no multiple of a block (a head has keys of its own, so a
+    block is 256 tokens: 700 = 2 query blocks and a rest of 188, one key
+    block of 512 and a rest of 188): the output and the
     gradients of both query parts, the keys, the shared key (summed over the
     heads) and the values against autodiff of plain attention."""
     args, cot = _operands(700)
@@ -90,6 +93,33 @@ def test_without_a_shared_operand_the_traced_program_is_the_older_one():
     both = str(jax.make_jaxpr(lambda *a: blocked.blocked_attention(a[0], a[1], a[2], None, a[3], a[4]))(
         q, k, v, qs, ks))
     assert both.count("pallas_call") == 1 and "f32[2,1,130,64]" in both
+
+
+@pytest.mark.parametrize("family,layers,heads", [("ling", 1, 8), ("kanana", 6, 32)])
+def test_what_the_two_pinned_tests_hold_beside_the_plan(monkeypatch, family, layers, heads):
+    """``tests/benchmark/test_benchmark_{ling,kanana}_reference.py::test_published_configuration_builds_abstractly``
+    (the benchmark's files; ``tests/conftest.py`` expects them to fail) hold
+    the published networks' counters to blocks of 128 tokens.  With the plan
+    told a group of 2, as they stand, they pass: the parameter trees, the
+    counts and the other counters are held.  The counters themselves are the
+    plan's a group of 1 gets: 16 blocks of 256 x 512 of 7 x 4 a head."""
+    from ape_x_dqn_tpu.models.dueling import build_network
+
+    tests = os.path.dirname(os.path.abspath(__file__))
+    for path in (os.path.join(tests, "benchmark"), os.path.join(os.path.dirname(tests), "benchmark")):
+        monkeypatch.syspath_prepend(path)      # as tests/benchmark/conftest.py has them
+    pinned = importlib.import_module(f"test_benchmark_{family}_reference")
+    cfg = pinned.PUBLISHED
+    net = build_network(cfg["network"], cfg["num_actions"], torso=cfg,
+                        channels=tuple(cfg["channels"]), hidden=cfg["hidden"])
+    assert net.attention_metrics((8, 84, 84, 32)) == {
+        "pairs_in_mask_latent": layers * 8 * 1_230_096.0,
+        "pairs_computed_latent": layers * 8 * 16 * 256 * 512.0,
+        "blocks_visited_latent": layers * 8 * heads * 16.0,
+        "blocks_total_latent": layers * 8 * heads * 7 * 4.0}
+    whole = blocked.plan
+    monkeypatch.setattr(blocked, "plan", lambda tokens, window, group: whole(tokens, window, max(group, 2)))
+    pinned.test_published_configuration_builds_abstractly()
 
 
 # ------------------------------------------------------------------ the router
@@ -161,7 +191,7 @@ def test_the_chips_numeric_check_passes_here_and_fails_on_a_lost_mechanism(monke
     fails it."""
     import chip_smoke
 
-    sizes = dict(rows=1, heads=2, tokens=200)
+    sizes = dict(rows=1, heads=2, tokens=300)       # a block of 256 tokens and a rest of 44
     if lost == "shared_key":
         whole = blocked.blocked_attention
         monkeypatch.setattr(blocked, "blocked_attention",

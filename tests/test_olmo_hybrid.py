@@ -415,4 +415,4 @@ def test_thirty_heads_of_128_go_through_the_causal_kernels():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name)
     assert olmo_hybrid.QkNormAttention.count(
         olmo_hybrid.spec_from_config(TORSO), "full_attention", 4, 1568)["blocks_total_full"] == (
-            4 * 3 * blocked.blocks_visited(1568, None)[1])
+            4 * 3 * blocked.blocks_visited(1568, None, 1)[1])

@@ -632,8 +632,8 @@ def _laguna_counters(s):
     # two layers and by 6 on three; the kernels compute the whole block
     assert want["blocks_visited_full"] == want["blocks_total_full"] == 4 * 2 * 4
     assert want["blocks_visited_window"] == want["blocks_total_window"] == 4 * 3 * 6
-    for kind, layers, span in (("full", 2, None), ("window", 3, 8)):
-        plan = blocked.plan(40, span)
+    for kind, layers, span, group in (("full", 2, None, 2), ("window", 3, 8, 3)):
+        plan = blocked.plan(40, span, group)       # 4 and 6 query heads on 2 key-value heads
         assert want[f"pairs_computed_{kind}"] == 4 * layers * plan.block_q * plan.block_kv
         assert want[f"pairs_computed_{kind}"] > want[f"pairs_in_mask_{kind}"]
     assert {k: float(v) for k, v in metrics.attention.items()} == {
@@ -886,7 +886,8 @@ def _ling_counters(s):
     assert {k: float(v) for k, v in metrics.delta.items()} == {
         "chunks": 3 * 3 * 4 * 3.0, "tokens_padded": 3 * 3 * 4 * 48.0, "tokens": 3 * 3 * 4 * 40.0}
     assert {k: float(v) for k, v in metrics.attention.items()} == {
-        "pairs_in_mask_latent": 3 * 4 * (40 * 41 // 2), "pairs_computed_latent": 3 * 4 * 128 * 512.0,
+        # a head has keys of its own: a block of 256 x 512 a head and row
+        "pairs_in_mask_latent": 3 * 4 * (40 * 41 // 2), "pairs_computed_latent": 3 * 4 * 256 * 512.0,
         "blocks_visited_latent": 3 * 4 * 4 * 1.0, "blocks_total_latent": 3 * 4 * 4 * 1.0}
     assert float(metrics.routing["held_pairs"]) > 0 and metrics.scan is None
     share = float(metrics.routing["groups_kept_hold_share"])       # a mean, not the forwards' sum
@@ -978,9 +979,9 @@ def _olmo_counters(s):
     # 40 tokens in chunks of 16: 3 chunks, 48 tokens walked, three linear layers, 4 rows, 3 forwards
     assert {k: float(v) for k, v in metrics.delta.items()} == {
         "chunks": 3 * 3 * 4 * 3.0, "tokens_padded": 3 * 3 * 4 * 48.0, "tokens": 3 * 3 * 4 * 40.0}
-    # one full layer of three heads: a block of 128 x 512 a head and row
+    # one full layer of three heads, a key-value head each: a block of 256 x 512 a head and row
     assert {k: float(v) for k, v in metrics.attention.items()} == {
-        "pairs_in_mask_full": 3 * 4 * (40 * 41 // 2), "pairs_computed_full": 3 * 4 * 128 * 512.0,
+        "pairs_in_mask_full": 3 * 4 * (40 * 41 // 2), "pairs_computed_full": 3 * 4 * 256 * 512.0,
         "blocks_visited_full": 3 * 4 * 3 * 1.0, "blocks_total_full": 3 * 4 * 3 * 1.0}
     assert metrics.routing is None and metrics.scan is None
 
@@ -1047,9 +1048,9 @@ def _kanana_structure(b: Built):
 def _kanana_counters(s):
     metrics = s.metrics
     assert "expert_bias" not in s.got_w["layer_0"]
-    # four latent layers of four heads: a block of 128 x 512 a head and row, 4 rows, 3 forwards
+    # four latent layers of four heads: a block of 256 x 512 a head and row, 4 rows, 3 forwards
     assert {k: float(v) for k, v in metrics.attention.items()} == {
-        "pairs_in_mask_latent": 4 * 3 * 4 * (40 * 41 // 2), "pairs_computed_latent": 4 * 3 * 4 * 128 * 512.0,
+        "pairs_in_mask_latent": 4 * 3 * 4 * (40 * 41 // 2), "pairs_computed_latent": 4 * 3 * 4 * 256 * 512.0,
         "blocks_visited_latent": 4 * 3 * 4 * 4 * 1.0, "blocks_total_latent": 4 * 3 * 4 * 4 * 1.0}
     assert float(metrics.routing["held_pairs"]) > 0
     assert metrics.scan is None and metrics.delta is None
