@@ -40,10 +40,10 @@ from typing import Any, Optional, Sequence
 # Network kinds whose torso is a stack of blocks built from ``ApexConfig.torso``
 # (the keys of models/dueling.TORSO_KINDS).
 TORSO_NETWORKS = ("lfm2_moe", "laguna_moe", "granite_hybrid", "solar_open2", "ling_hybrid",
-                  "olmo_hybrid", "kanana_moe")
+                  "olmo_hybrid", "kanana_moe", "nemotron_h")
 # Those whose observation is a history of single frames (``frame_history``).
 HISTORY_NETWORKS = ("laguna_moe", "granite_hybrid", "solar_open2", "ling_hybrid", "olmo_hybrid",
-                    "kanana_moe")
+                    "kanana_moe", "nemotron_h")
 
 
 @dataclasses.dataclass

@@ -225,24 +225,28 @@ def build_greedy_apply(network: nn.Module):
 TORSO_KINDS = {"lfm2_moe": "Lfm2MoeQ", "laguna_moe": "LagunaMoeQ",
                "granite_hybrid": "GraniteHybridQ", "solar_open2": "SolarOpen2Q",
                "ling_hybrid": "LingHybridQ", "olmo_hybrid": "OlmoHybridQ",
-               "kanana_moe": "KananaMoeQ"}
+               "kanana_moe": "KananaMoeQ", "nemotron_h": "NemotronHQ"}
 
 
 @launch_span("network")
 def build_network(kind: str, num_actions: int, **kwargs) -> nn.Module:
     """Factory keyed by config string: {"conv", "nature", "mlp", "lfm2_moe",
-    "laguna_moe", "granite_hybrid", "solar_open2", "ling_hybrid", "olmo_hybrid", "kanana_moe"}.
-    The last seven are torsos of blocks (``models/expert_torso.py``) and take ``torso``:
+    "laguna_moe", "granite_hybrid", "solar_open2", "ling_hybrid", "olmo_hybrid", "kanana_moe",
+    "nemotron_h"}.
+    The last eight are torsos of blocks (``models/expert_torso.py``) and take ``torso``:
     the published config's keys and the cut (``spec_from_config`` of
     ``models/lfm2_moe.py``, one frame's positions as tokens; of
     ``models/laguna_moe.py``, ``models/granite_hybrid.py``,
     ``models/solar_open2.py``, ``models/ling_hybrid.py``,
-    ``models/olmo_hybrid.py`` and ``models/kanana_moe.py``, a history of
+    ``models/olmo_hybrid.py``, ``models/kanana_moe.py`` and
+    ``models/nemotron_h.py``, a history of
     single frames: attention layers with sparse experts, state-space layers
     without, delta-rule layers with sparse experts and a share of heads, the
     same under a bounded gate beside a latent-attention layer and experts
     chosen by groups, scalar-gate delta-rule layers in post-norm dense blocks,
-    latent attention in every layer over sparse experts and shared ones)."""
+    latent attention in every layer over sparse experts and shared ones,
+    one-sublayer layers of grouped state-space mixers, latent experts of two
+    matrices and an attention layer, each a share of heads and columns)."""
     if kind == "conv":
         return DuelingDQN(num_actions=num_actions, **kwargs)
     if kind == "nature":

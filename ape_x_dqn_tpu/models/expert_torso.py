@@ -14,8 +14,13 @@ Obando-Ceron et al. 2024, arXiv:2402.08609, "PerConv"), projected to the
 torso's width and run through the layers the spec names, each
 
     h <- h + m Op(RMSNorm(h))     Op  = one of the spec's mixers, by layer type
-    h <- h + m FFN(RMSNorm(h))    FFN = SwiGLU | mixture of experts (+ a shared expert)
+    h <- h + m FFN(RMSNorm(h))    FFN = SwiGLU | mixture of experts (+ a shared expert) | none
 
+(the FFN kinds are ``FFNS``: ``dense``, ``moe`` and ``none``, a layer that is
+its mixer alone, for a model whose published layers are one sublayer each and
+do not all pair up; an expert's and the shared expert's rule is one of
+``RULES``: ``swiglu``, three matrices, ``silu(x W_1) * (x W_3)`` into ``W_2``,
+or ``relu2``, two, ``relu(x W_1)^2`` into ``W_2``)
 (``m`` the spec's ``residual_multiplier``, 1 unless a model states one; the
 projected tokens are likewise multiplied by its ``token_multiplier``)
 or, where the spec says ``post_norm`` (the Olmo family's reordered norm, OLMo
@@ -29,15 +34,23 @@ expert, whether an observation's tokens are one frame's positions or those
 of a history of frames.  ``models/lfm2_moe.py``, ``models/laguna_moe.py``,
 ``models/granite_hybrid.py``, ``models/solar_open2.py``,
 ``models/ling_hybrid.py``, ``models/olmo_hybrid.py`` and
-``models/kanana_moe.py`` make a spec from a published ``config.json``'s keys
-and bring their mixers (the last takes ``ling_hybrid``'s latent mixer, told
-that no head is gated); everything else is here, once.
+``models/kanana_moe.py`` and ``models/nemotron_h.py`` make a spec from a
+published ``config.json``'s keys and bring their mixers (the last but one
+takes ``ling_hybrid``'s latent mixer, told that no head is gated; the last
+``granite_hybrid``'s Mamba-2, told its groups, and ``solar_open2``'s softmax
+mixer, told that nothing is gated); everything else is here, once.
 
 The expert layer is one chip's share of an expert-parallel layer: it is told
 how many experts exist (the router's outputs), how many a token takes and
 which range ``[lo, hi)`` it holds.  It routes over all of them, normalises
 the gates over all the chosen ones, and computes its own experts' part of
-the sum; nothing stands in for the others.  A token's experts are chosen by
+the sum; nothing stands in for the others.  Where the spec names a latent
+(``moe_latent_size``, LatentMoE) the routed experts work in it, between two
+projections that all experts of the layer share and every chip holds whole
+(``w_down``, ``w_up``: no norm, activation or bias), while the router and the
+shared expert read the layer's input at its own width; the shared expert may
+be held by columns (``shared_expert_held``: a chip's slice of its width, whose
+part of ``W_2``'s sum goes on as it is).  A token's experts are chosen by
 selection, not by sorting (``choose``, ``ops/router_choice.py``): k rounds of
 "the largest biased score not yet taken, the first output that holds it",
 after the same rounds over the groups' scores where the router keeps groups;
@@ -104,7 +117,8 @@ from ape_x_dqn_tpu.ops.router_choice import router_choice
 from ape_x_dqn_tpu.types import ROUTING
 from ape_x_dqn_tpu.utils.profiling import part, pass_
 
-FFNS = ("dense", "moe")
+FFNS = ("dense", "moe", "none")
+RULES = ("swiglu", "relu2")   # an expert's, and the shared expert's
 SCORES = ("sigmoid", "softmax")
 # The balancing rule's rate (LFM2's published config has ``use_expert_bias``
 # and names no rule).  A fresh
@@ -150,6 +164,9 @@ class TorsoSpec:
     router_groups: int = 1            # the router's outputs lie in so many groups of consecutive ones
     router_groups_kept: int = 1       # and a token chooses among the experts of so many (``route``)
     post_norm: bool = False           # a block norms a sublayer's output, not its input
+    expert_rule: str = "swiglu"       # one of RULES: the routed experts' and the shared expert's
+    moe_latent_size: int = 0          # the routed experts' width (LatentMoE); 0: hidden_size
+    shared_expert_held: Optional[Tuple[int, int]] = None   # [lo, hi) of its columns; None: all
 
     def __post_init__(self):
         lo, hi = self.experts_held
@@ -162,6 +179,13 @@ class TorsoSpec:
                 f"{self.router_outputs} outputs")
         if self.score_function not in SCORES:
             raise ValueError(f"unknown score function {self.score_function!r}; {SCORES}")
+        if self.expert_rule not in RULES:
+            raise ValueError(f"unknown expert rule {self.expert_rule!r}; {RULES}")
+        if self.shared_expert_held is not None and not (
+                0 <= self.shared_expert_held[0] < self.shared_expert_held[1]
+                <= self.shared_expert_intermediate_size):
+            raise ValueError(f"shared_expert_held {self.shared_expert_held} is no range of the "
+                             f"shared expert's {self.shared_expert_intermediate_size} columns")
         groups, kept = self.router_groups, self.router_groups_kept
         if not 1 <= kept <= groups or (groups > 1 and (
                 self.router_outputs % groups
@@ -245,6 +269,21 @@ class SwiGLU(nn.Module):
         w3 = self.param("w3", _lecun(), (d, self.width), self.param_dtype)
         w2 = self.param("w2", _lecun(), (self.width, d), self.param_dtype)
         return (jax.nn.silu(u @ w1.astype(cd)) * (u @ w3.astype(cd))) @ w2.astype(cd)
+
+
+class Relu2(nn.Module):
+    """``relu(u W_1)^2 W_2``: the ``relu2`` rule's dense form (the shared expert)."""
+
+    width: int
+    compute_dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, u):
+        d, cd = u.shape[-1], self.compute_dtype
+        w1 = self.param("w1", _lecun(), (d, self.width), self.param_dtype)
+        w2 = self.param("w2", _lecun(), (self.width, d), self.param_dtype)
+        return jnp.square(jax.nn.relu(u @ w1.astype(cd))) @ w2.astype(cd)
 
 
 def _lane_sum(x):
@@ -381,20 +420,33 @@ def _combined(y, token, rows):
         return tuple(out)
 
 
-def _products(xs, w13, w2, sizes):
-    """(h, a, ys) of a tile's rows ``xs``: ``ys = (silu(h1) * h3) @ w2`` by
-    groups, ``[h1, h3] = xs @ w13``."""
+def _products(xs, w13, w2, sizes, rule: str):
+    """(h, a, ys) of a tile's rows ``xs``: ``ys = a @ w2`` by groups, ``a =
+    silu(h1) * h3`` of ``[h1, h3] = xs @ w13`` (``swiglu``) or ``relu(h)^2``
+    of ``h = xs @ w13`` (``relu2``: ``w13`` holds ``W_1`` alone)."""
     f = w2.shape[1]
     h = jax.lax.ragged_dot(xs, w13, sizes)
-    a = jax.nn.silu(h[:, :f]) * h[:, f:]
+    a = jnp.square(jax.nn.relu(h)) if rule == "relu2" else jax.nn.silu(h[:, :f]) * h[:, f:]
     return h, a, jax.lax.ragged_dot(a, w2, sizes)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def held_experts(u, w13, w2, gates, order, sizes, tile: int):
+def _pulled(h, da, f: int, rule: str):
+    """``da`` (float32) pulled back through ``_products``' rule to ``h``, in
+    ``h``'s type."""
+    if rule == "relu2":
+        return (da * 2.0 * jax.nn.relu(h.astype(jnp.float32))).astype(h.dtype)
+    h1, h3 = h[:, :f].astype(jnp.float32), h[:, f:].astype(jnp.float32)
+    s = jax.nn.sigmoid(h1)
+    return jnp.concatenate([da * h3 * s * (1.0 + h1 * (1.0 - s)), da * h1 * s],
+                           axis=-1).astype(h.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def held_experts(u, w13, w2, gates, order, sizes, tile: int, rule: str = "swiglu"):
     """The held experts' part of the layer's sum, [tokens, d] in ``u``'s type.
 
-    ``u`` [tokens, d]; ``w13`` [n, d, 2f], ``w2`` [n, f, d]; ``gates``
+    ``u`` [tokens, d]; ``w13`` [n, d, 2f] (``rule`` ``relu2``: [n, d, f], an
+    expert's one input matrix), ``w2`` [n, f, d]; ``gates``
     [tokens, k], zero off the held range; ``order`` [tokens * k]: the pairs
     sorted by expert, the held ones first; ``sizes`` [n]: the pairs on each
     held expert.  The sorted pairs are walked ``tile`` rows at a time over the
@@ -405,10 +457,10 @@ def held_experts(u, w13, w2, gates, order, sizes, tile: int):
     whole rows; the blocks are joined before the cast to ``u``'s type).  The
     backward pass walks the same tiles and computes each again, the tokens'
     gradient summed the same way: nothing of a tile is kept."""
-    return _held_experts_fwd(u, w13, w2, gates, order, sizes, tile)[0]
+    return _held_experts_fwd(u, w13, w2, gates, order, sizes, tile, rule)[0]
 
 
-def _held_experts_fwd(u, w13, w2, gates, order, sizes, tile: int):
+def _held_experts_fwd(u, w13, w2, gates, order, sizes, tile: int, rule: str):
     cd, k = u.dtype, gates.shape[1]
     with part("router"):
         padded, starts, ends, tiles = _walk(order, sizes, tile)
@@ -420,7 +472,7 @@ def _held_experts_fwd(u, w13, w2, gates, order, sizes, tile: int):
             _, token, live, gate, group = _tile(t, tile, k, padded, starts, ends, gates)
             xs = u[token]
         with part("experts"):
-            _, _, ys = _products(xs, w13c, w2c, group)
+            _, _, ys = _products(xs, w13c, w2c, group, rule)
             # Rows past the last group are the kernel's to leave unwritten.
             ys = jnp.where(live[:, None], ys.astype(jnp.float32) * gate[:, None], 0.0)
         with part("router"):
@@ -431,7 +483,7 @@ def _held_experts_fwd(u, w13, w2, gates, order, sizes, tile: int):
         return jnp.concatenate(y, axis=1).astype(cd), (u, w13, w2, gates, order, sizes)
 
 
-def _held_experts_bwd(tile: int, kept, dy):
+def _held_experts_bwd(tile: int, rule: str, kept, dy):
     u, w13, w2, gates, order, sizes = kept
     cd, k, f = u.dtype, gates.shape[1], w2.shape[1]
     # What the forward walk made and did not keep is made again, under a pass
@@ -452,15 +504,12 @@ def _held_experts_bwd(tile: int, kept, dy):
             dy_rows = dy[token]
         with part("experts"):
             with pass_("again"):
-                h, a, ys = _products(xs, w13c, w2c, group)
+                h, a, ys = _products(xs, w13c, w2c, group, rule)
             dy_rows = dy_rows.astype(jnp.float32)
             dgate = jnp.where(live, jnp.sum(dy_rows * ys.astype(jnp.float32), -1), 0.0)
             dys = jnp.where(live[:, None], dy_rows * gate[:, None], 0.0).astype(cd)
             da = jax.lax.ragged_dot(dys, w2t, group).astype(jnp.float32)
-            h1, h3 = h[:, :f].astype(jnp.float32), h[:, f:].astype(jnp.float32)
-            s = jax.nn.sigmoid(h1)
-            dh = jnp.concatenate([da * h3 * s * (1.0 + h1 * (1.0 - s)), da * h1 * s],
-                                 axis=-1).astype(cd)
+            dh = _pulled(h, da, f, rule)
             # The rows that ``h`` and ``da`` leave unwritten reach no sum: a
             # ragged contraction reads its groups' rows alone.
             dw2 = dw2 + jax.lax.ragged_dot_general(
@@ -502,8 +551,14 @@ class ExpertShare(nn.Module):
         bias = (jax.lax.stop_gradient(self.param(
             "expert_bias", _bias_init, (sp.router_outputs,), jnp.float32))
                 if sp.use_expert_bias else jnp.zeros((sp.router_outputs,), jnp.float32))
-        w13 = self.param("w13", _lecun(batch_axis=(0,)), (n, d, 2 * f), self.param_dtype)
-        w2 = self.param("w2", _lecun(batch_axis=(0,)), (n, f, d), self.param_dtype)
+        # the experts' width: the layer's, or a latent between two shared projections
+        width, relu2 = sp.moe_latent_size or d, sp.expert_rule == "relu2"
+        w13 = self.param("w1" if relu2 else "w13", _lecun(batch_axis=(0,)),
+                         (n, width, f if relu2 else 2 * f), self.param_dtype)
+        w2 = self.param("w2", _lecun(batch_axis=(0,)), (n, f, width), self.param_dtype)
+        if sp.moe_latent_size:
+            w_down = self.param("w_down", _lecun(), (d, width), self.param_dtype)
+            w_up = self.param("w_up", _lecun(), (width, d), self.param_dtype)
         shape = u.shape
         u = u.reshape(-1, d)
         rows = u.shape[0] * k  # every pair of every token
@@ -519,8 +574,15 @@ class ExpertShare(nn.Module):
             # Pairs on held experts first, by expert; the others after them.
             order = jnp.argsort(jnp.where(held, chosen - lo, n).reshape(-1), stable=True)
             gates = jnp.where(held, gates, 0.0)
-        y = held_experts(u.astype(cd), w13, w2, gates, order, load[lo:hi],
-                         tile_rows(rows, n, sp.router_outputs))
+        x = u.astype(cd)
+        if sp.moe_latent_size:
+            with part("latent_proj"):
+                x = x @ w_down.astype(cd)
+        y = held_experts(x, w13, w2, gates, order, load[lo:hi],
+                         tile_rows(rows, n, sp.router_outputs), sp.expert_rule)
+        if sp.moe_latent_size:
+            with part("latent_proj"):
+                y = y @ w_up.astype(cd)
         if not self.is_initializing():  # ``init`` returns parameters alone
             self.sow(ROUTING, "load", load)
             if kept is not None:    # tokens that keep a group with a held expert in it
@@ -559,6 +621,8 @@ class Block(nn.Module):
         with part("mixer"):
             u = RMSNorm(sp.norm_eps, cd, pd, name="operator_norm")(h)
             h = _added(h, mixer(u), m)
+        if self.ffn == "none":
+            return h, None
         if self.ffn == "dense":
             with part("dense_ffn"):
                 u = RMSNorm(sp.norm_eps, cd, pd, name="ffn_norm")(h)
@@ -569,8 +633,9 @@ class Block(nn.Module):
         if sp.shared_expert_intermediate_size:
             # Every chip of the layer computes it alike; it is added ungated.
             with part("shared_expert"):
-                y = y + SwiGLU(sp.shared_expert_intermediate_size, cd, pd,
-                               name="shared_expert")(u)
+                lo, hi = sp.shared_expert_held or (0, sp.shared_expert_intermediate_size)
+                shared = Relu2 if sp.expert_rule == "relu2" else SwiGLU
+                y = y + shared(hi - lo, cd, pd, name="shared_expert")(u)
         return _added(h, y, m), None
 
 

@@ -9,7 +9,13 @@ RMSNorm; the output projection.  Between the two projections an activation
 crosses HBM once a pass, in the compute type: the scan reads ``x`` and
 writes ``y`` as ``[chunks, B, H x P, chunk]``, a chunk's tokens in the lanes
 (a head of 64 does not fill them), so the convolution writes that layout
-and the gate and norm read it (``ops/pallas/scan_layout.py``).  ``attention`` layers are grouped-query
+and the gate and norm read it (``ops/pallas/scan_layout.py``).  This family
+has one group (``mamba_n_groups`` 1: ``B`` and ``C`` are shared by all heads
+and the norm is over all channels); the mixer itself is told its groups
+(``MambaSizes.groups``: ``B`` and ``C`` a group of consecutive heads, the norm
+over a group's channels) and the heads a chip holds of them
+(``MambaSizes.held``, whole groups), which ``models/nemotron_h.py`` states.
+``attention`` layers are grouped-query
 causal attention with no positional rule (``position_embedding_type``
 ``nope``), scores scaled by ``attention_multiplier``, in blocked kernels
 (``ops/pallas/blocked_attention.py``).  Every layer's FFN is the dense SwiGLU
@@ -33,7 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Mapping
+from typing import Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,17 +60,31 @@ A_RANGE, DT_RANGE = (1.0, 16.0), (1e-3, 1e-1)
 
 @dataclasses.dataclass(frozen=True)
 class MambaSizes:
-    """The published ``mamba_*`` keys."""
+    """The published ``mamba_*`` keys, and what a chip holds of the heads."""
 
-    heads: int
+    heads: int                        # published, before any share
     head_dim: int
     state: int
     conv: int                         # the convolution's taps
     chunk: int
+    groups: int = 1                   # B, C and the norm's mean square: one a group of heads
+    held: Optional[Tuple[int, int]] = None   # [lo, hi) of the heads, whole groups; None: all
 
     @property
     def inner(self) -> int:           # the scan's width, mamba_expand x hidden
         return self.heads * self.head_dim
+
+    def __post_init__(self):
+        lo, hi = self.held or (0, self.heads)
+        per = self.heads // self.groups
+        if self.heads % self.groups or not 0 <= lo < hi <= self.heads or lo % per or hi % per:
+            raise ValueError(f"heads {(lo, hi)} of {self.heads} are no whole groups of {per}")
+
+    @property
+    def share(self) -> tuple:
+        """(heads held, groups held)."""
+        lo, hi = self.held or (0, self.heads)
+        return hi - lo, (hi - lo) * self.groups // self.heads
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -78,25 +98,31 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 
 
 class Mamba2(nn.Module):
-    """``W_out norm(scan(conv(W_in u)) silu(z))``: module docstring."""
+    """``W_out norm(scan(conv(W_in u)) silu(z))``: module docstring.  It
+    divides by heads: a chip holds the whole groups ``MambaSizes.held`` names,
+    ``W_in``'s and the convolution's columns, ``A_log``, ``dt_bias``, ``D``
+    and the norm's weight of those heads and ``W_out``'s rows, and returns
+    their part of ``W_out``'s sum."""
 
     spec: TorsoSpec
     op: str
     compute_dtype: jnp.dtype
     param_dtype: jnp.dtype
+    divides_heads = True
 
     @nn.compact
     def __call__(self, u):
         sp, cd, pd, f32 = self.spec, self.compute_dtype, self.param_dtype, jnp.float32
         m: MambaSizes = sp.arg("mamba")
-        d, inner, n, k = sp.hidden_size, m.inner, m.state, m.conv
+        heads, groups = m.share
+        d, inner, n, k = sp.hidden_size, heads * m.head_dim, groups * m.state, m.conv
         mixed = inner + 2 * n                        # x | B | C: what the convolution sees
-        w_in = self.param("w_in", _lecun(), (d, inner + mixed + m.heads), pd)
+        w_in = self.param("w_in", _lecun(), (d, inner + mixed + heads), pd)
         kernel = self.param("conv_kernel", _lecun(-1), (mixed, k), pd)
         conv_bias = self.param("conv_bias", _bias_init, (mixed,), pd)
-        a_log = self.param("A_log", _a_log_init, (m.heads,), f32)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (m.heads,), f32)
-        skip = self.param("D", nn.initializers.ones, (m.heads,), f32)
+        a_log = self.param("A_log", _a_log_init, (heads,), f32)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (heads,), f32)
+        skip = self.param("D", nn.initializers.ones, (heads,), f32)
         norm = self.param("norm", nn.initializers.ones, (inner,), pd)
         w_out = self.param("w_out", _lecun(), (inner, d), pd)
 
@@ -106,10 +132,13 @@ class Mamba2(nn.Module):
         # the convolution and its SiLU write x, B, C as the scan reads them
         x = conv_to_chunks(u @ w_x, kernel[:inner], conv_bias[:inner], m.chunk, True)
         b, c = jnp.split(conv_to_chunks(bc, kernel[inner:], conv_bias[inner:], m.chunk, False), 2, -1)
+        if groups > 1:    # [chunks, B, Q, G x N] -> [chunks, B, G, Q, N]
+            b, c = (jnp.moveaxis(v.reshape(*v.shape[:3], groups, m.state), 3, 2) for v in (b, c))
         dt = cut(jax.nn.softplus(dt.astype(f32) + dt_bias), m.chunk, True)
-        y = scan_chunks(x.reshape(*x.shape[:2], m.heads, m.head_dim, -1), dt, -jnp.exp(a_log),
+        y = scan_chunks(x.reshape(*x.shape[:2], heads, m.head_dim, -1), dt, -jnp.exp(a_log),
                         b, c, skip)
-        return gated_norm(y.reshape(x.shape), u @ w_z, norm, sp.norm_eps) @ w_out.astype(cd)
+        return gated_norm(y.reshape(x.shape), u @ w_z, norm, sp.norm_eps,
+                          groups) @ w_out.astype(cd)
 
     @staticmethod
     def scan_count(spec: TorsoSpec, op: str, rows: int, tokens: int) -> dict:
@@ -171,7 +200,8 @@ def spec_from_config(cfg: Mapping) -> TorsoSpec:
         raise ValueError("this family's expert layers are not built here: num_local_experts "
                          "must be 0")
     if int(cfg["mamba_n_groups"]) != 1:
-        raise ValueError("the scan shares B and C over all heads: mamba_n_groups must be 1")
+        raise ValueError("this family's published configs have one group of heads: "
+                         "mamba_n_groups must be 1 (models/nemotron_h.py builds groups)")
     if cfg.get("position_embedding_type", "nope") != "nope":
         raise ValueError("the attention layers have no positional rule: "
                          "position_embedding_type must be nope")

@@ -211,13 +211,15 @@ class GatedNopeAttention(nn.Module):
     @staticmethod
     def held(spec: TorsoSpec) -> tuple:
         """(query heads held, key-value heads held: those the held query
-        heads read)."""
+        heads read).  The held heads are whole groups, or lie inside one (a
+        key-value head that several chips of the tensor-parallel group hold)."""
         heads, kv = spec.arg("num_attention_heads"), spec.arg("num_key_value_heads")
         lo, hi = held_heads(spec, heads)
         group = heads // kv
-        if lo % group or hi % group:
+        first, last = lo // group, (hi - 1) // group
+        if first != last and (lo % group or hi % group):
             raise ValueError(f"heads_held {(lo, hi)} cuts a group of {group} query heads")
-        return hi - lo, (hi - lo) // group
+        return hi - lo, last - first + 1
 
     @nn.compact
     def __call__(self, u):

@@ -7,7 +7,10 @@ Per head, with a state ``S`` in R^{P x N}, a decay ``A < 0`` and a skip ``D``:
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T        S_{-1} = 0
     y_t = S_t C_t + D x_t
 
-``B_t`` and ``C_t`` are shared by all heads (one group).  The sequence is cut
+``B_t`` and ``C_t`` are shared by the heads of a group: by all heads where
+there is one group, else by ``H / G`` consecutive heads each, and the groups
+are walked side by side (``_by_groups``: the chunk below, mapped over them).
+The sequence is cut
 in chunks of ``chunk`` tokens (padded at the end with ``dt = 0`` and ``x =
 0``: decay 1, no input; the padded rows are cut off and get no gradient) and
 walked a chunk at a time, carrying the state across.  Inside a chunk, with
@@ -18,7 +21,7 @@ walked a chunk at a time, carrying the state across.  Inside a chunk, with
         + D x_i
     S   = exp(L_end) S_prev + sum_j exp(L_end - L_j) dt_j x_j B_j^T
 
-so the work is matrix products: the scores ``C B^T`` once for all heads, a
+so the work is matrix products: the scores ``C B^T`` once a group, a
 product of ``[chunk, chunk]`` by ``[chunk, P]`` a head, and two products with
 the state.  Running sums, decays and the state are float32; the products
 take operands in ``x``'s type and accumulate in float32.
@@ -31,7 +34,7 @@ transposing copy, a pad and two copies that bring the chunks to the front,
 each over the whole array, in either direction.  ``scan_chunks`` therefore
 takes the sequence already cut and turned, ``dt`` as ``[chunks, B, H,
 chunk]``, ``B`` and ``C`` as ``[chunks, B, chunk, N]`` (``N`` of 128 fills
-the lanes), and every product of ``_chunk`` contracts over the last axis of
+the lanes; ``[chunks, B, G, chunk, N]`` where there are groups), and every product of ``_chunk`` contracts over the last axis of
 an operand as it lies; a caller makes that layout where it makes the values
 (``ops/pallas/scan_layout.py``); one that holds ``[B, T, H, P]`` cuts and
 joins with ``cut`` and ``join``.
@@ -98,15 +101,36 @@ def _chunk(state, x, dt, a, b, c, d):
     return state, y.astype(cd)
 
 
+def _by_groups(groups: int):
+    """``_chunk`` for ``b``, ``c`` [B, G, Q, N]: the heads lie in ``groups``
+    runs of consecutive ones, and a run reads its own group's ``b`` and ``c``."""
+
+    def split(v, axis: int):
+        return v.reshape(*v.shape[:axis], groups, -1, *v.shape[axis + 1:])
+
+    def chunk(state, x, dt, a, b, c, d):
+        after, y = jax.vmap(_chunk, in_axes=(1, 1, 1, 0, 1, 1, 0), out_axes=1)(
+            split(state, 1), split(x, 1), split(dt, 1), split(a, 0), b, c, split(d, 0))
+        return after.reshape(state.shape), y.reshape(x.shape)
+
+    return chunk
+
+
+def _chunk_of(b):
+    """The chunk's function for ``b`` cut in chunks: with a group axis or without."""
+    return _chunk if b.ndim == 4 else _by_groups(b.shape[2])
+
+
 def _walk(x, dt, a, b, c, d, keep: bool):
     """(y [chunks, B, H, P, Q]; with ``keep`` each chunk's incoming state,
     [chunks, B, H, P, N], else None)."""
     with part("ssm_scan"):
         first = jnp.zeros((*x.shape[1:4], b.shape[-1]), jnp.float32)
+        one = _chunk_of(b)
 
         def body(state, chunk):
             xc, dtc, bc, cc = chunk
-            after, y = _chunk(state, xc, dtc, a, bc, cc, d)
+            after, y = one(state, xc, dtc, a, bc, cc, d)
             return after, (y, state if keep else None)
 
         return jax.lax.scan(body, first, (x, dt, b, c))[1]
@@ -118,7 +142,8 @@ def scan_chunks(x, dt, a, b, c, d):
     over a sequence already cut in chunks, zeros past its end.
 
     ``x`` [chunks, B, H, P, Q]; ``dt`` [chunks, B, H, Q] float32, positive;
-    ``a`` [H] float32, negative; ``b``, ``c`` [chunks, B, Q, N]; ``d`` [H]
+    ``a`` [H] float32, negative; ``b``, ``c`` [chunks, B, Q, N], or [chunks,
+    B, G, Q, N] where the heads read them by groups of ``H / G``; ``d`` [H]
     float32."""
     return _walk(x, dt, a, b, c, d, keep=False)[0]
 
@@ -127,11 +152,13 @@ def _pull(kept, dy):
     """The cotangents of ``scan_chunks``' inputs from ``dy``, all cut."""
     x, dt, a, b, c, d, states = kept
     with part("ssm_scan"):
+        one = _chunk_of(b)
+
         def body(carry, chunk):
             d_after, da, dd = carry
             state, xc, dtc, bc, cc, dyc = chunk
             with pass_("again"):                                      # the chunk, computed again
-                _, pull = jax.vjp(_chunk, state, xc, dtc, a, bc, cc, d)
+                _, pull = jax.vjp(one, state, xc, dtc, a, bc, cc, d)
             d_state, dx, ddt, da_c, db, dc, dd_c = pull((d_after, dyc))
             return (d_state, da + da_c, dd + dd_c), (dx, ddt, db, dc)
 

@@ -16,8 +16,10 @@ makes the values also turns them, a block at a time in VMEM:
                     it, computes the sum again and writes the cotangent of
                     the sum, tokens major, with a chunk's part of the
                     kernel's and the bias' gradients.
-``gated_norm``      ``rmsnorm(y silu(z)) w`` over rows of ``C``, reading ``y``
-                    as the scan writes it and ``z`` as the projection does;
+``gated_norm``      ``rmsnorm(y silu(z)) w`` over rows of ``C`` (or, told
+                    groups, over each of a row's runs of ``C / groups``
+                    channels: a block is then one group's columns), reading
+                    ``y`` as the scan writes it and ``z`` as the projection does;
                     its backward pass reads ``y``, ``z`` and the cotangent,
                     computes the row again and writes ``dy`` in the scan's
                     layout (zeros past T) and ``dz`` in the projection's.
@@ -183,43 +185,52 @@ def _gated_norm_bwd_kernel(y_ref, z_ref, w_ref, d_ref, dy_ref, dz_ref, dw_ref, *
     dz_ref[0] = (dg * y * _silu_slope(z, sig)).astype(dz_ref.dtype)
 
 
-def _norm_specs(y, z, blocks: int, float32_rows: int):
-    """Grid, the three block specs and the VMEM a pass needs: its ``blocks``
-    of a chunk by ``C`` twice (one in flight) and ``float32_rows`` such
-    arrays in float32: 29 MiB forward and 53 backward at C = 4,096 and a
-    chunk of 256 (compiled for a v5e the kernels take 24 and 48; the default
-    is 16 of the chip's 128, and what a kernel reserves the compiler cannot
-    prefetch into: PERF.md section 6, PR 35)."""
+def _norm_specs(y, z, blocks: int, float32_rows: int, groups: int):
+    """Grid, the three block specs, the weight's gradient's and the VMEM a
+    pass needs: its ``blocks`` of a chunk by ``C`` twice (one in flight) and
+    ``float32_rows`` such arrays in float32: 29 MiB forward and 53 backward
+    at C = 4,096 and a chunk of 256 (compiled for a v5e the kernels take 24
+    and 48; the default is 16 of the chip's 128, and what a kernel reserves
+    the compiler cannot prefetch into: PERF.md section 6, PR 35).  With
+    ``groups`` over 1 a block is one group's ``C / groups`` columns and the
+    grid has the groups as its last axis."""
     n, rows, c, q = y.shape
-    cut = pl.BlockSpec((1, 1, c, q), lambda b, i: (i, b, 0, 0))
-    major = pl.BlockSpec((1, q, c), lambda b, i: (b, i, 0))
+    if c % groups:
+        raise ValueError(f"{c} channels are not {groups} groups")
+    c //= groups
+    g = lambda at: at or (0,)  # noqa: E731  the group's block; one group has no grid axis
+    cut = pl.BlockSpec((1, 1, c, q), lambda b, i, *at: (i, b, *g(at), 0))
+    major = pl.BlockSpec((1, q, c), lambda b, i, *at: (b, i, *g(at)))
+    weight = pl.BlockSpec((1, c), lambda b, i, *at: (0, *g(at)))
+    dweight = pl.BlockSpec((1, 1, 1, c), lambda b, i, *at: (b, i, 0, *g(at)))
     vmem = q * c * (2 * blocks * y.dtype.itemsize + 4 * float32_rows) + 2 ** 20
-    return (rows, n), cut, major, pl.BlockSpec((1, c), lambda b, i: (0, 0)), vmem
+    return (rows, n) + (groups,) * (groups > 1), cut, major, weight, dweight, vmem
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def gated_norm(y, z, w, eps: float):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def gated_norm(y, z, w, eps: float, groups: int = 1):
     """``rmsnorm(y silu(z)) w`` over the last axis of ``z`` [B, T, C], in
-    ``z``'s type; ``y`` [chunks, B, C, chunk] as the scan writes it, ``w``
-    [C] float32.  Gate, mean square and norm in float32."""
-    grid, cut, major, weight, vmem = _norm_specs(y, z, 3, 4)
+    ``z``'s type, the mean square over each of ``groups`` runs of ``C /
+    groups`` channels; ``y`` [chunks, B, C, chunk] as the scan writes it,
+    ``w`` [C] float32.  Gate, mean square and norm in float32."""
+    grid, cut, major, weight, _, vmem = _norm_specs(y, z, 3, 4, groups)
     return _call(functools.partial(_gated_norm_kernel, eps=eps), vmem, grid=grid,
                  in_specs=[cut, major, weight], out_specs=major,
                  out_shape=jax.ShapeDtypeStruct(z.shape, z.dtype))(y, z, w[None].astype(_F32))
 
 
-def _gated_norm_fwd(y, z, w, eps):
-    return gated_norm(y, z, w, eps), (y, z, w)
+def _gated_norm_fwd(y, z, w, eps, groups):
+    return gated_norm(y, z, w, eps, groups), (y, z, w)
 
 
-def _gated_norm_bwd(eps, kept, d):
+def _gated_norm_bwd(eps, groups, kept, d):
     y, z, w = kept
-    grid, cut, major, weight, vmem = _norm_specs(y, z, 5, 8)
+    grid, cut, major, weight, dweight, vmem = _norm_specs(y, z, 5, 8, groups)
     n, rows, c, _ = y.shape
     dy, dz, dw = _call(
         functools.partial(_gated_norm_bwd_kernel, eps=eps, tokens=z.shape[1]), vmem, grid=grid,
         in_specs=[cut, major, weight, major],
-        out_specs=[cut, major, pl.BlockSpec((1, 1, 1, c), lambda b, i: (b, i, 0, 0))],
+        out_specs=[cut, major, dweight],
         out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype), jax.ShapeDtypeStruct(z.shape, z.dtype),
                    jax.ShapeDtypeStruct((rows, n, 1, c), _F32)])(y, z, w[None].astype(_F32), d)
     return dy, dz, jnp.sum(dw, (0, 1, 2)).astype(w.dtype)

@@ -67,7 +67,8 @@ SCOPE_PREFIX = "stage:"   # device side: jax.named_scope("stage:<name>")
 # prefix would take the network's time out of ``forward`` and lose its
 # backward pass.  A part is read beside its stage, not in its place.
 PARTS = ("stem", "mixer", "router", "experts", "dense_ffn", "head",
-         "attn_window", "attn_full", "shared_expert", "ssm_scan", "delta_scan", "attn_latent")
+         "attn_window", "attn_full", "shared_expert", "ssm_scan", "delta_scan", "attn_latent",
+         "latent_proj")
 PART_PREFIX = "torso:"    # device side: jax.named_scope("torso:<name>")
 # The passes of a step, a third axis beside stages and parts and under a
 # prefix of its own for the same reason: the stage readers take the innermost
@@ -597,7 +598,7 @@ TIMED_IMPORTS = frozenset((
     "ape_x_dqn_tpu.models.lfm2_moe", "ape_x_dqn_tpu.models.laguna_moe",
     "ape_x_dqn_tpu.models.granite_hybrid", "ape_x_dqn_tpu.models.solar_open2",
     "ape_x_dqn_tpu.models.ling_hybrid", "ape_x_dqn_tpu.models.olmo_hybrid",
-    "ape_x_dqn_tpu.models.kanana_moe",
+    "ape_x_dqn_tpu.models.kanana_moe", "ape_x_dqn_tpu.models.nemotron_h",
     "ape_x_dqn_tpu.replay.device",
     "ape_x_dqn_tpu.replay.device_dedup", "ape_x_dqn_tpu.utils.checkpoint",
 ))

@@ -77,6 +77,27 @@ which owns the chip:
             where gates without the factor must fail.  With --kanana, or
             alone with --kanana-kernels (no network is built: about two
             minutes)
+  nemotron  configs/config13_nemotron3s_q_ep32.json (the 699 M parameter torso
+            of one-sublayer layers: Mamba-2 in groups, LatentMoE of relu2
+            experts in a latent of 1024 at 22 of 512, one NoPE GQA layer; a
+            TP4 x EP32 chip's heads, columns and 16 experts, over the same
+            history) with 4 thread actors, 12 learner steps at batch 2 on a
+            1,024-slot ring, as the laguna leg.  Only with --nemotron, and
+            after nemotron_kernels
+  nemotron_kernels  what that cell's comparison leaves to the chip (PERF.md,
+            section 6, PR 59): the Mamba-2 mixer whole at the held widths
+            (``granite_hybrid.Mamba2`` told 2 groups of 16 heads of 64, chunk
+            128, two rows of 1,568 tokens, bfloat16) against the mixer written
+            out in float32 with the recurrence a token at a time, where every
+            head on group 0's B and C, and the norm over all 2,048 channels,
+            must each fail; the LatentMoE layer whole (``ExpertShare`` under
+            this family's spec: 22 of 512, 16 held, the latent of 1024)
+            against the layer written out in float32 over masks, where
+            ``silu`` experts, and a router that reads the latent, must each
+            fail; and the router's 22 of 512 with its gates (normalised, times
+            5) against a sort on the host, where gates without the factor must
+            fail.  With --nemotron, or alone with --nemotron-kernels (no
+            network is built: about three minutes)
   olmo_kernels  the delta walk (``ops/chunked_delta.py``) against the
             recurrence stepped a token at a time in float32, in both forms:
             a decay a head and token at ``olmoh_q_l4``'s head sizes (30
@@ -1111,14 +1132,21 @@ MIXER_REL_WITHOUT_LATENT_NORM = 0.2
 GATE_ABS = 1e-5
 
 
-def _kanana_spec(config: str = "config12_kanana2_q_ep8.json", **over):
+def _committed_spec(config: str, **over):
+    """The spec of ``configs/<config>``'s torso, its family's ``spec_from_config``."""
+    import importlib
+
     from ape_x_dqn_tpu.config import load_config
-    from ape_x_dqn_tpu.models import kanana_moe
 
     here = os.path.dirname(os.path.abspath(__file__))
-    torso = load_config(os.path.join(here, "configs", config)).torso
-    return kanana_moe.spec_from_config(
-        dict({k: v for k, v in torso.items() if not k.startswith("_")}, **over))
+    cfg = load_config(os.path.join(here, "configs", config))
+    family = importlib.import_module("ape_x_dqn_tpu.models." + cfg.network)
+    return family.spec_from_config(
+        dict({k: v for k, v in cfg.torso.items() if not k.startswith("_")}, **over))
+
+
+def _kanana_spec(**over):
+    return _committed_spec("config12_kanana2_q_ep8.json", **over)
 
 
 def latent_mixer_against_plain(rows: int = 2, tokens: int = 1568, **over) -> dict:
@@ -1180,17 +1208,18 @@ def latent_mixer_against_plain(rows: int = 2, tokens: int = 1568, **over) -> dic
     return {"mixer": (near, far)}
 
 
-def gates_against_sorting(tokens: int = 12544, **over) -> dict:
-    """``expert_torso.route`` under the Kanana spec (6 of 128 sigmoid scores
-    by ``score + bias``, gates the chosen scores over their sum, times 2.448)
-    against a sort on the host: the same experts for every token, the gates
-    within ``GATE_ABS``; the gates without the factor are not."""
+def gates_against_sorting(tokens: int = 12544, spec=None, **over) -> dict:
+    """``expert_torso.route`` under ``spec`` (default the Kanana spec: 6 of 128
+    sigmoid scores by ``score + bias``, gates the chosen scores over their
+    sum, times 2.448) against a sort on the host: the same experts for every
+    token, the gates within ``GATE_ABS``; the gates without the factor are
+    not."""
     import jax
     import numpy as np
 
     from ape_x_dqn_tpu.models import expert_torso
 
-    spec = _kanana_spec(**over)
+    spec = spec or _kanana_spec(**over)
     outputs, k = spec.router_outputs, spec.num_experts_per_tok
     scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(7), (tokens, outputs)))
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(8), (outputs,))
@@ -1242,6 +1271,173 @@ def leg_kanana() -> None:
             f"param_version {pipe.worker.param_version} of {final['param_version']}")
 
     _train_on_histories("kanana", "config12_kanana2_q_ep8.json", steps, inspect)
+
+
+# The whole Mamba-2 mixer and the whole LatentMoE layer in bfloat16 against the
+# same written out in float32 on the same weights and input, ||got - want|| /
+# ||want||: their projections round to bfloat16 (read in Pallas' interpreter on
+# the CPU at the toy widths: 0.01 and 0.01; on the chip at the held widths the
+# leg prints them), and how far at least each lost mechanism lies.
+NEMOTRON_REL = 0.05
+NEMOTRON_REL_LOST = 0.15
+
+
+def _nemotron_spec():
+    return _committed_spec("config13_nemotron3s_q_ep32.json")
+
+
+def _near_and_far(got, want, wrongs: dict, what: str) -> dict:
+    import jax.numpy as jnp
+
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))  # noqa: E731
+    near = rel(got, want)
+    assert near <= NEMOTRON_REL, f"{what}: {near} from {what} written out"
+    far = {name: rel(got, wrong) for name, wrong in wrongs.items()}
+    for name, v in far.items():
+        assert v >= NEMOTRON_REL_LOST, f"{what}: {v}: the check would not see {name}"
+    return {"near": near, **far}
+
+
+def grouped_mixer_against_plain(rows: int = 2, tokens: int = 1568, spec=None) -> dict:
+    """``granite_hybrid.Mamba2`` under the Nemotron spec (the held groups,
+    chunked scan, the kernels of ``scan_layout``), bfloat16 compute, against
+    the mixer of ISSUE 59's section 1 written out in float32 with the
+    recurrence a token at a time: within ``NEMOTRON_REL``; the same written out
+    with every head on group 0's ``B`` and ``C``, and with the norm over all
+    held channels, each lie further than ``NEMOTRON_REL_LOST``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ape_x_dqn_tpu.models.granite_hybrid import Mamba2
+
+    spec = spec or _nemotron_spec()
+    m, d, eps = spec.arg("mamba"), spec.hidden_size, spec.norm_eps
+    heads, groups = m.share
+    inner, n, k = heads * m.head_dim, m.state, m.conv
+    mixer = Mamba2(spec, "mamba", jnp.bfloat16, jnp.float32)
+    ku, kp, kn, kc = jax.random.split(jax.random.PRNGKey(59), 4)
+    u = jax.random.normal(ku, (rows, tokens, d)).astype(jnp.bfloat16)
+    params = jax.jit(mixer.init)(kp, u)["params"]
+    # no skip and steps of about 0.3: what the layer writes is the state's readout, not ``D x``
+    # (at the published initialisation, steps of 0.001-0.1, the state's part is a hundredth of it);
+    # taps of 0.5 (the module draws them at 0.02 at these widths): the convolution's outputs are of
+    # order one and the gated row's mean square stands over the norm's eps, or there is no norm to lose
+    # and the last group's x three times the others', so that a group's mean square is its own
+    last = jnp.arange(params["w_in"].shape[1])
+    last = (last >= 2 * inner - inner // groups) & (last < 2 * inner)
+    params = dict(params, norm=1.0 + 0.3 * jax.random.normal(kn, (inner,)),
+                  D=jnp.zeros((heads,)), dt_bias=jnp.full((heads,), -1.0),
+                  conv_kernel=0.5 * jax.random.normal(kc, params["conv_kernel"].shape),
+                  w_in=jnp.where(last, 3.0, 1.0) * params["w_in"])
+
+    def plain(p, u, shared=False, over=groups):
+        with jax.default_matmul_precision("highest"):
+            f32 = jnp.float32
+            z, xbc, dt = jnp.split(u.astype(f32) @ p["w_in"], (inner, 2 * inner + 2 * groups * n), -1)
+            padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+            xbc = jax.nn.silu(sum(padded[:, j:j + tokens] * p["conv_kernel"][:, j] for j in range(k))
+                              + p["conv_bias"])
+            x, b, c = jnp.split(xbc, (inner, inner + groups * n), -1)
+            b, c = (v.reshape(rows, tokens, groups, n) for v in (b, c))
+            if shared:
+                b, c = (jnp.broadcast_to(v[:, :, :1], v.shape) for v in (b, c))
+            b, c = (jnp.repeat(v, heads // groups, axis=2) for v in (b, c))        # [B, T, H, N]
+            dt, a = jax.nn.softplus(dt + p["dt_bias"]), -jnp.exp(p["A_log"])
+            x = x.reshape(rows, tokens, heads, m.head_dim)
+
+            def step(state, token):
+                xt, dtt, bt, ct = token
+                state = (jnp.exp(dtt * a)[..., None, None] * state
+                         + (dtt[..., None] * xt)[..., None] * bt[:, :, None, :])
+                return state, jnp.sum(state * ct[:, :, None, :], -1) + p["D"][:, None] * xt
+
+            _, y = jax.lax.scan(step, jnp.zeros((rows, heads, m.head_dim, n), f32),
+                                tuple(jnp.moveaxis(v, 1, 0) for v in (x, dt, b, c)))
+            g = jnp.moveaxis(y, 0, 1).reshape(rows, tokens, inner) * jax.nn.silu(z)
+            g = g.reshape(rows, tokens, over, inner // over)
+            g = g / jnp.sqrt(jnp.mean(g * g, -1, keepdims=True) + eps)
+            return (g.reshape(rows, tokens, inner) * p["norm"]) @ p["w_out"]
+
+    got = jax.jit(lambda p, u: mixer.apply({"params": p}, u))(params, u).astype(jnp.float32)
+    return _near_and_far(got, jax.jit(plain)(params, u), {
+        "group 0's B and C on every head": jax.jit(lambda p, u: plain(p, u, shared=True))(params, u),
+        "a norm over all channels": jax.jit(lambda p, u: plain(p, u, over=1))(params, u)},
+        "the Mamba-2 mixer")
+
+
+def latent_experts_against_plain(rows: int = 2, tokens: int = 1568, spec=None) -> dict:
+    """``expert_torso.ExpertShare`` under the Nemotron spec (the router's 22 of
+    512, the walk over the 16 held experts' pairs in the latent), bfloat16
+    compute, against the layer written out in float32 over masks: within
+    ``NEMOTRON_REL``; the same written out with ``silu`` experts, and with the
+    router reading the latent, each lie further than ``NEMOTRON_REL_LOST``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ape_x_dqn_tpu.models.expert_torso import ExpertShare
+
+    spec = spec or _nemotron_spec()
+    lo, hi = spec.experts_held
+    layer = ExpertShare(spec, jnp.bfloat16, jnp.float32)
+    ku, kp = jax.random.split(jax.random.PRNGKey(60))
+    u = jax.random.normal(ku, (rows, tokens, spec.hidden_size)).astype(jnp.bfloat16)
+    params = jax.jit(layer.init)(kp, u)["params"]
+
+    def plain(p, u, rule="relu2", reads_latent=False):
+        with jax.default_matmul_precision("highest"):
+            u = u.astype(jnp.float32)
+            v = u @ p["w_down"]
+            scores = jax.nn.sigmoid(v @ p["router"][:v.shape[-1]] if reads_latent else u @ p["router"])
+            _, chosen = jax.lax.top_k(scores + p["expert_bias"], spec.num_experts_per_tok)
+            gates = jnp.take_along_axis(scores, chosen, -1)
+            gates = spec.routed_scaling_factor * gates / (jnp.sum(gates, -1, keepdims=True) + 1e-20)
+
+            def one(y, e_w):
+                e, w1, w2 = e_w
+                h = v @ w1
+                a = jax.nn.silu(h) if rule == "silu" else jnp.square(jax.nn.relu(h))
+                return y + jnp.sum(jnp.where(chosen == e, gates, 0.0), -1)[..., None] * (a @ w2), None
+
+            y, _ = jax.lax.scan(one, jnp.zeros_like(v), (jnp.arange(lo, hi), p["w1"], p["w2"]))
+            return y @ p["w_up"]
+
+    got = jax.jit(lambda p, u: layer.apply({"params": p}, u))(params, u).astype(jnp.float32)
+    return _near_and_far(got, jax.jit(plain)(params, u), {
+        "silu experts": jax.jit(lambda p, u: plain(p, u, rule="silu"))(params, u),
+        "a router that reads the latent": jax.jit(lambda p, u: plain(p, u, reads_latent=True))(params, u)},
+        "the LatentMoE layer")
+
+
+def leg_nemotron_kernels() -> None:
+    spec = _nemotron_spec()
+    say(f"nemotron_kernels: the Mamba-2 mixer from the mixer written out in float32 "
+        f"(near: limit {NEMOTRON_REL}; each lost mechanism: at least {NEMOTRON_REL_LOST}) "
+        f"{grouped_mixer_against_plain(spec=spec)}")
+    say(f"nemotron_kernels: the LatentMoE layer from the layer written out in float32 "
+        f"{latent_experts_against_plain(spec=spec)}")
+    say(f"nemotron_kernels: route against sorting {gates_against_sorting(spec=spec)}")
+
+
+def leg_nemotron() -> None:
+    steps = 12
+
+    def inspect(pipe, final):
+        check_run("nemotron", pipe, final, steps)
+        assert type(pipe.comps.network).__name__ == "NemotronHQ"
+        assert final["param_version"] >= 1, "nemotron: nothing was published"
+        attention, routing, scan = (final.get(k) or {} for k in ("attention", "routing", "scan"))
+        assert "delta" not in final, f"nemotron: delta-rule counters without such layers: {final}"
+        # batch 2, three forwards: five Mamba-2 layers of 13 chunks, one attention layer
+        assert scan.get("chunks", 0) == 2 * 3 * 5 * 13 and scan.get("tokens_padded", 0) == 2 * 3 * 5 * 1664, (
+            f"nemotron: no scan counters: {final}")
+        assert abs(attention.get("pairs_in_mask_full", 0) - 2 * 3 * (1568 * 1569 // 2)) <= 64, (
+            f"nemotron: no attention counters: {final}")
+        assert routing.get("held_pairs", 0) > 0 and routing.get("rows_walked", 0) >= routing["held_pairs"], (
+            f"nemotron: no routing counters: {final}")
+        say(f"nemotron: scan a step {scan}; attention a step {attention}; routing a step {routing}; "
+            f"actors adopted param_version {pipe.worker.param_version} of {final['param_version']}")
+
+    _train_on_histories("nemotron", "config13_nemotron3s_q_ep32.json", steps, inspect)
 
 
 def leg_olmo_kernels() -> None:
@@ -1339,6 +1535,10 @@ def main() -> int:
         legs = [("kanana_kernels", leg_kanana_kernels), ("kanana", leg_kanana)]
     if "--kanana-kernels" in sys.argv[1:]:
         legs = [("kanana_kernels", leg_kanana_kernels)]
+    if "--nemotron" in sys.argv[1:]:
+        legs = [("nemotron_kernels", leg_nemotron_kernels), ("nemotron", leg_nemotron)]
+    if "--nemotron-kernels" in sys.argv[1:]:
+        legs = [("nemotron_kernels", leg_nemotron_kernels)]
     if "--olmo-kernels" in sys.argv[1:]:
         legs = [("olmo_kernels", leg_olmo_kernels)]
     if "--first-conv" in sys.argv[1:]:

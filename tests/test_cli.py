@@ -75,7 +75,7 @@ def test_canonical_configs_load_and_validate():
 
     root = os.path.join(os.path.dirname(__file__), "..", "configs")
     paths = sorted(glob.glob(os.path.join(root, "*.json")))
-    assert len(paths) == 13, paths
+    assert len(paths) == 14, paths
     cfgs = {os.path.basename(p): load_config(p) for p in paths}
     assert cfgs["config1_pong_1actor.json"].actor.num_actors == 1
     c6 = cfgs["config6_lfm2moe_q_ep8.json"]
@@ -103,6 +103,12 @@ def test_canonical_configs_load_and_validate():
     assert c12.network == "kanana_moe" and c12.torso["kv_lora_rank"] == 512
     assert c12.torso["experts_held"] == [0, 16] and "heads_held" not in c12.torso
     assert c12.env.frame_stack == 32 and c12.learner.replay_sample_size == 8
+    c13 = cfgs["config13_nemotron3s_q_ep32.json"]
+    assert c13.network == "nemotron_h" and c13.torso["moe_latent_size"] == 1024
+    assert c13.torso["layers_held"] == list(range(27, 38)) and c13.torso["experts_held"] == [0, 16]
+    assert (c13.torso["heads_held"], c13.torso["mamba_heads_held"],
+            c13.torso["shared_expert_held"]) == ([0, 8], [0, 32], [0, 1344])
+    assert c13.env.frame_stack == 32 and c13.learner.replay_sample_size == 8
     assert cfgs["config2_breakout_8actors.json"].actor.num_actors == 8
     c3 = cfgs["config3_seaquest_256actors_2m.json"]
     assert c3.replay.capacity == 2_000_000

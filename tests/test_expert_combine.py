@@ -136,9 +136,9 @@ def test_what_a_dead_row_carries_reaches_no_sum(monkeypatch, k, held):
     want = tiled(params, u)
     products = expert_torso._products
 
-    def with_garbage(xs, w13, w2, sizes):
+    def with_garbage(xs, w13, w2, sizes, rule):
         dead = (jnp.arange(xs.shape[0]) >= jnp.sum(sizes))[:, None]
-        return tuple(jnp.where(dead, 1e3, x) for x in products(xs, w13, w2, sizes))
+        return tuple(jnp.where(dead, 1e3, x) for x in products(xs, w13, w2, sizes, rule))
 
     monkeypatch.setattr(expert_torso, "_products", with_garbage)
     got = _share(k, held, 1024, monkeypatch)[-1](params, u)

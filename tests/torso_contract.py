@@ -40,7 +40,8 @@ from ape_x_dqn_tpu.learner.train_step import (
     StepMetrics, build_train_step, init_train_state, make_optimizer,
 )
 from ape_x_dqn_tpu.models import (
-    dueling, expert_torso, granite_hybrid, kanana_moe, lfm2_moe, ling_hybrid, olmo_hybrid, solar_open2,
+    dueling, expert_torso, granite_hybrid, kanana_moe, lfm2_moe, ling_hybrid, nemotron_h, olmo_hybrid,
+    solar_open2,
 )
 from ape_x_dqn_tpu.models.dueling import build_greedy_apply, build_network
 from ape_x_dqn_tpu.ops.pallas import blocked_attention as blocked
@@ -111,6 +112,23 @@ LING = dict(
     num_experts_per_tok=2, score_function="sigmoid", moe_router_enable_expert_bias=True,
     expert_swiglu_limit_list=[0] * 10 + [4, 4], share_expert_swiglu_limit_list=[0] * 11 + [5],
     kda_chunk_size=16, channels=[8, 8, 8], hidden=32, expert_bias_update_rate=0.05,
+)
+
+# four of the pattern's layers paired into like blocks, a mixer alone, the attention block; half
+# of the published Mamba-2 heads (two groups of four), query heads and shared columns, four experts
+NEMOTRON = dict(
+    model_type="nemotron_h", hidden_size=64, intermediate_size=48, moe_intermediate_size=48,
+    moe_latent_size=32, moe_shared_expert_intermediate_size=64, n_shared_experts=1,
+    hybrid_override_pattern="ME*EMEMEM*EM", num_hidden_layers=7, layers_held=[4, 5, 6, 7, 8, 9, 10],
+    published=dict(num_hidden_layers=12, n_routed_experts=16, mamba_num_heads=8,
+                   num_attention_heads=4, num_key_value_heads=2),
+    mamba_num_heads=4, mamba_head_dim=16, ssm_state_size=16, conv_kernel=4, chunk_size=16,
+    n_groups=4, expand=2, num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+    heads_held=[0, 2], mamba_heads_held=[0, 4], shared_expert_held=[0, 32],
+    n_routed_experts=4, router_outputs=16, experts_held=[4, 8], num_experts_per_tok=3,
+    n_group=1, topk_group=1, norm_topk_prob=True, routed_scaling_factor=5.0,
+    mlp_hidden_act="relu2", layer_norm_epsilon=1e-5, channels=[8, 8, 8], hidden=32,
+    expert_bias_update_rate=0.05,
 )
 
 # three heads (no power of two) of 16 in the full layer; keys of 12 and values of 24 in the linear ones
@@ -1072,7 +1090,7 @@ def _kanana_others():
 
 
 def _kanana_config(row, spec, committed, committed_spec):
-    assert TORSO_NETWORKS[-1] == "kanana_moe" and HISTORY_NETWORKS[-1] == "kanana_moe"
+    assert TORSO_NETWORKS[6] == "kanana_moe" and HISTORY_NETWORKS[5] == "kanana_moe"
     assert spec.num_held == 2
     spec = committed_spec
     assert committed.learner.replay_sample_size == 8 and committed.learner.steps_per_call == 1
@@ -1087,6 +1105,96 @@ def _kanana_config(row, spec, committed, committed_spec):
             spec.heads_held, spec.routed_scaling_factor, spec.experts_held, spec.gate_norm_eps) == (
                 128, 6, 1, 1, None, 2.448, (0, 16), 1e-20)
     assert expert_torso.tile_rows(12544 * 6, 16, 128) == 12800      # the walk's tile at 128 outputs
+
+
+# ---------------------------------------------------------------- nemotron_h
+
+def _nemotron_structure(b: Built):
+    net, params = b.net(), b.params["params"]
+    assert params["Conv_0"]["kernel"].shape == (8, 8, 1, 8)     # one frame at a time
+    assert set(params) >= {"layers_0_1", "layer_2", "layer_3", "w_tok", "final_norm"}
+    assert set(params["layers_0_1"]) == {"operator_norm", "ffn_norm", "mamba", "moe", "shared_expert"}
+    assert set(params["layer_2"]) == {"operator_norm", "mamba"}           # a mixer alone: no FFN, no norm of one
+    assert set(params["layer_3"]) == {"operator_norm", "ffn_norm", "attention", "moe", "shared_expert"}
+    # two groups of two heads of 16 held: x | B | C = 64 + 2 x 2 x 16
+    assert {k: v.shape for k, v in params["layer_2"]["mamba"].items()} == {
+        "w_in": (64, 64 + 128 + 4), "conv_kernel": (128, 4), "conv_bias": (128,), "A_log": (4,),
+        "dt_bias": (4,), "D": (4,), "norm": (64,), "w_out": (64, 64)}
+    assert {k: v.shape for k, v in params["layer_3"]["attention"].items()} == {
+        "w_q": (64, 32), "w_k": (64, 16), "w_v": (64, 16), "w_o": (32, 64)}   # no gate, one key-value head
+    assert {k: v.shape for k, v in params["layer_3"]["moe"].items()} == {
+        "router": (64, 16), "expert_bias": (16,), "w1": (4, 32, 48), "w2": (4, 48, 32),
+        "w_down": (64, 32), "w_up": (32, 64)}                             # two matrices an expert, in the latent
+    assert {k: v.shape for k, v in params["layer_3"]["shared_expert"].items()} == {
+        "w1": (64, 32), "w2": (32, 64)}                                   # half its 64 columns
+    spec = net.spec
+    assert spec.layers == (("mamba", "moe"),) * 2 + (("mamba", "none"), ("attention", "moe"))
+    assert dict(spec.mixers) == {"mamba": granite_hybrid.Mamba2,
+                                 "attention": solar_open2.GatedNopeAttention}
+    assert (spec.router_outputs, spec.experts_held, spec.heads_held, spec.norm_eps, spec.frame_history,
+            spec.expert_rule, spec.moe_latent_size, spec.shared_expert_held, spec.gate_norm_eps,
+            spec.routed_scaling_factor) == (16, (4, 8), (0, 2), 1e-5, True, "relu2", 32, (0, 32), 1e-20, 5.0)
+    m = spec.arg("mamba")
+    assert (m.heads, m.groups, m.held, m.share, m.chunk) == (8, 4, (0, 4), (4, 2), 16)
+    out, sown = b.applied
+    assert out[2].shape == (2, 6) and bool(jnp.all(jnp.isfinite(out[2])))
+    assert float(net.routing_metrics(sown)["held_pairs"]) > 0
+    assert net.delta_metrics(b.x.shape) is None
+
+
+def _nemotron_counters(s):
+    metrics = s.metrics
+    # 40 tokens in chunks of 16: 3 chunks, 48 tokens walked, three Mamba-2 layers, 4 rows, 3 forwards
+    assert {k: float(v) for k, v in metrics.scan.items()} == {
+        "chunks": 3 * 3 * 4 * 3.0, "tokens_padded": 3 * 3 * 4 * 48.0, "tokens": 3 * 3 * 4 * 40.0}
+    # one attention layer of two held query heads on one key-value head
+    assert float(metrics.attention["pairs_in_mask_full"]) == 3 * 4 * (40 * 41 // 2)
+    assert float(metrics.attention["blocks_total_full"]) == 3 * 4 * 2 * 1.0
+    assert float(metrics.routing["held_pairs"]) > 0 and metrics.delta is None
+    assert set(metrics.routing) == {"held_pairs", "load_max", "load_mean", "rows_walked"}
+
+
+def _nemotron_others():
+    """The new spec fields default to what every expert torso had (SwiGLU
+    experts at the layer's width, the shared expert whole, an FFN in every
+    block), granite's Mamba-2 has one group and every head, and their
+    parameter trees hold the names they held."""
+    for kind in ("laguna_moe", "solar_open2", "ling_hybrid", "kanana_moe", "granite_hybrid"):
+        sp = network(kind).spec
+        assert (sp.expert_rule, sp.moe_latent_size, sp.shared_expert_held) == ("swiglu", 0, None), kind
+        assert all(ffn in ("dense", "moe") for _, ffn in sp.layers), kind
+    m = network("granite_hybrid").spec.arg("mamba")
+    assert (m.groups, m.held, m.share) == (1, None, (8, 1))
+    kanana = network("kanana_moe")
+    shapes = jax.eval_shape(kanana.init, jax.random.PRNGKey(3), obs(jax.random.PRNGKey(2)))["params"]
+    assert sorted(shapes["layers_1_3"]["moe"]) == ["expert_bias", "router", "w13", "w2"]
+    assert sorted(shapes["layers_1_3"]["shared_expert"]) == ["w1", "w2", "w3"]
+
+
+def _nemotron_config(row, spec, committed, committed_spec):
+    assert TORSO_NETWORKS[-1] == "nemotron_h" and HISTORY_NETWORKS[-1] == "nemotron_h"
+    assert spec.num_held == 4
+    spec = committed_spec
+    assert committed.learner.replay_sample_size == 8 and committed.learner.steps_per_call == 1
+    cell = json.load(open(os.path.join(ROOT, "benchmark", "configs", "nemotron3s_q_ep32.json")))
+    assert spec == nemotron_h.spec_from_config(cell)
+    assert spec.layers == (("mamba", "moe"),) * 4 + (("mamba", "none"), ("attention", "moe"))
+    m = spec.arg("mamba")
+    assert (spec.hidden_size, spec.moe_intermediate_size, spec.moe_latent_size,
+            spec.shared_expert_intermediate_size, spec.shared_expert_held, m.heads, m.head_dim, m.state,
+            m.conv, m.chunk, m.groups, m.held, m.share) == (
+                4096, 2688, 1024, 5376, (0, 1344), 128, 64, 128, 4, 128, 8, (0, 32), (32, 2))
+    assert (spec.router_outputs, spec.num_experts_per_tok, spec.router_groups, spec.heads_held,
+            spec.routed_scaling_factor, spec.experts_held, spec.gate_norm_eps, spec.expert_rule) == (
+                512, 22, 1, (0, 8), 5.0, (0, 16), 1e-20, "relu2")
+    assert solar_open2.GatedNopeAttention.held(spec) == (8, 1)      # eight query heads on key-value head 0
+    assert expert_torso.tile_rows(12544 * 22, 16, 512) == 11776     # the walk's tile at 22 of 512
+
+
+def _nemotron_loads(loads):
+    # the seven held layers as the reference holds them: M E M E M * E
+    assert loads.shape == (7, 16)
+    assert [float(v) for v in jnp.sum(loads, -1)] == [0.0, 480.0, 0.0, 480.0, 0.0, 0.0, 480.0]
 
 
 def _kanana_loads(loads):
@@ -1142,7 +1250,7 @@ ROWS = {row.name: row for row in (
         structure=_ling_structure, reference="ling3_q", bf16_tolerance=0.5,
         flags=("reference_ungrouped_router", "reference_drops_shared_key", "reference_unbounded_gate",
                "reference_resets_state"), loads=_ling_loads, bias_moved=(1, 2, 3),
-        others=_ling_others, parts_at=slice(-1, None), parts=("attn_latent",),
+        others=_ling_others, parts_at=slice(-2, -1), parts=("attn_latent",),
         scopes=("delta_scan", "attn_latent", "mixer", "router", "experts", "shared_expert",
                 "dense_ffn", "stem", "head"),
         scope_paths=("torso:mixer/latent_attention/torso:attn_latent",
@@ -1168,10 +1276,24 @@ ROWS = {row.name: row for row in (
         structure=_kanana_structure, reference="kanana2_q", bf16_tolerance=0.5,
         flags=("reference_drops_shared_key", "reference_skips_latent_norm",
                "reference_unscaled_gates"), loads=_kanana_loads, bias_moved=(1, 2, 3),
-        others=_kanana_others, parts_at=slice(-1, None), parts=("attn_latent",),
+        others=_kanana_others, parts_at=slice(-2, -1), parts=("attn_latent",),
         scopes=("attn_latent", "mixer", "router", "experts", "shared_expert", "dense_ffn", "stem",
                 "head"),
         scope_paths=("torso:mixer/latent_attention/torso:attn_latent", "transpose("),
         scopes_absent=("ssm_scan", "delta_scan", "attn_full", "attn_window"),
         compiled_part="attn_latent"),
+    Row("nemotron_h", NEMOTRON, "NemotronHQ", "config13_nemotron3s_q_ep32.json", _nemotron_config,
+        _nemotron_counters, kept_float32=("router", "expert_bias", "A_log", "dt_bias", "D"),
+        float32_leaves=("router", "expert_bias", "A_log", "dt_bias", "['D']"),
+        structure=_nemotron_structure, reference="nemotron3s_q", bf16_tolerance=0.5,
+        flags=("reference_shares_group0", "reference_norms_all_channels", "reference_silu_experts",
+               "reference_router_reads_latent", "reference_unscaled_gates"),
+        loads=_nemotron_loads, bias_moved=(1, 3, 6), others=_nemotron_others,
+        parts_at=slice(-1, None), parts=("latent_proj",),
+        scopes=("ssm_scan", "attn_full", "latent_proj", "mixer", "router", "experts", "shared_expert",
+                "stem", "head"),
+        scope_paths=("torso:mixer/mamba/torso:ssm_scan", "torso:mixer/attention/torso:attn_full",
+                     "moe/torso:latent_proj", "transpose("),
+        scopes_absent=("delta_scan", "attn_latent", "attn_window", "dense_ffn"),
+        walked_back="ssm_scan", compiled_part="latent_proj"),
 )}
